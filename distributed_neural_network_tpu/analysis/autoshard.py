@@ -99,8 +99,7 @@ def build_candidate_program(
     kwargs: dict | None = None,
     name: str = "candidate",
 ):
-    """The real step program for one candidate plan, built under
-    ``compat.trace_compat()`` (trace-only, any jax build)."""
+    """The real step program for one candidate plan (trace-only)."""
     kwargs = dict(kwargs or {})
     kwargs.setdefault("bucket_mb", TRACE_BUCKET_MB)
     if family == "lm":
@@ -108,21 +107,19 @@ def build_candidate_program(
 
         _require_devices(dims["dp"] * dims["sp"] * dims["tp"])
         mesh = lmtrain.create_lm_mesh(dims["dp"], dims["sp"], dims["tp"])
-        with compat.trace_compat():
-            return lmtrain.lm_step_program(
-                cfg, mesh, batch=batch, seq_len=seq_len, name=name,
-                optimizer=optimizer, **kwargs,
-            )
+        return lmtrain.lm_step_program(
+            cfg, mesh, batch=batch, seq_len=seq_len, name=name,
+            optimizer=optimizer, **kwargs,
+        )
     if family == "pp":
         from ..parallel import pipeline as ppl
 
         _require_devices(dims["dp"] * dims["pp"])
         mesh = ppl.create_pp_mesh(dims["dp"], dims["pp"], 1)
-        with compat.trace_compat():
-            return ppl.pp_step_program(
-                cfg, mesh, batch=batch, seq_len=seq_len, name=name,
-                optimizer=optimizer, **kwargs,
-            )
+        return ppl.pp_step_program(
+            cfg, mesh, batch=batch, seq_len=seq_len, name=name,
+            optimizer=optimizer, **kwargs,
+        )
     raise ValueError(f"unknown plan family {family!r} (use 'lm' or 'pp')")
 
 

@@ -8,9 +8,7 @@ analyzer pins collective STRUCTURE (which ops, which axes, how many, in
 what ratio to the parameter bytes), not production shapes, so traces stay
 sub-second on a laptop CPU and the manifests stay readable.
 
-All builders run under ``compat.trace_compat()`` so they work on jax
-builds without ``jax.shard_map`` (the step is only traced, never
-executed - compat.py).
+The steps are only traced, never executed.
 
 Meshes use 8 devices (the repo-standard
 ``--xla_force_host_platform_device_count=8`` virtual CPU mesh; tests get
@@ -20,8 +18,6 @@ it from conftest.py, tools/shardlint.py sets it before importing jax).
 from __future__ import annotations
 
 import jax
-
-from .. import compat
 
 # tiny trace model: big enough that every leaf family (embed/head/norms/
 # attention/mlp) is present and dims divide an 8-device mesh, small enough
@@ -77,11 +73,10 @@ def _lm(name, *, dp=4, sp=1, tp=1, optimizer="sgd", cfg_kwargs=None, **kw):
         _require_devices(dp * sp * tp)
         cfg = _trace_cfg(**(cfg_kwargs or {}))
         mesh = lmtrain.create_lm_mesh(dp, sp, tp)
-        with compat.trace_compat():
-            return lmtrain.lm_step_program(
-                cfg, mesh, batch=TRACE_BATCH, seq_len=TRACE_SEQ, name=name,
-                optimizer=optimizer, bucket_mb=TRACE_BUCKET_MB, **kw,
-            )
+        return lmtrain.lm_step_program(
+            cfg, mesh, batch=TRACE_BATCH, seq_len=TRACE_SEQ, name=name,
+            optimizer=optimizer, bucket_mb=TRACE_BUCKET_MB, **kw,
+        )
 
     return build
 
@@ -99,12 +94,11 @@ def _pp(name, *, dp=2, pp=2, optimizer="sgd", **kw):
         _require_devices(dp * pp)
         cfg = _trace_cfg()
         mesh = ppl.create_pp_mesh(dp, pp, 1)
-        with compat.trace_compat():
-            return ppl.pp_step_program(
-                cfg, mesh, batch=TRACE_BATCH, seq_len=TRACE_SEQ, name=name,
-                optimizer=optimizer, n_microbatches=2,
-                bucket_mb=TRACE_BUCKET_MB, **kw,
-            )
+        return ppl.pp_step_program(
+            cfg, mesh, batch=TRACE_BATCH, seq_len=TRACE_SEQ, name=name,
+            optimizer=optimizer, n_microbatches=2,
+            bucket_mb=TRACE_BUCKET_MB, **kw,
+        )
 
     return build
 
@@ -117,8 +111,7 @@ def _reshard(name, *, dp=4):
         _require_devices(dp)
         cfg = _trace_cfg()
         mesh = lmtrain.create_lm_mesh(dp, 1, 1)
-        with compat.trace_compat():
-            return reshard.reshard_step_program(cfg, mesh, name=name)
+        return reshard.reshard_step_program(cfg, mesh, name=name)
 
     return build
 
@@ -130,8 +123,7 @@ def _reshard_pp(name, *, dp=2, pp=2):
         _require_devices(dp * pp)
         cfg = _trace_cfg()
         mesh = ppl.create_pp_mesh(dp, pp, 1)
-        with compat.trace_compat():
-            return reshard.reshard_pp_step_program(cfg, mesh, name=name)
+        return reshard.reshard_pp_step_program(cfg, mesh, name=name)
 
     return build
 
@@ -142,13 +134,12 @@ def _cnn(name, phase):
         from ..data.cifar10 import load_split
         from ..train.engine import Engine, TrainConfig
 
-        with compat.trace_compat():
-            engine = Engine(
-                TrainConfig(nb_proc=4, batch_size=8, epochs=1),
-                load_split(True, source="synthetic", synthetic_size=64),
-                None,
-            )
-            progs = {p.name: p for p in engine.step_programs()}
+        engine = Engine(
+            TrainConfig(nb_proc=4, batch_size=8, epochs=1),
+            load_split(True, source="synthetic", synthetic_size=64),
+            None,
+        )
+        progs = {p.name: p for p in engine.step_programs()}
         if phase not in progs:
             raise RuntimeError(
                 f"{name}: engine exposed no {phase!r} program "

@@ -84,8 +84,7 @@ def _run_one_config(
     """One config's full build -> trace -> lint -> manifest pass:
     (exit_code, report_lines). Self-contained so `run_shardlint` can
     fan configs out over worker threads (tracing is abstract and
-    side-effect free; `compat.trace_compat` keeps its state
-    thread-local; manifest writes land in per-config files)."""
+    side-effect free; manifest writes land in per-config files)."""
     t0 = time.perf_counter()
     try:
         program = build_program(name)

@@ -1,102 +1,30 @@
-"""Version shims for the jax APIs the compiled paths target.
+"""The jax API surface the compiled paths are written against.
 
-The framework's shard_map programs are written against the modern API
-surface (``jax.shard_map`` with vma-typed autodiff, ``jax.lax.axis_size``).
-Older jax builds (pre-``jax.shard_map``; seen in CI containers at 0.4.x)
-carry the experimental predecessor, whose *execution* semantics differ in a
-way that matters here: without vma typing there is no typed-autodiff
-gradient psum and no ``pcast``, so the grad-sync schedules would run with
-silently different numerics. Running training on such a build is therefore
-refused, exactly as before this module existed (an ``AttributeError``
-naming ``jax.shard_map``).
-
-What IS supported everywhere is *abstract tracing*: the static analyzer
-(``distributed_neural_network_tpu.analysis``, tools/shardlint.py) only
-needs ``jax.make_jaxpr`` of the step program, never an executed step. Under
-``trace_compat()`` the builders fall back to
-``jax.experimental.shard_map.shard_map(check_rep=False)`` so the program
-can be traced and its collectives/donation audited on any jax. Manifests
-record which mode produced them (``trace_mode``), because the traced
-program differs across jax generations (pre-vma traces carry no implicit
-typed-autodiff psums - see docs/STATIC_ANALYSIS.md).
+One installation exists (jax 0.9 with the native, vma-typed
+``jax.shard_map``), so these are plain passthroughs: the step builders
+keep one spelling of ``shard_map`` / ``axis_size``, and the checked-in
+shardlint manifests keep the ``trace_mode`` field they were recorded
+with.
 """
 
 from __future__ import annotations
 
-import contextlib
-import os
-import threading
-
 import jax
-
-HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
-
-_tls = threading.local()
-
-
-def trace_compat_enabled() -> bool:
-    """True when the experimental-shard_map trace fallback may be used
-    (inside a ``trace_compat()`` block, or DNN_TPU_SHARDMAP_COMPAT=1)."""
-    if getattr(_tls, "trace_compat", False):
-        return True
-    return os.environ.get("DNN_TPU_SHARDMAP_COMPAT", "") == "1"
-
-
-@contextlib.contextmanager
-def trace_compat():
-    """Allow step BUILDERS to fall back to the experimental shard_map.
-
-    For ``jax.make_jaxpr``-style abstract analysis only - never wrap an
-    executed training step in this (on pre-vma jax the fallback's autodiff
-    inserts no typed gradient psums, so executing it would train with
-    different numerics than the modern program)."""
-    prev = getattr(_tls, "trace_compat", False)
-    _tls.trace_compat = True
-    try:
-        yield
-    finally:
-        _tls.trace_compat = prev
 
 
 def trace_mode() -> str:
-    """'native' when jax.shard_map exists, else 'compat' (the experimental
-    fallback without vma typing) - recorded in shardlint manifests."""
-    return "native" if HAS_NATIVE_SHARD_MAP else "compat"
+    """The ``trace_mode`` manifests record: always the native trace."""
+    return "native"
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` on modern jax; the experimental predecessor only
-    under ``trace_compat()`` (abstract tracing), else the same
-    ``AttributeError`` a direct ``jax.shard_map`` access would raise."""
-    if HAS_NATIVE_SHARD_MAP:
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    if trace_compat_enabled():
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        # check_rep=False: the old replication checker cannot infer the
-        # replication the vma-typed program relies on (no typed-autodiff
-        # psum exists to prove it), so checking is off for trace-compat
-        return _shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-    raise AttributeError(
-        "module 'jax' has no attribute 'shard_map': this jax build "
-        f"({jax.__version__}) predates the vma-typed shard_map the "
-        "compiled training paths require. Static analysis still works - "
-        "build the step inside "
-        "distributed_neural_network_tpu.compat.trace_compat() (what "
-        "tools/shardlint.py does) - but executing a step needs a modern jax."
+    """``jax.shard_map``."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
     )
 
 
 def axis_size(axis_name) -> int:
-    """Static mesh-axis size inside shard_map: ``jax.lax.axis_size`` where
-    it exists, else the classic ``psum(1, axis)`` constant-fold."""
-    lax_axis_size = getattr(jax.lax, "axis_size", None)
-    if lax_axis_size is not None:
-        return lax_axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Static mesh-axis size inside shard_map (``jax.lax.axis_size``)."""
+    return jax.lax.axis_size(axis_name)

@@ -51,6 +51,7 @@ from ..parallel.ring import (
     zigzag_positions,
     zigzag_ring_attention,
 )
+from ..runtime import on_tpu
 
 # "zigzag" = load-balanced causal ring attention; tokens must be fed in
 # zigzag shard order (parallel/ring.py zigzag_order) - ~2x the causal
@@ -597,6 +598,11 @@ def generate(
     if impl not in ("auto", "xla", "pallas", "pallas-interpret"):
         raise ValueError(f"unknown decode impl {impl!r} "
                          "(DNN_TPU_DECODE_IMPL)")
+    if impl == "pallas-interpret" and on_tpu():
+        raise ValueError(
+            "decode impl 'pallas-interpret' is the CPU test vehicle; on a "
+            "TPU use 'pallas' (DNN_TPU_DECODE_IMPL)"
+        )
     use_kernel = impl in ("pallas", "pallas-interpret")
     if use_kernel and offsets is not None:
         raise ValueError(

@@ -71,7 +71,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_pallas import _CompilerParams, _divisor_block, _struct
+from .flash_pallas import _divisor_block, _struct
 
 _LANES = 128
 _SUBLANES = 8
@@ -274,7 +274,7 @@ def decode_cache_attention(q, ck, cv, pos, *, block_k: int = 512,
             ],
         ),
         out_shape=_struct((b * h, _SUBLANES, d), q.dtype, q, ck, cv),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,9 +1,8 @@
 """Flash attention dispatch for local (per-device) long-context attention.
 
 The plain local kernel (`parallel/ring.py attention`) materializes the
-(B, H, S, S) score matrix, so single-chip long-context is HBM-bound: at
-seq 8192 it dominates step time (REPORT.md LM section). This module picks
-the flash implementation:
+(B, H, S, S) score matrix, so single-chip long-context is HBM-bound.
+This module picks the flash implementation:
 
 - **"own"** (default): this framework's Pallas kernels
   (`ops/flash_pallas.py`) - vma-typed outputs, so they compose with
@@ -15,23 +14,19 @@ the flash implementation:
   baseline for `tools/tune_flash.py` and as a fallback; single-device
   only (no vma typing).
 - Off-TPU both fall back to the plain kernel (Pallas TPU kernels are
-  Mosaic-only; the interpreter is not shard_map-compatible).
+  Mosaic-only; the interpreter is not shard_map-compatible). The decision
+  is `runtime.on_tpu()`, and the entry points print which side they took.
 
 Select with `DNN_TPU_FLASH_IMPL=own|lib` or the `impl=` argument. Block
 sizes: `tools/tune_flash.py` writes `tools/flash_tune_<device>_s<seq>.json`;
 `tuned_blocks()` loads the matching file's best own-kernel blocks at call
 time (cached), else `FlashBlocks()` defaults.
 
-Block-size tuning status: the round-2 sweep that picked uniform 1024
-blocks (and its "2.3x faster than XLA" result) was fenced only with
-`block_until_ready`, which is a NO-OP on this backend - those were
-dispatch-time artifacts and are RETRACTED (ROADMAP.md measurement-status
-note). The honest hard-fenced end-to-end numbers (round 3,
-BENCH_MATRIX.json) show flash at 1.25x the XLA+remat path (164.5k vs
-132.0k tok/s at d512/L8/seq2048/bf16), with the gap concentrated in the
-backward pass. What is solid is that flash never materializes the
-(B, H, S, S) score matrix, so the LM can drop --remat (the S^2 buffers
-were what forced it).
+Block-size tuning status: the checked-in tune files date from
+2026-08-01 and have not been re-measured on the current code or jax
+(ROADMAP.md "What the records say"). What is solid is that flash never
+materializes the (B, H, S, S) score matrix, so the LM can drop --remat
+(the S^2 buffers were what forced it).
 
 Sits alongside the mesh-level answers to long context (ring / Ulysses /
 zigzag sequence parallelism, `parallel/ring.py`): flash bounds the
@@ -49,17 +44,13 @@ import os
 import jax
 
 from ..parallel.ring import attention
+from ..runtime import on_tpu
 from .flash_pallas import FlashBlocks, flash_mha
 
 
 @functools.cache
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@functools.cache
 def _lib_available() -> bool:
-    if not _on_tpu():
+    if not on_tpu():
         return False
     try:
         from jax.experimental.pallas.ops.tpu import flash_attention  # noqa: F401
@@ -76,18 +67,18 @@ _TUNE_DIR = os.path.join(os.path.dirname(os.path.dirname(
 
 
 @functools.cache
-def tuned_blocks(s: int, head_dim: int) -> FlashBlocks:
+def tuned_blocks(s: int, head_dim: int,
+                 device_kind: str | None = None) -> FlashBlocks:
     """Best own-kernel blocks for (seq s, head_dim) from the tuner's JSON,
     else defaults. A tune file applies only when it was measured on THIS
-    device kind at THIS head_dim (mismatched tunings were never measured -
-    the guard the retracted r2 sweep lacked), and its seq must equal s or
-    divide it (divisor-tuned blocks still tile s; `FlashBlocks.resolve`
-    keeps them legal). Exact-seq files win; among divisor files the
-    largest seq wins."""
-    try:
-        dev = jax.devices()[0].device_kind.replace(" ", "_")
-    except Exception:
-        return FlashBlocks()
+    device kind (`device_kind`, default the attached device's) at THIS
+    head_dim (mismatched tunings were never measured), and its seq must
+    equal s or divide it (divisor-tuned blocks still tile s;
+    `FlashBlocks.resolve` keeps them legal). Exact-seq files win; among
+    divisor files the largest seq wins."""
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind
+    dev = device_kind.replace(" ", "_")
     pat = os.path.join(_TUNE_DIR, "flash_tune_*.json")
     best, best_seq = None, -1
     for path in glob.glob(pat):
@@ -114,9 +105,8 @@ def tuned_blocks(s: int, head_dim: int) -> FlashBlocks:
 @functools.cache
 def _lib_block_sizes(s: int, head_dim: int = 64):
     """Uniform provisional blocks for the LIBRARY kernel, or None for its
-    defaults (see module docstring: the 1024-uniform choice came from the
-    retracted round-2 sweep; kept because the honest round-3 end-to-end row
-    still beat XLA+remat with it). The kernel's `_verify_block` requires
+    defaults (the 1024-uniform choice is provisional: it has not been
+    re-measured on the current code). The kernel's `_verify_block` requires
     every block to divide the sequence length, so the size is the largest
     power-of-two divisor of S in [128, 1024]; None when none exists or
     head_dim != 64 (never measured)."""
@@ -181,12 +171,12 @@ def flash_local_attention(q, k, v, *, causal: bool = True,
                 "the library flash kernel has no quantized path; use "
                 "impl='own' (default) for attn quantization"
             )
-        if not _on_tpu():
+        if not on_tpu():
             return quantized_attention(q, k, v, causal=causal, fmt=quant)
         return flash_mha(q, k, v, causal=causal,
                          blocks=tuned_blocks(q.shape[1], q.shape[-1]),
                          quant=quant)
-    if not _on_tpu():
+    if not on_tpu():
         return attention(q, k, v, causal=causal)
     impl = impl or os.environ.get("DNN_TPU_FLASH_IMPL", "own")
     if impl == "lib":

@@ -80,12 +80,6 @@ from jax.experimental.pallas import tpu as pltpu
 from ..parallel.collectives import vma_union
 from .quant import QUANT_FORMATS, quantize
 
-# jax renamed TPUCompilerParams -> CompilerParams across generations;
-# alias so the kernels build (and the CPU interpret tests run) on both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 _NEG_BIG = -1e30  # large-negative mask; avoids -inf NaN propagation
 _LANES = 128  # TPU lane width: per-row residuals are lane-replicated
 _QEPS = 1e-30  # scale floor for the in-kernel p quantization
@@ -238,7 +232,7 @@ def _fwd_call(q, k, v, *, blocks, scale, causal, interpret):
             pltpu.VMEM((bq, _LANES), jnp.float32),  # running denom l
             pltpu.VMEM((bq, d), jnp.float32),       # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -400,7 +394,7 @@ def _fwd_quant_call(q, k, v, *, blocks, scale, causal, interpret, fmt):
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -490,7 +484,7 @@ def _bwd_call(q, k, v, o, lse, do, *, blocks, scale, causal, interpret):
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta_l = jnp.broadcast_to(delta[..., None], (bh, s, _LANES))
     lse_l = jnp.broadcast_to(lse[..., None], (bh, s, _LANES))
-    arb = _CompilerParams(
+    arb = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
     )
 

@@ -42,21 +42,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..parallel.collectives import vma_union
+from ..runtime import on_tpu
 
 # batch tile: 8-row sublane alignment, big enough to keep the MXU busy
 _TILE_B = 128
-
-
-def _on_tpu() -> bool:
-    try:
-        dev = jax.devices()[0]
-    except Exception:
-        return False
-    return dev.platform == "tpu" or "TPU" in getattr(dev, "device_kind", "")
-
-
-def _interpret_default() -> bool:
-    return not _on_tpu()
 
 
 def _fwd_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, w3_ref, b3_ref,
@@ -278,7 +267,7 @@ def fused_mlp3(x, w1, b1, w2, b2, w3, b3, *, tile=_TILE_B, interpret=None):
     """
     args = [jnp.asarray(a, jnp.float32) for a in (x, w1, b1, w2, b2, w3, b3)]
     if interpret is None:
-        if not _on_tpu():
+        if not on_tpu():
             return mlp3_reference(*args)
         interpret = False
     return _fused_mlp3(*args, tile, bool(interpret))

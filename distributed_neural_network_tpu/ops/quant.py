@@ -1,8 +1,6 @@
 """Low-precision quantization primitives: the fp8/int8 fast path's core.
 
-At bf16 the stack's raw-speed levers are exhausted scheduling-side
-(53.7% MFU at d1024/L16, 81% at seq 32k - BENCH_MATRIX.json); the next
-multiplier on v5e is PRECISION: int8/fp8 operands halve HBM traffic and
+Beyond bf16 scheduling, the next multiplier on v5e is PRECISION: int8/fp8 operands halve HBM traffic and
 double MXU throughput on hardware with native low-precision matmul
 units, and an int8 KV cache directly doubles the serving stack's
 concurrent-sequence capacity (serve/kv_cache.py). This module is the

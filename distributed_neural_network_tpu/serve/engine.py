@@ -80,6 +80,7 @@ from ..models.transformer import (
 )
 from ..ops.decode_pallas import decode_cache_attention, decode_kernel_ok
 from ..ops.quant import prequantize_weight, quantized_matmul
+from ..runtime import on_tpu
 from .kv_cache import KVCacheConfig, OutOfBlocks, PagedKVCache
 
 _INT8_MAX = 127.0
@@ -524,10 +525,29 @@ class ServeEngine:
             return "pallas"
         # auto: the kernel only pays on TPU (off-TPU it would run the
         # Pallas interpreter - a test vehicle, not a fast path)
-        return (
-            "pallas"
-            if legal and jax.default_backend() == "tpu" else "xla"
-        )
+        return "pallas" if legal and on_tpu() else "xla"
+
+    def _bucket_widths(self, max_width_blocks: int | None = None) -> list:
+        """The power-of-two width buckets (in blocks) up to the cap."""
+        max_w = _bucket(max_width_blocks or self.kv.cfg.max_blocks_per_seq)
+        widths = []
+        w = 1
+        while w <= max_w:
+            widths.append(w)
+            w *= 2
+        return widths
+
+    def decode_route(self) -> str:
+        """The decode attention route over the width buckets, for the
+        CLI's ``decode -> ...`` line and ``GET /v1/status``: 'pallas' or
+        'xla', with the widths that take the other side named when the
+        buckets split (narrow buckets admit no sublane-legal k block)."""
+        routes = {W: self._attn_route(W) for W in self._bucket_widths()}
+        kinds = set(routes.values())
+        if len(kinds) == 1:
+            return kinds.pop()
+        xla_w = ",".join(str(W) for W, r in routes.items() if r == "xla")
+        return f"pallas (xla at width {xla_w})"
 
     # ------------------------------------------------------ jitted steps
 
@@ -543,7 +563,7 @@ class ServeEngine:
         neg = jnp.asarray(-1e30, jnp.float32)
         quantized = self.quantized
         attn_route = self._attn_route(W)
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
         mm = _make_mm(self.weight_quantized, dt)
 
         def xla_attend(q, ks, vs, live):
@@ -1132,12 +1152,7 @@ class ServeEngine:
         on the first request that needs it - a TTFT spike production
         serving cannot afford. Returns the number of programs built."""
         bs = self.kv.cfg.block_size
-        max_w = _bucket(max_width_blocks or self.kv.cfg.max_blocks_per_seq)
-        widths = []
-        w = 1
-        while w <= max_w:
-            widths.append(w)
-            w *= 2
+        widths = self._bucket_widths(max_width_blocks)
         batches = []
         b = 1
         while b <= self.ecfg.max_batch:

@@ -132,6 +132,7 @@ class ServeServer:
             # (after warmup() the counts match the manifest and must
             # never grow - analysis/serve_trace.py)
             "compiled_programs": eng.compiled_programs(),
+            "decode_route": eng.decode_route(),
             "weight_dtype": eng.weight_dtype_name(),
             "spec_decode": eng.spec_k,
             "spec_draft_layers": eng.draft_layers if eng.spec_k else 0,
@@ -463,6 +464,9 @@ def main(argv=None) -> int:
         p.error(f"--precision: unknown mode(s) {sorted(bad)} "
                 "(choose from bf16, int8-kv, int8-w)")
 
+    from ..runtime import enable_compile_cache
+
+    print(f"(compile cache: {enable_compile_cache()})", flush=True)
     params, cfg = build_model(args)
     engine = ServeEngine(params, cfg, EngineConfig(
         max_batch=args.max_batch,
@@ -525,7 +529,7 @@ def main(argv=None) -> int:
         f"weights {engine.weight_dtype_name()}; "
         + (f"spec-decode k={engine.spec_k} "
            f"E={engine.draft_layers}; " if engine.spec_k else "")
-        + "endpoints: "
+        + f"decode -> {engine.decode_route()}; endpoints: "
         "POST /v1/generate, GET /v1/status, GET /v1/requests, "
         "/metrics, /healthz)",
         flush=True,

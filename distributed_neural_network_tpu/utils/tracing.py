@@ -811,18 +811,25 @@ def device_memory_snapshot() -> dict[str, dict] | None:
     return snap or None
 
 
-def compiled_flops(fn, *args, **kwargs) -> float | None:
-    """FLOPs of one call from ``fn.lower(...).compile().cost_analysis()``.
-
-    Returns None (never raises) when the function can't lower, the backend
-    doesn't report cost analysis, or the report carries no positive
-    ``flops`` entry - callers fall back to an analytic estimate.
-    cost_analysis() shape differs across jax versions (dict, or a
-    one-element list of dicts); both are handled.
-    """
+def compile_step(fn, *args, **kwargs):
+    """``fn.lower(...).compile()``, or None (never raises) when the
+    function can't lower or compile - the one AOT compile behind both
+    `compiled_flops` and `mosaic_custom_calls`."""
     try:
-        lowered = fn.lower(*args, **kwargs)
-        analysis = lowered.compile().cost_analysis()
+        return fn.lower(*args, **kwargs).compile()
+    except Exception:
+        return None
+
+
+def flops_from_compiled(compiled) -> float | None:
+    """The positive ``flops`` entry of ``compiled.cost_analysis()``, or
+    None when the backend reports none. cost_analysis() shape differs
+    across jax versions (dict, or a one-element list of dicts); both are
+    handled."""
+    if compiled is None:
+        return None
+    try:
+        analysis = compiled.cost_analysis()
     except Exception:
         return None
     if isinstance(analysis, (list, tuple)):
@@ -835,3 +842,20 @@ def compiled_flops(fn, *args, **kwargs) -> float | None:
     except (TypeError, ValueError):
         return None
     return flops if flops > 0 else None
+
+
+def compiled_flops(fn, *args, **kwargs) -> float | None:
+    """FLOPs of one call from ``fn.lower(...).compile().cost_analysis()``.
+
+    Returns None (never raises) when the function can't lower, the backend
+    doesn't report cost analysis, or the report carries no positive
+    ``flops`` entry - callers fall back to an analytic estimate.
+    """
+    return flops_from_compiled(compile_step(fn, *args, **kwargs))
+
+
+def mosaic_custom_calls(compiled) -> int:
+    """How many Mosaic (Pallas TPU) kernels the compiled program calls:
+    the proof that a `pallas` route really ran the kernel. 0 off-TPU,
+    where the plain-XLA paths stand in."""
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')

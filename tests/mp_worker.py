@@ -21,10 +21,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main() -> int:
-    from distributed_neural_network_tpu.train.cli import honor_platform_env
-
-    honor_platform_env()
-
     from distributed_neural_network_tpu.parallel.distributed import initialize
 
     did_init = initialize()

@@ -34,10 +34,6 @@ ACC_LEN = 12  # divisible by every world size the tests use (1/2/3/4/6)
 
 
 def main() -> int:
-    from distributed_neural_network_tpu.train.cli import honor_platform_env
-
-    honor_platform_env()
-
     from distributed_neural_network_tpu.parallel.distributed import (
         distribute_host_data,
         initialize,

@@ -471,8 +471,10 @@ def test_lm_train_overlap_grad_sync_and_compilation_cache(tmp_path):
     carries the schedule, the trace holds one grad_bucket event per
     bucket, StepStats attributes per-bucket collective bytes, and a
     second run against the same --compilation-cache-dir records a
-    (cache-hit) compile step no slower than the cold one."""
+    (cache-hit) compile step no slower than the cold one. The flag
+    places the cache only while JAX_COMPILATION_CACHE_DIR is unset."""
     env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
     ).strip()

@@ -317,13 +317,17 @@ def test_train_config_validates_grad_sync():
         _cfg(bucket_mb=0.0)
 
 
-def test_cli_passes_grad_sync_and_compilation_cache(tmp_path):
+def test_cli_passes_grad_sync_and_compilation_cache(tmp_path, monkeypatch):
     """The shared CLI surface plumbs --grad-sync/--bucket-mb into
     TrainConfig and --compilation-cache-dir into jax's persistent-cache
-    config (restored after the check)."""
+    config (restored after the check) - the flag applies only while
+    JAX_COMPILATION_CACHE_DIR is unset, so the variable is unset here."""
     import argparse
 
+    from distributed_neural_network_tpu import runtime
     from distributed_neural_network_tpu.train import cli
+
+    monkeypatch.delenv(runtime.CACHE_ENV, raising=False)
 
     p = argparse.ArgumentParser()
     cli.add_common_flags(p, epochs=2, batch_size=16)
@@ -339,7 +343,9 @@ def test_cli_passes_grad_sync_and_compilation_cache(tmp_path):
 
     prev = jax.config.jax_compilation_cache_dir
     try:
-        assert cli.enable_compilation_cache(str(tmp_path / "cache"))
+        assert runtime.enable_compile_cache(
+            args.compilation_cache_dir
+        ) == str(tmp_path / "cache")
         assert jax.config.jax_compilation_cache_dir == str(tmp_path / "cache")
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)

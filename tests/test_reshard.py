@@ -804,16 +804,14 @@ def test_latest_meta_skips_corrupt_newest(tmp_path):
 
 def test_reshard_step_program_traces_with_gather(n_devices):
     """The shardlint config: one tiled all_gather over 'data' per state
-    leaf, at the padded buffer size (traceable on any jax via
-    trace_compat - the same contract the checked-in manifest pins)."""
-    from distributed_neural_network_tpu import compat
+    leaf, at the padded buffer size (the same contract the checked-in
+    manifest pins)."""
     from distributed_neural_network_tpu.analysis.trace import collect_trace
 
     cfg = _cfg()
     mesh = lmtrain.create_lm_mesh(4, 1, 1)
-    with compat.trace_compat():
-        prog = R.reshard_step_program(cfg, mesh)
-        facts = collect_trace(prog.make_jaxpr())
+    prog = R.reshard_step_program(cfg, mesh)
+    facts = collect_trace(prog.make_jaxpr())
     n_leaves = len(jax.tree.leaves(prog.abstract_args[0]))
     gathers = [c for c in facts.collectives if c.op == "all_gather"]
     assert sum(c.count for c in gathers) == n_leaves
@@ -856,7 +854,6 @@ def test_reshard_pp_step_program_traces_with_gather_pair(n_devices):
     (layers) leaf gathers twice - data-axis segment gather + pipe-axis
     stage concat - while replicated leaves take one data gather (the
     contract the checked-in manifest pins)."""
-    from distributed_neural_network_tpu import compat
     from distributed_neural_network_tpu.analysis.trace import collect_trace
     from distributed_neural_network_tpu.parallel.pipeline import (
         create_pp_mesh,
@@ -864,9 +861,8 @@ def test_reshard_pp_step_program_traces_with_gather_pair(n_devices):
 
     cfg = _cfg()
     mesh = create_pp_mesh(2, 2, 1)
-    with compat.trace_compat():
-        prog = R.reshard_pp_step_program(cfg, mesh)
-        facts = collect_trace(prog.make_jaxpr())
+    prog = R.reshard_pp_step_program(cfg, mesh)
+    facts = collect_trace(prog.make_jaxpr())
     flat = prog.abstract_args[0]
     n_leaves = len(jax.tree.leaves(flat))
     n_layer_leaves = len(jax.tree.leaves(flat["layers"]))

@@ -1,11 +1,10 @@
 """shardlint: static sharding/collective/donation analysis (analysis/).
 
 Everything here runs on the 8-virtual-CPU-device mesh with NO step
-execution - the analyzer traces via jax.make_jaxpr under
-compat.trace_compat(), so the suite passes on jax builds both with and
-without jax.shard_map (the canonical-config traces differ across jax
-generations, which is why manifests are version-stamped; the
-checked-in-manifest conformance test skips on a version mismatch).
+execution - the analyzer traces via jax.make_jaxpr (the canonical-config
+traces differ across jax generations, which is why manifests are
+version-stamped; the checked-in-manifest conformance test skips on a
+version mismatch).
 """
 
 import importlib.util
@@ -134,13 +133,12 @@ def test_collect_trace_counts_collectives_and_scan_multiplicity():
         g = jax.lax.all_gather(x, "data", tiled=True)
         return c + g.sum()
 
-    with compat.trace_compat():
-        fn = jax.jit(
-            compat.shard_map(
-                body, mesh=mesh, in_specs=(P("data"),), out_specs=P(None),
-                check_vma=False,
-            )
+    fn = jax.jit(
+        compat.shard_map(
+            body, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
+            check_vma=False,
         )
+    )
     prog = _toy_program(fn, jax.ShapeDtypeStruct((8, 4), jnp.float32))
     facts = analysis.collect_trace(prog.make_jaxpr())
     by_op = {c.op: c for c in facts.collectives}
@@ -377,7 +375,7 @@ def test_step_program_exposes_traceable_metadata(n_devices):
 
 def test_engine_exposes_step_specs(n_devices):
     """train/engine.py publishes the spec metadata shardlint's CNN config
-    audits (built under trace_compat so it works on any jax)."""
+    audits."""
     prog = analysis.build_program("cnn_dp")
     assert prog.meta["family"] == "cnn"
     assert prog.donate == (1,)  # the epoch path donates momentum only
@@ -411,13 +409,12 @@ def _while_psum_program(extra_scan_psums: int = 0):
             acc = acc + acc2
         return acc
 
-    with compat.trace_compat():
-        fn = jax.jit(
-            compat.shard_map(
-                body, mesh=mesh, in_specs=(P("data"),), out_specs=P(None),
-                check_vma=False,
-            )
+    fn = jax.jit(
+        compat.shard_map(
+            body, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
+            check_vma=False,
         )
+    )
     return _toy_program(fn, jax.ShapeDtypeStruct((8, 4), jnp.float32))
 
 

@@ -1,0 +1,164 @@
+"""The main path's Pallas kernels compile for a described TPU v5e.
+
+No chip is attached here: `jax.experimental.topologies` describes a
+`v5e:2x2` host and the installed TPU compiler builds each kernel for it at
+the widths `chip_smoke.py` runs (the `on-chip-measurement` guide, section
+2). This catches what interpret mode cannot - a block that does not tile,
+a kernel over its VMEM budget, a kernel that cannot be partitioned under
+`shard_map` - at no chip time. Nothing executes, so nothing here is a
+measurement.
+"""
+
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from distributed_neural_network_tpu.ops.decode_pallas import (
+    decode_cache_attention,
+)
+from distributed_neural_network_tpu.ops.flash import tuned_blocks
+from distributed_neural_network_tpu.ops.flash_pallas import flash_mha
+from distributed_neural_network_tpu.ops.pallas_kernels import fused_mlp3
+from distributed_neural_network_tpu.utils.tracing import mosaic_custom_calls
+
+# the LM smoke's attention geometry (bench.py's flagship rows)
+B, S, H = 16, 2048, 8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _compile_cache_off():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _on_one_chip(topo, shapes):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+
+
+def _flash(topo, head_dim, *, grad, quant=None):
+    blocks = tuned_blocks(S, head_dim, topo.devices[0].device_kind)
+    attn = functools.partial(flash_mha, causal=True, blocks=blocks,
+                             quant=quant)
+    if grad:
+        def loss(q, k, v):
+            return attn(q, k, v).astype(jnp.float32).sum()
+
+        fn = jax.grad(loss, argnums=(0, 1, 2))
+    else:
+        fn = attn
+    x = ((B, S, H, head_dim), jnp.bfloat16)
+    return fn, _on_one_chip(topo, [x, x, x])
+
+
+def _decode(topo, batch, total, *, int8):
+    # the serve smoke: d512 / 8 heads -> Dh 64, 16-token blocks, per-slot
+    # positions; `total` = bucket width x block size
+    dh = 64
+    cache = ((batch, H, total, dh), jnp.int8 if int8 else jnp.bfloat16)
+    shapes = [((batch, H, dh), jnp.bfloat16), cache, cache,
+              ((batch,), jnp.int32)]
+    if not int8:
+        return decode_cache_attention, _on_one_chip(topo, shapes)
+    scale = ((batch, H, total), jnp.float32)
+
+    def fn(q, ck, cv, pos, ks, vs):
+        return decode_cache_attention(q, ck, cv, pos, k_scale=ks,
+                                      v_scale=vs)
+
+    return fn, _on_one_chip(topo, shapes + [scale, scale])
+
+
+def _mlp3(topo):
+    # the CNN's classifier head at the smoke's batch 16
+    dims = [(16, 400), (400, 120), (120,), (120, 84), (84,), (84, 10),
+            (10,)]
+
+    def loss(*a):
+        return fused_mlp3(*a, interpret=False).sum()
+
+    return (jax.grad(loss, argnums=tuple(range(7))),
+            _on_one_chip(topo, [(d, jnp.float32) for d in dims]))
+
+
+def _flash_dp2_tp2(topo):
+    """The kernel's vma-typed outputs under shard_map(check_vma=True) on
+    the four described chips: batch over `data`, heads over `tensor` - the
+    layout `lm_train.py --dp 2 --tp 2 --attn flash` gives attention."""
+    mesh = Mesh(
+        [[topo.devices[0], topo.devices[1]],
+         [topo.devices[2], topo.devices[3]]],
+        ("data", "tensor"),
+    )
+    blocks = tuned_blocks(S, 64, topo.devices[0].device_kind)
+    spec = P("data", None, "tensor", None)
+
+    def loss(q, k, v):
+        o = flash_mha(q, k, v, causal=True, blocks=blocks)
+        return jax.lax.psum(
+            o.astype(jnp.float32).sum(), ("data", "tensor")
+        )
+
+    fn = jax.shard_map(
+        jax.grad(loss, argnums=(0, 1, 2)), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=(spec, spec, spec),
+        check_vma=True,
+    )
+    x = jax.ShapeDtypeStruct(
+        (B, S, H, 64), jnp.bfloat16, sharding=NamedSharding(mesh, spec)
+    )
+    return fn, [x, x, x]
+
+
+CASES = {
+    "flash_fwd_d64": lambda t: _flash(t, 64, grad=False),
+    "flash_bwd_d64": lambda t: _flash(t, 64, grad=True),
+    "flash_fwd_d128": lambda t: _flash(t, 128, grad=False),
+    "flash_bwd_d128": lambda t: _flash(t, 128, grad=True),
+    "flash_int8_fwd_d64": lambda t: _flash(t, 64, grad=False, quant="int8"),
+    "flash_fp8_fwd_d64": lambda t: _flash(t, 64, grad=False, quant="fp8"),
+    "flash_int8_fwd_d128": lambda t: _flash(t, 128, grad=False, quant="int8"),
+    "flash_fp8_fwd_d128": lambda t: _flash(t, 128, grad=False, quant="fp8"),
+    "decode_bf16_b1_w1": lambda t: _decode(t, 1, 16, int8=False),
+    "decode_bf16_b8_w16": lambda t: _decode(t, 8, 256, int8=False),
+    "decode_int8_b1_w2": lambda t: _decode(t, 1, 32, int8=True),
+    "decode_int8_b8_w16": lambda t: _decode(t, 8, 256, int8=True),
+    "fused_mlp3_fwd_bwd": _mlp3,
+    "flash_bwd_dp2_tp2_shard_map": _flash_dp2_tp2,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(topo, case):
+    fn, args = CASES[case](topo)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert mosaic_custom_calls(compiled) > 0, (
+        f"{case}: no Mosaic custom call in the program"
+    )

@@ -4,9 +4,7 @@
 The CPU suite exercises `ops/flash_pallas.py` and `ops/pallas_kernels.py`
 through the Pallas *interpreter* (tests/test_flash_pallas.py,
 tests/test_pallas.py); Mosaic-compiled behavior is only truly covered on
-TPU, and the r4 hardware session measured *timing*, not parity (r4
-VERDICT weak #4). This script runs on the real chip, under the same
-single claim as the fill pass, and checks:
+TPU. This script runs on the chip (one process holds it) and checks:
 
   1. own flash fwd+bwd, compiled Mosaic vs the Pallas interpreter on the
      SAME f32 inputs (small shape) - the exact "compiled != interpreter"
@@ -20,7 +18,7 @@ Writes tools/flash_parity_<device>.json: one row per check with a
 normalized max-abs error (max|a-b| / (max|b|+eps)) and pass/fail, plus
 an overall "ok". Exit 0 iff every row passed.
 
-Usage (real TPU, one claim):  python tools/flash_parity_check.py
+Usage (on the TPU):  python tools/flash_parity_check.py
 """
 
 from __future__ import annotations
@@ -53,7 +51,13 @@ def main() -> int:
         mlp3_reference,
     )
 
-    if jax.default_backend() != "tpu":
+    from distributed_neural_network_tpu.runtime import (
+        enable_compile_cache,
+        on_tpu,
+    )
+
+    enable_compile_cache()
+    if not on_tpu():
         print(json.dumps({"error": "parity check needs a TPU backend"}))
         return 1
 

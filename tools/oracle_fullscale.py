@@ -21,7 +21,7 @@ this is an algorithm-identity check, not a perf measurement). Wall cost is
 
 Writes tools/oracle_fullscale_result.json: per-epoch oracle/engine train
 loss, their abs diff, and the max param rel err - the drift curve of f32
-XLA vs f64 numpy over the full 25-epoch horizon, which REPORT.md's
+XLA vs f64 numpy over the full 25-epoch horizon, which report.py's
 accuracy-parity section cites.
 """
 
@@ -66,9 +66,6 @@ def _max_rel_err(a, b):
 
 
 def main() -> int:
-    from distributed_neural_network_tpu.train.cli import honor_platform_env
-
-    honor_platform_env()
     import jax
     import numpy as np
 
