@@ -278,6 +278,7 @@ def decode_cache_attention(q, ck, cv, pos, *, block_k: int = 512,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="decode_paged_attn",
     )(pos_arr, *operands)
     return o[:, 0].reshape(b, h, d)
 

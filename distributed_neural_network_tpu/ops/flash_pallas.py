@@ -236,6 +236,7 @@ def _fwd_call(q, k, v, *, blocks, scale, causal, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     # keep only lane 0 as the residual: between fwd and bwd the saved lse
     # is (bh, s), not 128x that (the broadcast back happens in _bwd_call)
@@ -398,6 +399,7 @@ def _fwd_quant_call(q, k, v, *, blocks, scale, causal, interpret, fmt):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd_quant",
     )(q_q, k_q, v_q, sq_l, sk_l, sv_l)
     return o, lse[..., 0]
 
@@ -520,6 +522,7 @@ def _bwd_call(q, k, v, o, lse, do, *, blocks, scale, causal, interpret):
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=arb,
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse_l, delta_l)
 
     # dkv: grid (bh, k blocks, q inner); under causality q blocks strictly
@@ -561,6 +564,7 @@ def _bwd_call(q, k, v, o, lse, do, *, blocks, scale, causal, interpret):
         ],
         compiler_params=arb,
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse_l, delta_l)
     return dq, dk, dv
 

@@ -72,6 +72,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..models.transformer import (
     TransformerConfig,
@@ -85,6 +86,10 @@ from .kv_cache import KVCacheConfig, OutOfBlocks, PagedKVCache
 
 _INT8_MAX = 127.0
 _SCALE_EPS = 1e-30
+
+# the phases that partition `ServeEngine.step` on the host's clock; each
+# is also a `serve.<phase>` span in a profile (docs/SERVING.md "Metrics")
+STEP_PHASES = ("prefill_host", "decode_host", "fetch", "emit", "spec")
 
 # the weight matrices --precision int8-w stores quantized (per-column
 # int8 codes + per-column f32 scales, ops/quant.py prequantize_weight);
@@ -1439,9 +1444,35 @@ class ServeEngine:
     def step(self) -> dict:
         """One engine tick. Returns per-tick stats for the scheduler's
         ledger/metrics: ``{"decode_tokens", "prefill_tokens",
-        "finished", "parked", "batch", "prefill_s", "decode_s"}``
-        (span seconds measured by the caller via the returned work
-        counts - the engine itself is clock-free for testability).
+        "finished", "parked", "batch", "phase_s", "decode_call",
+        "prefill_calls"}``.
+
+        ``phase_s`` partitions the call on ``time.perf_counter``, one
+        key per `STEP_PHASES` entry and every instant from entry to
+        return in exactly one: ``prefill_host`` (the chunked-prefill
+        loop: blocks, arrays, transfers and each `_prefill_fn` dispatch
+        up to its return), ``decode_host`` (batch selection to the
+        return of `_decode_fn`'s dispatch), ``fetch``
+        (``np.asarray(nxt)``: the host blocked until the tick's programs
+        have finished), ``emit`` (the per-sequence loop after the fetch
+        with its `on_token` callbacks, and retiring) and ``spec``
+        (`_spec_step` whole). Each is also a ``serve.<phase>``
+        `TraceAnnotation`, inert while no profile is taken. Dispatch is
+        asynchronous, so the host can time the wait for the prefill and
+        the decode program together (``fetch``) and not each apart:
+        that is why the scheduler's split of the step between the
+        ledger's "prefill" and "decode" by token counts stays an
+        apportioning.
+
+        ``decode_call`` is ``(B, W, live)`` for the tick's decode
+        dispatch, None without one: the batch and width-in-blocks
+        bucket the program is shaped for (``B * W * block_size`` padded
+        positions), and the cache positions its queries attend to,
+        ``pos + 1`` summed over the batch. ``prefill_calls`` lists
+        ``(C, W, live)`` per prefill dispatch: the chunk bucket, the
+        width, and ``n * pos0 + n * (n + 1) / 2`` for ``n`` tokens from
+        ``pos0`` (each attends to all before it and itself). The
+        speculative path's programs are not counted.
 
         For per-request attribution (serve/reqtrace.py) the dict also
         carries ``per_seq`` - ``{seq_id: {"prefill", "decode",
@@ -1454,13 +1485,25 @@ class ServeEngine:
         carry ``spec`` - ``{"proposed", "accepted", "steps",
         "draft_s", "verify_s", "per_slot"}`` (``per_slot`` = accepted
         drafts per slot, the acceptance-histogram input)."""
+        phase_s = dict.fromkeys(STEP_PHASES, 0.0)
+        t_mark = time.perf_counter()
+
+        def lap(phase: str) -> None:
+            # everything since the last reading belongs to `phase`
+            nonlocal t_mark
+            now = time.perf_counter()
+            phase_s[phase] += now - t_mark
+            t_mark = now
+
         ecfg = self.ecfg
         bs = self.kv.cfg.block_size
         with self.lock:
             todo = list(self.active)
         parked: list[Sequence] = []
         stats = {"decode_tokens": 0, "prefill_tokens": 0, "finished": 0,
-                 "parked": 0, "batch": 0, "per_seq": {}, "preempted": []}
+                 "parked": 0, "batch": 0, "per_seq": {}, "preempted": [],
+                 "phase_s": phase_s, "decode_call": None,
+                 "prefill_calls": []}
 
         def seqstat(s: Sequence) -> dict:
             d = stats["per_seq"].get(s.seq_id)
@@ -1476,158 +1519,179 @@ class ServeEngine:
 
         # ---- chunked prefill phase (prefill_chunk > 1 only)
         if ecfg.prefill_chunk > 1:
-            budget = ecfg.prefill_token_budget or ecfg.prefill_chunk
+            with TraceAnnotation("serve.prefill_host"):
+                budget = ecfg.prefill_token_budget or ecfg.prefill_chunk
+                for seq in todo:
+                    if budget <= 0:
+                        break
+                    if not seq.in_prefill or seq.finished:
+                        continue
+                    # leave the LAST prompt token to the decode batch:
+                    # its logits produce the first generated token there,
+                    # so first-token sampling/argmax runs on the same path
+                    # for every sequence
+                    remaining = seq.prompt_len - 1 - seq.pos
+                    if remaining <= 0:
+                        continue
+                    n = min(remaining, ecfg.prefill_chunk, budget)
+                    try:
+                        self.kv.ensure_range(seq.seq_id, seq.pos + n - 1)
+                    except OutOfBlocks:
+                        parked.append(seq)
+                        seqstat(seq)["parked"] = True
+                        continue
+                    C = _bucket(n)
+                    W = _bucket(
+                        (seq.pos + n - 1) // bs + 1
+                    )
+                    toks = np.zeros((C,), np.int32)
+                    toks[:n] = seq.prompt[seq.pos: seq.pos + n]
+                    table = self.kv.table([seq.seq_id], W)[0]
+                    fn = self._prefill_fn(C, W)
+                    stats["prefill_calls"].append(
+                        (C, W, n * seq.pos + n * (n + 1) // 2)
+                    )
+                    tail = (
+                        jnp.asarray(toks), jnp.int32(seq.pos),
+                        jnp.asarray(table), jnp.int32(n),
+                    )
+                    if self.quantized:
+                        (self.k_pool, self.v_pool, self.k_scale,
+                         self.v_scale, _) = fn(
+                            self.params, self.k_pool, self.v_pool,
+                            self.k_scale, self.v_scale, *tail,
+                        )
+                    else:
+                        self.k_pool, self.v_pool, _ = fn(
+                            self.params, self.k_pool, self.v_pool, *tail,
+                        )
+                    seq.pos += n
+                    budget -= n
+                    self.prefill_tokens += n
+                    stats["prefill_tokens"] += n
+                    seqstat(seq)["prefill"] += n
+        lap("prefill_host")
+
+        # ---- decode batch: plain slots (one token each) + speculative
+        # slots (k drafts verified in one multi-position step)
+        with TraceAnnotation("serve.decode_host"):
+            batch: list[Sequence] = []
+            spec_batch: list[Sequence] = []
             for seq in todo:
-                if budget <= 0:
-                    break
-                if not seq.in_prefill or seq.finished:
+                if seq.finished or seq in parked:
                     continue
-                # leave the LAST prompt token to the decode batch: its
-                # logits produce the first generated token there, so
-                # first-token sampling/argmax runs on the same path for
-                # every sequence
-                remaining = seq.prompt_len - 1 - seq.pos
-                if remaining <= 0:
-                    continue
-                n = min(remaining, ecfg.prefill_chunk, budget)
+                if ecfg.prefill_chunk > 1 and seq.in_prefill and (
+                    seq.pos < seq.prompt_len - 1
+                ):
+                    continue  # still mid-chunked-prefill; next tick
+                if self.spec_k and self._spec_eligible(seq):
+                    try:
+                        self.kv.ensure_range(
+                            seq.seq_id, seq.pos + self.spec_k
+                        )
+                        spec_batch.append(seq)
+                        continue
+                    except OutOfBlocks:
+                        pass  # degrade to the one-block plain path
                 try:
-                    self.kv.ensure_range(seq.seq_id, seq.pos + n - 1)
+                    self.kv.ensure(seq.seq_id, seq.pos)
                 except OutOfBlocks:
                     parked.append(seq)
                     seqstat(seq)["parked"] = True
                     continue
-                C = _bucket(n)
-                W = _bucket(
-                    (seq.pos + n - 1) // bs + 1
+                batch.append(seq)
+
+            stats["parked"] = len(parked)
+            if parked:
+                self.stall_events += 1
+            if not batch and not spec_batch:
+                if parked:
+                    # every active sequence is parked on blocks: preempt
+                    # the youngest so the others' next allocation can
+                    # succeed
+                    victim = self._preempt_youngest(parked)
+                    stats["preempted"].append({
+                        "seq_id": victim.seq_id,
+                        "tokens_held": len(victim.out),
+                        "preemptions": victim.preemptions,
+                    })
+                lap("decode_host")
+                return stats
+
+            if batch:
+                B = _bucket(len(batch))
+                if B > ecfg.max_batch:
+                    B = ecfg.max_batch
+                    batch = batch[:B]
+                W = _bucket(max(
+                    s.pos // bs + 1 for s in batch
+                ))
+                tok = np.zeros((B,), np.int32)
+                pos = np.zeros((B,), np.int32)
+                temps = np.zeros((B,), np.float32)
+                keys = np.zeros((B, 2), np.uint32)
+                for i, s in enumerate(batch):
+                    tok[i] = s.next_input()
+                    pos[i] = s.pos
+                    temps[i] = s.temperature
+                    keys[i] = self._sample_key(s)
+                table = self.kv.table(
+                    [s.seq_id for s in batch] + [-1] * (B - len(batch)), W
                 )
-                toks = np.zeros((C,), np.int32)
-                toks[:n] = seq.prompt[seq.pos: seq.pos + n]
-                table = self.kv.table([seq.seq_id], W)[0]
-                fn = self._prefill_fn(C, W)
+                fn = self._decode_fn(B, W)
+                stats["decode_call"] = (
+                    B, W, int(pos.sum()) + len(batch)
+                )
                 tail = (
-                    jnp.asarray(toks), jnp.int32(seq.pos),
-                    jnp.asarray(table), jnp.int32(n),
+                    jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(table),
+                    jnp.asarray(temps), jnp.asarray(keys),
                 )
                 if self.quantized:
-                    (self.k_pool, self.v_pool, self.k_scale,
-                     self.v_scale, _) = fn(
+                    (self.k_pool, self.v_pool, self.k_scale, self.v_scale,
+                     nxt, _) = fn(
                         self.params, self.k_pool, self.v_pool,
                         self.k_scale, self.v_scale, *tail,
                     )
                 else:
-                    self.k_pool, self.v_pool, _ = fn(
+                    self.k_pool, self.v_pool, nxt, _ = fn(
                         self.params, self.k_pool, self.v_pool, *tail,
                     )
-                seq.pos += n
-                budget -= n
-                self.prefill_tokens += n
-                stats["prefill_tokens"] += n
-                seqstat(seq)["prefill"] += n
-
-        # ---- decode batch: plain slots (one token each) + speculative
-        # slots (k drafts verified in one multi-position step)
-        batch: list[Sequence] = []
-        spec_batch: list[Sequence] = []
-        for seq in todo:
-            if seq.finished or seq in parked:
-                continue
-            if ecfg.prefill_chunk > 1 and seq.in_prefill and (
-                seq.pos < seq.prompt_len - 1
-            ):
-                continue  # still mid-chunked-prefill; next tick
-            if self.spec_k and self._spec_eligible(seq):
-                try:
-                    self.kv.ensure_range(
-                        seq.seq_id, seq.pos + self.spec_k
-                    )
-                    spec_batch.append(seq)
-                    continue
-                except OutOfBlocks:
-                    pass  # degrade to the one-block plain path
-            try:
-                self.kv.ensure(seq.seq_id, seq.pos)
-            except OutOfBlocks:
-                parked.append(seq)
-                seqstat(seq)["parked"] = True
-                continue
-            batch.append(seq)
-
-        stats["parked"] = len(parked)
-        if parked:
-            self.stall_events += 1
-        if not batch and not spec_batch:
-            if parked:
-                # every active sequence is parked on blocks: preempt the
-                # youngest so the others' next allocation can succeed
-                victim = self._preempt_youngest(parked)
-                stats["preempted"].append({
-                    "seq_id": victim.seq_id,
-                    "tokens_held": len(victim.out),
-                    "preemptions": victim.preemptions,
-                })
-            return stats
-
+        lap("decode_host")
         if batch:
-            B = _bucket(len(batch))
-            if B > ecfg.max_batch:
-                B = ecfg.max_batch
-                batch = batch[:B]
-            W = _bucket(max(
-                s.pos // bs + 1 for s in batch
-            ))
-            tok = np.zeros((B,), np.int32)
-            pos = np.zeros((B,), np.int32)
-            temps = np.zeros((B,), np.float32)
-            keys = np.zeros((B, 2), np.uint32)
-            for i, s in enumerate(batch):
-                tok[i] = s.next_input()
-                pos[i] = s.pos
-                temps[i] = s.temperature
-                keys[i] = self._sample_key(s)
-            table = self.kv.table(
-                [s.seq_id for s in batch] + [-1] * (B - len(batch)), W
-            )
-            fn = self._decode_fn(B, W)
-            tail = (
-                jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(table),
-                jnp.asarray(temps), jnp.asarray(keys),
-            )
-            if self.quantized:
-                (self.k_pool, self.v_pool, self.k_scale, self.v_scale,
-                 nxt, _) = fn(
-                    self.params, self.k_pool, self.v_pool,
-                    self.k_scale, self.v_scale, *tail,
-                )
-            else:
-                self.k_pool, self.v_pool, nxt, _ = fn(
-                    self.params, self.k_pool, self.v_pool, *tail,
-                )
-            nxt = np.asarray(nxt)
-            for i, s in enumerate(batch):
-                consumed_at = s.pos
-                s.pos += 1
-                if consumed_at >= s.prompt_len - 1:
-                    # prediction for generated-token index j; after a
-                    # preemption the replay re-derives tokens the
-                    # sequence already holds (j < len(out)) -
-                    # deterministic by construction (greedy, or the
-                    # per-position sampling key), so they are dropped,
-                    # not re-appended/re-streamed
-                    j = consumed_at + 1 - s.prompt_len
-                    if j == len(s.out):
-                        self._emit(s, int(nxt[i]))
+            with TraceAnnotation("serve.fetch"):
+                nxt = np.asarray(nxt)
+            lap("fetch")
+            with TraceAnnotation("serve.emit"):
+                for i, s in enumerate(batch):
+                    consumed_at = s.pos
+                    s.pos += 1
+                    if consumed_at >= s.prompt_len - 1:
+                        # prediction for generated-token index j; after a
+                        # preemption the replay re-derives tokens the
+                        # sequence already holds (j < len(out)) -
+                        # deterministic by construction (greedy, or the
+                        # per-position sampling key), so they are dropped,
+                        # not re-appended/re-streamed
+                        j = consumed_at + 1 - s.prompt_len
+                        if j == len(s.out):
+                            self._emit(s, int(nxt[i]))
+                        else:
+                            seqstat(s)["replayed"] += 1
+                        self.decode_tokens += 1
+                        stats["decode_tokens"] += 1
+                        seqstat(s)["decode"] += 1
                     else:
-                        seqstat(s)["replayed"] += 1
-                    self.decode_tokens += 1
-                    stats["decode_tokens"] += 1
-                    seqstat(s)["decode"] += 1
-                else:
-                    self.prefill_tokens += 1
-                    stats["prefill_tokens"] += 1
-                    seqstat(s)["prefill"] += 1
+                        self.prefill_tokens += 1
+                        stats["prefill_tokens"] += 1
+                        seqstat(s)["prefill"] += 1
+            lap("emit")
         if spec_batch:
-            self._spec_step(spec_batch, stats, seqstat)
+            with TraceAnnotation("serve.spec"):
+                self._spec_step(spec_batch, stats, seqstat)
+            lap("spec")
         self.ticks += 1
         stats["batch"] = len(batch) + len(spec_batch)
-        stats["finished"] = len(self._retire_finished())
+        with TraceAnnotation("serve.emit"):
+            stats["finished"] = len(self._retire_finished())
+        lap("emit")
         return stats

@@ -55,9 +55,11 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 
+from jax.profiler import TraceAnnotation
+
 from ..utils.goodput import GoodputLedger
 from ..utils.obs import NULL_REGISTRY
-from .engine import ServeEngine, Sequence, export_descriptor
+from .engine import STEP_PHASES, ServeEngine, Sequence, export_descriptor
 from .reqtrace import RequestTraceRecorder
 
 # histogram buckets for TTFT / inter-token latency: 1 ms .. 60 s
@@ -65,6 +67,12 @@ LATENCY_BUCKETS = (
     0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
     1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
 )
+
+
+# the phases that partition the loop thread's time
+# (`serve_loop_seconds_total{phase}`): the scheduler's own and the
+# engine's, each also a `serve.<phase>` span in a profile
+LOOP_PHASES = ("admit", "books", "wait") + STEP_PHASES
 
 
 class AdmissionError(Exception):
@@ -315,6 +323,35 @@ class ServeScheduler:
             "Accepted draft tokens per speculative slot-step",
             buckets=tuple(float(i) for i in range(spec_k)),
         )
+        # where the loop thread's time goes and what the bucket programs
+        # are shaped for against what they hold (docs/SERVING.md
+        # "Metrics"); published a whole tick at a time (_publish_tick)
+        loop_s = r.counter(
+            "serve_loop_seconds_total",
+            "Seconds of the serve loop thread, by phase",
+        )
+        self._m_loop_s = {p: loop_s.labels(phase=p) for p in LOOP_PHASES}
+        self._m_decode_calls = r.counter(
+            "serve_decode_calls_total",
+            "Decode dispatches by bucket (batch, width in blocks)",
+        )
+        self._m_prefill_calls = r.counter(
+            "serve_prefill_calls_total",
+            "Prefill dispatches by bucket (chunk, width in blocks)",
+        )
+        decode_pos = r.counter(
+            "serve_decode_positions_total",
+            "Cache positions of decode dispatches: live (attended to) "
+            "and padded (the bucket's shape)",
+        )
+        prefill_pos = r.counter(
+            "serve_prefill_positions_total",
+            "Cache positions of prefill dispatches: live and padded",
+        )
+        self._m_decode_live = decode_pos.labels(kind="live")
+        self._m_decode_padded = decode_pos.labels(kind="padded")
+        self._m_prefill_live = prefill_pos.labels(kind="live")
+        self._m_prefill_padded = prefill_pos.labels(kind="padded")
         if r is not NULL_REGISTRY:
             self.ledger.publish(r)
 
@@ -643,100 +680,171 @@ class ServeScheduler:
 
     def _loop(self) -> None:
         eng = self.engine
-        kv = eng.kv
         cfg = self.cfg
+        now = self.ledger.now
+        # the loop thread's seconds by phase since the last tick was
+        # published: everything between two readings of the clock goes
+        # to exactly one phase, so the phases sum to the thread's wall
+        # time, less what `eng.step` spends outside its own phases
+        phase_s = dict.fromkeys(LOOP_PHASES, 0.0)
+        t_mark = now()
+
+        def lap(phase: str) -> float:
+            nonlocal t_mark
+            t = now()
+            phase_s[phase] += t - t_mark
+            t_mark = t
+            return t
+
         while self._running:
             if self._draining:
-                self._drain_sweep()
-                with self._work:
+                with TraceAnnotation("serve.admit"):
+                    self._drain_sweep()
+                lap("admit")
+                with TraceAnnotation("serve.wait"), self._work:
                     self._work.wait(timeout=cfg.idle_poll_s)
+                lap("wait")
                 continue
             with self._work:
                 have_queued = self._queued > 0
             if not have_queued and not eng.has_work() and not eng.preempted:
-                with self._work:
+                with TraceAnnotation("serve.wait"), self._work:
                     self._work.wait(timeout=cfg.idle_poll_s)
+                lap("wait")
                 continue
 
-            t_form0 = self.ledger.now()
-            self._enact_cancels()
-            # re-admit preempted sequences first (streamed state)
-            while eng.preempted and len(eng.active) < eng.ecfg.max_batch:
-                s = eng.preempted[0]
-                if not kv.can_fit(s.prompt_len + 1):
-                    break
-                eng.preempted.popleft()
-                eng.add(s)
-                # replay starts at pos 0: back to prefill until the
-                # engine re-derives the held tokens
-                self.reqtrace.mark(s.seq_id, "prefill")
-            # admit new requests round-robin while capacity lasts
-            while len(eng.active) < eng.ecfg.max_batch:
+            with TraceAnnotation("serve.tick", tick=eng.ticks):
+                # one pair of readings serves the ledger and the phase
+                t_form0 = t_mark
+                with TraceAnnotation("serve.admit"):
+                    self._admit_ready()
+                t_form1 = lap("admit")
+                if t_form1 > t_form0:
+                    self.ledger.add(
+                        "batch_formation_idle", t_form0, t_form1
+                    )
+                if not eng.has_work():
+                    continue
+                preempted_before = len(eng.preempted)
+                t0 = lap("admit")
+                with TraceAnnotation("serve.step"):
+                    stats = eng.step()
+                t1 = t_mark = now()
+                for phase, dt in stats["phase_s"].items():
+                    phase_s[phase] += dt
+                with TraceAnnotation("serve.books"):
+                    self._m_steps.inc()
+                    self._publish_tick(phase_s, stats)
+                    self._account_step(stats, t0, t1, preempted_before)
+                lap("books")
+
+    def _admit_ready(self) -> None:
+        """The admission pass of one loop iteration (loop thread):
+        enact cancels, re-admit preempted sequences, admit queued
+        requests while capacity lasts."""
+        eng = self.engine
+        kv = eng.kv
+        self._enact_cancels()
+        # re-admit preempted sequences first (streamed state)
+        while eng.preempted and len(eng.active) < eng.ecfg.max_batch:
+            s = eng.preempted[0]
+            if not kv.can_fit(s.prompt_len + 1):
+                break
+            eng.preempted.popleft()
+            eng.add(s)
+            # replay starts at pos 0: back to prefill until the
+            # engine re-derives the held tokens
+            self.reqtrace.mark(s.seq_id, "prefill")
+        # admit new requests round-robin while capacity lasts
+        while len(eng.active) < eng.ecfg.max_batch:
+            with self._work:
+                nxt = self._next_request() if self._queued > 0 else None
+                if nxt is not None:
+                    self._m_queue.set(self._queued)
+            if nxt is None:
+                break
+            need = kv.cfg.blocks_for_tokens(len(nxt.prompt) + 1)
+            if need + self.cfg.block_headroom > kv.free_blocks:
+                # no room for this prompt yet: back to the head of
+                # its tenant FIFO (it keeps its place; 429 pressure
+                # builds behind the queue bound), stop admitting
                 with self._work:
-                    nxt = self._next_request() if self._queued > 0 else None
-                    if nxt is not None:
-                        self._m_queue.set(self._queued)
-                if nxt is None:
-                    break
-                need = kv.cfg.blocks_for_tokens(len(nxt.prompt) + 1)
-                if need + cfg.block_headroom > kv.free_blocks:
-                    # no room for this prompt yet: back to the head of
-                    # its tenant FIFO (it keeps its place; 429 pressure
-                    # builds behind the queue bound), stop admitting
-                    with self._work:
-                        self._tenants[nxt.api_key].appendleft(nxt)
-                        self._queued += 1
-                        self._m_queue.set(self._queued)
-                    break
-                self._admit_one(nxt)
-            t_form1 = self.ledger.now()
-            if t_form1 > t_form0:
-                self.ledger.add("batch_formation_idle", t_form0, t_form1)
+                    self._tenants[nxt.api_key].appendleft(nxt)
+                    self._queued += 1
+                    self._m_queue.set(self._queued)
+                break
+            self._admit_one(nxt)
 
-            if not eng.has_work():
-                continue
-            preempted_before = len(eng.preempted)
-            t0 = self.ledger.now()
-            stats = eng.step()
-            t1 = self.ledger.now()
-            self._m_steps.inc()
-            self.reqtrace.observe_step(stats, t0, t1)
-            if len(eng.preempted) > preempted_before:
-                self._m_preempt.inc(len(eng.preempted) - preempted_before)
-            spec = stats.get("spec")
-            if spec:
-                if spec["proposed"]:
-                    self._m_spec_proposed.inc(spec["proposed"])
-                if spec["accepted"]:
-                    self._m_spec_accepted.inc(spec["accepted"])
-                for a in spec.get("per_slot", ()):
-                    self._m_spec_accept_hist.observe(float(a))
-            dec, pre = stats["decode_tokens"], stats["prefill_tokens"]
-            span = t1 - t0
-            if dec + pre > 0 and span > 0:
-                # one fenced step span, apportioned to the two phases by
-                # token counts - prefill and decode genuinely share the
-                # batch (token-level continuous batching), so the split
-                # is the honest per-phase cost
-                t_split = t0 + span * (pre / (dec + pre))
-                if pre > 0:
-                    self.ledger.add("prefill", t0, t_split)
-                if dec > 0:
-                    self.ledger.add("decode", t_split, t1)
-                self._m_tokens.labels(kind="prefill").inc(pre)
-                self._m_tokens.labels(kind="decode").inc(dec)
-                self.ledger.note_steps(1, tokens=float(dec))
-            elif span > 0:
-                # a tick that moved nothing: block exhaustion (possibly
-                # including preemption work)
-                self.ledger.add("kv_alloc_stall", t0, t1)
-            self._m_active.set(len(eng.active))
-            self._m_kv_used.set(kv.blocks_in_use)
-            self._m_kv_bytes_used.set(
-                kv.blocks_in_use * self._kv_block_bytes
-            )
-            self.ledger.maybe_publish()
-            self.ledger.maybe_write()
-            self.registry.beat(eng.ticks)
-            if not self.registry.ready and eng.ticks > 0:
-                self.registry.mark_ready()
+    def _publish_tick(self, phase_s: dict, stats: dict) -> None:
+        """Everything the registry learns of one tick's time and
+        shapes, in one place and after `serve_engine_steps_total` has
+        counted it: a scrape between two ticks sees whole ticks (one
+        step's end to the next, as that counter beats), so
+        delta(seconds) / delta(steps) is a mean per tick. Empties
+        ``phase_s``."""
+        for phase, dt in phase_s.items():
+            self._m_loop_s[phase].inc(dt)
+            phase_s[phase] = 0.0
+        bs = self.engine.ecfg.block_size
+        call = stats["decode_call"]
+        if call is not None:
+            B, W, live = call
+            self._m_decode_calls.labels(
+                batch=str(B), width_blocks=str(W)
+            ).inc()
+            self._m_decode_live.inc(live)
+            self._m_decode_padded.inc(B * W * bs)
+        for C, W, live in stats["prefill_calls"]:
+            self._m_prefill_calls.labels(
+                chunk=str(C), width_blocks=str(W)
+            ).inc()
+            self._m_prefill_live.inc(live)
+            self._m_prefill_padded.inc(C * W * bs)
+
+    def _account_step(self, stats: dict, t0: float, t1: float,
+                      preempted_before: int) -> None:
+        """The books of one tick (loop thread): per-request traces, the
+        ledger's share of the step ``t0..t1``, token counters, gauges,
+        the heartbeat."""
+        eng = self.engine
+        kv = eng.kv
+        self.reqtrace.observe_step(stats, t0, t1)
+        if len(eng.preempted) > preempted_before:
+            self._m_preempt.inc(len(eng.preempted) - preempted_before)
+        spec = stats.get("spec")
+        if spec:
+            if spec["proposed"]:
+                self._m_spec_proposed.inc(spec["proposed"])
+            if spec["accepted"]:
+                self._m_spec_accepted.inc(spec["accepted"])
+            for a in spec.get("per_slot", ()):
+                self._m_spec_accept_hist.observe(float(a))
+        dec, pre = stats["decode_tokens"], stats["prefill_tokens"]
+        span = t1 - t0
+        if dec + pre > 0 and span > 0:
+            # one fenced step span, apportioned to the two phases by
+            # token counts - prefill and decode genuinely share the
+            # batch (token-level continuous batching), so the split
+            # is the honest per-phase cost
+            t_split = t0 + span * (pre / (dec + pre))
+            if pre > 0:
+                self.ledger.add("prefill", t0, t_split)
+            if dec > 0:
+                self.ledger.add("decode", t_split, t1)
+            self._m_tokens.labels(kind="prefill").inc(pre)
+            self._m_tokens.labels(kind="decode").inc(dec)
+            self.ledger.note_steps(1, tokens=float(dec))
+        elif span > 0:
+            # a tick that moved nothing: block exhaustion (possibly
+            # including preemption work)
+            self.ledger.add("kv_alloc_stall", t0, t1)
+        self._m_active.set(len(eng.active))
+        self._m_kv_used.set(kv.blocks_in_use)
+        self._m_kv_bytes_used.set(
+            kv.blocks_in_use * self._kv_block_bytes
+        )
+        self.ledger.maybe_publish()
+        self.ledger.maybe_write()
+        self.registry.beat(eng.ticks)
+        if not self.registry.ready and eng.ticks > 0:
+            self.registry.mark_ready()

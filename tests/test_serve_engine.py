@@ -9,8 +9,12 @@ Bars:
 - KV exhaustion preempts rather than crashes, the replay is exact, and
   streamed tokens are never duplicated;
 - sampling is deterministic per (seed, position) - preemption-safe -
-  and the admission-time validation rejects what could never run.
+  and the admission-time validation rejects what could never run;
+- every `step()` partitions its own wall time into the documented
+  phases and reports the buckets it dispatched with their live positions.
 """
+
+import time
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +23,7 @@ import pytest
 
 from distributed_neural_network_tpu.models import transformer as tfm
 from distributed_neural_network_tpu.serve.engine import (
+    STEP_PHASES,
     EngineConfig,
     Sequence,
     ServeEngine,
@@ -213,3 +218,94 @@ def test_cancel_frees_blocks_mid_flight(params, n_devices):
     assert eng.kv.blocks_in_use == 0
     assert not eng.has_work()
     assert eng.cancel(0) is False  # idempotent
+
+
+# ------------------------------------------- phases, buckets and positions
+
+
+def _timed_step(eng):
+    t0 = time.perf_counter()
+    st = eng.step()
+    return st, time.perf_counter() - t0
+
+
+def _check_phases(st, wall):
+    ph = st["phase_s"]
+    assert tuple(ph) == STEP_PHASES == (
+        "prefill_host", "decode_host", "fetch", "emit", "spec")
+    assert all(v >= 0.0 for v in ph.values()), ph
+    # entry to return, every instant in exactly one phase
+    assert abs(sum(ph.values()) - wall) <= max(0.05 * wall, 0.002), (ph, wall)
+
+
+@pytest.mark.parametrize("chunk,spec", [(1, 0), (4, 0), (4, 2)])
+def test_step_phase_seconds_partition_the_call(params, n_devices, chunk,
+                                               spec):
+    eng = ServeEngine(params, CFG, EngineConfig(
+        max_batch=4, num_blocks=32, block_size=4, max_seq_len=64,
+        prefill_chunk=chunk, spec_decode=spec,
+    ))
+    for i, n in enumerate((9, 5)):
+        eng.add(Sequence(i, _prompt(40 + i, n), 4))
+    seen = dict.fromkeys(STEP_PHASES, 0.0)
+    steps = 0
+    while eng.has_work():
+        st, wall = _timed_step(eng)
+        _check_phases(st, wall)
+        for k, v in st["phase_s"].items():
+            seen[k] += v
+        steps += 1
+        assert steps < 100
+    assert seen["prefill_host"] > 0 and seen["decode_host"] > 0
+    assert seen["emit"] > 0
+    # greedy slots from their prompt's last token on go through
+    # `_spec_step` whole, which fetches for itself
+    assert (seen["spec"] > 0) == bool(spec)
+    assert (seen["fetch"] > 0) == (not spec)
+
+
+def test_step_reports_buckets_and_live_positions(params, n_devices):
+    """One sequence, prompt 13, chunk 4, blocks of 4: three prefill
+    chunks at positions 0, 4, 8 (the first two ticks dispatch no decode:
+    the sequence is mid-prefill), then decodes from position 12."""
+    eng = ServeEngine(params, CFG, EngineConfig(
+        max_batch=4, num_blocks=32, block_size=4, max_seq_len=64,
+        prefill_chunk=4,
+    ))
+    eng.add(Sequence(0, _prompt(50, 13), 2))
+    got = []
+    while eng.has_work():
+        st = eng.step()
+        got.append((st["prefill_calls"], st["decode_call"]))
+    assert got == [
+        ([(4, 1, 4 * 0 + 10)], None),
+        ([(4, 2, 4 * 4 + 10)], None),
+        ([(4, 4, 4 * 8 + 10)], (1, 4, 13)),
+        ([], (1, 4, 14)),
+    ]
+    # live positions do not depend on the chunking: a token at position
+    # p attends to p + 1, so the prompt's first 12 sum to 12 * 13 / 2
+    assert sum(c[2] for calls, _ in got for c in calls) == 12 * 13 // 2
+
+
+def test_all_parked_tick_still_partitions(params, n_devices):
+    """The early return (nothing could run, the youngest is preempted)
+    carries the same keys: its time is prefill_host and decode_host."""
+    eng = ServeEngine(params, CFG, EngineConfig(
+        max_batch=4, num_blocks=6, block_size=2, max_seq_len=16,
+    ))
+    for i in range(3):
+        eng.add(Sequence(i, _prompt(30 + i, 4), 6))
+    early = 0
+    for _ in range(200):
+        if not (eng.has_work() or eng.preempted):
+            break
+        st, wall = _timed_step(eng)
+        _check_phases(st, wall)
+        if st["batch"] == 0:
+            early += 1
+            assert st["decode_call"] is None
+            assert st["phase_s"]["fetch"] == st["phase_s"]["emit"] == 0.0
+        if eng.preempted and eng.kv.can_fit(4):
+            eng.add(eng.preempted.popleft())
+    assert early > 0, "no tick was ever all parked"

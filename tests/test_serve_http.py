@@ -14,7 +14,10 @@ Bars:
   record renders/gates through tools/goodput.py, and the committed
   serving baseline is self-consistent;
 - /metrics carries the serve_* series and live_top renders the serving
-  view from them.
+  view from them;
+- the loop thread's time is partitioned into phases that sum to its
+  wall time, the bucket and position counters equal a hand count, and
+  the same phases are nested spans in a profiler trace.
 """
 
 import http.client
@@ -289,6 +292,187 @@ def test_serving_ledger_conserves_and_renders(params, tmp_path,
     )
     assert r.returncode == 2
     assert "taxonomy mismatch" in r.stderr
+
+
+def _wait_done(req, timeout=60):
+    while True:
+        kind, payload = req.events.get(timeout=timeout)
+        if kind == "done":
+            return payload
+        assert kind == "token", payload
+
+
+def _family(registry, name):
+    """{label tuple: value} of one counter family, as a scrape shows it."""
+    out = {}
+    for line in registry.render().splitlines():
+        if line.startswith(name + "{"):
+            labels, _, value = line[len(name):].rpartition(" ")
+            out[labels] = float(value)
+    return out
+
+
+def test_loop_phases_and_positions_over_a_scripted_run(params, n_devices):
+    """Two sequences of known lengths (prompts 9 and 5, answers 3 and
+    2; chunks and blocks of 4), both queued before the loop starts, so
+    the schedule is fixed: tick 1 prefills A[0:4] (nothing decodes),
+    tick 2 A[4:8] and decodes A, tick 3 B[0:4] and decodes A and B, tick
+    4 decodes both and retires both."""
+    from distributed_neural_network_tpu.serve.scheduler import LOOP_PHASES
+
+    registry = MetricsRegistry()
+    engine = ServeEngine(params, CFG, EngineConfig(
+        max_batch=4, num_blocks=32, block_size=4, max_seq_len=64,
+        prefill_chunk=4,
+    ))
+    scheduler = ServeScheduler(
+        engine, SchedulerConfig(max_queue=8), registry=registry,
+    )
+    loop_s = registry.get("serve_loop_seconds_total")
+
+    def published():
+        return sum(loop_s.labels(phase=p).value for p in LOOP_PHASES)
+
+    # at a step's entry the registry holds whole ticks up to the last
+    # step's end, which the wrapper read on the scheduler's own clock
+    inner, ends, sums = engine.step, [], []
+
+    def step():
+        sums.append(published())
+        st = inner()
+        ends.append(scheduler.ledger.now())
+        return st
+
+    engine.step = step
+    reqs = [scheduler.submit(ServeRequest(
+        prompt=_prompt(700 + i, n), max_new_tokens=new,
+    )) for i, (n, new) in enumerate([(9, 3), (5, 2)])]
+    scheduler.start()
+    for r in reqs:
+        assert _wait_done(r)["status"] == "done"
+    scheduler.close(finalize=False)
+
+    assert registry.counter("serve_engine_steps_total").value == 4
+    assert len(ends) == 4
+    # the phases between the first and the last tick's end sum to the
+    # loop thread's wall time there (the last tick is published whole)
+    wall = ends[-1] - ends[0]
+    assert published() - sums[1] == pytest.approx(wall, rel=0.05)
+    phases = _family(registry, "serve_loop_seconds_total")
+    assert set(phases) == {'{phase="%s"}' % p for p in LOOP_PHASES}
+    assert phases['{phase="spec"}'] == 0.0
+    assert all(v >= 0.0 for v in phases.values())
+    for p in ("admit", "books", "prefill_host", "decode_host", "fetch",
+              "emit"):
+        assert phases['{phase="%s"}' % p] > 0.0, p
+
+    # the hand count: a prompt token at position p attends to p + 1, a
+    # decode at position p likewise (in closed form, whatever the chunks:
+    # P (P - 1) / 2 a prompt, N P + N (N - 1) / 2 an answer); padded is
+    # the bucket's shape
+    assert (4 * 0 + 10) + (4 * 4 + 10) + (4 * 0 + 10) == 9 * 8 // 2 + 5 * 4 // 2
+    assert 9 + (10 + 5) + (11 + 6) == (3 * 9 + 3) + (2 * 5 + 1)
+    assert _family(registry, "serve_prefill_positions_total") == {
+        '{kind="live"}': (4 * 0 + 10) + (4 * 4 + 10) + (4 * 0 + 10),
+        '{kind="padded"}': 4 * 1 * 4 + 4 * 2 * 4 + 4 * 1 * 4,
+    }
+    assert _family(registry, "serve_decode_positions_total") == {
+        '{kind="live"}': 9 + (10 + 5) + (11 + 6),
+        '{kind="padded"}': 1 * 4 * 4 + 2 * 4 * 4 + 2 * 4 * 4,
+    }
+    assert _family(registry, "serve_prefill_calls_total") == {
+        '{chunk="4",width_blocks="1"}': 2,
+        '{chunk="4",width_blocks="2"}': 1,
+    }
+    assert _family(registry, "serve_decode_calls_total") == {
+        '{batch="1",width_blocks="4"}': 1,
+        '{batch="2",width_blocks="4"}': 2,
+    }
+
+
+def test_profile_nests_fetch_in_step_in_tick(params, tmp_path, n_devices):
+    """The spans land in the profiler's own trace, nested by containment
+    on the loop thread, the tick's number in the event's stats."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    registry = MetricsRegistry()
+    engine = ServeEngine(params, CFG, EngineConfig(
+        max_batch=4, num_blocks=32, block_size=4, max_seq_len=64,
+    ))
+    scheduler = ServeScheduler(
+        engine, SchedulerConfig(max_queue=8), registry=registry,
+    ).start()
+
+    def three_ticks(upto):
+        req = scheduler.submit(ServeRequest(
+            prompt=_prompt(710, 2), max_new_tokens=2))
+        assert _wait_done(req)["status"] == "done"
+        # the answer's last token leaves inside the step; the beat is
+        # the last of the tick's books
+        deadline = time.monotonic() + 30
+        while registry.last_step() != upto:
+            assert time.monotonic() < deadline
+            time.sleep(0.005)
+        time.sleep(0.05)
+
+    three_ticks(3)  # the same shapes once before: nothing compiles below
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        three_ticks(6)
+    finally:
+        jax.profiler.stop_trace()
+        scheduler.close(finalize=False)
+    (path,) = glob.glob(str(
+        tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    lines = [
+        [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+          dict(ev.stats)) for ev in line.events
+         if ev.name.startswith("serve.")]
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:") for line in plane.lines
+    ]
+    (events,) = [ev for ev in lines if ev]  # one thread carries them all
+
+    def inside(child, parents):
+        return [p for p in parents
+                if p[1] <= child[1] and child[2] <= p[2]]
+
+    by_name = {}
+    for ev in events:
+        by_name.setdefault(ev[0], []).append(ev)
+    assert len(by_name["serve.tick"]) == len(by_name["serve.step"]) == 3
+    assert [t[3]["tick"] for t in by_name["serve.tick"]] == [3, 4, 5]
+    for fetch in by_name["serve.fetch"]:
+        (step,) = inside(fetch, by_name["serve.step"])
+        (tick,) = inside(step, by_name["serve.tick"])
+    assert len(by_name["serve.fetch"]) == 3
+    for name in ("serve.admit", "serve.books"):
+        assert all(inside(ev, by_name["serve.tick"])
+                   for ev in by_name[name]), name
+    for name in ("serve.decode_host", "serve.emit"):
+        assert all(inside(ev, by_name["serve.step"])
+                   for ev in by_name[name]), name
+
+
+def test_null_registry_loop_runs_and_publishes_nothing(params, n_devices):
+    from distributed_neural_network_tpu.utils.obs import NULL_REGISTRY
+
+    engine = ServeEngine(params, CFG, EngineConfig(
+        max_batch=4, num_blocks=32, block_size=4, max_seq_len=64,
+        prefill_chunk=4,
+    ))
+    scheduler = ServeScheduler(engine, SchedulerConfig(max_queue=8)).start()
+    assert scheduler.registry is NULL_REGISTRY
+    prompt = _prompt(720, 7)
+    req = scheduler.submit(ServeRequest(prompt=prompt, max_new_tokens=4))
+    done = _wait_done(req)
+    scheduler.close(finalize=False)
+    assert done["tokens"] == _oracle(params, prompt, 4)
+    assert NULL_REGISTRY.render() == ""
+    assert NULL_REGISTRY.get("serve_loop_seconds_total") is None
+    assert all(c.value == 0.0 for c in scheduler._m_loop_s.values())
 
 
 def test_committed_serve_baseline_is_valid():
