@@ -38,11 +38,18 @@ HBM byte convention (documented so manifests are comparable): per call,
 ``hbm_bytes = weight_bytes + gather out-bytes + scatter update-bytes +
 non-pool I/O bytes``. Weights stream once per call (the layer scan
 reads every layer's slice exactly once); the paged pools are charged
-by what the program actually touches - the gathered table span and the
-scattered updates - never by pool size, which is what makes a paged
-decode step memory-cheap in the first place. Elementwise FLOPs are
-excluded (matmul-dominated programs; ``flops`` counts dot_general
-only, scan multiplicity folded in).
+by what the JAXPR touches - the gathered table span and the scattered
+updates - never by pool size. That is a statement about the program as
+written, not as compiled: a jaxpr walk cannot see what the compiler
+adds to alias a donated pool, and until PR 25 the compiled programs
+did move the pool whole (as the layer scan's xs/ys it was two buffers:
+a pool copied, every layer's slab sliced out and written back - 44 % of
+the device's busy time at 1.3 B, PERF.md section 6). What guards the
+compiled side now is tests/test_serve_pool_inplace.py (no bucket
+program's ``temp_size_in_bytes`` reaches a layer's slab, every donated
+pool is aliased) and the live ``serve_program_temp_bytes{family}``
+gauge. Elementwise FLOPs are excluded (matmul-dominated programs;
+``flops`` counts dot_general only, scan multiplicity folded in).
 """
 
 from __future__ import annotations

@@ -314,6 +314,27 @@ def resume_sequence(desc: dict, *, seq_id: int | None = None,
     )
 
 
+def _read_rows(pool, l, idx):
+    """Rows ``idx`` of layer ``l`` of a stacked ``(L, n, ...)`` pool (a
+    KV pool, or its ``(L, num_blocks, H)`` int8 scales): ONE gather from
+    the flat ``(L * n, ...)`` view at ``l * n + idx``, so ``pool[l]`` is
+    never a value of the program. ``l`` may be an array that broadcasts
+    against ``idx`` (the drafter reads its E layers at once)."""
+    n = pool.shape[1]
+    return pool.reshape((-1,) + pool.shape[2:])[l * n + idx]
+
+
+def _write_rows(pool, l, idx, val, op: str = "set"):
+    """`_read_rows`' counterpart: ONE scatter (``op`` = "set" or "max")
+    of ``val`` into rows ``idx`` of layer ``l``, returned at the pool's
+    own shape. On a pool that is donated AND loop-carried the scatter
+    updates in place and moves ``val``'s bytes alone
+    (tests/test_serve_pool_inplace.py)."""
+    n = pool.shape[1]
+    flat = pool.reshape((-1,) + pool.shape[2:])
+    return getattr(flat.at[l * n + idx], op)(val).reshape(pool.shape)
+
+
 def _bucket(n: int, lo: int = 1) -> int:
     """Smallest power of two >= n (>= lo)."""
     b = lo
@@ -392,6 +413,8 @@ class ServeEngine:
         self._prefill_fns: dict = {}
         self._draft_fns: dict = {}
         self._verify_fns: dict = {}
+        # family -> largest compiled temp_size_in_bytes, set by warmup()
+        self.program_temp_bytes: dict = {}
         self.ticks = 0
         self.decode_tokens = 0
         self.prefill_tokens = 0
@@ -587,8 +610,8 @@ class ServeEngine:
         def step(params, k_pool, v_pool, k_scale, v_scale,
                  tok, pos, table, temps, keys):
             # tok/pos (B,), table (B, W), temps (B,), keys (B, 2);
-            # k_scale/v_scale (L, num_blocks, H) f32 (None-shaped dummies
-            # never reach here: the bf16 wrapper below drops them)
+            # k_scale/v_scale (L, num_blocks, H) f32, or None under a
+            # bf16 pool (an empty pytree in the scan's carry)
             x = params["embed"][tok].astype(dt)[:, None, :]
             x = x + _sinusoid_pe(pos, cfg.d_model, dt)[:, None, :]
             blk = table[jnp.arange(B), pos // bs]
@@ -599,7 +622,7 @@ class ServeEngine:
             live = (jnp.arange(S)[None, :] <= pos[:, None])[:, None, None, :]
             rows = blk[:, None] * bs + jnp.arange(bs)[None, :]  # (B, bs)
 
-            def append_q8(pool, scales, val):
+            def append_q8(pool, scales, l, val):
                 # quantize-on-append with a per-(block, head) running
                 # scale: a token whose amax outgrows the block's scale
                 # RE-QUANTIZES the block's existing slab under the new
@@ -610,18 +633,18 @@ class ServeEngine:
                 # sequence's own writes - preemption replay is bitwise
                 # (tested).
                 a = jnp.max(jnp.abs(val.astype(jnp.float32)), -1)  # (B,H)
-                s_old = scales[blk]                                # (B,H)
+                s_old = _read_rows(scales, l, blk)                 # (B,H)
                 s_new = jnp.maximum(s_old, a / _INT8_MAX)
                 ratio = jnp.where(
                     s_new > 0.0,
                     s_old / jnp.maximum(s_new, _SCALE_EPS), 1.0
                 )
-                slab = pool[rows].astype(jnp.float32)   # (B, bs, H, Dh)
-                slab = jnp.clip(
+                slab = _read_rows(pool, l, rows).astype(jnp.float32)
+                slab = jnp.clip(                        # (B, bs, H, Dh)
                     jnp.round(slab * ratio[:, None, :, None]),
                     -_INT8_MAX, _INT8_MAX,
                 ).astype(jnp.int8)
-                pool = pool.at[rows].set(slab)
+                pool = _write_rows(pool, l, rows, slab)
                 q8 = jnp.clip(
                     jnp.round(
                         val.astype(jnp.float32)
@@ -629,36 +652,48 @@ class ServeEngine:
                     ),
                     -_INT8_MAX, _INT8_MAX,
                 ).astype(jnp.int8)
-                pool = pool.at[flat].set(q8)
-                scales = scales.at[blk].set(s_new)
+                pool = _write_rows(pool, l, flat, q8)
+                scales = _write_rows(scales, l, blk, s_new)
                 return pool, scales
 
-            def layer_step(x, lcaches):
-                if quantized:
-                    lp, ck, cv, ksc, vsc = lcaches
-                else:
-                    lp, ck, cv = lcaches
-                    ksc = vsc = None
+            def layer_step(carry, layer):
+                # the pools ride the CARRY (layer index in xs): one
+                # buffer from the donated argument to the output, rows
+                # addressed at layer l, no layer's slab ever a value.
+                # The scan is not unrolled: a body of one layer feeds
+                # the matmuls from the stacked weights, a body of eight
+                # slices their weights out into a temporary first (17 ms
+                # of a 45 ms decode program at 1.3 B; PERF.md section 6)
+                x, k_pool, v_pool, k_scale, v_scale = carry
+                lp, l = layer
                 h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"]).astype(dt)
                 q = mm(h, lp["wq"]).reshape(B, 1, H, Dh)
                 k = mm(h, lp["wk"]).reshape(B, H, Dh)
                 v = mm(h, lp["wv"]).reshape(B, H, Dh)
                 if quantized:
-                    ck, ksc = append_q8(ck, ksc, k)
-                    cv, vsc = append_q8(cv, vsc, v)
-                    ks_q = ck[gather_idx]          # (B, S, H, Dh) int8
-                    vs_q = cv[gather_idx]
+                    k_pool, k_scale = append_q8(k_pool, k_scale, l, k)
+                    v_pool, v_scale = append_q8(v_pool, v_scale, l, v)
+                else:
+                    k_pool = _write_rows(k_pool, l, flat, k)
+                    v_pool = _write_rows(v_pool, l, flat, v)
+                ks_g = _read_rows(k_pool, l, gather_idx)   # (B, S, H, Dh)
+                vs_g = _read_rows(v_pool, l, gather_idx)
+                if quantized:
                     # per-slot scale view: same block-table addressing,
                     # one repeat per block (B, W, H) -> (B, S, H)
-                    k_slot = jnp.repeat(ksc[table], bs, axis=1)
-                    v_slot = jnp.repeat(vsc[table], bs, axis=1)
+                    k_slot = jnp.repeat(
+                        _read_rows(k_scale, l, table), bs, axis=1
+                    )
+                    v_slot = jnp.repeat(
+                        _read_rows(v_scale, l, table), bs, axis=1
+                    )
                     if attn_route == "pallas":
                         # the tuned decode kernel reads the int8 stream
                         # directly - dequant fused in its k-block loop
                         o = decode_cache_attention(
                             q.reshape(B, H, Dh),
-                            ks_q.transpose(0, 2, 1, 3),
-                            vs_q.transpose(0, 2, 1, 3),
+                            ks_g.transpose(0, 2, 1, 3),
+                            vs_g.transpose(0, 2, 1, 3),
                             pos,
                             k_scale=k_slot.transpose(0, 2, 1),
                             v_scale=v_slot.transpose(0, 2, 1),
@@ -666,45 +701,36 @@ class ServeEngine:
                         ).reshape(B, 1, H * Dh)
                     else:
                         ks = (
-                            ks_q.astype(jnp.float32) * k_slot[..., None]
+                            ks_g.astype(jnp.float32) * k_slot[..., None]
                         ).astype(dt).transpose(0, 2, 1, 3)
                         vs = (
-                            vs_q.astype(jnp.float32) * v_slot[..., None]
+                            vs_g.astype(jnp.float32) * v_slot[..., None]
                         ).astype(dt).transpose(0, 2, 1, 3)
                         o = xla_attend(q, ks, vs, live)
+                elif attn_route == "pallas":
+                    o = decode_cache_attention(
+                        q.reshape(B, H, Dh),
+                        ks_g.transpose(0, 2, 1, 3),
+                        vs_g.transpose(0, 2, 1, 3),
+                        pos, interpret=interpret,
+                    ).reshape(B, 1, H * Dh)
                 else:
-                    ck = ck.at[flat].set(k)
-                    cv = cv.at[flat].set(v)
-                    if attn_route == "pallas":
-                        o = decode_cache_attention(
-                            q.reshape(B, H, Dh),
-                            ck[gather_idx].transpose(0, 2, 1, 3),
-                            cv[gather_idx].transpose(0, 2, 1, 3),
-                            pos, interpret=interpret,
-                        ).reshape(B, 1, H * Dh)
-                    else:
-                        ks = ck[gather_idx].transpose(0, 2, 1, 3)
-                        vs = cv[gather_idx].transpose(0, 2, 1, 3)
-                        o = xla_attend(q, ks, vs, live)
+                    o = xla_attend(
+                        q, ks_g.transpose(0, 2, 1, 3),
+                        vs_g.transpose(0, 2, 1, 3), live,
+                    )
                 x = x + mm(o, lp["wo"])
                 h2 = _layer_norm(
                     x, lp["ln2_scale"], lp["ln2_bias"]
                 ).astype(dt)
                 h2 = jax.nn.gelu(mm(h2, lp["w1"]) + lp["b1"].astype(dt))
                 x = x + mm(h2, lp["w2"]) + lp["b2"].astype(dt)
-                if quantized:
-                    return x, (ck, cv, ksc, vsc)
-                return x, (ck, cv)
+                return (x, k_pool, v_pool, k_scale, v_scale), None
 
-            if quantized:
-                xs = (params["layers"], k_pool, v_pool, k_scale, v_scale)
-            else:
-                xs = (params["layers"], k_pool, v_pool)
-            x, out = jax.lax.scan(layer_step, x, xs, unroll=min(L, 8))
-            if quantized:
-                k_pool, v_pool, k_scale, v_scale = out
-            else:
-                k_pool, v_pool = out
+            (x, k_pool, v_pool, k_scale, v_scale), _ = jax.lax.scan(
+                layer_step, (x, k_pool, v_pool, k_scale, v_scale),
+                (params["layers"], jnp.arange(L)),
+            )
             h = _layer_norm(
                 x, params["lnf_scale"], params["lnf_bias"]
             ).astype(dt)
@@ -721,10 +747,16 @@ class ServeEngine:
         # the pools (and under int8 their scales) are donated: every
         # call site threads them through and rebinds the outputs, and
         # an un-donated pool double-buffers the engine's largest
-        # allocation for the life of the step. Params are NEVER donated
-        # (they are not returned - donating them would free the weights
-        # after the first call). servelint audits this contract
-        # per bucket (analysis/serve_trace.py).
+        # allocation for the life of the step. Donation alone does not
+        # make the update in place: the pools must also be LOOP-CARRIED
+        # through the layer scan (as scan xs/ys they are two buffers,
+        # and the compiler copies the pool to alias them - the parent of
+        # PR 25 moved 4.8 GB pools several times a program), which
+        # tests/test_serve_pool_inplace.py pins on the compiled
+        # programs. Params are NEVER donated (they are not returned -
+        # donating them would free the weights after the first call).
+        # servelint audits the donation contract per bucket
+        # (analysis/serve_trace.py).
         if quantized:
             fn = jax.jit(step, donate_argnums=(1, 2, 3, 4))
         else:
@@ -772,7 +804,7 @@ class ServeEngine:
                 jnp.arange(S)[None, :] <= pv[:, None]
             )[None, None, :, :]  # (1, 1, C, S)
 
-            def append_q8(pool, scales, val):
+            def append_q8(pool, scales, l, val):
                 # chunk form of the decode append: the chunk's per-block
                 # amax arrives by scatter-max (commutative ->
                 # deterministic under duplicate block ids), then the
@@ -784,19 +816,23 @@ class ServeEngine:
                     jnp.max(jnp.abs(val.astype(jnp.float32)), -1),
                     0.0,
                 )                                         # (C, H)
-                new_scales = scales.at[blkv].max(a / _INT8_MAX)
+                s_old = _read_rows(scales, l, table)      # (W, H)
+                scales = _write_rows(
+                    scales, l, blkv, a / _INT8_MAX, "max"
+                )
+                s_new = _read_rows(scales, l, table)
                 ratio = jnp.where(
-                    new_scales > 0.0,
-                    scales / jnp.maximum(new_scales, _SCALE_EPS), 1.0
-                )                                         # (nb, H)
-                ratio_slot = jnp.repeat(ratio[table], bs, axis=0)
-                slab = pool[gather_idx].astype(jnp.float32)  # (S, H, Dh)
-                slab = jnp.clip(
+                    s_new > 0.0,
+                    s_old / jnp.maximum(s_new, _SCALE_EPS), 1.0
+                )
+                ratio_slot = jnp.repeat(ratio, bs, axis=0)
+                slab = _read_rows(pool, l, gather_idx).astype(jnp.float32)
+                slab = jnp.clip(                          # (S, H, Dh)
                     jnp.round(slab * ratio_slot[..., None]),
                     -_INT8_MAX, _INT8_MAX,
                 ).astype(jnp.int8)
-                pool = pool.at[gather_idx].set(slab)
-                s_tok = new_scales[blkv]                  # (C, H)
+                pool = _write_rows(pool, l, gather_idx, slab)
+                s_tok = _read_rows(scales, l, blkv)       # (C, H)
                 q8 = jnp.clip(
                     jnp.round(
                         val.astype(jnp.float32)
@@ -804,37 +840,40 @@ class ServeEngine:
                     ),
                     -_INT8_MAX, _INT8_MAX,
                 ).astype(jnp.int8)
-                pool = pool.at[flat].set(q8)
-                return pool, new_scales
+                pool = _write_rows(pool, l, flat, q8)
+                return pool, scales
 
-            def layer_step(x, lcaches):
-                if quantized:
-                    lp, ck, cv, ksc, vsc = lcaches
-                else:
-                    lp, ck, cv = lcaches
-                    ksc = vsc = None
+            def layer_step(carry, layer):
+                # pools in the carry, rows at layer l: see _decode_fn
+                x, k_pool, v_pool, k_scale, v_scale = carry
+                lp, l = layer
                 h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"]).astype(dt)
                 q = mm(h, lp["wq"]).reshape(1, C, H, Dh)
                 k = mm(h, lp["wk"]).reshape(C, H, Dh)
                 v = mm(h, lp["wv"]).reshape(C, H, Dh)
                 if quantized:
-                    ck, ksc = append_q8(ck, ksc, k)
-                    cv, vsc = append_q8(cv, vsc, v)
-                    k_slot = jnp.repeat(ksc[table], bs, axis=0)  # (S, H)
-                    v_slot = jnp.repeat(vsc[table], bs, axis=0)
-                    ks = (
-                        ck[gather_idx].astype(jnp.float32)
-                        * k_slot[..., None]
-                    ).astype(dt)[None].transpose(0, 2, 1, 3)
-                    vs = (
-                        cv[gather_idx].astype(jnp.float32)
-                        * v_slot[..., None]
-                    ).astype(dt)[None].transpose(0, 2, 1, 3)
+                    k_pool, k_scale = append_q8(k_pool, k_scale, l, k)
+                    v_pool, v_scale = append_q8(v_pool, v_scale, l, v)
                 else:
-                    ck = ck.at[flat].set(k)
-                    cv = cv.at[flat].set(v)
-                    ks = ck[gather_idx][None].transpose(0, 2, 1, 3)
-                    vs = cv[gather_idx][None].transpose(0, 2, 1, 3)
+                    k_pool = _write_rows(k_pool, l, flat, k)
+                    v_pool = _write_rows(v_pool, l, flat, v)
+                ks = _read_rows(k_pool, l, gather_idx)       # (S, H, Dh)
+                vs = _read_rows(v_pool, l, gather_idx)
+                if quantized:
+                    k_slot = jnp.repeat(
+                        _read_rows(k_scale, l, table), bs, axis=0
+                    )                                        # (S, H)
+                    v_slot = jnp.repeat(
+                        _read_rows(v_scale, l, table), bs, axis=0
+                    )
+                    ks = (
+                        ks.astype(jnp.float32) * k_slot[..., None]
+                    ).astype(dt)
+                    vs = (
+                        vs.astype(jnp.float32) * v_slot[..., None]
+                    ).astype(dt)
+                ks = ks[None].transpose(0, 2, 1, 3)
+                vs = vs[None].transpose(0, 2, 1, 3)
                 scores = jnp.einsum(
                     "bqhd,bhsd->bhqs", q, ks
                 ).astype(jnp.float32)
@@ -851,19 +890,12 @@ class ServeEngine:
                 ).astype(dt)
                 h2 = jax.nn.gelu(mm(h2, lp["w1"]) + lp["b1"].astype(dt))
                 x = x + mm(h2, lp["w2"]) + lp["b2"].astype(dt)
-                if quantized:
-                    return x, (ck, cv, ksc, vsc)
-                return x, (ck, cv)
+                return (x, k_pool, v_pool, k_scale, v_scale), None
 
-            if quantized:
-                xs = (params["layers"], k_pool, v_pool, k_scale, v_scale)
-            else:
-                xs = (params["layers"], k_pool, v_pool)
-            x, out = jax.lax.scan(layer_step, x, xs, unroll=min(L, 8))
-            if quantized:
-                k_pool, v_pool, k_scale, v_scale = out
-            else:
-                k_pool, v_pool = out
+            (x, k_pool, v_pool, k_scale, v_scale), _ = jax.lax.scan(
+                layer_step, (x, k_pool, v_pool, k_scale, v_scale),
+                (params["layers"], jnp.arange(L)),
+            )
             h = _layer_norm(
                 x, params["lnf_scale"], params["lnf_bias"]
             ).astype(dt)
@@ -913,13 +945,16 @@ class ServeEngine:
             gather_idx = (
                 (table * bs)[:, :, None] + jnp.arange(bs)[None, None, :]
             ).reshape(B, S)
-            hk = k_pool[:E][:, gather_idx]     # (E, B, S, H, Dh)
-            hv = v_pool[:E][:, gather_idx]
+            layers = jnp.arange(E)[:, None, None]
+            hk = _read_rows(k_pool, layers, gather_idx)  # (E, B, S, H, Dh)
+            hv = _read_rows(v_pool, layers, gather_idx)
             if quantized:
                 k_slot = jnp.repeat(
-                    k_scale[:E][:, table], bs, axis=2
+                    _read_rows(k_scale, layers, table), bs, axis=2
                 )                               # (E, B, S, H)
-                v_slot = jnp.repeat(v_scale[:E][:, table], bs, axis=2)
+                v_slot = jnp.repeat(
+                    _read_rows(v_scale, layers, table), bs, axis=2
+                )
                 hk = (hk.astype(jnp.float32) * k_slot[..., None]).astype(dt)
                 hv = (hv.astype(jnp.float32) * v_slot[..., None]).astype(dt)
             hk = hk.transpose(0, 1, 3, 2, 4)   # (E, B, H, S, Dh)
@@ -1037,26 +1072,30 @@ class ServeEngine:
                 jnp.arange(S)[None, None, :] <= pv[:, :, None]
             )[:, None]                                       # (B,1,K,S)
 
-            def append_q8(pool, scales, val):
+            def append_q8(pool, scales, l, val):
                 # batch form of the chunked-prefill append: per-block
                 # amax by scatter-max (commutative -> deterministic
                 # under duplicate block ids), whole-table-span requant
                 # under the grown scales, then the K new tokens written
                 # at their final scales
                 a = jnp.max(jnp.abs(val.astype(jnp.float32)), -1)  # (B,K,H)
-                new_scales = scales.at[blkv].max(a / _INT8_MAX)
+                s_old = _read_rows(scales, l, table)         # (B, W, H)
+                scales = _write_rows(
+                    scales, l, blkv, a / _INT8_MAX, "max"
+                )
+                s_new = _read_rows(scales, l, table)
                 ratio = jnp.where(
-                    new_scales > 0.0,
-                    scales / jnp.maximum(new_scales, _SCALE_EPS), 1.0
-                )                                            # (nb, H)
-                ratio_slot = jnp.repeat(ratio[table], bs, axis=1)
-                slab = pool[gather_idx].astype(jnp.float32)  # (B,S,H,Dh)
-                slab = jnp.clip(
+                    s_new > 0.0,
+                    s_old / jnp.maximum(s_new, _SCALE_EPS), 1.0
+                )
+                ratio_slot = jnp.repeat(ratio, bs, axis=1)
+                slab = _read_rows(pool, l, gather_idx).astype(jnp.float32)
+                slab = jnp.clip(                             # (B,S,H,Dh)
                     jnp.round(slab * ratio_slot[..., None]),
                     -_INT8_MAX, _INT8_MAX,
                 ).astype(jnp.int8)
-                pool = pool.at[gather_idx].set(slab)
-                s_tok = new_scales[blkv]                     # (B, K, H)
+                pool = _write_rows(pool, l, gather_idx, slab)
+                s_tok = _read_rows(scales, l, blkv)          # (B, K, H)
                 q8 = jnp.clip(
                     jnp.round(
                         val.astype(jnp.float32)
@@ -1064,15 +1103,13 @@ class ServeEngine:
                     ),
                     -_INT8_MAX, _INT8_MAX,
                 ).astype(jnp.int8)
-                pool = pool.at[flat].set(q8)
-                return pool, new_scales
+                pool = _write_rows(pool, l, flat, q8)
+                return pool, scales
 
-            def layer_step(x, lcaches):
-                if quantized:
-                    lp, ck, cv, ksc, vsc = lcaches
-                else:
-                    lp, ck, cv = lcaches
-                    ksc = vsc = None
+            def layer_step(carry, layer):
+                # pools in the carry, rows at layer l: see _decode_fn
+                x, k_pool, v_pool, k_scale, v_scale = carry
+                lp, l = layer
                 h = _layer_norm(
                     x, lp["ln1_scale"], lp["ln1_bias"]
                 ).astype(dt)
@@ -1080,23 +1117,28 @@ class ServeEngine:
                 k = mm(h, lp["wk"]).reshape(B, K, H, Dh)
                 v = mm(h, lp["wv"]).reshape(B, K, H, Dh)
                 if quantized:
-                    ck, ksc = append_q8(ck, ksc, k)
-                    cv, vsc = append_q8(cv, vsc, v)
-                    k_slot = jnp.repeat(ksc[table], bs, axis=1)  # (B,S,H)
-                    v_slot = jnp.repeat(vsc[table], bs, axis=1)
-                    ks = (
-                        ck[gather_idx].astype(jnp.float32)
-                        * k_slot[..., None]
-                    ).astype(dt).transpose(0, 2, 1, 3)
-                    vs = (
-                        cv[gather_idx].astype(jnp.float32)
-                        * v_slot[..., None]
-                    ).astype(dt).transpose(0, 2, 1, 3)
+                    k_pool, k_scale = append_q8(k_pool, k_scale, l, k)
+                    v_pool, v_scale = append_q8(v_pool, v_scale, l, v)
                 else:
-                    ck = ck.at[flat].set(k)
-                    cv = cv.at[flat].set(v)
-                    ks = ck[gather_idx].transpose(0, 2, 1, 3)
-                    vs = cv[gather_idx].transpose(0, 2, 1, 3)
+                    k_pool = _write_rows(k_pool, l, flat, k)
+                    v_pool = _write_rows(v_pool, l, flat, v)
+                ks = _read_rows(k_pool, l, gather_idx)    # (B, S, H, Dh)
+                vs = _read_rows(v_pool, l, gather_idx)
+                if quantized:
+                    k_slot = jnp.repeat(
+                        _read_rows(k_scale, l, table), bs, axis=1
+                    )                                     # (B, S, H)
+                    v_slot = jnp.repeat(
+                        _read_rows(v_scale, l, table), bs, axis=1
+                    )
+                    ks = (
+                        ks.astype(jnp.float32) * k_slot[..., None]
+                    ).astype(dt)
+                    vs = (
+                        vs.astype(jnp.float32) * v_slot[..., None]
+                    ).astype(dt)
+                ks = ks.transpose(0, 2, 1, 3)
+                vs = vs.transpose(0, 2, 1, 3)
                 scores = jnp.einsum(
                     "bqhd,bhsd->bhqs", q, ks
                 ).astype(jnp.float32) / np.sqrt(Dh)
@@ -1112,19 +1154,12 @@ class ServeEngine:
                 ).astype(dt)
                 h2 = jax.nn.gelu(mm(h2, lp["w1"]) + lp["b1"].astype(dt))
                 x = x + mm(h2, lp["w2"]) + lp["b2"].astype(dt)
-                if quantized:
-                    return x, (ck, cv, ksc, vsc)
-                return x, (ck, cv)
+                return (x, k_pool, v_pool, k_scale, v_scale), None
 
-            if quantized:
-                xs = (params["layers"], k_pool, v_pool, k_scale, v_scale)
-            else:
-                xs = (params["layers"], k_pool, v_pool)
-            x, out = jax.lax.scan(layer_step, x, xs, unroll=min(L, 8))
-            if quantized:
-                k_pool, v_pool, k_scale, v_scale = out
-            else:
-                k_pool, v_pool = out
+            (x, k_pool, v_pool, k_scale, v_scale), _ = jax.lax.scan(
+                layer_step, (x, k_pool, v_pool, k_scale, v_scale),
+                (params["layers"], jnp.arange(L)),
+            )
             h = _layer_norm(
                 x, params["lnf_scale"], params["lnf_bias"]
             ).astype(dt)
@@ -1148,6 +1183,23 @@ class ServeEngine:
         self._verify_fns[(B, W)] = fn
         return fn
 
+    def _pools(self) -> tuple:
+        """The donated operands of a bucket program, in its order."""
+        if self.quantized:
+            return (self.k_pool, self.v_pool, self.k_scale, self.v_scale)
+        return (self.k_pool, self.v_pool)
+
+    def _run_writer(self, fn, *tail) -> tuple:
+        """Dispatch one pool-writing bucket program: the pools (and int8
+        scales) go in donated and are rebound from its leading outputs;
+        returns the outputs after them."""
+        pools = self._pools()
+        out = fn(self.params, *pools, *tail)
+        self.k_pool, self.v_pool = out[:2]
+        if self.quantized:
+            self.k_scale, self.v_scale = out[2:4]
+        return out[len(pools):]
+
     # ----------------------------------------------------------- warmup
 
     def warmup(self, *, max_width_blocks: int | None = None) -> int:
@@ -1155,99 +1207,67 @@ class ServeEngine:
         (all writes land in the scratch block, so live state is
         untouched). Without warmup each new bucket pays its XLA compile
         on the first request that needs it - a TTFT spike production
-        serving cannot afford. Returns the number of programs built."""
+        serving cannot afford. Returns the number of programs built,
+        and leaves in ``program_temp_bytes`` each family's largest
+        compiled ``temp_size_in_bytes`` (the scheduler's
+        ``serve_program_temp_bytes{family}``): a program that moved a
+        pool or a layer's slab instead of its rows would show there."""
         bs = self.kv.cfg.block_size
         widths = self._bucket_widths(max_width_blocks)
-        batches = []
-        b = 1
-        while b <= self.ecfg.max_batch:
-            batches.append(b)
-            b *= 2
         n = 0
-        for B in batches:
-            for W in widths:
-                fn = self._decode_fn(B, W)
-                args = (
-                    self.params, self.k_pool, self.v_pool,
-                ) + ((self.k_scale, self.v_scale) if self.quantized
-                     else ()) + (
-                    jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-                    jnp.zeros((B, W), jnp.int32),
-                    jnp.zeros((B,), jnp.float32),
-                    jnp.zeros((B, 2), jnp.uint32),
+
+        def pow2(cap):
+            return [1 << i for i in range(cap.bit_length())]
+
+        def warm(family, fn, *tail):
+            nonlocal n
+            args = (
+                self.draft_params if family == "draft" else self.params,
+                *self._pools(), *tail,
+            )
+            # the executable the call below runs (jit builds it once),
+            # asked for its temporaries: no second compile, no tick cost
+            mem = fn.lower(*args).compile().memory_analysis()
+            if mem is not None:
+                self.program_temp_bytes[family] = max(
+                    self.program_temp_bytes.get(family, 0),
+                    int(mem.temp_size_in_bytes),
                 )
+            if family == "draft":
+                fn(*args)  # read-only: no pool state to rebind
+            else:
+                self._run_writer(fn, *tail)
                 if self.quantized:
-                    (self.k_pool, self.v_pool, self.k_scale,
-                     self.v_scale, _, _) = fn(*args)
                     # warmup writes land in the scratch block; its scale
                     # is garbage by contract, but reset anyway so a
                     # fresh engine stays bitwise clean
                     self.k_scale = self.k_scale.at[:, 0, :].set(0.0)
                     self.v_scale = self.v_scale.at[:, 0, :].set(0.0)
-                else:
-                    self.k_pool, self.v_pool, _, _ = fn(*args)
-                n += 1
+            n += 1
+
+        def zeros(*shape):
+            return jnp.zeros(shape, jnp.int32)
+
+        for B in pow2(self.ecfg.max_batch):
+            for W in widths:
+                warm("decode", self._decode_fn(B, W), zeros(B), zeros(B),
+                     zeros(B, W), jnp.zeros((B,), jnp.float32),
+                     jnp.zeros((B, 2), jnp.uint32))
         if self.ecfg.prefill_chunk > 1:
-            chunks = []
-            c = 1
-            while c <= self.ecfg.prefill_chunk:
-                chunks.append(c)
-                c *= 2
-            for C in chunks:
+            for C in pow2(self.ecfg.prefill_chunk):
                 for W in widths:
-                    if C > W * bs:
-                        continue
-                    fn = self._prefill_fn(C, W)
-                    args = (
-                        self.params, self.k_pool, self.v_pool,
-                    ) + ((self.k_scale, self.v_scale) if self.quantized
-                         else ()) + (
-                        jnp.zeros((C,), jnp.int32), jnp.int32(0),
-                        jnp.zeros((W,), jnp.int32), jnp.int32(0),
-                    )
-                    if self.quantized:
-                        (self.k_pool, self.v_pool, self.k_scale,
-                         self.v_scale, _) = fn(*args)
-                        self.k_scale = self.k_scale.at[:, 0, :].set(0.0)
-                        self.v_scale = self.v_scale.at[:, 0, :].set(0.0)
-                    else:
-                        self.k_pool, self.v_pool, _ = fn(*args)
-                    n += 1
+                    if C <= W * bs:
+                        warm("prefill", self._prefill_fn(C, W), zeros(C),
+                             jnp.int32(0), zeros(W), jnp.int32(0))
         if self.spec_k:
             # the speculative bucket families: drafter + K-position
-            # verify per (batch, width). Dummy writes land in the
-            # scratch block (zero tables), like every other warmup call.
-            K = self.spec_k + 1
-            for B in batches:
+            # verify per (batch, width)
+            for B in pow2(self.ecfg.max_batch):
                 for W in widths:
-                    dfn = self._draft_fn(B, W)
-                    dargs = (
-                        self.draft_params, self.k_pool, self.v_pool,
-                    ) + ((self.k_scale, self.v_scale) if self.quantized
-                         else ()) + (
-                        jnp.zeros((B,), jnp.int32),
-                        jnp.zeros((B,), jnp.int32),
-                        jnp.zeros((B, W), jnp.int32),
-                    )
-                    dfn(*dargs)  # read-only: no pool state to restore
-                    n += 1
-                    vfn = self._verify_fn(B, W)
-                    vargs = (
-                        self.params, self.k_pool, self.v_pool,
-                    ) + ((self.k_scale, self.v_scale) if self.quantized
-                         else ()) + (
-                        jnp.zeros((B, K), jnp.int32),
-                        jnp.zeros((B,), jnp.int32),
-                        jnp.zeros((B, W), jnp.int32),
-                    )
-                    if self.quantized:
-                        (self.k_pool, self.v_pool, self.k_scale,
-                         self.v_scale, _) = vfn(*vargs)
-                        self.k_scale = self.k_scale.at[:, 0, :].set(0.0)
-                        self.v_scale = self.v_scale.at[:, 0, :].set(0.0)
-                    else:
-                        self.k_pool, self.v_pool, _ = vfn(*vargs)
-                    n += 1
+                    warm("draft", self._draft_fn(B, W), zeros(B), zeros(B),
+                         zeros(B, W))
+                    warm("verify", self._verify_fn(B, W),
+                         zeros(B, self.spec_k + 1), zeros(B), zeros(B, W))
         return n
 
     # ------------------------------------------------------------ the tick
@@ -1357,13 +1377,10 @@ class ServeEngine:
             )
             fn = self._draft_fn(Bd, W)
             t0 = time.perf_counter()
-            args = (
-                self.draft_params, self.k_pool, self.v_pool,
-            ) + ((self.k_scale, self.v_scale) if self.quantized
-                 else ()) + (
+            out_d = np.asarray(fn(       # asarray = device sync
+                self.draft_params, *self._pools(),
                 jnp.asarray(dtok), jnp.asarray(dpos), jnp.asarray(dtable),
-            )
-            out_d = np.asarray(fn(*args))  # asarray = device sync
+            ))
             draft_s = time.perf_counter() - t0
             for row, idx in enumerate(need_draft):
                 drafts[idx] = out_d[row]
@@ -1383,16 +1400,7 @@ class ServeEngine:
         fn = self._verify_fn(B, W)
         tail = (jnp.asarray(toks), jnp.asarray(pos0), jnp.asarray(table))
         t0 = time.perf_counter()
-        if self.quantized:
-            (self.k_pool, self.v_pool, self.k_scale, self.v_scale,
-             nxt) = fn(
-                self.params, self.k_pool, self.v_pool,
-                self.k_scale, self.v_scale, *tail,
-            )
-        else:
-            self.k_pool, self.v_pool, nxt = fn(
-                self.params, self.k_pool, self.v_pool, *tail,
-            )
+        (nxt,) = self._run_writer(fn, *tail)
         nxt = np.asarray(nxt)
         verify_s = time.perf_counter() - t0
 
@@ -1551,20 +1559,10 @@ class ServeEngine:
                     stats["prefill_calls"].append(
                         (C, W, n * seq.pos + n * (n + 1) // 2)
                     )
-                    tail = (
-                        jnp.asarray(toks), jnp.int32(seq.pos),
+                    self._run_writer(
+                        fn, jnp.asarray(toks), jnp.int32(seq.pos),
                         jnp.asarray(table), jnp.int32(n),
                     )
-                    if self.quantized:
-                        (self.k_pool, self.v_pool, self.k_scale,
-                         self.v_scale, _) = fn(
-                            self.params, self.k_pool, self.v_pool,
-                            self.k_scale, self.v_scale, *tail,
-                        )
-                    else:
-                        self.k_pool, self.v_pool, _ = fn(
-                            self.params, self.k_pool, self.v_pool, *tail,
-                        )
                     seq.pos += n
                     budget -= n
                     self.prefill_tokens += n
@@ -1642,20 +1640,11 @@ class ServeEngine:
                 stats["decode_call"] = (
                     B, W, int(pos.sum()) + len(batch)
                 )
-                tail = (
-                    jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(table),
-                    jnp.asarray(temps), jnp.asarray(keys),
+                nxt, _ = self._run_writer(
+                    fn, jnp.asarray(tok), jnp.asarray(pos),
+                    jnp.asarray(table), jnp.asarray(temps),
+                    jnp.asarray(keys),
                 )
-                if self.quantized:
-                    (self.k_pool, self.v_pool, self.k_scale, self.v_scale,
-                     nxt, _) = fn(
-                        self.params, self.k_pool, self.v_pool,
-                        self.k_scale, self.v_scale, *tail,
-                    )
-                else:
-                    self.k_pool, self.v_pool, nxt, _ = fn(
-                        self.params, self.k_pool, self.v_pool, *tail,
-                    )
         lap("decode_host")
         if batch:
             with TraceAnnotation("serve.fetch"):

@@ -284,6 +284,16 @@ class ServeScheduler:
         self._m_kv_bytes_total.set(
             engine.kv.cfg.usable_blocks * self._kv_block_bytes
         )
+        # beside the pool's bytes, what the bucket programs hold beyond
+        # their operands: a family whose figure nears a pool's size
+        # copies the pool instead of addressing its rows (empty until
+        # the engine has run warmup())
+        m_temp = r.gauge(
+            "serve_program_temp_bytes",
+            "Largest compiled temp_size_in_bytes of a bucket family",
+        )
+        for family, nbytes in engine.program_temp_bytes.items():
+            m_temp.labels(family=family).set(nbytes)
         self._m_kv_capacity = r.gauge(
             "serve_kv_capacity_sequences",
             "Concurrent max_seq_len sequences the pool holds",

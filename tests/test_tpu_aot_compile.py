@@ -162,3 +162,49 @@ def test_kernel_compiles_for_v5e(topo, case):
     assert mosaic_custom_calls(compiled) > 0, (
         f"{case}: no Mosaic custom call in the program"
     )
+
+
+@pytest.mark.parametrize("family,n", [
+    ("decode", 1), ("decode", 2), ("prefill", 1), ("prefill", 8),
+])
+def test_serve_program_holds_no_slab_on_v5e(topo, family, n):
+    """The TPU compiler's side of tests/test_serve_pool_inplace.py, in the
+    chip's own pool dtype: 16 layers, so the layer scan stays a loop (a
+    short one unrolls whole and hides a pool threaded as xs/ys, which cost
+    0.45-0.7 GB of temporaries here), and batch / chunk 1 as well, whose
+    single-row scatter the compiler turns into a dynamic-update-slice."""
+    from distributed_neural_network_tpu.models import transformer as tfm
+    from distributed_neural_network_tpu.serve.engine import (
+        EngineConfig,
+        ServeEngine,
+    )
+
+    cfg = tfm.TransformerConfig(
+        vocab_size=256, d_model=256, n_heads=2, n_layers=16, d_ff=512,
+        dtype=jnp.bfloat16,
+    )
+    width = 4
+    eng = ServeEngine(
+        tfm.init_params(jax.random.key(0), cfg), cfg,
+        EngineConfig(max_batch=2, num_blocks=1024, block_size=16,
+                     max_seq_len=width * 16, prefill_chunk=8,
+                     decode_impl="xla"),
+    )
+    i32 = jnp.int32
+    if family == "decode":
+        fn = eng._decode_fn(n, width)
+        tail = [((n,), i32), ((n,), i32), ((n, width), i32),
+                ((n,), jnp.float32), ((n, 2), jnp.uint32)]
+    else:
+        fn = eng._prefill_fn(n, width)
+        tail = [((n,), i32), ((), i32), ((width,), i32), ((), i32)]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    params, k_pool, v_pool = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (eng.params, eng.k_pool, eng.v_pool),
+    )
+    mem = fn.lower(
+        params, k_pool, v_pool, *_on_one_chip(topo, tail)
+    ).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < eng.k_pool[0].nbytes
+    assert mem.alias_size_in_bytes >= 2 * eng.k_pool.nbytes
