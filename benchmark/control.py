@@ -42,14 +42,13 @@ def main() -> int:
     from distributed_neural_network_tpu.runtime import enable_compile_cache
 
     enable_compile_cache()
-    model, tr = spec["config"], spec["traffic"]
-    dims = train._model_dims(model)
+    model, tr, family = spec["config"], spec["traffic"], spec["family"]
     out_dir = os.path.join(harness.ROOT, "chiprun_out", "control")
     os.makedirs(out_dir, exist_ok=True)
     passed_wrongly = []
     for seed in (int(s) for s in args.seeds.split(",")):
         batch_fn = weights.make_batch_fn(seed, batch=tr["batch"], seq=tr["seq"],
-                                         vocab=model["vocab_size"])
+                                         vocab=family.weights.vocab(model))
 
         def half(i):
             tok, tgt = batch_fn(i)
@@ -59,12 +58,12 @@ def main() -> int:
 
         devs = jax.devices()[:device["count"]]
 
-        ref = train.reference_steps(seed, dims, model, tr, batch_fn,
+        ref = train.reference_steps(seed, family, model, tr, batch_fn,
                                     devices=devs, keep_first_grad=True)
         ref_grad = ref.pop("first_grad")
 
         def steps(fn=batch_fn, prec="f32", fault=""):
-            return train.reference_steps(seed, dims, model, tr, fn, prec,
+            return train.reference_steps(seed, family, model, tr, fn, prec,
                                          devices=devs, fault=fault,
                                          against=ref_grad)
 

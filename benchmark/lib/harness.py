@@ -1,5 +1,6 @@
-"""What every cell shares: finding its files by name, the look for the chip,
-the compile counter, the per-layer readers and the result line."""
+"""What every cell shares: finding its files by name (configuration, family,
+traffic, limits, readers), the look for the chip, the compile counter and the
+result line."""
 
 from __future__ import annotations
 
@@ -9,6 +10,7 @@ import os
 import statistics
 import sys
 import time
+import types
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -20,9 +22,65 @@ def load_json(*parts) -> dict:
         return json.load(f)
 
 
+# A family is a directory `families/<name>/` of four modules. What the
+# harness itself calls of each, by the kind of cell; what a per-layer reader
+# calls of `arith` is between the reader and the families of its cells.
+FAMILY_PARTS = ("weights", "reference", "program", "arith")
+FAMILY_ENTRIES = {
+    "train": {"weights": ("shapes", "make", "vocab"),
+              "reference": ("loss_and_grads",), "program": ("config",)},
+    "serve": {"weights": ("make", "vocab"),
+              "reference": ("served_logits",), "program": ("config",)},
+}
+
+
+def families_present() -> list:
+    d = os.path.join(BENCH_DIR, "families")
+    return sorted(n for n in os.listdir(d)
+                  if os.path.isdir(os.path.join(d, n)) and n[:1].isalnum())
+
+
+def load_family(name: str, kind: str | None = None):
+    """The family's four parts, imported by name from `families/<name>/` as
+    a package of their own (so `from . import weights` works inside it): an
+    object with `.name`, `.weights`, `.reference`, `.program`, `.arith`. A
+    part that is missing, or lacks an entry a cell of `kind` calls, ends the
+    run."""
+    d = os.path.join(BENCH_DIR, "families", name)
+    if not os.path.isdir(d):
+        raise SystemExit(f"no family {name!r} under benchmark/families; "
+                         f"there is {families_present()}")
+    lacks = [p + ".py" for p in FAMILY_PARTS
+             if not os.path.isfile(os.path.join(d, p + ".py"))]
+    if lacks:
+        raise SystemExit(f"family {name!r} lacks {lacks}: a family is "
+                         f"{', '.join(p + '.py' for p in FAMILY_PARTS)} "
+                         f"under benchmark/families/{name}/")
+    pkg_name = "bench_family_" + "".join(
+        c if c.isalnum() else "_" for c in name)
+    for stale in [m for m in sys.modules
+                  if m == pkg_name or m.startswith(pkg_name + ".")]:
+        del sys.modules[stale]
+    pkg = types.ModuleType(pkg_name)
+    pkg.__path__ = [d]
+    sys.modules[pkg_name] = pkg
+    family = types.SimpleNamespace(name=name)
+    for part in FAMILY_PARTS:
+        setattr(family, part,
+                importlib.import_module(f"{pkg_name}.{part}"))
+    for part, entries in FAMILY_ENTRIES.get(kind, {}).items():
+        lacks = [e for e in entries
+                 if not callable(getattr(getattr(family, part), e, None))]
+        if lacks:
+            raise SystemExit(f"family {name!r}: {part}.py lacks {lacks}, "
+                             f"which a {kind!r} cell calls")
+    return family
+
+
 def load_spec(workload: str) -> dict:
-    """The cell's entry in BENCHMARK.json with its configuration, traffic and
-    limits, each found by the name the entry gives."""
+    """The cell's entry in BENCHMARK.json with its configuration, that
+    configuration's family, its traffic and its limits, each found by the
+    name the entry gives."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -33,6 +91,10 @@ def load_spec(workload: str) -> dict:
     cfg_entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     with open(os.path.join(ROOT, cfg_entry["file"])) as f:
         config = json.load(f)
+    if "family" not in config:
+        raise SystemExit(f"{cfg_entry['file']} names no \"family\"; "
+                         f"benchmark/families has {families_present()}")
+    traffic = load_json("traffic", cell["traffic"] + ".json")
 
     def reports(metric):
         return workload in metric.get("workloads", [workload])
@@ -43,7 +105,8 @@ def load_spec(workload: str) -> dict:
                  if reports(m) and m["moves"] in moved]
     return {
         "cell": cell, "config": config,
-        "traffic": load_json("traffic", cell["traffic"] + ".json"),
+        "family": load_family(config["family"], traffic.get("kind")),
+        "traffic": traffic,
         "limits": load_json("limits", workload + ".json"),
         "end_to_end": end_to_end, "per_layer": per_layer,
     }

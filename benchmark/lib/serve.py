@@ -14,7 +14,7 @@ import sys
 import threading
 import time
 
-from . import compare, harness, reference, traffic as tgen, weights, xtrace
+from . import compare, harness, traffic as tgen, xtrace
 
 
 def counters(registry) -> dict:
@@ -120,42 +120,41 @@ def pick_sample(finished: list, seed: int, n: int) -> list:
     return [longest] + rest[: n - 1]
 
 
-def reference_gaps(sample, prompts, *, seed, dims, n_heads, max_len,
+def reference_gaps(sample, prompts, *, seed, family, model, max_len,
                    precision="f32") -> dict:
-    """The reference once over each sampled prompt with its served tokens:
-    by how much each served token's logit lies below the reference's best.
-    With a lower `precision` also, under "control", the same numbers for the
-    token that this precision puts first at each position."""
-    import jax
+    """The family's reference once over each sampled prompt with its served
+    tokens: by how much each served token's logit lies below the reference's
+    best. With a lower `precision` also, under "control", the same numbers
+    for the token that this precision puts first at each position."""
     import numpy as np
 
-    params = weights.make_params(seed, **dims)
-    ref_fn = reference.make_served_logits(n_heads, "f32")
-    low_fn = (reference.make_served_logits(n_heads, precision)
-              if precision != "f32" else None)
     max_rows = max(len(r["tokens"]) for r in sample)
-    served, control, n_tokens = [], [], 0
-    for r in sample:
+    seqs = np.zeros((len(sample), max_len), np.int32)
+    rows = np.zeros((len(sample), max_rows), np.int32)
+    for i, r in enumerate(sample):
         prompt, toks = prompts[r["idx"]], r["tokens"]
-        seq = np.zeros((1, max_len), np.int32)
         full = (prompt + toks)[:max_len]
-        seq[0, :len(full)] = full
-        rows = np.zeros((max_rows,), np.int32)
-        rows[:len(toks)] = np.arange(len(prompt) - 1,
-                                     len(prompt) - 1 + len(toks))
-        logits = np.asarray(jax.device_get(ref_fn(params, seq, rows)))
-        logits = logits[:len(toks)]
-        served.append(compare.served_gap(logits, toks))
-        n_tokens += len(toks)
-        if low_fn is not None:
-            low = np.asarray(jax.device_get(low_fn(params, seq, rows)))
+        seqs[i, :len(full)] = full
+        rows[i, :len(toks)] = np.arange(len(prompt) - 1,
+                                        len(prompt) - 1 + len(toks))
+    served_logits = family.reference.served_logits
+    logits = served_logits(seed, model, seqs, rows, "f32")
+    low = (served_logits(seed, model, seqs, rows, precision)
+           if precision != "f32" else None)
+    served, control = [], []
+    for i, r in enumerate(sample):
+        n = len(r["tokens"])
+        served.append(compare.served_gap(logits[i, :n], r["tokens"]))
+        if low is not None:
             control.append(compare.served_gap(
-                logits, low[:len(toks)].argmax(axis=-1)))
+                logits[i, :n], low[i, :n].argmax(axis=-1)))
+
     def widest_and_mean(gaps):
         return {"served_logit_gap": float(max(g.max() for g in gaps)),
                 "served_logit_gap_mean": float(np.concatenate(gaps).mean())}
 
-    out = dict(widest_and_mean(served), tokens_compared=n_tokens)
+    out = dict(widest_and_mean(served),
+               tokens_compared=sum(len(r["tokens"]) for r in sample))
     if control:
         # under the program's names: the control stands in its place
         out["control"] = widest_and_mean(control)
@@ -171,7 +170,6 @@ class Server:
     def __init__(self, spec, seed, stages, wrap_engine=None):
         import jax.numpy as jnp
 
-        from distributed_neural_network_tpu.models import transformer as tfm
         from distributed_neural_network_tpu.serve.engine import (
             EngineConfig,
             ServeEngine,
@@ -183,19 +181,14 @@ class Server:
         )
         from distributed_neural_network_tpu.utils.obs import MetricsRegistry
 
-        model, tr = spec["config"], spec["traffic"]
+        model, tr, family = spec["config"], spec["traffic"], spec["family"]
         eng = tr["engine"]
-        self.dims = dict(d=model["n_embd"], n_layers=model["n_layer"],
-                         d_ff=model["n_inner"], vocab=model["vocab_size"])
-        cfg = tfm.TransformerConfig(
-            vocab_size=self.dims["vocab"], d_model=self.dims["d"],
-            n_heads=model["n_head"], n_layers=self.dims["n_layers"],
-            d_ff=self.dims["d_ff"], dtype=jnp.bfloat16)
+        cfg = family.program.config(model, tr, jnp.bfloat16)
         # in the type they are served in: `cfg.dtype`. (`serve/http.py main`
         # hands the engine float32 weights and every step casts them; at
         # these widths that does not fit beside a pool worth having -
         # PERF.md section 7.)
-        params = weights.make_params(seed, **self.dims, dtype=jnp.bfloat16)
+        params = family.weights.make(seed, model, dtype=jnp.bfloat16)
         engine = ServeEngine(params, cfg, EngineConfig(
             max_batch=eng["max_batch"], num_blocks=eng["num_blocks"],
             block_size=eng["block_size"], max_seq_len=eng["max_seq_len"],
@@ -269,7 +262,8 @@ def run(spec, *, seed, seconds, trace, device, t_start, wrap_engine=None,
     stages = harness.Stages(t_start)
     compiles = harness.CompileCounter()
     enable_compile_cache()
-    model, tr = spec["config"], spec["traffic"]
+    model, tr, family = spec["config"], spec["traffic"], spec["family"]
+    vocab = family.weights.vocab(model)
     workload = spec["cell"]["name"]
     stages.mark("import_and_device")
     server = Server(spec, seed, stages, wrap_engine)
@@ -294,7 +288,7 @@ def run(spec, *, seed, seconds, trace, device, t_start, wrap_engine=None,
         finally:
             jax.profiler.stop_trace()
 
-    doc = drive(server, traffic_file, seed=seed, vocab=model["vocab_size"],
+    doc = drive(server, traffic_file, seed=seed, vocab=vocab,
                 seconds=seconds, grace_s=tr["grace_s"],
                 out_file=harness.out_path(workload, seed, trace,
                                           "requests.json"),
@@ -304,7 +298,7 @@ def run(spec, *, seed, seconds, trace, device, t_start, wrap_engine=None,
     peak = harness.memory_peak_bytes(jax.devices()[:1])
     server.close()
     ticks, tick_ends = server.watch.ticks(), [r[0] for r in server.watch.rows]
-    n_programs, dims = server.n_programs, server.dims
+    n_programs = server.n_programs
     summary = None
     if trace:
         tr_all = xtrace.read_trace(
@@ -327,7 +321,7 @@ def run(spec, *, seed, seconds, trace, device, t_start, wrap_engine=None,
 
     # free the program's state, then the reference over a sample
     del server, ticks, tick_ends
-    pool = tgen.request_pool(tr, seed, model["vocab_size"])
+    pool = tgen.request_pool(tr, seed, vocab)
     prompts = {r["idx"]: pool[r["idx"] % len(pool)]["prompt"] for r in records}
     finished = [r for r in records if r["status"] == "completed"
                 and r["tokens"]]
@@ -336,7 +330,7 @@ def run(spec, *, seed, seconds, trace, device, t_start, wrap_engine=None,
     control = None
     if sample:
         gaps = reference_gaps(
-            sample, prompts, seed=seed, dims=dims, n_heads=model["n_head"],
+            sample, prompts, seed=seed, family=family, model=model,
             max_len=tr["engine"]["max_seq_len"], precision=precision)
         in_place = gaps.pop("control", None)
         numbers.update({k: (v, "") for k, v in gaps.items()})
@@ -375,7 +369,7 @@ def run(spec, *, seed, seconds, trace, device, t_start, wrap_engine=None,
         obs = {"trace": summary, "ticks": tick_rows, "lat": lat,
                "counters_window": doc["counters_window"],
                "counters_traced": state["counters_traced"],
-               "model": model, "traffic": tr,
+               "model": model, "family": family, "traffic": tr,
                "chips": 1, "seconds": seconds, "device_kind": device["kind"],
                "memory_peak_bytes": peak,
                "compiles_in_window": compiles.count}

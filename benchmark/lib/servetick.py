@@ -1,7 +1,8 @@
 """What the readers of the serving tick share: the growth of the program's
 counters over the traced window (`obs["counters_traced"]`, the registry as a
-scrape shows it at the window's two edges), and the work of the decode
-attention counted from live positions.
+scrape shows it at the window's two edges), and the least time for the decode
+attention, whose work the cell's family counts from live positions
+(`families/<family>/arith.py`).
 
 The program publishes a tick's seconds and positions together, after
 `serve_engine_steps_total` has counted it (`serve/scheduler.py
@@ -45,19 +46,6 @@ def pad_pct(obs: dict, family: str):
     return 100.0 * (1.0 - live / padded)
 
 
-def decode_attn_flops(model: dict, live: float) -> float:
-    """One query a sequence: 2*d for its scores and 2*d for the weighted sum
-    of the values, per layer, for each cached position it attends to."""
-    return 4.0 * model["n_layer"] * model["n_embd"] * live
-
-
-def decode_attn_bytes(model: dict, live: float,
-                      itemsize: int = KV_ITEMSIZE) -> float:
-    """Least HBM traffic: K and V of every live position read once per layer
-    (the query, the output and the block table are left out)."""
-    return 2.0 * model["n_layer"] * model["n_embd"] * itemsize * live
-
-
 def decode_attn_least_seconds(obs: dict):
     """(least seconds for the decode attention of the traced window, which
     peak bounds it), from live positions alone: the same whatever kernel,
@@ -65,6 +53,8 @@ def decode_attn_least_seconds(obs: dict):
     live = growth(obs, 'serve_decode_positions_total{kind="live"}')
     if not live:
         return None
+    work = obs["family"].arith
     return arith.roofline_seconds(
-        decode_attn_flops(obs["model"], live),
-        decode_attn_bytes(obs["model"], live), obs["device_kind"])
+        work.decode_attn_flops(obs["model"], live),
+        work.decode_attn_bytes(obs["model"], live, KV_ITEMSIZE),
+        obs["device_kind"])

@@ -11,11 +11,6 @@ import time
 from . import compare, harness, reference, weights, xtrace
 
 
-def _model_dims(model: dict) -> dict:
-    return dict(d=model["n_embd"], n_layers=model["n_layer"],
-                d_ff=model["n_inner"], vocab=model["vocab_size"])
-
-
 def _steady(intervals, n: int, tol: float) -> bool:
     if len(intervals) < n:
         return False
@@ -86,7 +81,6 @@ def run(spec, *, seed, seconds, trace, device, t_start, wrap_step=None):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from distributed_neural_network_tpu.models import transformer as tfm
     from distributed_neural_network_tpu.runtime import enable_compile_cache
     from distributed_neural_network_tpu.train import lm as lmtrain
 
@@ -94,18 +88,15 @@ def run(spec, *, seed, seconds, trace, device, t_start, wrap_step=None):
     compiles = harness.CompileCounter()
     enable_compile_cache()
     model, tr, chips = spec["config"], spec["traffic"], spec["cell"]["chips"]
+    family = spec["family"]
     workload = spec["cell"]["name"]
     devices = jax.devices()[:chips]
     stages.mark("import_and_device")
 
-    dims = _model_dims(model)
-    cfg = tfm.TransformerConfig(
-        vocab_size=dims["vocab"], d_model=dims["d"], n_heads=model["n_head"],
-        n_layers=dims["n_layers"], d_ff=dims["d_ff"], dtype=jnp.bfloat16,
-        remat=tr["remat"], remat_policy=tr["remat_policy"])
+    cfg = family.program.config(model, tr, jnp.bfloat16)
     mesh = lmtrain.create_lm_mesh(tr["dp"], 1, tr["tp"])
     _, p_shard, _ = lmtrain.make_lm_shardings(cfg, mesh, tr["optimizer"])
-    params = weights.make_params(seed, **dims, shardings=p_shard)
+    params = family.weights.make(seed, model, shardings=p_shard)
     mom = lmtrain.init_lm_momentum(params, mesh, tr["optimizer"])
     step = lmtrain.make_lm_train_step(
         cfg, mesh, lr=tr["lr"], momentum=tr["b1"], attn_impl=tr["attn"],
@@ -113,7 +104,8 @@ def run(spec, *, seed, seconds, trace, device, t_start, wrap_step=None):
     if wrap_step is not None:
         step = wrap_step(step)
     batch_fn = weights.make_batch_fn(
-        seed, batch=tr["batch"], seq=tr["seq"], vocab=model["vocab_size"],
+        seed, batch=tr["batch"], seq=tr["seq"],
+        vocab=family.weights.vocab(model),
         sharding=NamedSharding(mesh, P(lmtrain.DATA_AXIS, lmtrain.SEQ_AXIS)))
     loop = Loop(step, batch_fn, params, mom)
     del params, mom
@@ -140,7 +132,7 @@ def run(spec, *, seed, seconds, trace, device, t_start, wrap_step=None):
             prog["first_grad"] = jax.device_get(_scaled_bf16(m1, g_scale))
             del m1
         prog["losses"].append(loss)
-    p0 = weights.make_params(seed, **dims, shardings=p_shard)
+    p0 = family.weights.make(seed, model, shardings=p_shard)
     change = reference.diff_norms(loop.params, p0)
     del p0
     prog["losses"] = [float(x) for x in prog["losses"]]
@@ -202,7 +194,7 @@ def run(spec, *, seed, seconds, trace, device, t_start, wrap_step=None):
 
     # free the program's state, then the reference follows the same steps
     del loop, step
-    ref = reference_steps(seed, dims, model, tr, batch_fn, devices=devices,
+    ref = reference_steps(seed, family, model, tr, batch_fn, devices=devices,
                           against=prog.pop("first_grad"))
     prog["grad_diff"] = ref["grad_diff"]
     numbers = compare.train_numbers(prog, ref)
@@ -219,7 +211,8 @@ def run(spec, *, seed, seconds, trace, device, t_start, wrap_step=None):
                      "idle_gaps": summary["idle_gaps"]}
         obs = {"gaps_ms": gaps_ms, "trace": summary, "traced_rate": traced_rate,
                "traced_steps": len(tdone),
-               "model": model, "traffic": tr, "chips": chips,
+               "model": model, "family": family, "traffic": tr,
+               "chips": chips,
                "device_kind": device["kind"], "memory_peak_bytes": peak,
                "compiles_in_window": compiles.count}
         metrics = harness.read_per_layer(spec, obs)
@@ -233,11 +226,11 @@ def run(spec, *, seed, seconds, trace, device, t_start, wrap_step=None):
                "numbers": {k: [v, d] for k, (v, d) in numbers.items()}})
 
 
-def reference_steps(seed, dims, model, tr, batch_fn, precision="f32",
+def reference_steps(seed, family, model, tr, batch_fn, precision="f32",
                     devices=None, fault="", against=None,
                     keep_first_grad=False) -> dict:
-    """The plain reference through the same first steps: losses, the first
-    gradient's norms, the change's norms. On one device, or with its leaves
+    """The family's plain reference through the same first steps: losses, the
+    first gradient's norms, the change's norms. On one device, or with its leaves
     spread over the cell's chips where one cannot hold them. `against` is
     another side's first gradient (a tree on the host): the per-leaf norms of
     this side's difference from it come back as "grad_diff";
@@ -247,13 +240,12 @@ def reference_steps(seed, dims, model, tr, batch_fn, precision="f32",
 
     shard = None
     if devices is not None and len(devices) > 1:
-        shard = reference.spread_over(devices, weights.param_shapes(**dims))
+        shard = reference.spread_over(devices, family.weights.shapes(model))
     adam = tr["optimizer"] == "adam"
-    p = weights.make_params(seed, **dims, shardings=shard)
+    p, fn = family.reference.loss_and_grads(seed, model, tr, precision, fault,
+                                            shardings=shard)
     m = jax.tree.map(jnp.zeros_like, p)
     v = jax.tree.map(jnp.zeros_like, p) if adam else None
-    fn = reference.make_loss_and_grads(model["n_head"], precision,
-                                       tr["reference_rows_per_block"], fault)
     out = {"losses": []}
     for t in range(1, tr["check_steps"] + 1):
         tok, tgt = jax.device_get(batch_fn(t - 1))
@@ -280,7 +272,7 @@ def reference_steps(seed, dims, model, tr, batch_fn, precision="f32",
         out["losses"].append(float(loss))
         del g
     del m, v
-    p0 = weights.make_params(seed, **dims, shardings=shard)
+    p0 = family.weights.make(seed, model, shardings=shard)
     out["change"] = compare.flat_norms(
         jax.device_get(reference.diff_norms(p, p0)))
     return out

@@ -14,7 +14,7 @@ from dataclasses import dataclass, field
 
 OPS_LINE = "XLA Ops"
 ASYNC_LINE = "Async XLA Ops"
-HOST_PREFIX = "bench."
+HOST_PREFIX = ("bench.", "serve.")  # the harness's spans and the program's
 COLLECTIVE_PREFIXES = ("all-reduce", "all-gather", "reduce-scatter",
                        "collective-permute", "all-to-all", "send", "recv")
 
@@ -24,7 +24,7 @@ class Trace:
     """Events as (name, start_ns, duration_ns). `device[i]` is chip i's
     operation line (serial), `overlapped[i]` its line of asynchronous
     operations (collectives that run beside the compute); `host` holds the
-    harness's own annotations."""
+    annotations of the harness and of the program (`HOST_PREFIX`)."""
     device: dict = field(default_factory=dict)
     host: list = field(default_factory=list)
     overlapped: dict = field(default_factory=dict)
@@ -150,23 +150,51 @@ def is_collective(name: str) -> bool:
     return name.lower().startswith(COLLECTIVE_PREFIXES)
 
 
+def innermost(host, lo_ns: int, hi_ns: int) -> list:
+    """[lo, hi) cut at every annotation's start and end: sorted (start, end,
+    name) pieces, each named after the shortest annotation that covers it
+    (the innermost of a nest, whatever thread it is on), `unattributed` where
+    none does."""
+    starts = sorted((e for e in host if e[1] < hi_ns and e[1] + e[2] > lo_ns),
+                    key=lambda e: e[1])
+    cuts = sorted({lo_ns, hi_ns}
+                  | {max(s, lo_ns) for _, s, _ in starts}
+                  | {min(s + d, hi_ns) for _, s, d in starts})
+    pieces, active, nxt = [], [], 0
+    for a, b in zip(cuts, cuts[1:]):
+        while nxt < len(starts) and starts[nxt][1] <= a:
+            active.append(starts[nxt])
+            nxt += 1
+        active = [e for e in active if e[1] + e[2] > a]
+        # an annotation's whole length decides, of two alike the later start
+        owner = (min(active, key=lambda e: (e[2], -e[1]))[0] if active
+                 else "unattributed")
+        if pieces and pieces[-1][2] == owner and pieces[-1][1] == a:
+            pieces[-1][1] = b
+        else:
+            pieces.append([a, b, owner])
+    return pieces
+
+
 def idle_gaps(events, host, lo_ns: int, hi_ns: int) -> dict:
-    """Idle nanoseconds of one chip inside [lo, hi), by the harness
-    annotation that covers the middle of each gap (innermost one wins;
-    `unattributed` where none does)."""
+    """Idle nanoseconds of one chip inside [lo, hi), each gap split among
+    the annotations by overlap: every piece of it goes to the innermost
+    annotation that covers that piece (`unattributed` where none does)."""
     edges = [lo_ns]
     for s, e in union((s, s + d) for _, s, d in clip(events, lo_ns, hi_ns)):
         edges += [s, e]
     edges.append(hi_ns)
+    pieces, at = innermost(host, lo_ns, hi_ns), 0
     out: dict = {}
     for a, b in zip(edges[0::2], edges[1::2]):
-        if b <= a:
-            continue
-        mid, owner, width = (a + b) // 2, "unattributed", None
-        for name, s, d in host:
-            if s <= mid < s + d and (width is None or d < width):
-                owner, width = name, d
-        out[owner] = out.get(owner, 0) + (b - a)
+        while at < len(pieces) and pieces[at][1] <= a:
+            at += 1
+        i = at
+        while i < len(pieces) and pieces[i][0] < b:
+            s, e, owner = pieces[i]
+            if min(e, b) > max(s, a):
+                out[owner] = out.get(owner, 0) + min(e, b) - max(s, a)
+            i += 1
     return out
 
 

@@ -2,8 +2,8 @@
 could take for the traced window's decode attention over the time its Mosaic
 calls took. The work is counted from live positions
 (`serve_decode_positions_total{kind="live"}`): K and V of each read once per
-layer, 4 * d FLOPs each per layer (`lib/servetick.py`); memory bounds it, one
-FLOP a byte. So it reads the same whatever kernel, bucket or layout does the
+layer, 4 * d FLOPs each per layer (the family's `arith.decode_attn_flops`,
+`_bytes`, through `lib/servetick.py`); memory bounds it, one FLOP a byte. So it reads the same whatever kernel, bucket or layout does the
 work.
 
 The reader of the longdoc cell (moves serve_tokens_per_s)."""

@@ -1,4 +1,5 @@
-"""The whole step as a share of the bf16 peak: 2 FLOPs per matmul parameter for
+"""The whole step as a share of the bf16 peak: the model's forward FLOPs as its
+family counts them (`arith.forward_flops`: 2 per matmul parameter) for
 every prompt and generated token the server processed in the traced window
 (`serve_tokens_total{kind}` at its edges) over peak times the window. The
 attention over the cache is left out (no per-tick positions yet), so it
@@ -20,5 +21,5 @@ def read(obs):
     if n <= 0:
         return None
     peak = arith.peaks(obs["device_kind"])["flops_bf16"]
-    return 100.0 * arith.forward_flops(obs["model"], n, 0) / (
+    return 100.0 * obs["family"].arith.forward_flops(obs["model"], n, 0) / (
         peak * t["window_s"])

@@ -1,5 +1,6 @@
 """The least time the chip could take for the attention the traced steps
-need (the larger of FLOPs over peak and bytes over bandwidth, from shapes;
+need on one chip (the larger of FLOPs over peak and bytes over bandwidth, from
+shapes, as the cell's family counts them: `arith.flash_train_flops`, `_bytes`;
 at these shapes compute bounds it) over the Mosaic kernels' summed time."""
 from lib import arith
 
@@ -9,9 +10,9 @@ def read(obs):
     if not t.get("mosaic_s") or not obs.get("traced_steps"):
         return None
     tr = obs["traffic"]
-    rows = tr["batch"] // tr["dp"]
-    model = dict(obs["model"], n_embd=obs["model"]["n_embd"] // tr["tp"])
+    rows, work = tr["batch"] // tr["dp"], obs["family"].arith
     least, _ = arith.roofline_seconds(
-        arith.flash_train_flops(model, rows, tr["seq"]),
-        arith.flash_train_bytes(model, rows, tr["seq"]), obs["device_kind"])
+        work.flash_train_flops(obs["model"], rows, tr["seq"], tr["tp"]),
+        work.flash_train_bytes(obs["model"], rows, tr["seq"], tr["tp"]),
+        obs["device_kind"])
     return 100.0 * least * obs["traced_steps"] / t["mosaic_s"]

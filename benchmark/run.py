@@ -7,7 +7,9 @@ A new process: it holds the cell's chips, makes weights and inputs from the
 seed, warms up (set-up), measures for `--seconds`, compares what the timed
 path produced with the plain reference, and prints the result as the last
 line of standard output. The cell's kind ("train" or "serve") is named in its
-traffic file; everything else about a cell is data under `benchmark/`.
+traffic file, and its model's family (`families/<family>/`: weights, plain
+reference, program configuration, arithmetic) in its configuration's file;
+everything else about a cell is data under `benchmark/`.
 """
 
 import time

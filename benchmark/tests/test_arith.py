@@ -1,8 +1,10 @@
-"""The FLOP and byte functions against hand-worked values."""
+"""The FLOP and byte functions against hand-worked values: the chip's in
+`lib/arith.py`, the model's in its family's `arith.py`."""
 import pytest
 
-from lib import arith, harness
+from lib import arith as chip, harness
 
+arith = harness.load_family("gpt2").arith
 GPT2M = harness.load_json("configs", "gpt2-medium.json")
 CEREBRAS = harness.load_json("configs", "cerebras-gpt-1.3b.json")
 
@@ -30,10 +32,20 @@ def test_flash_kernels_need_1_44_tflop_a_step():
                                                                     rel=1e-3)
     # 12 tensors of 8 * 1024 * 1024 bf16 a layer
     assert arith.flash_train_bytes(GPT2M, 8, 1024) == 24 * 12 * 2 * 8 * 2**20
-    t, bound = arith.roofline_seconds(
+    t, bound = chip.roofline_seconds(
         arith.flash_train_flops(GPT2M, 8, 1024),
         arith.flash_train_bytes(GPT2M, 8, 1024), "TPU v5 lite")
     assert bound == "compute" and t == pytest.approx(7.32e-3, rel=1e-2)
+
+
+def test_a_tensor_parallel_chip_holds_its_share_of_the_heads():
+    # what `metrics/train_flash_roofline.py` asks for on dp 2 x tp 2: 4 rows
+    # and half of the width a chip
+    assert arith.flash_train_flops(CEREBRAS, 4, 2048, 2) == (
+        24 * 3.5 * 2.0 * 4 * 2048 * 2048 * 1024)
+    assert arith.flash_train_bytes(CEREBRAS, 4, 2048, 2) == (
+        24 * 12.0 * 4 * 2048 * 1024 * 2)
+    assert arith.kv_bytes_per_token(CEREBRAS) == 196_608
 
 
 def test_forward_flops():
@@ -43,4 +55,4 @@ def test_forward_flops():
 
 def test_unknown_device_is_an_error():
     with pytest.raises(SystemExit):
-        arith.peaks("TPU v9")
+        chip.peaks("TPU v9")

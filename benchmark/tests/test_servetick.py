@@ -8,6 +8,7 @@ import pytest
 from lib import harness
 
 CEREBRAS = harness.load_json("configs", "cerebras-gpt-1.3b.json")
+FAMILY = harness.load_family(CEREBRAS["family"])
 CELL = "cerebras-gpt-1.3b.serve-longdoc"
 
 # 16 ticks in the traced window; the registry already held 100
@@ -58,7 +59,7 @@ WANT = {
 
 def observation(counters_close):
     return {"counters_traced": (OPEN, counters_close), "model": CEREBRAS,
-            "device_kind": "TPU v5 lite",
+            "family": FAMILY, "device_kind": "TPU v5 lite",
             "trace": {"window_s": 4.0, "busy_s": 3.1, "mosaic_s": 0.096}}
 
 
@@ -108,5 +109,5 @@ def test_the_phases_sum_to_the_tick_and_the_roofline_is_memory_bound():
     least, bound = servetick.decode_attn_least_seconds(obs)
     assert bound == "memory"
     assert least == pytest.approx(0.03841, rel=1e-3)
-    assert servetick.decode_attn_bytes(CEREBRAS, 1) == 196_608
-    assert servetick.decode_attn_flops(CEREBRAS, 1) == 196_608
+    assert FAMILY.arith.decode_attn_bytes(CEREBRAS, 1) == 196_608
+    assert FAMILY.arith.decode_attn_flops(CEREBRAS, 1) == 196_608

@@ -25,6 +25,26 @@ def test_self_times_busy_and_gaps_by_hand():
     assert s["device_ops"][0] == ["while", pytest.approx(50e-9)]
 
 
+def test_a_gap_is_split_among_the_innermost_spans_by_overlap():
+    # one idle gap, 100-200, under a window that covers everything, a tick
+    # 90-210 and two of its phases: 100-130 goes to the one, 130-180 to the
+    # other, 180-200 to the tick itself; 300-340 lies outside every tick
+    ev = [("fusion.1", 0, 100), ("fusion.2", 200, 100)]
+    host = [("bench.traced_window", 0, 340), ("serve.tick", 90, 120),
+            ("serve.prefill_host", 95, 35), ("serve.decode_host", 130, 50)]
+    assert x.idle_gaps(ev, host, 0, 340) == {
+        "serve.prefill_host": 30, "serve.decode_host": 50, "serve.tick": 20,
+        "bench.traced_window": 40}
+    # the window's edge cuts a nest: the part inside still goes to the inner
+    assert x.idle_gaps(ev, host, 120, 340) == {
+        "serve.prefill_host": 10, "serve.decode_host": 50, "serve.tick": 20,
+        "bench.traced_window": 40}
+    s = x.summarize(x.Trace({0: ev}, host, {}), 0, 340)
+    assert s["busy_s"] == pytest.approx(200e-9)        # the seconds stay
+    assert s["idle_gaps"][0] == ["serve.decode_host", pytest.approx(50e-9)]
+    assert x.HOST_PREFIX == ("bench.", "serve.")
+
+
 def test_clip_cuts_events_at_the_window():
     assert x.clip([("a", 0, 10), ("b", 20, 10)], 5, 25) == [
         ("a", 5, 5), ("b", 20, 5)]

@@ -1,13 +1,17 @@
 """Tiny cells for the CPU: the harness's own path with the look for a chip
-skipped (`device` is handed in), at sizes a test run can hold."""
+skipped (`device` is handed in), at sizes a test run can hold. Every family
+under `benchmark/families/` brings its tiny configuration (`tiny.json`), and
+the tests that take `family` run once for each."""
 
+import functools
 import json
 import os
 import time
 
+from lib import harness
+
 DEVICE = {"platform": "cpu", "kind": "TPU v5 lite", "count": 1}
-MODEL = {"n_embd": 64, "n_layer": 3, "n_head": 4, "n_inner": 256,
-         "vocab_size": 101}
+FAMILIES = harness.families_present()
 TRAIN = {"kind": "train", "batch": 4, "seq": 32, "dp": 1, "tp": 1,
          "optimizer": "adam", "lr": 3e-4, "b1": 0.9, "attn": "flash",
          "remat": True, "remat_policy": "dots_saveable", "check_steps": 3,
@@ -27,24 +31,42 @@ SERVE = {"kind": "serve", "loop": "closed", "callers": 4, "pool": 8,
 NO_LIMIT = 1e9
 
 
-def train_spec(limits=None, **changes):
-    names = ("loss_step1", "loss_step2", "loss_step3", "grad_norm_gap",
-             "change_norm_gap", "grad_diff_norm")
+def model(family: str) -> dict:
+    return harness.load_json("families", family, "tiny.json")
+
+
+@functools.lru_cache(maxsize=None)
+def loaded(family: str, kind: str):
+    """One load a family and kind: a reload would drop what its modules
+    have jitted."""
+    return harness.load_family(family, kind)
+
+
+TRAIN_NUMBERS = ("loss_step1", "loss_step2", "loss_step3", "grad_norm_gap",
+                 "change_norm_gap", "grad_diff_norm")
+
+
+def train_spec(family, limits=None, **changes):
     traffic = dict(TRAIN, **changes)
     chips = traffic["dp"] * traffic["tp"]
-    return {"cell": {"name": f"tiny.train{chips}", "chips": chips},
-            "config": MODEL, "traffic": traffic,
-            "limits": limits or dict.fromkeys(names, NO_LIMIT),
+    return {"cell": {"name": f"tiny.{family}.train{chips}", "chips": chips},
+            "config": model(family),
+            "family": loaded(family, "train"),
+            "traffic": traffic,
+            "limits": limits or dict.fromkeys(TRAIN_NUMBERS, NO_LIMIT),
             "end_to_end": [], "per_layer": []}
 
 
-def serve_spec(tmp_path, limits=None, **changes):
+def serve_spec(family, tmp_path, limits=None, **changes):
     traffic = dict(SERVE, **changes)
     path = os.path.join(str(tmp_path), "traffic.json")
     with open(path, "w") as f:
         json.dump(traffic, f)
-    return {"cell": {"name": "tiny.serve", "chips": 1, "traffic": "tiny"},
-            "traffic_file": path, "config": MODEL, "traffic": traffic,
+    return {"cell": {"name": f"tiny.{family}.serve", "chips": 1,
+                     "traffic": "tiny"},
+            "traffic_file": path, "config": model(family),
+            "family": loaded(family, "serve"),
+            "traffic": traffic,
             "limits": limits or {"served_logit_gap": NO_LIMIT,
                                  "requests_short": 0},
             "end_to_end": [{"name": "serve_tokens_per_s", "unit": "tokens/s"},
