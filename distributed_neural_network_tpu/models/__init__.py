@@ -1,1 +1,13 @@
 """Subpackage: models."""
+
+import importlib
+
+# The model modules a configuration file's "family" can name
+# (`lm_train.py --model-config`); GPT-2's block (`transformer`) is built from
+# the trainer's own flags. A module is imported when its family is asked for.
+FAMILIES = {"nemotron_h": "nemotron_h"}
+
+
+def family_module(family: str):
+    """The module that runs `family` (KeyError where there is none)."""
+    return importlib.import_module(f"{__name__}.{FAMILIES[family]}")

@@ -35,6 +35,7 @@ Design choices, TPU-first:
 from __future__ import annotations
 
 import os
+import sys
 from dataclasses import dataclass
 
 import jax
@@ -58,6 +59,19 @@ from ..runtime import on_tpu
 # throughput of "ring" at scale. "flash" = Pallas TPU flash kernel for the
 # LOCAL (seq_axis=None) case - long contexts on one chip (ops/flash.py).
 ATTN_IMPLS = ("full", "ring", "ulysses", "zigzag", "flash")
+
+# What `train/lm.py` asks of a model's module (reached from a configuration
+# as `cfg.module`): `NAME`, `init_params`, `param_specs`, `apply_hidden` ->
+# (hidden, aux), `refuse_axes`, and what `aux` is. Here it is the expert
+# layers' balancing loss: averaged over the mesh and added to the loss at
+# `aux_weight`. (Where it is not a loss, it is counts: summed over the mesh
+# and handed out as the step's last output.)
+NAME = "transformer"
+AUX_IS_LOSS = True
+
+
+def refuse_axes(*, seq_axis=None, tp_axis=None, ep_axis=None) -> None:
+    """This model runs under every axis `train/lm.py` lays out."""
 
 
 @dataclass(frozen=True)
@@ -116,6 +130,11 @@ class TransformerConfig:
     def head_dim(self) -> int:
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def module(self):
+        """The module that runs this configuration."""
+        return sys.modules[__name__]
 
 
 def init_params(key: jax.Array, cfg: TransformerConfig):

@@ -191,3 +191,22 @@ def flash_local_attention(q, k, v, *, causal: bool = True,
         raise ValueError(f"unknown flash impl {impl!r} (use 'own' or 'lib')")
     return flash_mha(q, k, v, causal=causal,
                      blocks=tuned_blocks(q.shape[1], q.shape[-1]))
+
+
+def grouped_query_flash_attention(q, k, v, *, causal: bool = True):
+    """q (B, S, H, D) against k/v (B, S, H_kv, D), H a multiple of H_kv:
+    query head h reads KV head h // (H / H_kv). The kernels behind
+    `flash_local_attention` assume H_kv == H, so each KV head is repeated
+    for its query heads first: a copy of (H / H_kv - 1) x the K and V bytes
+    (and its sum in the backward pass) that a grouped index map in the
+    kernels' block specs would save (`ops/flash_pallas.py`)."""
+    import jax.numpy as jnp
+
+    h, h_kv = q.shape[2], k.shape[2]
+    if h % h_kv:
+        raise ValueError(
+            f"{h} query heads do not divide into {h_kv} key-value heads")
+    if h != h_kv:
+        k = jnp.repeat(k, h // h_kv, axis=2)
+        v = jnp.repeat(v, h // h_kv, axis=2)
+    return flash_local_attention(q, k, v, causal=causal)

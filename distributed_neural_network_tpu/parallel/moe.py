@@ -45,6 +45,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .collectives import vary_like
+
 
 def expert_capacity(n_tokens: int, n_experts: int, top_k: int, factor: float) -> int:
     """Per-source-device capacity slots per expert (static)."""
@@ -197,3 +199,202 @@ def moe_ffn(
         z = jax.scipy.special.logsumexp(logits, axis=-1)
         aux = aux + jnp.float32(z_loss_weight) * jnp.mean(z * z)
     return out, aux
+
+
+# ------------------------------------------- a chip's share of the experts
+#
+# The layer below is the expert layer as expert parallelism leaves it on one
+# chip: the router scores every routed expert of the model, the chip holds
+# `w_up.shape[0]` of them (the experts `first`, `first + 1`, ...), and it
+# computes the part of the layer's result that its own experts give, plus
+# the shared expert, which every chip computes alike. What the absent
+# experts would add is not computed and nothing stands in for it: across
+# chips it arrives by the exchange, which a later PR adds.
+#
+# No token is ever dropped. The pairs (token, chosen expert) whose expert
+# is held are sorted by expert into a buffer sized for the worst case (every
+# pair of every token lands here) at a static shape, each expert's rows
+# starting on a tile boundary, and the two products of the expert MLP are
+# grouped products over that buffer: one tile of rows at a time against its
+# expert's matrices, the tiles past the last held pair included. The buffer
+# is an index map and is never filled: a tile's rows are gathered when its
+# turn comes.
+
+
+def sigmoid_topk_route(x, wr, bias, *, top_k: int, scale: float):
+    """x (T, d), wr (d, E), bias (E,) -> (experts (T, k) int32, weights
+    (T, k) float32). Scores are sigmoid(x wr) in float32 at the highest
+    matmul precision (a choice that flips with the rounding of x moves an
+    expert's gradient in the first order); the k largest of score + bias
+    are chosen, and their weights are the unbiased scores over their sum,
+    times `scale`. The bias selects only: no gradient reaches it."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), wr.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, experts = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    return experts, scale * chosen / chosen.sum(-1, keepdims=True)
+
+
+def relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def _tiles(src, valid, row_weight, tile_group):
+    """What the tile loop scans: each tile's rows and its expert."""
+    return tuple(v.reshape(tile_group.shape[0], -1)
+                 for v in (src, valid, row_weight)) + (tile_group,)
+
+
+@jax.custom_vjp
+def grouped_expert_mlp(x, w_up, w_down, row_weight, src, valid, tile_group):
+    """The held experts' MLPs over the sorted pairs, one tile of rows at a
+    time: x (T, d), w_up (H, d, f), w_down (H, f, d); row m of the (never
+    materialised) buffer is token `src[m]` where `valid[m]`, weighs
+    `row_weight[m]` and belongs to expert `tile_group[m // tile]` with all
+    of its tile (H: no expert owns the tile, and its rows are all nought).
+    Each tile is gathered, taken through `relu(. W_up)^2 W_down` of its
+    expert - the grouped product up and the grouped product down -
+    weighted, and added to its tokens' rows of the float32 result (T, d).
+    The backward pass walks the same tiles again and recomputes each;
+    nothing of the size of the buffer is kept or made.
+
+    EVERY tile of the worst-case buffer is multiplied, the unowned ones
+    too (noughts, against the last expert): the step's time then does not
+    depend on where the router sends the tokens, which under Adam from
+    step 0 with no warm-up swings from nothing to everything within ten
+    steps (`PERF.md` section 6, PR 27). Skipping the unowned tiles (a
+    `lax.cond` on `g <= last`, measured: 581 ms a step for 1,015) is owed
+    to this layer, not a gain for a later PR to claim: it comes back with
+    the schedule that keeps the load even (`ROADMAP.md` Reach A3)."""
+    last, f32 = w_up.shape[0] - 1, jnp.float32
+
+    def one(y, tile):
+        s, ok, wr, g = tile
+        g = jnp.minimum(g, last)    # an unowned tile: all nought, any expert
+        xb = jnp.where(ok[:, None], x[s], 0)
+        yb = relu2(xb @ w_up[g]) @ w_down[g]
+        return y.at[s].add(yb.astype(f32) * wr[:, None]), None
+
+    y0 = vary_like(jnp.zeros(x.shape, f32), x, w_up, w_down, row_weight)
+    y, _ = jax.lax.scan(one, y0, _tiles(src, valid, row_weight, tile_group))
+    return y
+
+
+def _grouped_expert_mlp_bwd(res, dy):
+    x, w_up, w_down, row_weight, src, valid, tile_group = res
+    last, f32, dt = w_up.shape[0] - 1, jnp.float32, x.dtype
+
+    def one(carry, tiled):
+        s, ok, wr, g = tiled
+        g = jnp.minimum(g, last)
+        dx, d_up, d_down = carry
+        xb = jnp.where(ok[:, None], x[s], 0)
+        r = jax.nn.relu(xb @ w_up[g])
+        h = r * r
+        gy = dy[s].astype(dt)
+        # y_row = wr (h W_down): one product gy W_down^T gives both the
+        # weight's gradient, <h, gy W_down^T>, and, times wr, h's
+        d_h = gy @ w_down[g].T
+        d_wr = jnp.sum(h.astype(f32) * d_h, axis=-1)
+        d_down = d_down.at[g].add(jnp.matmul(
+            h.T, (gy * wr[:, None]).astype(dt), preferred_element_type=f32))
+        d_pre = (d_h * wr[:, None] * (2 * r)).astype(dt)
+        d_up = d_up.at[g].add(
+            jnp.matmul(xb.T, d_pre, preferred_element_type=f32))
+        d_xb = jnp.where(ok[:, None], d_pre @ w_up[g].T, 0)
+        return (dx.at[s].add(d_xb.astype(f32)), d_up, d_down), d_wr
+
+    like = (x, w_up, w_down, row_weight, dy)
+    carry = tuple(vary_like(jnp.zeros(t.shape, f32), *like)
+                  for t in (x, w_up, w_down))
+    (dx, d_up, d_down), d_wr = jax.lax.scan(
+        one, carry, _tiles(src, valid, row_weight, tile_group))
+    return (dx.astype(dt), d_up.astype(w_up.dtype),
+            d_down.astype(w_down.dtype), d_wr.reshape(-1), None, None, None)
+
+
+grouped_expert_mlp.defvjp(
+    lambda *args: (grouped_expert_mlp(*args), args), _grouped_expert_mlp_bwd)
+
+
+def held_pairs_layout(experts, *, first: int, n_held: int, tile: int):
+    """experts (T, k), the chosen experts of every token over all routed
+    experts -> where each pair whose expert is held (`first <= e < first +
+    n_held`) lies in the buffer, at static shapes sized for every pair
+    landing here: `src` (M,) the token of each row, `valid` (M,), `pos`
+    (T, k) the row of each pair (M where its expert is absent), `tile_group`
+    (M / tile,) the held expert that owns each tile (`n_held` for none),
+    and the counts `load` (n_held,) and `absent` ()."""
+    t, k = experts.shape
+    n_pairs = t * k
+    m = (-(-n_pairs // tile) + n_held) * tile
+    local = experts.reshape(-1) - first
+    group = jnp.where((local >= 0) & (local < n_held), local, n_held)
+    # (a comparison and a sum, not a scatter-add of every pair into a few
+    # bins: no collisions whose cost moves with the routing)
+    counts = jnp.sum(group[:, None] == jnp.arange(n_held + 1), axis=0,
+                     dtype=jnp.int32)
+    load = counts[:n_held]
+    padded = -(-load // tile) * tile
+    ends = jnp.cumsum(padded)
+    row0 = jnp.append(ends - padded, m)          # the absent pairs: no row
+    order = jnp.argsort(group, stable=True)      # pairs by expert, absent last
+    by_group = group[order]
+    rank = jnp.arange(n_pairs, dtype=jnp.int32) - (
+        jnp.cumsum(counts) - counts)[by_group]
+    row = jnp.where(by_group < n_held, row0[by_group] + rank, m)
+    pos = jnp.zeros((n_pairs,), jnp.int32).at[order].set(row).reshape(t, k)
+    # a row no pair owns names a token of its own (and weighs nought): rows
+    # that all named token 0 would collide in the scatter-adds, whose time
+    # on the chip grows with collisions
+    src = (jnp.arange(m, dtype=jnp.int32) % t).at[row].set(
+        order // k, mode="drop")
+    valid = jnp.zeros((m,), bool).at[row].set(True, mode="drop")
+    tile_group = jnp.searchsorted(
+        ends, jnp.arange(m // tile, dtype=jnp.int32) * tile,
+        side="right").astype(jnp.int32)
+    return src, valid, pos, tile_group, load, counts[n_held]
+
+
+TILE = 512  # rows of one tile of the grouped product
+
+
+def moe_held_ffn(x, wr, bias, w_up, w_down, shared_up, shared_down, *,
+                 first: int, top_k: int, scale: float):
+    """A chip's share of a sigmoid-routed expert layer on a flat batch.
+
+    x (T, d) in the compute dtype; wr (d, E) and bias (E,) over all E
+    routed experts; w_up (H, d, f), w_down (H, f, d) the H experts held
+    here, which are experts `first .. first + H - 1`; the shared expert
+    (d, fs), (fs, d). Every expert is relu(x W_up)^2 W_down with no gate
+    and no bias. Returns (y (T, d), stats): y is the weighted sum over each
+    token's chosen experts that are held, plus the shared expert; stats
+    counts, as int32, the pairs routed to `held` and to `absent` experts,
+    the pairs `dropped` (held pairs that found no row: 0 by construction),
+    and each held expert's `load` (H,).
+    """
+    dt = x.dtype
+    n_held = w_up.shape[0]
+    # under shard_map the replicated matrices become device-varying before
+    # the grouped product (a custom VJP), so that typed autodiff sums their
+    # gradients over the mesh as it does for every other leaf
+    w_up, w_down = (vary_like(w.astype(dt), x) for w in (w_up, w_down))
+    with jax.named_scope("lm.moe.route"):
+        experts, weights = sigmoid_topk_route(x, wr, bias, top_k=top_k,
+                                              scale=scale)
+        src, valid, pos, tile_group, load, absent = held_pairs_layout(
+            experts, first=first, n_held=n_held, tile=TILE)
+        # each row's weight, for the combine (differentiable in `weights`)
+        row_weight = jnp.zeros(src.shape, jnp.float32).at[pos].set(
+            weights, mode="drop")
+    with jax.named_scope("lm.moe.experts"):
+        y = grouped_expert_mlp(x, w_up, w_down, row_weight, src, valid,
+                               tile_group).astype(dt)
+    with jax.named_scope("lm.moe.shared"):
+        y = y + relu2(x @ shared_up.astype(dt)) @ shared_down.astype(dt)
+    held = load.sum()
+    stats = {"held": held, "absent": absent,
+             "dropped": held - valid.sum().astype(jnp.int32), "load": load}
+    return y, stats

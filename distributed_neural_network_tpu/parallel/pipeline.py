@@ -314,6 +314,15 @@ def pipeline_lm_loss(
     return loss
 
 
+def _transformer_only(cfg) -> None:
+    if not isinstance(cfg, tfm.TransformerConfig):
+        raise ValueError(
+            f"{cfg.module.NAME}: the pipeline "
+            "(axis 'pipe') is not supported - its stages scan one stacked "
+            "kind of block; this model's layers are of several kinds and "
+            "it runs under data parallelism only")
+
+
 def pp_wiring(cfg: tfm.TransformerConfig, mesh: Mesh):
     """(tp, ep, sync_axes, specs) for a pipeline mesh - the single source
     of the axis/spec derivation shared by make_pp_train_step,
@@ -321,6 +330,7 @@ def pp_wiring(cfg: tfm.TransformerConfig, mesh: Mesh):
     agree or shardings silently desynchronize)."""
     from ..train.lm import _ep_axis
 
+    _transformer_only(cfg)
     tp = TP_AXIS if mesh.shape.get(TP_AXIS, 1) > 1 else None
     ep = _ep_axis(cfg, mesh)
     sync = tuple(a for a in (DATA_AXIS,) if a in mesh.axis_names)
@@ -465,6 +475,7 @@ def make_pp_train_step(
     carry the 1/dp shard. Matches "end" up to float reassociation; not
     compatible with expert parallelism.
     """
+    _transformer_only(cfg)
     pp = mesh.shape.get(PIPE_AXIS, 1)
     v = interleave
     if v < 1:

@@ -351,6 +351,13 @@ class ServeEngine:
     between ticks."""
 
     def __init__(self, params, cfg: TransformerConfig, ecfg: EngineConfig):
+        if not isinstance(cfg, TransformerConfig):
+            raise ValueError(
+                f"{cfg.module.NAME}: the serving "
+                "engine does not run this model - its Mamba-2 layers carry "
+                "a recurrent state that the paged KV cache has no place "
+                "for, and the engine's programs know one kind of block"
+            )
         if cfg.n_experts:
             raise ValueError(
                 "the serving engine supports dense models; MoE decode "

@@ -73,7 +73,7 @@ def _named_spec_leaves(specs):
 def _ep_axis(cfg, mesh: Mesh) -> str | None:
     """Experts shard over the data axis (GShard convention) when present."""
     dp = mesh.shape.get(DATA_AXIS, 1)
-    if cfg.n_experts and dp > 1:
+    if getattr(cfg, "n_experts", 0) and dp > 1:
         if cfg.n_experts % dp:
             raise ValueError(
                 f"n_experts ({cfg.n_experts}) must be divisible by the data-"
@@ -89,7 +89,7 @@ def shard_params(params, cfg, mesh: Mesh, rules=None):
     (``rules`` overrides the built-in partition-rule table - the
     ``--sharding rules:<file>`` path, parallel/rules.py)."""
     tp = TP_AXIS if mesh.shape.get(TP_AXIS, 1) > 1 else None
-    specs = tfm.param_specs(
+    specs = cfg.module.param_specs(
         cfg, tp_axis=tp, ep_axis=_ep_axis(cfg, mesh), rules=rules
     )
     return jax.tree.map(
@@ -140,7 +140,7 @@ def auto_loss_chunks(b: int, s: int, vocab: int) -> int:
     return s
 
 
-def lm_loss(
+def lm_loss_and_aux(
     params,
     tokens,
     targets,
@@ -154,14 +154,20 @@ def lm_loss(
     aux_weight: float = 0.01,
     loss_chunks: int = 0,
 ):
-    """Mean next-token cross-entropy over the *global* token count (plus the
-    weighted MoE load-balancing aux when cfg.n_experts).
+    """(loss, aux): mean next-token cross-entropy over the *global* token
+    count, and what the model's `apply_hidden` hands back beside the hidden
+    state, reduced over `axes` as the model's module declares it
+    (`AUX_IS_LOSS`): a loss term (the MoE load-balancing aux) is averaged
+    and, when cfg.n_experts, added to the loss at `aux_weight`; anything
+    else (routing counts) is summed. `jax.value_and_grad(..., has_aux=True)`
+    is how a step takes the pair.
 
     loss_chunks > 1 computes the CE in that many sequence chunks without
     ever materializing the full (B, S, vocab) logits tensor
     (`_ce_sum_chunked`); 0 auto-picks a chunking that bounds each chunk's
     logits to ~64 MB (1 = explicit single-pass)."""
-    x, aux = tfm.apply_hidden(
+    model = cfg.module
+    x, aux = model.apply_hidden(
         params,
         tokens,
         cfg,
@@ -186,13 +192,18 @@ def lm_loss(
     if axes:
         total = jax.lax.psum(local_sum, axes)
         n = jax.lax.psum(local_n, axes)
-        aux = jax.lax.pmean(aux, axes)
+        aux = (jax.lax.pmean if model.AUX_IS_LOSS else jax.lax.psum)(aux, axes)
     else:
         total, n = local_sum, local_n
     loss = total / n
-    if cfg.n_experts:
+    if model.AUX_IS_LOSS and cfg.n_experts:
         loss = loss + aux_weight * aux
-    return loss
+    return loss, aux
+
+
+def lm_loss(params, tokens, targets, cfg, **kw):
+    """`lm_loss_and_aux`'s loss alone."""
+    return lm_loss_and_aux(params, tokens, targets, cfg, **kw)[0]
 
 
 OPTIMIZERS = ("sgd", "adam", "zero", "zero-adam")
@@ -246,8 +257,7 @@ def init_lm_momentum(params, mesh: Mesh, optimizer: str = "sgd"):
     raise ValueError(f"unknown optimizer {optimizer!r} (use one of {OPTIMIZERS})")
 
 
-def lm_wiring(cfg: tfm.TransformerConfig, mesh: Mesh, optimizer: str = "sgd",
-              rules=None):
+def lm_wiring(cfg, mesh: Mesh, optimizer: str = "sgd", rules=None):
     """(sp, tp, ep, sync_axes, specs, mom_spec, data_spec) for a dp x sp x
     tp mesh - the single source of the axis/spec derivation shared by
     `make_lm_train_step`, `lm_step_program`, and the static analyzer
@@ -262,7 +272,8 @@ def lm_wiring(cfg: tfm.TransformerConfig, mesh: Mesh, optimizer: str = "sgd",
     tp = TP_AXIS if mesh.shape.get(TP_AXIS, 1) > 1 else None
     ep = _ep_axis(cfg, mesh)
     sync_axes = tuple(a for a in (DATA_AXIS, SEQ_AXIS) if a in mesh.axis_names)
-    specs = tfm.param_specs(cfg, tp_axis=tp, ep_axis=ep, rules=rules)
+    cfg.module.refuse_axes(seq_axis=sp, tp_axis=tp, ep_axis=ep)
+    specs = cfg.module.param_specs(cfg, tp_axis=tp, ep_axis=ep, rules=rules)
     data_spec = P(DATA_AXIS, SEQ_AXIS)
     if optimizer not in OPTIMIZERS:
         raise ValueError(
@@ -298,8 +309,7 @@ def lm_wiring(cfg: tfm.TransformerConfig, mesh: Mesh, optimizer: str = "sgd",
     return sp, tp, ep, sync_axes, specs, mom_spec, data_spec
 
 
-def make_lm_shardings(cfg: tfm.TransformerConfig, mesh: Mesh,
-                      optimizer: str = "sgd", rules=None):
+def make_lm_shardings(cfg, mesh: Mesh, optimizer: str = "sgd", rules=None):
     """(specs, param_shardings, mom_shardings) for one mesh/optimizer -
     the placement triple the elastic driver (train/elastic.py) rebuilds
     whenever the mesh changes under a run (shrink/grow resume), derived
@@ -317,7 +327,7 @@ def make_lm_shardings(cfg: tfm.TransformerConfig, mesh: Mesh,
 
 
 def make_lm_train_step(
-    cfg: tfm.TransformerConfig,
+    cfg,
     mesh: Mesh,
     *,
     lr: float = 0.1,
@@ -411,10 +421,25 @@ def make_lm_train_step(
       grad_sync='end' with accum_steps >= 2 - the mean per-microbatch
       squared grad norm feeding the gradient-noise-scale estimator.
       Default-off leaves the compiled program unchanged.
+
+    A model whose module declares `AUX_IS_LOSS = False`
+    (`models/nemotron_h.py`) returns one output more, after every other:
+    what its `apply_hidden` hands back beside the hidden state, summed over
+    the mesh (there the expert layers' routing counts, int32: `held`,
+    `absent`, `dropped` (layers,), `load` (layers, experts held)).
+    Accumulation and the ZeRO optimizers, which would have to carry it
+    through their own loops, are refused for such a model by name.
     """
     sp, tp, ep, sync_axes, specs, mom_spec, data_spec = lm_wiring(
         cfg, mesh, optimizer, rules=rules
     )
+    aux_out = not cfg.module.AUX_IS_LOSS  # counts, handed out with the step
+    if aux_out and (accum_steps > 1 or optimizer.startswith("zero")):
+        raise ValueError(
+            f"{cfg.module.NAME}: accum_steps={accum_steps} / optimizer="
+            f"{optimizer!r} is not supported - the step hands the model's "
+            "counts straight through; use accum_steps=1 with 'sgd' or 'adam'"
+        )
 
     if accum_steps < 1:
         raise ValueError(f"accum_steps must be >= 1, got {accum_steps}")
@@ -433,7 +458,7 @@ def make_lm_train_step(
         )
 
     def fwd_bwd_one(params, tokens, targets):
-        return jax.value_and_grad(lm_loss)(
+        (loss, aux), grads = jax.value_and_grad(lm_loss_and_aux, has_aux=True)(
             params,
             tokens,
             targets,
@@ -445,6 +470,7 @@ def make_lm_train_step(
             axes=sync_axes,
             loss_chunks=loss_chunks,
         )
+        return ((loss, aux) if aux_out else loss), grads
 
     from ..ops.schedule import accumulate_fwd_bwd
 
@@ -541,6 +567,8 @@ def make_lm_train_step(
             loss, grads, msq_small = fwd_bwd(params, tokens, targets)
         else:
             loss, grads = fwd_bwd(params, tokens, targets)
+        if aux_out:
+            loss, aux = loss
         if fault_plan is not None:
             from ..parallel.fault import inject_step_faults
 
@@ -621,6 +649,8 @@ def make_lm_train_step(
             out = out + (health,)
         if dynamics:
             out = out + (dyn,)
+        if aux_out:
+            out = out + (aux,)
         return out
 
     # attn='flash' composes with dp x tp meshes since round 4: the own
@@ -683,6 +713,8 @@ def make_lm_train_step(
         out_specs = out_specs + (
             dynamics_out_specs(specs, with_upd=True, with_gns=want_gns),
         )
+    if aux_out:
+        out_specs = out_specs + (P(),)
     if has_step:
         return jax.jit(
             compat.shard_map(
@@ -706,13 +738,12 @@ def make_lm_train_step(
     )
 
 
-def abstract_lm_state(cfg: tfm.TransformerConfig, mesh: Mesh,
-                      optimizer: str = "sgd"):
+def abstract_lm_state(cfg, mesh: Mesh, optimizer: str = "sgd"):
     """(params, mom) as ShapeDtypeStruct pytrees - the step's state
     signature without allocating anything (jax.eval_shape over the real
     init functions, so analysis can never drift from training)."""
     params = jax.eval_shape(
-        lambda k: tfm.init_params(k, cfg),
+        lambda k: cfg.module.init_params(k, cfg),
         jax.ShapeDtypeStruct((2,), jnp.uint32),
     )
     dp = mesh.shape.get(DATA_AXIS, 1)
@@ -739,7 +770,7 @@ def abstract_lm_state(cfg: tfm.TransformerConfig, mesh: Mesh,
 
 
 def lm_step_program(
-    cfg: tfm.TransformerConfig,
+    cfg,
     mesh: Mesh,
     *,
     batch: int,
@@ -794,7 +825,7 @@ def lm_step_program(
             # ONLY where this is set, and a declared-quantized step whose
             # trace shows none fails (the quantized path silently fell
             # back) - analysis/lint.py quantized_dtype_lint
-            "quant": cfg.attn_quant or None,
+            "quant": getattr(cfg, "attn_quant", "") or None,
         },
     )
 
@@ -899,6 +930,50 @@ def make_traced_step(
         return out
 
     return traced_step
+
+
+class RoutingCounters:
+    """Publishes the expert layers' routing counts that a `NemotronHConfig`
+    step returns as its last output, one step behind the dispatch (reading
+    a step's counts waits for that step, so the one just dispatched is kept
+    and the one before it read): `lm_moe_pairs_total{where=held|absent}`,
+    `lm_moe_dropped_total` (always 0: no pair is ever dropped) and, a
+    layer, `lm_moe_expert_load_max_over_mean{layer}`, the fullest held
+    expert's tokens over the mean held expert's in the last step read."""
+
+    def __init__(self, registry):
+        pairs = registry.counter(
+            "lm_moe_pairs_total",
+            "(token, chosen expert) pairs routed to experts this chip "
+            "holds (held) and to experts of other chips (absent)")
+        self._held = pairs.labels(where="held")
+        self._absent = pairs.labels(where="absent")
+        self._dropped = registry.counter(
+            "lm_moe_dropped_total",
+            "Held pairs that found no row in the pair buffer (always 0)")
+        self._skew = registry.gauge(
+            "lm_moe_expert_load_max_over_mean",
+            "Tokens of the fullest held expert over the mean held "
+            "expert's, per expert layer, in the last step read")
+        self._pending = None
+
+    def push(self, routing) -> None:
+        pending, self._pending = self._pending, routing
+        if pending is not None:
+            self._publish(pending)
+
+    def flush(self) -> None:
+        self.push(None)
+
+    def _publish(self, routing) -> None:
+        r = jax.device_get(routing)
+        self._held.inc(float(r["held"].sum()))
+        self._absent.inc(float(r["absent"].sum()))
+        self._dropped.inc(float(r["dropped"].sum()))
+        for layer, load in enumerate(r["load"]):
+            mean = float(load.mean())
+            self._skew.labels(layer=str(layer)).set(
+                float(load.max()) / mean if mean else 0.0)
 
 
 def make_copy_task(key, *, batch, seq_len, vocab):

@@ -94,6 +94,16 @@ def main(argv=None) -> int:
     p.add_argument("--n-layers", type=int, default=4)
     p.add_argument("--d-ff", type=int, default=512)
     p.add_argument("--dtype", choices=("float32", "bfloat16"), default="float32")
+    p.add_argument("--model-config", default="", metavar="FILE",
+                   help="train the model a configuration file of the "
+                   "benchmark's form describes (benchmark/configs/*.json, "
+                   "benchmark/families/*/tiny.json) in place of the "
+                   "--d-model/--n-heads/... transformer. Its \"family\" "
+                   "picks the model module: 'nemotron_h' (Mamba-2, expert "
+                   "and attention layers in one pattern, "
+                   "models/nemotron_h.py; --dp only, attention by the flash kernels). "
+                   "--vocab, --d-model, --n-heads, --n-layers, --d-ff and "
+                   "--experts are then unused")
     p.add_argument(
         "--precision", choices=("bf16", "fp8", "int8", "int8-kv"),
         default="bf16",
@@ -572,20 +582,51 @@ def main(argv=None) -> int:
     from distributed_neural_network_tpu.train import lm as lmtrain
 
     initialize()
-    cfg = tfm.TransformerConfig(
-        vocab_size=args.vocab,
-        d_model=args.d_model,
-        n_heads=args.n_heads,
-        n_layers=args.n_layers,
-        d_ff=args.d_ff,
-        dtype=jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32,
-        remat=args.remat,
-        remat_attn=args.remat_attn,
-        remat_policy=args.remat_policy,
-        n_experts=args.experts,
-        attn_quant="" if args.precision == "bf16" else args.precision,
+    dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
+    if args.model_config:
+        with open(args.model_config) as f:
+            published = json.load(f)
+        from distributed_neural_network_tpu import models
+
+        try:
+            family = models.family_module(published.get("family"))
+        except KeyError:
+            raise SystemExit(
+                f"--model-config {args.model_config}: family "
+                f"{published.get('family')!r} has no model module here "
+                f"(there is {sorted(models.FAMILIES)}; GPT-2's block is the "
+                "--d-model/--n-heads/... flags)"
+            ) from None
+        args.attn = "flash"  # no sequence axis: the local kernels
+        args.vocab = published["vocab_size"]
+        cfg = family.from_published(
+            published, dtype=dtype, remat=args.remat,
+            remat_policy=args.remat_policy,
+        )
+    else:
+        cfg = tfm.TransformerConfig(
+            vocab_size=args.vocab,
+            d_model=args.d_model,
+            n_heads=args.n_heads,
+            n_layers=args.n_layers,
+            d_ff=args.d_ff,
+            dtype=dtype,
+            remat=args.remat,
+            remat_attn=args.remat_attn,
+            remat_policy=args.remat_policy,
+            n_experts=args.experts,
+            attn_quant="" if args.precision == "bf16" else args.precision,
+        )
+    # the analytic FLOP count is the transformer's (train/measure.py); a
+    # --model-config model's is kept with the benchmark
+    # (benchmark/families/*/arith.py) and its MFU line is left out
+    from distributed_neural_network_tpu.train.measure import (
+        model_flops_per_token,
     )
-    if args.n_heads % max(args.tp, 1):
+
+    flops_tok = (0.0 if args.model_config
+                 else model_flops_per_token(cfg, args.seq_len))
+    if not args.model_config and args.n_heads % max(args.tp, 1):
         raise SystemExit(f"--n-heads {args.n_heads} must divide by --tp {args.tp}")
 
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -639,7 +680,7 @@ def main(argv=None) -> int:
             f"tp{args.tp}, optimizer {result.chosen.optimizer})"
         )
 
-    params = tfm.init_params(jax.random.key(args.seed), cfg)
+    params = cfg.module.init_params(jax.random.key(args.seed), cfg)
     pipe = args.pp > 1
     # guard defaults for the pipeline branch (pp + guard/chaos is rejected
     # at argparse; these keep the shared loop code below uniform)
@@ -1093,7 +1134,6 @@ def main(argv=None) -> int:
     mosaic_calls = None  # counted from the compiled step under --step-stats
     if args.trace_out or args.step_stats:
         from distributed_neural_network_tpu.train.measure import (
-            model_flops_per_token as _mfpt,
             peak_flops as _peakf,
         )
 
@@ -1164,7 +1204,7 @@ def main(argv=None) -> int:
             compilation_cache_dir=args.compilation_cache_dir,
             flops_per_step=(
                 hw_flops if hw_flops is not None
-                else _mfpt(cfg, args.seq_len) * args.batch_size * args.seq_len
+                else flops_tok * args.batch_size * args.seq_len
             ),
             flops_source="cost_analysis" if hw_flops is not None else "analytic",
             peak_flops_per_device=_peakf(
@@ -1404,9 +1444,12 @@ def main(argv=None) -> int:
             f"{mesh_desc}, accum_steps={args.accum_steps})"
         )
 
-    # the dynamics bundle rides LAST in the step output: after the health
-    # bundle when the guard is on (train/lm.py make_lm_train_step)
+    # the dynamics bundle rides after the health bundle when the guard is
+    # on (train/lm.py make_lm_train_step); a model's routing counts, where
+    # its step hands any out, come after every other output
     dyn_idx = 4 if guard_on else 3
+    routing_pub = (None if cfg.module.AUX_IS_LOSS
+                   else lmtrain.RoutingCounters(registry))
     while i < end_step:
         if guard is not None and (i - step0) % args.snapshot_every == 0:
             # settle the in-flight observation BEFORE snapshotting, so the
@@ -1432,6 +1475,8 @@ def main(argv=None) -> int:
         else:
             out = step(params, mom, tokens, targets)
         params, mom, loss = out[0], out[1], out[2]
+        if routing_pub is not None:
+            routing_pub.push(out[-1])
         if dsink is not None:
             # BEFORE the health pipe: both are one-step lagged, so when
             # the guard judges step i-1 below, the sink must already have
@@ -1534,6 +1579,8 @@ def main(argv=None) -> int:
         )
     if preempt is not None:
         preempt.uninstall()
+    if routing_pub is not None:
+        routing_pub.flush()
     if dsink is not None:
         # settle before the health pipe's final flush (provenance for the
         # last judged step), then close the JSONL stream
@@ -1550,21 +1597,18 @@ def main(argv=None) -> int:
             ck.save(last_step, {"params": params, "mom": mom},
                     ckpt_meta(last_step, float(loss)))
         ck.close()
-    from distributed_neural_network_tpu.train.measure import (
-        model_flops_per_token,
-        peak_flops,
-    )
+    from distributed_neural_network_tpu.train.measure import peak_flops
 
     # timed_steps counts post-compile steps actually executed (guard
     # replays included, preempted tails excluded), so tokens/s stays
     # honest under rollbacks and early exits
     dt = time.perf_counter() - t0 - eval_s if timed_steps else 0.0
     tok_s = args.batch_size * args.seq_len * timed_steps / dt if dt else 0.0
-    flops_tok = model_flops_per_token(cfg, args.seq_len)
     model_flops_s = flops_tok * tok_s
     n_dev = mesh.devices.size
     peak = peak_flops(jax.devices()[0].device_kind, args.dtype)
-    mfu = model_flops_s / (peak * n_dev) * 100.0 if peak else None
+    mfu = (model_flops_s / (peak * n_dev) * 100.0
+           if peak and flops_tok else None)
     if mfu is not None:
         peak_label = (
             "bf16" if args.dtype == "bfloat16" else "f32 (0.5x bf16 MXU)"
@@ -1576,7 +1620,11 @@ def main(argv=None) -> int:
             f"= {flops_tok / 1e6:.1f}M"
         )
     if args.generate > 0:
-        if pipe:
+        if not hasattr(cfg.module, "generate"):
+            print("(--generate skipped: decoding a --model-config model "
+                  "needs its recurrent state beside the KV cache, which "
+                  "generate() does not carry)")
+        elif pipe:
             print("(--generate skipped: decode needs the non-pipeline "
                   "param layout; rerun without --pp)")
         else:

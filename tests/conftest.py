@@ -13,6 +13,12 @@ use, which is after this conftest runs.
 
 import os
 
+# `benchmark/tests`' cases take `BENCH` and `ROOT` from their conftest; the
+# tier-1 suite imports some of those cases
+# (tests/test_benchmark_contract.py), where `conftest` is this file.
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+
 _flag = "--xla_force_host_platform_device_count=8"
 if _flag not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " " + _flag).strip()
