@@ -256,9 +256,10 @@ def export_descriptor(seq: Sequence) -> dict:
     The contract is the same one preemption replay rests on: the seeded
     model is identical on every replica, greedy decode is a pure
     function of the token history, and sampled slots key on the
-    ABSOLUTE position (`_sample_key` folds ``seq.pos``) - so prefilling
-    ``prompt + already-emitted tokens`` on any replica reconstructs the
-    byte-identical KV state and the next sampling key, and the
+    ABSOLUTE position (`_row_keys` folds ``pos`` into the request
+    seed's key) - so prefilling ``prompt + already-emitted tokens`` on
+    any replica reconstructs the byte-identical KV state and the next
+    sampling key, and the
     continuation matches the stream a single never-failing replica
     would have produced. ``emitted`` holds only tokens the client has
     already seen (the dedup rule: they become prompt on resume, never
@@ -333,6 +334,23 @@ def _write_rows(pool, l, idx, val, op: str = "set"):
     n = pool.shape[1]
     flat = pool.reshape((-1,) + pool.shape[2:])
     return getattr(flat.at[l * n + idx], op)(val).reshape(pool.shape)
+
+
+@jax.jit
+def _row_keys(seeds, pos):
+    """Each decode row's sampling key from its request's seed (low 32
+    bits) and absolute position: (B,) uint32, (B,) int32 -> (B, 2)
+    uint32, the bits of ``fold_in(PRNGKey(seq.seed), seq.pos)``, so
+    preemption replay and the fleet's re-submission sample the same
+    tokens. The result goes into the decode program from the device:
+    the host fetches nothing between a tick's prefill and decode
+    dispatches. A program of its own, one a batch size, and not part of
+    the decode programs: for the TPU the generator is lowered unrolled,
+    and one more of it in each of the grid's 40 decode programs made a
+    warm server start 10-21 s later (PERF.md section 6, PR 28)."""
+    return jax.vmap(
+        lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
+    )(seeds, pos)
 
 
 def _bucket(n: int, lo: int = 1) -> int:
@@ -1256,6 +1274,8 @@ class ServeEngine:
             return jnp.zeros(shape, jnp.int32)
 
         for B in pow2(self.ecfg.max_batch):
+            # as `step` calls it: host arrays in, the keys left on the device
+            _row_keys(np.zeros((B,), np.uint32), np.zeros((B,), np.int32))
             for W in widths:
                 warm("decode", self._decode_fn(B, W), zeros(B), zeros(B),
                      zeros(B, W), jnp.zeros((B,), jnp.float32),
@@ -1278,12 +1298,6 @@ class ServeEngine:
         return n
 
     # ------------------------------------------------------------ the tick
-
-    def _sample_key(self, seq: Sequence) -> np.ndarray:
-        """Per-(sequence, position) sampling key: deterministic across
-        preemption replay."""
-        k = jax.random.PRNGKey(seq.seed)
-        return np.asarray(jax.random.fold_in(k, seq.pos), np.uint32)
 
     def _emit(self, seq: Sequence, tok: int) -> None:
         """One NEW generated token: record, maybe retire, stream."""
@@ -1634,12 +1648,12 @@ class ServeEngine:
                 tok = np.zeros((B,), np.int32)
                 pos = np.zeros((B,), np.int32)
                 temps = np.zeros((B,), np.float32)
-                keys = np.zeros((B, 2), np.uint32)
+                seeds = np.zeros((B,), np.uint32)
                 for i, s in enumerate(batch):
                     tok[i] = s.next_input()
                     pos[i] = s.pos
                     temps[i] = s.temperature
-                    keys[i] = self._sample_key(s)
+                    seeds[i] = s.seed & 0xFFFFFFFF
                 table = self.kv.table(
                     [s.seq_id for s in batch] + [-1] * (B - len(batch)), W
                 )
@@ -1650,7 +1664,7 @@ class ServeEngine:
                 nxt, _ = self._run_writer(
                     fn, jnp.asarray(tok), jnp.asarray(pos),
                     jnp.asarray(table), jnp.asarray(temps),
-                    jnp.asarray(keys),
+                    _row_keys(seeds, pos),
                 )
         lap("decode_host")
         if batch:

@@ -9,12 +9,17 @@ Bars:
 - KV exhaustion preempts rather than crashes, the replay is exact, and
   streamed tokens are never duplicated;
 - sampling is deterministic per (seed, position) - preemption-safe -
-  and the admission-time validation rejects what could never run;
+  under the very keys the host would derive, though a program on the
+  device derives them, and the admission-time validation rejects what
+  could never run;
+- a tick that decodes reads the device once (the fetch) and runs its
+  bucket programs, the key program and nothing else;
 - every `step()` partitions its own wall time into the documented
   phases and reports the buckets it dispatched with their live positions.
 """
 
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +27,7 @@ import numpy as np
 import pytest
 
 from distributed_neural_network_tpu.models import transformer as tfm
+from distributed_neural_network_tpu.serve import engine as engine_mod
 from distributed_neural_network_tpu.serve.engine import (
     STEP_PHASES,
     EngineConfig,
@@ -152,6 +158,152 @@ def test_sampling_deterministic_per_seed(params, n_devices):
     assert a1 == a2  # per-(seed, position) keys: replayable
     assert a1 != b   # a different seed actually samples differently
     assert all(0 <= t < 32 for t in a1)
+
+
+def _host_key(seed, pos):
+    # the definition the engine's host code had until PR 28, written out
+    return np.asarray(
+        jax.random.fold_in(jax.random.PRNGKey(seed), pos), np.uint32
+    )
+
+
+@pytest.mark.parametrize("pos", [0, 1, 127, 2047])
+@pytest.mark.parametrize(
+    "seed", [0, 1, 2**31 - 1, 2**31, 2**32 - 1, 2**32 + 5, -1]
+)
+def test_program_derives_the_hosts_key(n_devices, seed, pos):
+    """What `step` hands the key program (the seed's low 32 bits) and
+    what the program makes of it, against the per-sequence derivation on
+    the host, bit for bit; the other rows of the batch do not matter."""
+    seeds = np.array([9, seed & 0xFFFFFFFF, 0], np.uint32)
+    poss = np.array([3, pos, 0], np.int32)
+    got = np.asarray(engine_mod._row_keys(seeds, poss))
+    assert got.dtype == np.uint32 and got.shape == (3, 2)
+    np.testing.assert_array_equal(got[1], _host_key(seed, pos))
+
+
+def test_sampled_tokens_are_categorical_under_the_hosts_key(params,
+                                                            n_devices):
+    """Engine level: the keys the decode program is handed are the
+    host-derived keys of (each row's seed, its position), and every
+    token a temperature-1 row gets is `jax.random.categorical` under
+    that key over the logits the program returned for the row; a greedy
+    row beside it is the argmax."""
+    eng = ServeEngine(params, CFG, EngineConfig(
+        max_batch=4, num_blocks=32, block_size=4, max_seq_len=64,
+    ))
+    seqs = [
+        Sequence(0, _prompt(40, 6), 10, temperature=1.0, seed=2**32 + 5),
+        Sequence(1, _prompt(41, 3), 10, temperature=1.0, seed=-1),
+        Sequence(2, _prompt(42, 5), 10),
+    ]
+    calls = []
+    run = eng._run_writer
+
+    def recording(fn, *tail):
+        out = run(fn, *tail)
+        calls.append([np.asarray(a) for a in (*tail, *out)])
+        return out
+
+    eng._run_writer = recording
+    for s in seqs:
+        eng.add(s)
+    want = {s.seq_id: [] for s in seqs}
+    while eng.has_work():
+        # token at a time and blocks for all: every live sequence decodes
+        rows = [s for s in eng.active if not s.finished]
+        assert eng.step()["batch"] == len(rows)
+        _, pos, _, temps, keys, nxt, logits = calls.pop()
+        for i, s in enumerate(rows):
+            key = _host_key(s.seed, int(pos[i]))
+            np.testing.assert_array_equal(keys[i], key)
+            assert temps[i] == s.temperature
+            if s.temperature > 0:
+                tok = int(jax.random.categorical(key, logits[i]))
+            else:
+                tok = int(np.argmax(logits[i]))
+            assert int(nxt[i]) == tok, (s.seq_id, int(pos[i]))
+            if pos[i] >= s.prompt_len - 1:
+                want[s.seq_id].append(tok)
+    assert not calls
+    for s in seqs:
+        assert s.out == want[s.seq_id] and len(s.out) == 10
+
+
+class _Noted:
+    """A module as the engine's code sees it (`np`, `jnp`, `jax`): every
+    function of it that the engine CALLS goes to `log` as (dotted name,
+    arguments). Types (dtypes) pass through untouched."""
+
+    def __init__(self, mod, name, log):
+        self._mod, self._name, self._log = mod, name, log
+
+    def __getattr__(self, attr):
+        val, name = getattr(self._mod, attr), f"{self._name}.{attr}"
+        if isinstance(val, types.ModuleType):
+            return _Noted(val, name, self._log)
+        if not callable(val) or isinstance(val, type):
+            return val
+
+        def noted(*args, **kw):
+            self._log.append((name, args))
+            return val(*args, **kw)
+
+        return noted
+
+
+@pytest.mark.parametrize("temperature", [0.0, 1.0])
+@pytest.mark.parametrize("chunk", [1, 8])
+def test_decoding_tick_reads_the_device_once(params, n_devices,
+                                             monkeypatch, chunk,
+                                             temperature):
+    """Between a tick's prefill dispatch and its decode dispatch the
+    host must not wait on the device: over the ticks that carry a decode
+    batch, the engine turns a device array into a host array exactly
+    once (the fetch), and the only programs it runs are the tick's
+    prefill bucket programs, the key program and the decode bucket
+    program, in that order (its other `jax` calls are the host-to-device
+    transfers of the operands)."""
+    eng = ServeEngine(params, CFG, EngineConfig(
+        max_batch=4, num_blocks=32, block_size=4, max_seq_len=64,
+        prefill_chunk=chunk,
+    ))
+    eng.warmup()  # every bucket traced before the modules are stood in for
+    log, ran = [], []
+
+    def counted(family, fn):
+        return lambda *a: ran.append(family) or fn(*a)
+
+    for name in ("np", "jnp", "jax"):
+        monkeypatch.setattr(
+            engine_mod, name, _Noted(getattr(engine_mod, name), name, log)
+        )
+    for family, cache in (("decode", eng._step_fns),
+                          ("prefill", eng._prefill_fns)):
+        for key, fn in cache.items():
+            cache[key] = counted(family, fn)
+    monkeypatch.setattr(
+        engine_mod, "_row_keys", counted("keys", engine_mod._row_keys)
+    )
+    for i, n in enumerate((13, 5, 9)):
+        eng.add(Sequence(i, _prompt(60 + i, n), 6,
+                         temperature=temperature, seed=100 + i))
+    decoding = 0
+    while eng.has_work():
+        del log[:], ran[:]
+        st = eng.step()
+        if st["decode_call"] is None:
+            continue
+        decoding += 1
+        reads = [n for n, a in log
+                 if n == "np.asarray" and isinstance(a[0], jax.Array)]
+        assert len(reads) == 1, log
+        assert ran == (
+            ["prefill"] * len(st["prefill_calls"]) + ["keys", "decode"]
+        )
+        others = {n for n, _ in log if not n.startswith("np.")}
+        assert others <= {"jnp.asarray"}, others
+    assert decoding >= 6
 
 
 def test_warmup_leaves_state_clean(params, n_devices):
