@@ -302,26 +302,40 @@ def _attend(q, k, v, *, impl, seq_axis, s_local, quant: str = ""):
     )
 
 
-def transformer_block(x, lp, cfg: TransformerConfig, *, attend, tp_axis=None,
-                      ep_axis=None, capacity=None):
-    """One pre-norm block on x (B, S_local, d) with layer params lp.
+def plain_mm(dt):
+    """How a caller multiplies by a weight unless it says otherwise: ``x @
+    w`` at the model dtype. The serving engine hands the block halves its
+    int8-weight product in this one's place (serve/engine.py `_make_mm`)."""
+    def mm(x, w):
+        return x @ w.astype(dt)
+    return mm
 
-    `attend`: (q, k, v) -> output, each (B, S_local, H_local, head_dim) -
-    the caller chooses full/ring/Ulysses and the causal offset convention.
-    Returns (x, aux) where aux is the MoE load-balancing loss (0.0 dense).
-    Shared by `apply_with_aux` (flat or dp/sp/tp-sharded execution) and the
-    pipeline schedule (`parallel/pipeline.py`), so the block math lives in
-    exactly one place.
-    """
+
+def block_qkv(x, lp, cfg: TransformerConfig, mm=None):
+    """The block's first half, up to what its caller does with a cache:
+    LayerNorm and the three projections of x (..., d), each returned as
+    (..., H_local, head_dim)."""
+    mm = mm or plain_mm(cfg.dtype)
+    h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"]).astype(cfg.dtype)
+
+    def heads(w):
+        y = mm(h, w)
+        return y.reshape(*y.shape[:-1], -1, cfg.head_dim)
+
+    return heads(lp["wq"]), heads(lp["wk"]), heads(lp["wv"])
+
+
+def block_out(x, o, lp, cfg: TransformerConfig, mm=None, *, tp_axis=None,
+              ep_axis=None, capacity=None, moe_dispatch=None):
+    """The block's second half: the attention output o (..., H_local,
+    head_dim) through `wo` into the residual x (..., d), then LayerNorm
+    and the GELU MLP - or, with `cfg.n_experts`, the expert layer at the
+    `capacity` and `moe_dispatch` its caller names (the configuration's
+    dispatch by default). Returns (x, aux), aux the expert layer's
+    balancing loss (0.0 dense)."""
     dt = cfg.dtype
-    b, s_local = x.shape[:2]
-    d_local_heads = lp["wq"].shape[-1] // cfg.head_dim
-    h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"]).astype(dt)
-    q = (h @ lp["wq"].astype(dt)).reshape(b, s_local, d_local_heads, cfg.head_dim)
-    k = (h @ lp["wk"].astype(dt)).reshape(b, s_local, d_local_heads, cfg.head_dim)
-    v = (h @ lp["wv"].astype(dt)).reshape(b, s_local, d_local_heads, cfg.head_dim)
-    o = attend(q, k, v)
-    o = o.reshape(b, s_local, -1) @ lp["wo"].astype(dt)
+    mm = mm or plain_mm(dt)
+    o = mm(o.reshape(*o.shape[:-2], -1), lp["wo"])
     if tp_axis is not None:
         o = jax.lax.psum(o, tp_axis)
     x = x + o
@@ -329,23 +343,19 @@ def transformer_block(x, lp, cfg: TransformerConfig, *, attend, tp_axis=None,
     h = _layer_norm(x, lp["ln2_scale"], lp["ln2_bias"]).astype(dt)
     if cfg.n_experts:
         y, aux = moe_ffn(
-            h.reshape(b * s_local, cfg.d_model),
-            lp["wr"],
-            lp["w1"],
-            lp["b1"],
-            lp["w2"],
-            lp["b2"],
+            h.reshape(-1, cfg.d_model),
+            lp["wr"], lp["w1"], lp["b1"], lp["w2"], lp["b2"],
             top_k=cfg.moe_top_k,
             capacity=capacity,
             ep_axis=ep_axis,
             tp_axis=tp_axis,
-            dispatch_impl=cfg.moe_dispatch,
+            dispatch_impl=moe_dispatch or cfg.moe_dispatch,
             z_loss_weight=cfg.moe_z_weight,
         )
-        x = x + y.reshape(b, s_local, cfg.d_model)
+        x = x + y.reshape(x.shape)
     else:
-        h = jax.nn.gelu(h @ lp["w1"].astype(dt) + lp["b1"].astype(dt))
-        h = h @ lp["w2"].astype(dt)
+        h = jax.nn.gelu(mm(h, lp["w1"]) + lp["b1"].astype(dt))
+        h = mm(h, lp["w2"])
         if tp_axis is not None:
             h = jax.lax.psum(h, tp_axis)
         x = x + h + lp["b2"].astype(dt)
@@ -353,24 +363,55 @@ def transformer_block(x, lp, cfg: TransformerConfig, *, attend, tp_axis=None,
     return x, aux
 
 
-def apply_hidden(
-    params,
-    tokens,
-    cfg: TransformerConfig,
-    *,
-    seq_axis: str | None = None,
-    tp_axis: str | None = None,
-    ep_axis: str | None = None,
-    attn_impl: str = "ring",
-):
-    """tokens (B, S_local) int32 -> (hidden (B, S_local, d_model), aux).
+def transformer_block(x, lp, cfg: TransformerConfig, *, attend, tp_axis=None,
+                      ep_axis=None, capacity=None):
+    """One pre-norm block on x (B, S_local, d) with layer params lp.
 
-    The pre-head forward: embedding + blocks + final layer norm, WITHOUT the
-    vocab projection. Loss paths that chunk the cross-entropy (train/lm.py)
-    consume this directly so the (B, S, vocab) logits tensor is never
-    materialized whole - at vocab 32k/seq 2048 that tensor is GBs of HBM
-    traffic and the single biggest single-chip LM cost.
+    `attend`: (q, k, v) -> output, each (B, S_local, H_local, head_dim) -
+    the caller chooses full/ring/Ulysses and the causal offset convention.
+    Returns (x, aux) where aux is the MoE load-balancing loss (0.0 dense).
+    `apply_hidden` (flat or dp/sp/tp-sharded execution) and the pipeline
+    schedule (`parallel/pipeline.py`) come through here; a caller whose
+    middle is more than a function of (q, k, v) - `generate` and the
+    serving engine's bucket programs, which update a cache there - calls
+    the two halves itself.
     """
+    q, k, v = block_qkv(x, lp, cfg)
+    return block_out(x, attend(q, k, v), lp, cfg, tp_axis=tp_axis,
+                     ep_axis=ep_axis, capacity=capacity)
+
+
+def masked_attention(q, ks, vs, live, dt):
+    """Attention of queries q (B, Q, H, Dh) over cached keys and values
+    (B, H, S, Dh), where `live` (broadcast against (B, H, Q, S)) says
+    which cache positions a query may see: scores in float32 over
+    sqrt(Dh), softmax, probabilities back at `dt`. Returns (B, Q, H, Dh).
+    What `generate` and the serving engine run where no kernel does."""
+    scores = jnp.einsum("bqhd,bhsd->bhqs", q, ks).astype(jnp.float32)
+    scores = scores / np.sqrt(q.shape[-1])
+    neg = jnp.asarray(-1e30, jnp.float32)
+    probs = jax.nn.softmax(jnp.where(live, scores, neg), axis=-1)
+    return jnp.einsum("bhqs,bhsd->bqhd", probs.astype(dt), vs)
+
+
+def final_norm(params, x, dt):
+    """The final LayerNorm, at `dt`: the hidden a chunked loss takes."""
+    return _layer_norm(x, params["lnf_scale"], params["lnf_bias"]).astype(dt)
+
+
+def final_logits(params, x, dt):
+    """Final LayerNorm and head of x (..., d) -> (..., vocab) float32, the
+    head's product accumulated and kept in float32 (logits rounded to
+    bfloat16 tie, and a greedy token is an argmax). A caller slices the
+    positions it wants first."""
+    head = params["head"].astype(dt).astype(jnp.float32)
+    return final_norm(params, x, dt) @ head
+
+
+def _blocks(params, tokens, cfg: TransformerConfig, *, seq_axis=None,
+            tp_axis=None, ep_axis=None, attn_impl="ring"):
+    """Embedding and the scanned blocks: tokens (B, S_local) -> (x (B,
+    S_local, d_model) before the final norm, mean aux over layers)."""
     dt = cfg.dtype
     b, s_local = tokens.shape
     x = params["embed"][tokens].astype(dt)
@@ -391,23 +432,30 @@ def apply_hidden(
         attend = jax.checkpoint(attend)
 
     def block(x, lp):
-        return transformer_block(
-            x,
-            lp,
-            cfg,
-            attend=attend,
-            tp_axis=tp_axis,
-            ep_axis=ep_axis,
-            capacity=cap,
-        )
+        return transformer_block(x, lp, cfg, attend=attend, tp_axis=tp_axis,
+                                 ep_axis=ep_axis, capacity=cap)
 
     if cfg.remat:
         policy = (getattr(jax.checkpoint_policies, cfg.remat_policy)
                   if cfg.remat_policy else None)
         block = jax.checkpoint(block, policy=policy)
     x, aux = jax.lax.scan(block, x, params["layers"])
-    x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"]).astype(dt)
     return x, aux.mean()
+
+
+def apply_hidden(params, tokens, cfg: TransformerConfig, *, seq_axis=None,
+                 tp_axis=None, ep_axis=None, attn_impl="ring"):
+    """tokens (B, S_local) int32 -> (hidden (B, S_local, d_model), aux).
+
+    The pre-head forward: embedding + blocks + final layer norm, WITHOUT the
+    vocab projection. Loss paths that chunk the cross-entropy (train/lm.py)
+    consume this directly so the (B, S, vocab) logits tensor is never
+    materialized whole - at vocab 32k/seq 2048 that tensor is GBs of HBM
+    traffic and the single biggest single-chip LM cost.
+    """
+    x, aux = _blocks(params, tokens, cfg, seq_axis=seq_axis, tp_axis=tp_axis,
+                     ep_axis=ep_axis, attn_impl=attn_impl)
+    return final_norm(params, x, cfg.dtype), aux
 
 
 def apply_with_aux(
@@ -430,17 +478,9 @@ def apply_with_aux(
     `ep_axis` when given) and `aux` is the mean Switch load-balancing loss
     over layers (0.0 for dense).
     """
-    x, aux = apply_hidden(
-        params,
-        tokens,
-        cfg,
-        seq_axis=seq_axis,
-        tp_axis=tp_axis,
-        ep_axis=ep_axis,
-        attn_impl=attn_impl,
-    )
-    logits = (x @ params["head"].astype(cfg.dtype)).astype(jnp.float32)
-    return logits, aux
+    x, aux = _blocks(params, tokens, cfg, seq_axis=seq_axis, tp_axis=tp_axis,
+                     ep_axis=ep_axis, attn_impl=attn_impl)
+    return final_logits(params, x, cfg.dtype), aux
 
 
 def apply(params, tokens, cfg: TransformerConfig, **kw):
@@ -606,13 +646,11 @@ def generate(
     prompt_pad = jnp.pad(prompt, ((0, 0), (0, max_new_tokens)))
     # caches are (L, B, H, total, Dh): collapsing (B, H) for the decode
     # kernel is then a free reshape. DNN_TPU_DECODE_IMPL selects the
-    # per-step attention: "auto"/"xla" (the XLA chain - measured FASTER
-    # than the fused kernel at d512/cache<=640: 2.59 vs 3.69 ms/step at
-    # b16/hd64, r5; XLA lowers the whole step as one well-tiled batched
-    # einsum and a per-layer pallas_call costs more than it fuses),
-    # "pallas" (the ops/decode_pallas.py kernel - kept selectable for
-    # larger caches where dead-block skipping should eventually win),
-    # "pallas-interpret" (CPU-testable kernel path).
+    # per-step attention: "auto"/"xla" (`masked_attention`), "pallas" (the
+    # ops/decode_pallas.py kernel), "pallas-interpret" (the kernel on the
+    # CPU, for tests). No benchmark cell runs `generate`: it is the tests'
+    # token-exact reference for the serving engine, whose own "auto"
+    # takes the kernel on a TPU (serve/engine.py `_attn_route`).
     impl = os.environ.get("DNN_TPU_DECODE_IMPL", "auto")
     if impl not in ("auto", "xla", "pallas", "pallas-interpret"):
         raise ValueError(f"unknown decode impl {impl!r} "
@@ -642,17 +680,19 @@ def generate(
     cache_k = jnp.zeros((L, b, H, total, Dh), dt)
     cache_v = jnp.zeros((L, b, H, total, Dh), dt)
     pe_all = _sinusoid_pe(jnp.arange(total), cfg.d_model, dt)
-    neg = jnp.asarray(-1e30, jnp.float32)
+    # positions a query at `pos` may see: the static cache up to pos, and
+    # in a left-padded batch not the pad columns before each row's offset
+    # (they never existed)
+    slots = jnp.arange(total)
 
     def layer_step(xp, lcaches):
         (x, pos) = xp
         lp, ck, cv = lcaches
-        h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"]).astype(dt)
-        q = (h @ lp["wq"].astype(dt)).reshape(b, 1, H, Dh)
-        k = (h @ lp["wk"].astype(dt)).reshape(b, H, 1, Dh)
-        v = (h @ lp["wv"].astype(dt)).reshape(b, H, 1, Dh)
-        ck = jax.lax.dynamic_update_slice_in_dim(ck, k, pos, axis=2)
-        cv = jax.lax.dynamic_update_slice_in_dim(cv, v, pos, axis=2)
+        q, k, v = block_qkv(x, lp, cfg)            # each (b, 1, H, Dh)
+        ck = jax.lax.dynamic_update_slice_in_dim(
+            ck, k.transpose(0, 2, 1, 3), pos, axis=2)
+        cv = jax.lax.dynamic_update_slice_in_dim(
+            cv, v.transpose(0, 2, 1, 3), pos, axis=2)
         if use_kernel:
             # fused single-query kernel: one pallas_call instead of the
             # einsum/softmax/einsum chain, dead cache blocks skipped
@@ -660,43 +700,15 @@ def generate(
             o = decode_cache_attention(
                 q.reshape(b, H, Dh), ck, cv, pos,
                 interpret=impl == "pallas-interpret",
-            ).reshape(b, 1, H * Dh)
+            )[:, None]
         else:
-            # scores over the full static cache, future slots masked out
-            scores = jnp.einsum(
-                "bqhd,bhsd->bhqs", q, ck
-            ).astype(jnp.float32)
-            scores = scores / np.sqrt(Dh)
-            live = (jnp.arange(total) <= pos)[None, :]
+            live = (slots <= pos)[None, :]
             if offsets is not None:
-                # left-padded batch: pad columns (before each row's
-                # offset) never existed - mask their cache entries out
-                live = live & (
-                    jnp.arange(total)[None, :] >= offsets[:, None]
-                )
-            live = live[:, None, None, :]
-            probs = jax.nn.softmax(jnp.where(live, scores, neg), axis=-1)
-            o = jnp.einsum("bhqs,bhsd->bqhd", probs.astype(dt), cv)
-            o = o.reshape(b, 1, H * Dh)
-        x = x + o @ lp["wo"].astype(dt)
-        h2 = _layer_norm(x, lp["ln2_scale"], lp["ln2_bias"]).astype(dt)
-        if cfg.n_experts:
-            # dense dispatch at decode shapes (B tokens/step): capacity =
-            # B guarantees zero drops. Parity caveat: the training
-            # forward uses moe_capacity_factor and CAN drop tokens under
-            # router imbalance, so cached decode matches the
-            # teacher-forced forward exactly only in the no-drop regime
-            # (dropped training tokens pass through the residual with no
-            # expert output; decode never drops)
-            y, _ = moe_ffn(
-                h2.reshape(b, cfg.d_model),
-                lp["wr"], lp["w1"], lp["b1"], lp["w2"], lp["b2"],
-                top_k=cfg.moe_top_k, capacity=b, dispatch_impl="dense",
-            )
-            x = x + y.reshape(b, 1, cfg.d_model)
-        else:
-            h2 = jax.nn.gelu(h2 @ lp["w1"].astype(dt) + lp["b1"].astype(dt))
-            x = x + h2 @ lp["w2"].astype(dt) + lp["b2"].astype(dt)
+                live = live & (slots[None, :] >= offsets[:, None])
+            o = masked_attention(q, ck, cv, live[:, None, None, :], dt)
+        # an expert layer: dense dispatch at capacity b (b tokens a step)
+        # drops no token (the docstring's parity caveat)
+        x, _ = block_out(x, o, lp, cfg, capacity=b, moe_dispatch="dense")
         return (x, pos), (ck, cv)
 
     def time_step(carry, pos):
@@ -717,14 +729,13 @@ def generate(
         x = params["embed"][tok].astype(dt)[:, None, :] + pe
         (x, _), (ck, cv) = jax.lax.scan(
             layer_step, (x, pos), (params["layers"], ck, cv),
-            # unrolling the (short) layer scan lets XLA overlap across
-            # layers inside one decode step - measured r5: 1.19 -> 0.82
-            # ms/step at cache 256, 2.59 -> 2.41 at cache 640 (b16/hd64).
-            # Chunked so deep stacks don't blow up compile time.
+            # unrolled in chunks of 8 so that XLA can overlap across
+            # layers at small widths. At 1.3 B the serving engine found the
+            # opposite and keeps its scan rolled (serve/engine.py
+            # `_scan_layers`); no cell measures this one.
             unroll=min(L, 8),
         )
-        h = _layer_norm(x, params["lnf_scale"], params["lnf_bias"]).astype(dt)
-        logits = (h[:, 0] @ params["head"].astype(dt)).astype(jnp.float32)
+        logits = final_logits(params, x[:, 0], dt)
         if temperature > 0.0:
             if top_k > 0:
                 kth = jax.lax.top_k(logits, top_k)[0][:, -1:]

@@ -266,9 +266,7 @@ def pipeline_lm_loss(
 
     # final norm + head + CE for my share, seq-chunked so the (k*mb, S,
     # vocab) logits never materialize whole (same trick as train/lm.py)
-    h = tfm._layer_norm(
-        mine, params["lnf_scale"], params["lnf_bias"]
-    ).astype(dt)
+    h = tfm.final_norm(params, mine, dt)
     rows = k * mb
     x_rows = h.reshape(rows, s, cfg.d_model)
     t_rows = my_tgt.reshape(rows, s)

@@ -38,28 +38,26 @@ greedy decoding (and the per-position sampling keys) make the replay
 deterministic, and already-streamed tokens are not re-emitted.
 
 **Speculative decoding** (``spec_decode = k > 0``): greedy slots break
-the one-token-per-tick ceiling. A cheap drafter - the SAME model
-early-exited after its first ``spec_draft_layers`` blocks
-(models/transformer.py early_exit_params: shared embed/final-LN/head,
-no second weight set) - proposes k tokens per slot in one jitted call
-that READS the paged pool but writes nothing (in-flight draft K/V live
-in a per-call buffer, so the pool - and under int8 its running scales -
-never sees a draft). One target-model VERIFY step then consumes
-``[t0, d1..dk]`` at positions ``pos..pos+k`` in a single call (a new
-per-(batch, k+1, table-width) jitted bucket family, pre-compiled by
-`warmup()`), writing all k+1 KV entries optimistically and returning
-the greedy prediction at every position. The host accepts the longest
-draft prefix that matches, emits ``a+1`` tokens (the all-rejected step
-emits exactly 1 - the same token plain decode would), and REWINDS the
-block-table write cursor past the rejected suffix
-(`kv_cache.py rewind` - the same bookkeeping preemption replay
-performs, so replay/cancel invariants carry over byte-identically and
-greedy streams stay token-exact vs the offline `generate()` oracle).
-Sampled slots (temperature > 0) take the plain decode path untouched -
-their per-(seed, position) keys never see speculation. Preemption
-replay feeds already-known tokens back as drafts (guaranteed
-acceptance under greedy determinism), so replay advances k+1 positions
-per tick instead of one.
+the one-token-per-tick ceiling (docs/SERVING.md "Speculative decoding").
+A drafter - the SAME model early-exited after its first
+``spec_draft_layers`` blocks (models/transformer.py early_exit_params) -
+proposes k tokens per slot in one call that READS the pool and writes
+nothing (`_draft_fn`); one VERIFY step (`_verify_fn`) consumes ``[t0,
+d1..dk]`` at ``pos..pos+k``, writes all k+1 KV entries optimistically and
+returns the greedy prediction at every position. The host accepts the
+longest matching draft prefix, emits ``a+1`` tokens and REWINDS the
+write cursor past the rest (`kv_cache.py rewind`, preemption replay's own
+bookkeeping, so greedy streams stay token-exact vs offline `generate()`).
+Sampled slots take the plain decode path untouched; preemption replay
+feeds already-known tokens back as drafts, k+1 positions a tick.
+
+**Who owns what**: the block's arithmetic is the model module's
+(models/transformer.py `block_qkv`, `block_out`, `masked_attention`,
+`final_logits`); a bucket family here owns its cache step alone, between
+q/k/v and the attention output (decode: one row written, the bucket
+gathered, the kernel or `masked_attention`; prefill and verify: the chunk
+written, the span gathered; draft: a local buffer beside the pre-gathered
+history, nothing written).
 """
 
 from __future__ import annotations
@@ -68,17 +66,15 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from ..models.transformer import (
-    TransformerConfig,
-    _layer_norm,
-    _sinusoid_pe,
-)
+from ..models import transformer as tfm
+from ..models.transformer import TransformerConfig, _sinusoid_pe
 from ..ops.decode_pallas import decode_cache_attention, decode_kernel_ok
 from ..ops.quant import prequantize_weight, quantized_matmul
 from ..runtime import on_tpu
@@ -122,9 +118,7 @@ def _make_mm(weight_quantized: bool, dt):
     low-precision dot against the prequantized codes (activation rows
     quantized per call, int8 x int8 -> int32, f32 dequant)."""
     if not weight_quantized:
-        def mm(x, w):
-            return x @ w.astype(dt)
-        return mm
+        return tfm.plain_mm(dt)
 
     def mm(x, w):
         shp = x.shape
@@ -153,7 +147,7 @@ class EngineConfig:
     # (docs/SERVING.md "int8 KV cache")
     kv_dtype: str = "bf16"
     # per-step attention under the paged gather: "xla" = the einsum/
-    # softmax/einsum chain (PR 12 path), "pallas" = the tuned decode
+    # softmax/einsum chain (`masked_attention`), "pallas" = the tuned decode
     # kernel (ops/decode_pallas.py) reading the gathered bucket with
     # per-slot positions (int8 pools stream quantized with fused
     # dequant), "auto" = pallas on TPU when the bucket's width admits a
@@ -163,8 +157,8 @@ class EngineConfig:
     # speculative decoding: k > 0 lets each GREEDY slot emit up to k+1
     # tokens per tick (draft k with the early-exit drafter, verify all
     # of them in one multi-position target step, rewind the rejected
-    # suffix). 0 = off (every slot is one token per tick, the PR 12
-    # contract). docs/SERVING.md "Speculative decoding"
+    # suffix). 0 = off (every slot is one token per tick).
+    # docs/SERVING.md "Speculative decoding"
     spec_decode: int = 0
     # early-exit depth of the drafter (first E blocks of the same
     # model); 0 = auto: max(1, n_layers // 8) - the measured
@@ -179,15 +173,12 @@ class EngineConfig:
     weight_dtype: str = "bf16"
 
     def __post_init__(self):
-        if self.kv_dtype not in ("bf16", "int8"):
-            raise ValueError(
-                f"kv_dtype must be 'bf16' or 'int8', got {self.kv_dtype!r}"
-            )
-        if self.weight_dtype not in ("bf16", "int8"):
-            raise ValueError(
-                f"weight_dtype must be 'bf16' or 'int8', got "
-                f"{self.weight_dtype!r}"
-            )
+        for name in ("kv_dtype", "weight_dtype"):
+            if getattr(self, name) not in ("bf16", "int8"):
+                raise ValueError(
+                    f"{name} must be 'bf16' or 'int8', got "
+                    f"{getattr(self, name)!r}"
+                )
         if self.decode_impl not in ("auto", "xla", "pallas"):
             raise ValueError(
                 f"decode_impl must be auto/xla/pallas, got "
@@ -336,6 +327,164 @@ def _write_rows(pool, l, idx, val, op: str = "set"):
     return getattr(flat.at[l * n + idx], op)(val).reshape(pool.shape)
 
 
+def _span_idx(table, bs: int):
+    """The pool rows of a block table's whole span, in order: block ids
+    (..., W) -> slots (..., W * bs)."""
+    return (
+        (table * bs)[..., None] + jnp.arange(bs)
+    ).reshape(*table.shape[:-1], -1)
+
+
+def _slot_scales(scales, l, table, bs: int):
+    """An int8 pool's per-(block, head) scales as one scale a slot of the
+    table's span: the same block-table addressing, one repeat a block,
+    (..., W, H) -> (..., W * bs, H)."""
+    return jnp.repeat(_read_rows(scales, l, table), bs, axis=-2)
+
+
+def _read_span(pool, scales, l, table, idx, bs: int, dt):
+    """The table's span at layer ``l`` as (..., S, H, Dh) values at
+    ``dt``: the rows as they are, or - under an int8 pool, ``scales`` not
+    None - dequantized by their blocks' scales."""
+    rows = _read_rows(pool, l, idx)
+    if scales is None:
+        return rows
+    slot = _slot_scales(scales, l, table, bs)
+    return (rows.astype(jnp.float32) * slot[..., None]).astype(dt)
+
+
+def _round_q8(x):
+    return jnp.clip(jnp.round(x), -_INT8_MAX, _INT8_MAX).astype(jnp.int8)
+
+
+def _shrink(s_old, s_new):
+    """What a block's stored codes are multiplied by when its scale grows
+    from s_old to s_new (1 where the block is still empty)."""
+    return jnp.where(
+        s_new > 0.0, s_old / jnp.maximum(s_new, _SCALE_EPS), 1.0
+    )
+
+
+def _append_block(pool, scales, l, val, *, blk, rows, flat):
+    """Decode's write: one new row ``val`` (B, H, Dh) a slot, at pool row
+    ``flat`` (B,) of block ``blk`` (B,), whose bs rows are ``rows`` (B,
+    bs). Returns (pool, scales).
+
+    A bf16 pool (``scales`` None) takes one scatter. An int8 pool
+    quantizes on append with a per-(block, head) running scale: a token
+    whose amax outgrows the block's scale RE-QUANTIZES the block's
+    existing slab under the new scale (one (B, bs) gather/scatter - the
+    block is already hot), so every stored code is always ``value /
+    scales[block]``. Scale growth is monotone per block and both it and
+    the re-rounding depend only on this sequence's own writes -
+    preemption replay is bitwise (tested)."""
+    if scales is None:
+        return _write_rows(pool, l, flat, val), None
+    a = jnp.max(jnp.abs(val.astype(jnp.float32)), -1)      # (B, H)
+    s_old = _read_rows(scales, l, blk)                     # (B, H)
+    s_new = jnp.maximum(s_old, a / _INT8_MAX)
+    slab = _read_rows(pool, l, rows).astype(jnp.float32)   # (B, bs, H, Dh)
+    pool = _write_rows(
+        pool, l, rows,
+        _round_q8(slab * _shrink(s_old, s_new)[:, None, :, None]),
+    )
+    pool = _write_rows(pool, l, flat, _round_q8(
+        val.astype(jnp.float32) / jnp.maximum(s_new[..., None], _SCALE_EPS)
+    ))
+    return pool, _write_rows(scales, l, blk, s_new)
+
+
+def _append_span(pool, scales, l, val, *, table, idx, blkv, flat,
+                 valid=None):
+    """Prefill's and verify's write: new rows ``val`` (..., n, H, Dh) at
+    pool rows ``flat`` (..., n) of blocks ``blkv`` (..., n) of the span
+    ``idx`` (..., S) of ``table`` (..., W); verify has a batch axis in
+    front, prefill's one sequence has none and masks its chunk's dead
+    tail with ``valid`` (n,). Returns (pool, scales).
+
+    The span form of `_append_block` under an int8 pool: the new rows'
+    per-block amax arrives by scatter-max (commutative -> deterministic
+    under duplicate block ids), then the whole span is re-quantized under
+    the grown scales (it is being gathered for attention anyway) and the
+    new rows written at their final scales."""
+    if scales is None:
+        return _write_rows(pool, l, flat, val), None
+    bs = idx.shape[-1] // table.shape[-1]
+    a = jnp.max(jnp.abs(val.astype(jnp.float32)), -1)      # (..., n, H)
+    if valid is not None:
+        a = jnp.where(valid[:, None], a, 0.0)
+    s_old = _read_rows(scales, l, table)                   # (..., W, H)
+    scales = _write_rows(scales, l, blkv, a / _INT8_MAX, "max")
+    s_new = _read_rows(scales, l, table)
+    shrink = jnp.repeat(_shrink(s_old, s_new), bs, axis=-2)
+    slab = _read_rows(pool, l, idx).astype(jnp.float32)    # (..., S, H, Dh)
+    pool = _write_rows(pool, l, idx, _round_q8(slab * shrink[..., None]))
+    s_tok = _read_rows(scales, l, blkv)                    # (..., n, H)
+    pool = _write_rows(pool, l, flat, _round_q8(
+        val.astype(jnp.float32) / jnp.maximum(s_tok[..., None], _SCALE_EPS)
+    ))
+    return pool, scales
+
+
+def _scan_layers(cfg, mm, params, x, pools, cache_step):
+    """The layers of a pool-writing bucket program (decode, prefill,
+    verify): each is the model's block (models/transformer.py `block_qkv`,
+    `block_out`) around the family's own ``cache_step(q, k, v, l, pools)
+    -> (o, pools)``, which writes the new rows at layer ``l`` and attends
+    over what it reads back. Returns (x, pools).
+
+    ``pools`` = (k_pool, v_pool, k_scale, v_scale), the scales None under
+    a bf16 pool (an empty pytree: nothing carried). Donated pools are
+    updated in place only if they also ride the CARRY, the layer index in
+    xs: one buffer from the argument to the output, rows addressed at
+    layer l, no layer's slab ever a value (as scan xs/ys they are two
+    buffers and the compiler copies the pool to alias them: the parent of
+    PR 25 moved 4.8 GB pools several times a program;
+    tests/test_serve_pool_inplace.py pins it on the compiled programs).
+    The scan is not unrolled: a body of one layer feeds the matmuls from
+    the stacked weights, a body of eight slices their weights out into a
+    temporary first (17 ms of a 45 ms decode program at 1.3 B; PERF.md
+    section 6)."""
+    def layer_step(carry, layer):
+        x, pools = carry
+        lp, l = layer
+        q, k, v = tfm.block_qkv(x, lp, cfg, mm)
+        o, pools = cache_step(q, k, v, l, pools)
+        x, _ = tfm.block_out(x, o, lp, cfg, mm)
+        return (x, pools), None
+
+    (x, pools), _ = jax.lax.scan(
+        layer_step, (x, pools),
+        (params["layers"], jnp.arange(cfg.n_layers)),
+    )
+    return x, pools
+
+
+def _write_then_attend(q, k, v, l, pools, *, at, live, dt):
+    """Prefill's and verify's cache step: write the new rows (`at`:
+    `_append_span`'s addressing), read the table's span back - the rows
+    just written included - and attend under ``live``. Verify's q/k/v are
+    (B, K, H, Dh) under a (B, W) table; prefill's one sequence has a (W,)
+    table, and the leading axis of 1 of its (1, C, H, Dh) comes off for
+    the write and goes back on for the attention."""
+    k_pool, v_pool, k_scale, v_scale = pools
+    table, idx = at["table"], at["idx"]
+    bs = idx.shape[-1] // table.shape[-1]
+    one_seq = table.ndim == 1
+    if one_seq:
+        k, v = k[0], v[0]
+    k_pool, k_scale = _append_span(k_pool, k_scale, l, k, **at)
+    v_pool, v_scale = _append_span(v_pool, v_scale, l, v, **at)
+    ks = _read_span(k_pool, k_scale, l, table, idx, bs, dt)
+    vs = _read_span(v_pool, v_scale, l, table, idx, bs, dt)
+    if one_seq:
+        ks, vs = ks[None], vs[None]
+    o = tfm.masked_attention(
+        q, ks.transpose(0, 2, 1, 3), vs.transpose(0, 2, 1, 3), live, dt
+    )
+    return o, (k_pool, v_pool, k_scale, v_scale)
+
+
 @jax.jit
 def _row_keys(seeds, pos):
     """Each decode row's sampling key from its request's seed (low 32
@@ -388,6 +537,7 @@ class ServeEngine:
         if self.weight_quantized:
             params = _prequantize_params(params)
         self.params = jax.device_put(params)
+        self._mm = _make_mm(self.weight_quantized, cfg.dtype)
         self.spec_k = ecfg.spec_decode
         self.draft_layers = 0
         self.draft_params = None
@@ -395,43 +545,31 @@ class ServeEngine:
             self.draft_layers = (
                 ecfg.spec_draft_layers or max(1, cfg.n_layers // 8)
             )
-            if self.draft_layers > cfg.n_layers:
-                raise ValueError(
-                    f"spec_draft_layers {self.draft_layers} > model "
-                    f"n_layers {cfg.n_layers}"
-                )
             if self.spec_k + 1 >= ecfg.max_seq_len:
                 raise ValueError(
                     f"spec_decode {self.spec_k} leaves no room under "
                     f"max_seq_len {ecfg.max_seq_len}"
                 )
-            # the drafter IS the target model early-exited: slice the
-            # stacked layer axis once (embed / final LN / head shared) -
-            # works identically on prequantized int8-w trees
-            self.draft_params = {
-                **self.params,
-                "layers": jax.tree.map(
-                    lambda p: p[: self.draft_layers],
-                    self.params["layers"],
-                ),
-            }
-        dt = cfg.dtype
+            # the drafter IS the target model early-exited: the stacked
+            # layer axis sliced once (embed / final LN / head shared; a
+            # prequantized int8-w tree slices the same way); refuses a
+            # depth the model does not have
+            self.draft_params = tfm.early_exit_params(
+                self.params, self.draft_layers)
         L, H, Dh = cfg.n_layers, cfg.n_heads, cfg.head_dim
         slots = self.kv.cfg.pool_slots
         self.quantized = ecfg.kv_dtype == "int8"
+        pool_dt = jnp.int8 if self.quantized else cfg.dtype
+        self.k_pool = jnp.zeros((L, slots, H, Dh), pool_dt)
+        self.v_pool = jnp.zeros((L, slots, H, Dh), pool_dt)
+        self.k_scale = self.v_scale = None
         if self.quantized:
             # int8 pool + per-(block, head) f32 scales: the one extra
             # small array rides the SAME block-table addressing (scale
             # of slot s = scales[table[s // bs]]), so every gather/
             # scatter index the bf16 path computes is reused verbatim
-            self.k_pool = jnp.zeros((L, slots, H, Dh), jnp.int8)
-            self.v_pool = jnp.zeros((L, slots, H, Dh), jnp.int8)
             self.k_scale = jnp.zeros((L, ecfg.num_blocks, H), jnp.float32)
             self.v_scale = jnp.zeros((L, ecfg.num_blocks, H), jnp.float32)
-        else:
-            self.k_pool = jnp.zeros((L, slots, H, Dh), dt)
-            self.v_pool = jnp.zeros((L, slots, H, Dh), dt)
-            self.k_scale = self.v_scale = None
         self.lock = threading.Lock()
         self.active: list[Sequence] = []
         self._step_fns: dict = {}
@@ -513,10 +651,9 @@ class ServeEngine:
         from ..analysis.cost import kv_block_bytes
 
         cfg = self.cfg
-        dtype = self.kv_dtype_name()
         return kv_block_bytes(
             cfg.n_layers, cfg.n_heads, cfg.head_dim,
-            self.ecfg.block_size, "f32" if dtype == "f32" else dtype,
+            self.ecfg.block_size, self.kv_dtype_name(),
         )
 
     def compiled_programs(self) -> dict:
@@ -536,20 +673,21 @@ class ServeEngine:
         fams["total"] = sum(fams.values())
         return fams
 
-    def _free_seq(self, seq_id: int) -> int:
-        """Free a sequence's blocks; under int8 KV also zero the freed
-        blocks' scales - a reused block must start from scale 0 or the
-        previous owner's scale would leak into the new sequence's
-        quantization (breaking both accuracy and the deterministic
-        preemption replay)."""
-        if not self.quantized:
-            return self.kv.free(seq_id)
-        blocks = self.kv.seq_block_ids(seq_id)
-        n = self.kv.free(seq_id)
-        if blocks:
+    def _zero_scales(self, blocks) -> None:
+        """Under int8 KV a block that goes back to the pool starts again
+        from scale 0, or the previous owner's scale would leak into the
+        new sequence's quantization (breaking both accuracy and the
+        deterministic preemption replay)."""
+        if self.quantized and blocks:
             idx = jnp.asarray(blocks, jnp.int32)
             self.k_scale = self.k_scale.at[:, idx, :].set(0.0)
             self.v_scale = self.v_scale.at[:, idx, :].set(0.0)
+
+    def _free_seq(self, seq_id: int) -> int:
+        """Free a sequence's blocks (and zero their int8 scales)."""
+        blocks = self.kv.seq_block_ids(seq_id) if self.quantized else ()
+        n = self.kv.free(seq_id)
+        self._zero_scales(blocks)
         return n
 
     def _attn_route(self, W: int) -> str:
@@ -604,162 +742,95 @@ class ServeEngine:
 
     # ------------------------------------------------------ jitted steps
 
+    def _jit_bucket(self, program, *, writes_pools: bool = True):
+        """jit one bucket program, written as ``program(params, k_pool,
+        v_pool, k_scale, v_scale, *tail)``. A bf16 pool has no scales:
+        its callable is ``(params, k_pool, v_pool, *tail)``, hands the
+        program None for both (an empty pytree, so no operand) and drops
+        them from what a pool-writing program returns.
+
+        The pools (and under int8 their scales) are donated: every call
+        site threads them through and rebinds the outputs, and an
+        un-donated pool double-buffers the engine's largest allocation
+        for the life of the step (that the update is also in place is
+        `_scan_layers`' doing). Params are NEVER donated (they are not
+        returned - donating them would free the weights after the first
+        call). The drafter READS the pools and returns only draft tokens,
+        so it has nothing to alias and donates nothing. servelint audits
+        the donation contract per bucket (analysis/serve_trace.py)."""
+        if self.quantized:
+            return jax.jit(
+                program, donate_argnums=(1, 2, 3, 4) if writes_pools else ())
+
+        def bucket(params, k_pool, v_pool, *tail):
+            out = program(params, k_pool, v_pool, None, None, *tail)
+            return out[:2] + out[4:] if writes_pools else out
+
+        bucket.__name__ = program.__name__ + "_bf16"
+        return jax.jit(bucket, donate_argnums=(1, 2) if writes_pools else ())
+
     def _decode_fn(self, B: int, W: int):
         fn = self._step_fns.get((B, W))
         if fn is not None:
             return fn
-        cfg, kv = self.cfg, self.kv.cfg
-        dt = cfg.dtype
-        L, H, Dh = cfg.n_layers, cfg.n_heads, cfg.head_dim
-        bs = kv.block_size
+        cfg, dt = self.cfg, self.cfg.dtype
+        H, Dh = cfg.n_heads, cfg.head_dim
+        bs = self.kv.cfg.block_size
         S = W * bs
-        neg = jnp.asarray(-1e30, jnp.float32)
-        quantized = self.quantized
-        attn_route = self._attn_route(W)
-        interpret = not on_tpu()
-        mm = _make_mm(self.weight_quantized, dt)
-
-        def xla_attend(q, ks, vs, live):
-            # the PR 12 chain, byte-identical for the bf16 pool
-            scores = jnp.einsum(
-                "bqhd,bhsd->bhqs", q, ks
-            ).astype(jnp.float32)
-            scores = scores / np.sqrt(Dh)
-            probs = jax.nn.softmax(
-                jnp.where(live, scores, neg), axis=-1
-            )
-            return jnp.einsum(
-                "bhqs,bhsd->bqhd", probs.astype(dt), vs
-            ).reshape(B, 1, H * Dh)
+        use_kernel = self._attn_route(W) == "pallas"
 
         def step(params, k_pool, v_pool, k_scale, v_scale,
                  tok, pos, table, temps, keys):
             # tok/pos (B,), table (B, W), temps (B,), keys (B, 2);
-            # k_scale/v_scale (L, num_blocks, H) f32, or None under a
-            # bf16 pool (an empty pytree in the scan's carry)
+            # k_scale/v_scale (L, num_blocks, H) f32, or None
             x = params["embed"][tok].astype(dt)[:, None, :]
             x = x + _sinusoid_pe(pos, cfg.d_model, dt)[:, None, :]
             blk = table[jnp.arange(B), pos // bs]
-            flat = blk * bs + pos % bs
-            gather_idx = (
-                (table * bs)[:, :, None] + jnp.arange(bs)[None, None, :]
-            ).reshape(B, S)
+            at = {
+                "blk": blk,
+                "rows": blk[:, None] * bs + jnp.arange(bs)[None, :],
+                "flat": blk * bs + pos % bs,
+            }
+            idx = _span_idx(table, bs)                        # (B, S)
             live = (jnp.arange(S)[None, :] <= pos[:, None])[:, None, None, :]
-            rows = blk[:, None] * bs + jnp.arange(bs)[None, :]  # (B, bs)
 
-            def append_q8(pool, scales, l, val):
-                # quantize-on-append with a per-(block, head) running
-                # scale: a token whose amax outgrows the block's scale
-                # RE-QUANTIZES the block's existing slab under the new
-                # scale (one (B, bs) gather/scatter - the block is
-                # already hot), so every stored code is always ``value /
-                # scales[block]``. Scale growth is monotone per block
-                # and both it and the re-rounding depend only on this
-                # sequence's own writes - preemption replay is bitwise
-                # (tested).
-                a = jnp.max(jnp.abs(val.astype(jnp.float32)), -1)  # (B,H)
-                s_old = _read_rows(scales, l, blk)                 # (B,H)
-                s_new = jnp.maximum(s_old, a / _INT8_MAX)
-                ratio = jnp.where(
-                    s_new > 0.0,
-                    s_old / jnp.maximum(s_new, _SCALE_EPS), 1.0
+            def cache_step(q, k, v, l, pools):
+                # write this position's row, gather the bucket, attend
+                k_pool, v_pool, k_scale, v_scale = pools
+                k_pool, k_scale = _append_block(
+                    k_pool, k_scale, l, k.reshape(B, H, Dh), **at)
+                v_pool, v_scale = _append_block(
+                    v_pool, v_scale, l, v.reshape(B, H, Dh), **at)
+                pools = (k_pool, v_pool, k_scale, v_scale)
+                if not use_kernel:
+                    ks = _read_span(k_pool, k_scale, l, table, idx, bs, dt)
+                    vs = _read_span(v_pool, v_scale, l, table, idx, bs, dt)
+                    return tfm.masked_attention(
+                        q, ks.transpose(0, 2, 1, 3),
+                        vs.transpose(0, 2, 1, 3), live, dt,
+                    ), pools
+                # the tuned decode kernel masks on `pos` itself and reads
+                # an int8 pool's stream as it is, the dequantization fused
+                # in its k-block loop
+                slot = {} if k_scale is None else {
+                    "k_scale": _slot_scales(
+                        k_scale, l, table, bs).transpose(0, 2, 1),
+                    "v_scale": _slot_scales(
+                        v_scale, l, table, bs).transpose(0, 2, 1),
+                }
+                o = decode_cache_attention(
+                    q.reshape(B, H, Dh),
+                    _read_rows(k_pool, l, idx).transpose(0, 2, 1, 3),
+                    _read_rows(v_pool, l, idx).transpose(0, 2, 1, 3),
+                    pos, interpret=not on_tpu(), **slot,
                 )
-                slab = _read_rows(pool, l, rows).astype(jnp.float32)
-                slab = jnp.clip(                        # (B, bs, H, Dh)
-                    jnp.round(slab * ratio[:, None, :, None]),
-                    -_INT8_MAX, _INT8_MAX,
-                ).astype(jnp.int8)
-                pool = _write_rows(pool, l, rows, slab)
-                q8 = jnp.clip(
-                    jnp.round(
-                        val.astype(jnp.float32)
-                        / jnp.maximum(s_new[..., None], _SCALE_EPS)
-                    ),
-                    -_INT8_MAX, _INT8_MAX,
-                ).astype(jnp.int8)
-                pool = _write_rows(pool, l, flat, q8)
-                scales = _write_rows(scales, l, blk, s_new)
-                return pool, scales
+                return o[:, None], pools
 
-            def layer_step(carry, layer):
-                # the pools ride the CARRY (layer index in xs): one
-                # buffer from the donated argument to the output, rows
-                # addressed at layer l, no layer's slab ever a value.
-                # The scan is not unrolled: a body of one layer feeds
-                # the matmuls from the stacked weights, a body of eight
-                # slices their weights out into a temporary first (17 ms
-                # of a 45 ms decode program at 1.3 B; PERF.md section 6)
-                x, k_pool, v_pool, k_scale, v_scale = carry
-                lp, l = layer
-                h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"]).astype(dt)
-                q = mm(h, lp["wq"]).reshape(B, 1, H, Dh)
-                k = mm(h, lp["wk"]).reshape(B, H, Dh)
-                v = mm(h, lp["wv"]).reshape(B, H, Dh)
-                if quantized:
-                    k_pool, k_scale = append_q8(k_pool, k_scale, l, k)
-                    v_pool, v_scale = append_q8(v_pool, v_scale, l, v)
-                else:
-                    k_pool = _write_rows(k_pool, l, flat, k)
-                    v_pool = _write_rows(v_pool, l, flat, v)
-                ks_g = _read_rows(k_pool, l, gather_idx)   # (B, S, H, Dh)
-                vs_g = _read_rows(v_pool, l, gather_idx)
-                if quantized:
-                    # per-slot scale view: same block-table addressing,
-                    # one repeat per block (B, W, H) -> (B, S, H)
-                    k_slot = jnp.repeat(
-                        _read_rows(k_scale, l, table), bs, axis=1
-                    )
-                    v_slot = jnp.repeat(
-                        _read_rows(v_scale, l, table), bs, axis=1
-                    )
-                    if attn_route == "pallas":
-                        # the tuned decode kernel reads the int8 stream
-                        # directly - dequant fused in its k-block loop
-                        o = decode_cache_attention(
-                            q.reshape(B, H, Dh),
-                            ks_g.transpose(0, 2, 1, 3),
-                            vs_g.transpose(0, 2, 1, 3),
-                            pos,
-                            k_scale=k_slot.transpose(0, 2, 1),
-                            v_scale=v_slot.transpose(0, 2, 1),
-                            interpret=interpret,
-                        ).reshape(B, 1, H * Dh)
-                    else:
-                        ks = (
-                            ks_g.astype(jnp.float32) * k_slot[..., None]
-                        ).astype(dt).transpose(0, 2, 1, 3)
-                        vs = (
-                            vs_g.astype(jnp.float32) * v_slot[..., None]
-                        ).astype(dt).transpose(0, 2, 1, 3)
-                        o = xla_attend(q, ks, vs, live)
-                elif attn_route == "pallas":
-                    o = decode_cache_attention(
-                        q.reshape(B, H, Dh),
-                        ks_g.transpose(0, 2, 1, 3),
-                        vs_g.transpose(0, 2, 1, 3),
-                        pos, interpret=interpret,
-                    ).reshape(B, 1, H * Dh)
-                else:
-                    o = xla_attend(
-                        q, ks_g.transpose(0, 2, 1, 3),
-                        vs_g.transpose(0, 2, 1, 3), live,
-                    )
-                x = x + mm(o, lp["wo"])
-                h2 = _layer_norm(
-                    x, lp["ln2_scale"], lp["ln2_bias"]
-                ).astype(dt)
-                h2 = jax.nn.gelu(mm(h2, lp["w1"]) + lp["b1"].astype(dt))
-                x = x + mm(h2, lp["w2"]) + lp["b2"].astype(dt)
-                return (x, k_pool, v_pool, k_scale, v_scale), None
-
-            (x, k_pool, v_pool, k_scale, v_scale), _ = jax.lax.scan(
-                layer_step, (x, k_pool, v_pool, k_scale, v_scale),
-                (params["layers"], jnp.arange(L)),
+            x, pools = _scan_layers(
+                cfg, self._mm, params, x, (k_pool, v_pool, k_scale, v_scale),
+                cache_step,
             )
-            h = _layer_norm(
-                x, params["lnf_scale"], params["lnf_bias"]
-            ).astype(dt)
-            logits = h[:, 0] @ params["head"].astype(dt).astype(jnp.float32)
+            logits = tfm.final_logits(params, x[:, 0], dt)
             greedy = jnp.argmax(logits, axis=-1)
             sampled = jax.vmap(
                 lambda k_, lg, t: jax.random.categorical(
@@ -767,49 +838,18 @@ class ServeEngine:
                 )
             )(keys, logits, temps)
             nxt = jnp.where(temps > 0.0, sampled, greedy).astype(jnp.int32)
-            return k_pool, v_pool, k_scale, v_scale, nxt, logits
+            return *pools, nxt, logits
 
-        # the pools (and under int8 their scales) are donated: every
-        # call site threads them through and rebinds the outputs, and
-        # an un-donated pool double-buffers the engine's largest
-        # allocation for the life of the step. Donation alone does not
-        # make the update in place: the pools must also be LOOP-CARRIED
-        # through the layer scan (as scan xs/ys they are two buffers,
-        # and the compiler copies the pool to alias them - the parent of
-        # PR 25 moved 4.8 GB pools several times a program), which
-        # tests/test_serve_pool_inplace.py pins on the compiled
-        # programs. Params are NEVER donated (they are not returned -
-        # donating them would free the weights after the first call).
-        # servelint audits the donation contract per bucket
-        # (analysis/serve_trace.py).
-        if quantized:
-            fn = jax.jit(step, donate_argnums=(1, 2, 3, 4))
-        else:
-            # bf16 keeps the PR 12 signature (no scale operands)
-            def step_bf16(params, k_pool, v_pool, tok, pos, table,
-                          temps, keys):
-                k_pool, v_pool, _, _, nxt, logits = step(
-                    params, k_pool, v_pool, None, None, tok, pos, table,
-                    temps, keys,
-                )
-                return k_pool, v_pool, nxt, logits
-
-            fn = jax.jit(step_bf16, donate_argnums=(1, 2))
-        self._step_fns[(B, W)] = fn
+        fn = self._step_fns[(B, W)] = self._jit_bucket(step)
         return fn
 
     def _prefill_fn(self, C: int, W: int):
         fn = self._prefill_fns.get((C, W))
         if fn is not None:
             return fn
-        cfg, kv = self.cfg, self.kv.cfg
-        dt = cfg.dtype
-        L, H, Dh = cfg.n_layers, cfg.n_heads, cfg.head_dim
-        bs = kv.block_size
+        cfg, dt = self.cfg, self.cfg.dtype
+        bs = self.kv.cfg.block_size
         S = W * bs
-        neg = jnp.asarray(-1e30, jnp.float32)
-        quantized = self.quantized
-        mm = _make_mm(self.weight_quantized, dt)
 
         def prefill(params, k_pool, v_pool, k_scale, v_scale,
                     toks, pos0, table, n_valid):
@@ -819,128 +859,25 @@ class ServeEngine:
             x = params["embed"][toks].astype(dt)[None]  # (1, C, d)
             x = x + _sinusoid_pe(pv, cfg.d_model, dt)[None]
             flat = table[pv // bs] * bs + pv % bs
-            flat = jnp.where(valid, flat, 0)  # dead tail -> scratch
-            blkv = jnp.where(valid, table[pv // bs], 0)  # (C,) block ids
-            gather_idx = (
-                (table * bs)[:, None] + jnp.arange(bs)[None, :]
-            ).reshape(S)
+            at = {
+                "table": table,
+                "idx": _span_idx(table, bs),                  # (S,)
+                "blkv": jnp.where(valid, table[pv // bs], 0),
+                "flat": jnp.where(valid, flat, 0),  # dead tail -> scratch
+                "valid": valid,
+            }
             # query at chunk offset q attends to positions <= pos0 + q
             live = (
                 jnp.arange(S)[None, :] <= pv[:, None]
             )[None, None, :, :]  # (1, 1, C, S)
 
-            def append_q8(pool, scales, l, val):
-                # chunk form of the decode append: the chunk's per-block
-                # amax arrives by scatter-max (commutative ->
-                # deterministic under duplicate block ids), then the
-                # whole table span is re-quantized under the grown
-                # scales (it is being gathered for attention anyway)
-                # and the chunk written at its final scales
-                a = jnp.where(
-                    valid[:, None],
-                    jnp.max(jnp.abs(val.astype(jnp.float32)), -1),
-                    0.0,
-                )                                         # (C, H)
-                s_old = _read_rows(scales, l, table)      # (W, H)
-                scales = _write_rows(
-                    scales, l, blkv, a / _INT8_MAX, "max"
-                )
-                s_new = _read_rows(scales, l, table)
-                ratio = jnp.where(
-                    s_new > 0.0,
-                    s_old / jnp.maximum(s_new, _SCALE_EPS), 1.0
-                )
-                ratio_slot = jnp.repeat(ratio, bs, axis=0)
-                slab = _read_rows(pool, l, gather_idx).astype(jnp.float32)
-                slab = jnp.clip(                          # (S, H, Dh)
-                    jnp.round(slab * ratio_slot[..., None]),
-                    -_INT8_MAX, _INT8_MAX,
-                ).astype(jnp.int8)
-                pool = _write_rows(pool, l, gather_idx, slab)
-                s_tok = _read_rows(scales, l, blkv)       # (C, H)
-                q8 = jnp.clip(
-                    jnp.round(
-                        val.astype(jnp.float32)
-                        / jnp.maximum(s_tok[..., None], _SCALE_EPS)
-                    ),
-                    -_INT8_MAX, _INT8_MAX,
-                ).astype(jnp.int8)
-                pool = _write_rows(pool, l, flat, q8)
-                return pool, scales
-
-            def layer_step(carry, layer):
-                # pools in the carry, rows at layer l: see _decode_fn
-                x, k_pool, v_pool, k_scale, v_scale = carry
-                lp, l = layer
-                h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"]).astype(dt)
-                q = mm(h, lp["wq"]).reshape(1, C, H, Dh)
-                k = mm(h, lp["wk"]).reshape(C, H, Dh)
-                v = mm(h, lp["wv"]).reshape(C, H, Dh)
-                if quantized:
-                    k_pool, k_scale = append_q8(k_pool, k_scale, l, k)
-                    v_pool, v_scale = append_q8(v_pool, v_scale, l, v)
-                else:
-                    k_pool = _write_rows(k_pool, l, flat, k)
-                    v_pool = _write_rows(v_pool, l, flat, v)
-                ks = _read_rows(k_pool, l, gather_idx)       # (S, H, Dh)
-                vs = _read_rows(v_pool, l, gather_idx)
-                if quantized:
-                    k_slot = jnp.repeat(
-                        _read_rows(k_scale, l, table), bs, axis=0
-                    )                                        # (S, H)
-                    v_slot = jnp.repeat(
-                        _read_rows(v_scale, l, table), bs, axis=0
-                    )
-                    ks = (
-                        ks.astype(jnp.float32) * k_slot[..., None]
-                    ).astype(dt)
-                    vs = (
-                        vs.astype(jnp.float32) * v_slot[..., None]
-                    ).astype(dt)
-                ks = ks[None].transpose(0, 2, 1, 3)
-                vs = vs[None].transpose(0, 2, 1, 3)
-                scores = jnp.einsum(
-                    "bqhd,bhsd->bhqs", q, ks
-                ).astype(jnp.float32)
-                scores = scores / np.sqrt(Dh)
-                probs = jax.nn.softmax(
-                    jnp.where(live, scores, neg), axis=-1
-                )
-                o = jnp.einsum(
-                    "bhqs,bhsd->bqhd", probs.astype(dt), vs
-                ).reshape(1, C, H * Dh)
-                x = x + mm(o, lp["wo"])
-                h2 = _layer_norm(
-                    x, lp["ln2_scale"], lp["ln2_bias"]
-                ).astype(dt)
-                h2 = jax.nn.gelu(mm(h2, lp["w1"]) + lp["b1"].astype(dt))
-                x = x + mm(h2, lp["w2"]) + lp["b2"].astype(dt)
-                return (x, k_pool, v_pool, k_scale, v_scale), None
-
-            (x, k_pool, v_pool, k_scale, v_scale), _ = jax.lax.scan(
-                layer_step, (x, k_pool, v_pool, k_scale, v_scale),
-                (params["layers"], jnp.arange(L)),
+            x, pools = _scan_layers(
+                cfg, self._mm, params, x, (k_pool, v_pool, k_scale, v_scale),
+                partial(_write_then_attend, at=at, live=live, dt=dt),
             )
-            h = _layer_norm(
-                x, params["lnf_scale"], params["lnf_bias"]
-            ).astype(dt)
-            logits = h[0] @ params["head"].astype(dt).astype(jnp.float32)
-            return k_pool, v_pool, k_scale, v_scale, logits  # (C, vocab)
+            return *pools, tfm.final_logits(params, x[0], dt)  # (C, vocab)
 
-        # pool donation: same contract as _decode_fn (params never)
-        if quantized:
-            fn = jax.jit(prefill, donate_argnums=(1, 2, 3, 4))
-        else:
-            def prefill_bf16(params, k_pool, v_pool, toks, pos0, table,
-                             n_valid):
-                k_pool, v_pool, _, _, logits = prefill(
-                    params, k_pool, v_pool, None, None, toks, pos0,
-                    table, n_valid,
-                )
-                return k_pool, v_pool, logits
-
-            fn = jax.jit(prefill_bf16, donate_argnums=(1, 2))
-        self._prefill_fns[(C, W)] = fn
+        fn = self._prefill_fns[(C, W)] = self._jit_bucket(prefill)
         return fn
 
     def _draft_fn(self, B: int, W: int):
@@ -952,38 +889,25 @@ class ServeEngine:
         fn = self._draft_fns.get((B, W))
         if fn is not None:
             return fn
-        cfg, kv = self.cfg, self.kv.cfg
-        dt = cfg.dtype
+        cfg, dt = self.cfg, self.cfg.dtype
         E, K = self.draft_layers, self.spec_k
         H, Dh = cfg.n_heads, cfg.head_dim
-        bs = kv.block_size
+        bs = self.kv.cfg.block_size
         S = W * bs
-        neg = jnp.asarray(-1e30, jnp.float32)
-        quantized = self.quantized
-        mm = _make_mm(self.weight_quantized, dt)
 
         def draft(params, k_pool, v_pool, k_scale, v_scale,
                   tok, pos, table):
             # tok/pos (B,), table (B, W) -> (B, K) greedy draft tokens.
             # Gather + (int8) dequantize the E layers of pool history
             # ONCE - it is invariant across the K draft steps.
-            gather_idx = (
-                (table * bs)[:, :, None] + jnp.arange(bs)[None, None, :]
-            ).reshape(B, S)
+            idx = _span_idx(table, bs)                        # (B, S)
             layers = jnp.arange(E)[:, None, None]
-            hk = _read_rows(k_pool, layers, gather_idx)  # (E, B, S, H, Dh)
-            hv = _read_rows(v_pool, layers, gather_idx)
-            if quantized:
-                k_slot = jnp.repeat(
-                    _read_rows(k_scale, layers, table), bs, axis=2
-                )                               # (E, B, S, H)
-                v_slot = jnp.repeat(
-                    _read_rows(v_scale, layers, table), bs, axis=2
-                )
-                hk = (hk.astype(jnp.float32) * k_slot[..., None]).astype(dt)
-                hv = (hv.astype(jnp.float32) * v_slot[..., None]).astype(dt)
-            hk = hk.transpose(0, 1, 3, 2, 4)   # (E, B, H, S, Dh)
-            hv = hv.transpose(0, 1, 3, 2, 4)
+            hk = _read_span(                         # (E, B, H, S, Dh)
+                k_pool, k_scale, layers, table, idx, bs, dt
+            ).transpose(0, 1, 3, 2, 4)
+            hv = _read_span(
+                v_pool, v_scale, layers, table, idx, bs, dt
+            ).transpose(0, 1, 3, 2, 4)
             hist_live = (jnp.arange(S)[None, :] < pos[:, None])  # (B, S)
             bufk = jnp.zeros((E, B, H, K, Dh), dt)
             bufv = jnp.zeros((E, B, H, K, Dh), dt)
@@ -991,70 +915,40 @@ class ServeEngine:
             for i in range(K):
                 x = params["embed"][tok].astype(dt)[:, None, :]
                 x = x + _sinusoid_pe(pos + i, cfg.d_model, dt)[:, None, :]
-                loc = jnp.broadcast_to(
-                    (jnp.arange(K) <= i)[None, :], (B, K)
-                )
+                loc = jnp.broadcast_to(jnp.arange(K) <= i, (B, K))
                 live = jnp.concatenate(
                     [hist_live, loc], axis=1
                 )[:, None, None, :]             # (B, 1, 1, S + K)
 
                 def layer_step(x, lc, i=i):
+                    # the model's block around the local buffer beside
+                    # the pre-gathered history
                     lp, lhk, lhv, bk, bv = lc
-                    h = _layer_norm(
-                        x, lp["ln1_scale"], lp["ln1_bias"]
-                    ).astype(dt)
-                    q = mm(h, lp["wq"]).reshape(B, 1, H, Dh)
-                    kk = mm(h, lp["wk"]).reshape(B, H, 1, Dh)
-                    vv = mm(h, lp["wv"]).reshape(B, H, 1, Dh)
+                    q, k, v = tfm.block_qkv(x, lp, cfg, self._mm)
                     bk = jax.lax.dynamic_update_slice_in_dim(
-                        bk, kk, i, axis=2
+                        bk, k.transpose(0, 2, 1, 3), i, axis=2
                     )
                     bv = jax.lax.dynamic_update_slice_in_dim(
-                        bv, vv, i, axis=2
+                        bv, v.transpose(0, 2, 1, 3), i, axis=2
                     )
-                    ks = jnp.concatenate([lhk, bk], axis=2)
-                    vs = jnp.concatenate([lhv, bv], axis=2)
-                    scores = jnp.einsum(
-                        "bqhd,bhsd->bhqs", q, ks
-                    ).astype(jnp.float32) / np.sqrt(Dh)
-                    probs = jax.nn.softmax(
-                        jnp.where(live, scores, neg), axis=-1
+                    o = tfm.masked_attention(
+                        q, jnp.concatenate([lhk, bk], axis=2),
+                        jnp.concatenate([lhv, bv], axis=2), live, dt,
                     )
-                    o = jnp.einsum(
-                        "bhqs,bhsd->bqhd", probs.astype(dt), vs
-                    ).reshape(B, 1, H * Dh)
-                    x = x + mm(o, lp["wo"])
-                    h2 = _layer_norm(
-                        x, lp["ln2_scale"], lp["ln2_bias"]
-                    ).astype(dt)
-                    h2 = jax.nn.gelu(
-                        mm(h2, lp["w1"]) + lp["b1"].astype(dt)
-                    )
-                    x = x + mm(h2, lp["w2"]) + lp["b2"].astype(dt)
+                    x, _ = tfm.block_out(x, o, lp, cfg, self._mm)
                     return x, (bk, bv)
 
                 x, (bufk, bufv) = jax.lax.scan(
                     layer_step, x, (params["layers"], hk, hv, bufk, bufv),
                     unroll=min(E, 8),
                 )
-                h = _layer_norm(
-                    x, params["lnf_scale"], params["lnf_bias"]
-                ).astype(dt)
-                logits = h[:, 0] @ params["head"].astype(dt).astype(jnp.float32)
+                logits = tfm.final_logits(params, x[:, 0], dt)
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 drafts.append(tok)
             return jnp.stack(drafts, axis=1)    # (B, K)
 
-        if quantized:
-            fn = jax.jit(draft)
-        else:
-            def draft_bf16(params, k_pool, v_pool, tok, pos, table):
-                return draft(
-                    params, k_pool, v_pool, None, None, tok, pos, table
-                )
-
-            fn = jax.jit(draft_bf16)
-        self._draft_fns[(B, W)] = fn
+        fn = self._draft_fns[(B, W)] = self._jit_bucket(
+            draft, writes_pools=False)
         return fn
 
     def _verify_fn(self, B: int, W: int):
@@ -1067,15 +961,10 @@ class ServeEngine:
         fn = self._verify_fns.get((B, W))
         if fn is not None:
             return fn
-        cfg, kv = self.cfg, self.kv.cfg
-        dt = cfg.dtype
-        L, H, Dh = cfg.n_layers, cfg.n_heads, cfg.head_dim
+        cfg, dt = self.cfg, self.cfg.dtype
         K = self.spec_k + 1
-        bs = kv.block_size
+        bs = self.kv.cfg.block_size
         S = W * bs
-        neg = jnp.asarray(-1e30, jnp.float32)
-        quantized = self.quantized
-        mm = _make_mm(self.weight_quantized, dt)
 
         def verify(params, k_pool, v_pool, k_scale, v_scale,
                    toks, pos0, table):
@@ -1086,133 +975,38 @@ class ServeEngine:
                 pv.reshape(-1), cfg.d_model, dt
             ).reshape(B, K, cfg.d_model)
             blkv = jnp.take_along_axis(table, pv // bs, axis=1)  # (B, K)
-            flat = blkv * bs + pv % bs                           # (B, K)
-            gather_idx = (
-                (table * bs)[:, :, None] + jnp.arange(bs)[None, None, :]
-            ).reshape(B, S)
+            at = {
+                "table": table,
+                "idx": _span_idx(table, bs),                  # (B, S)
+                "blkv": blkv,
+                "flat": blkv * bs + pv % bs,
+            }
             # query (b, i) attends pool slots <= pos0[b] + i (its own
-            # just-written position included - write-then-gather, the
-            # chunked-prefill pattern with a batch axis)
+            # just-written position included)
             live = (
                 jnp.arange(S)[None, None, :] <= pv[:, :, None]
             )[:, None]                                       # (B,1,K,S)
 
-            def append_q8(pool, scales, l, val):
-                # batch form of the chunked-prefill append: per-block
-                # amax by scatter-max (commutative -> deterministic
-                # under duplicate block ids), whole-table-span requant
-                # under the grown scales, then the K new tokens written
-                # at their final scales
-                a = jnp.max(jnp.abs(val.astype(jnp.float32)), -1)  # (B,K,H)
-                s_old = _read_rows(scales, l, table)         # (B, W, H)
-                scales = _write_rows(
-                    scales, l, blkv, a / _INT8_MAX, "max"
-                )
-                s_new = _read_rows(scales, l, table)
-                ratio = jnp.where(
-                    s_new > 0.0,
-                    s_old / jnp.maximum(s_new, _SCALE_EPS), 1.0
-                )
-                ratio_slot = jnp.repeat(ratio, bs, axis=1)
-                slab = _read_rows(pool, l, gather_idx).astype(jnp.float32)
-                slab = jnp.clip(                             # (B,S,H,Dh)
-                    jnp.round(slab * ratio_slot[..., None]),
-                    -_INT8_MAX, _INT8_MAX,
-                ).astype(jnp.int8)
-                pool = _write_rows(pool, l, gather_idx, slab)
-                s_tok = _read_rows(scales, l, blkv)          # (B, K, H)
-                q8 = jnp.clip(
-                    jnp.round(
-                        val.astype(jnp.float32)
-                        / jnp.maximum(s_tok[..., None], _SCALE_EPS)
-                    ),
-                    -_INT8_MAX, _INT8_MAX,
-                ).astype(jnp.int8)
-                pool = _write_rows(pool, l, flat, q8)
-                return pool, scales
-
-            def layer_step(carry, layer):
-                # pools in the carry, rows at layer l: see _decode_fn
-                x, k_pool, v_pool, k_scale, v_scale = carry
-                lp, l = layer
-                h = _layer_norm(
-                    x, lp["ln1_scale"], lp["ln1_bias"]
-                ).astype(dt)
-                q = mm(h, lp["wq"]).reshape(B, K, H, Dh)
-                k = mm(h, lp["wk"]).reshape(B, K, H, Dh)
-                v = mm(h, lp["wv"]).reshape(B, K, H, Dh)
-                if quantized:
-                    k_pool, k_scale = append_q8(k_pool, k_scale, l, k)
-                    v_pool, v_scale = append_q8(v_pool, v_scale, l, v)
-                else:
-                    k_pool = _write_rows(k_pool, l, flat, k)
-                    v_pool = _write_rows(v_pool, l, flat, v)
-                ks = _read_rows(k_pool, l, gather_idx)    # (B, S, H, Dh)
-                vs = _read_rows(v_pool, l, gather_idx)
-                if quantized:
-                    k_slot = jnp.repeat(
-                        _read_rows(k_scale, l, table), bs, axis=1
-                    )                                     # (B, S, H)
-                    v_slot = jnp.repeat(
-                        _read_rows(v_scale, l, table), bs, axis=1
-                    )
-                    ks = (
-                        ks.astype(jnp.float32) * k_slot[..., None]
-                    ).astype(dt)
-                    vs = (
-                        vs.astype(jnp.float32) * v_slot[..., None]
-                    ).astype(dt)
-                ks = ks.transpose(0, 2, 1, 3)
-                vs = vs.transpose(0, 2, 1, 3)
-                scores = jnp.einsum(
-                    "bqhd,bhsd->bhqs", q, ks
-                ).astype(jnp.float32) / np.sqrt(Dh)
-                probs = jax.nn.softmax(
-                    jnp.where(live, scores, neg), axis=-1
-                )
-                o = jnp.einsum(
-                    "bhqs,bhsd->bqhd", probs.astype(dt), vs
-                ).reshape(B, K, H * Dh)
-                x = x + mm(o, lp["wo"])
-                h2 = _layer_norm(
-                    x, lp["ln2_scale"], lp["ln2_bias"]
-                ).astype(dt)
-                h2 = jax.nn.gelu(mm(h2, lp["w1"]) + lp["b1"].astype(dt))
-                x = x + mm(h2, lp["w2"]) + lp["b2"].astype(dt)
-                return (x, k_pool, v_pool, k_scale, v_scale), None
-
-            (x, k_pool, v_pool, k_scale, v_scale), _ = jax.lax.scan(
-                layer_step, (x, k_pool, v_pool, k_scale, v_scale),
-                (params["layers"], jnp.arange(L)),
+            x, pools = _scan_layers(
+                cfg, self._mm, params, x, (k_pool, v_pool, k_scale, v_scale),
+                partial(_write_then_attend, at=at, live=live, dt=dt),
             )
-            h = _layer_norm(
-                x, params["lnf_scale"], params["lnf_bias"]
-            ).astype(dt)
-            logits = h @ params["head"].astype(dt).astype(jnp.float32)  # (B,K,v)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return k_pool, v_pool, k_scale, v_scale, nxt
+            logits = tfm.final_logits(params, x, dt)         # (B, K, v)
+            return *pools, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-        # pool donation: same contract as _decode_fn (params never).
-        # _draft_fn stays donation-free by design - it READS the pools
-        # and returns only draft tokens, so there is nothing to alias.
-        if quantized:
-            fn = jax.jit(verify, donate_argnums=(1, 2, 3, 4))
-        else:
-            def verify_bf16(params, k_pool, v_pool, toks, pos0, table):
-                k_pool, v_pool, _, _, nxt = verify(
-                    params, k_pool, v_pool, None, None, toks, pos0, table
-                )
-                return k_pool, v_pool, nxt
-
-            fn = jax.jit(verify_bf16, donate_argnums=(1, 2))
-        self._verify_fns[(B, W)] = fn
+        fn = self._verify_fns[(B, W)] = self._jit_bucket(verify)
         return fn
 
     def _pools(self) -> tuple:
         """The donated operands of a bucket program, in its order."""
-        if self.quantized:
-            return (self.k_pool, self.v_pool, self.k_scale, self.v_scale)
-        return (self.k_pool, self.v_pool)
+        pools = (self.k_pool, self.v_pool, self.k_scale, self.v_scale)
+        return pools if self.quantized else pools[:2]
+
+    def _table(self, seqs: list, B: int, W: int):
+        """The (B, W) block table of a batch bucket: a row a sequence, the
+        bucket's spare rows on the scratch block."""
+        return self.kv.table(
+            [s.seq_id for s in seqs] + [-1] * (B - len(seqs)), W)
 
     def _run_writer(self, fn, *tail) -> tuple:
         """Dispatch one pool-writing bucket program: the pools (and int8
@@ -1262,12 +1056,10 @@ class ServeEngine:
                 fn(*args)  # read-only: no pool state to rebind
             else:
                 self._run_writer(fn, *tail)
-                if self.quantized:
-                    # warmup writes land in the scratch block; its scale
-                    # is garbage by contract, but reset anyway so a
-                    # fresh engine stays bitwise clean
-                    self.k_scale = self.k_scale.at[:, 0, :].set(0.0)
-                    self.v_scale = self.v_scale.at[:, 0, :].set(0.0)
+                # warmup writes land in the scratch block; its scale is
+                # garbage by contract, but reset anyway so a fresh engine
+                # stays bitwise clean
+                self._zero_scales([0])
             n += 1
 
         def zeros(*shape):
@@ -1354,13 +1146,8 @@ class ServeEngine:
 
     def _rewind_seq(self, seq_id: int, n_tokens: int) -> None:
         """Rewind the KV write cursor past a rejected speculative
-        suffix; freed blocks get their int8 scales zeroed (the same
-        history-free-reuse contract `_free_seq` keeps)."""
-        freed = self.kv.rewind(seq_id, n_tokens)
-        if freed and self.quantized:
-            idx = jnp.asarray(freed, jnp.int32)
-            self.k_scale = self.k_scale.at[:, idx, :].set(0.0)
-            self.v_scale = self.v_scale.at[:, idx, :].set(0.0)
+        suffix; freed blocks get their int8 scales zeroed."""
+        self._zero_scales(self.kv.rewind(seq_id, n_tokens))
 
     def _spec_step(self, batch: list, stats: dict, seqstat) -> None:
         """The speculative phase of one tick: draft k tokens per slot
@@ -1384,18 +1171,13 @@ class ServeEngine:
                 need_draft.append(idx)
         draft_s = 0.0
         if need_draft:
-            Bd = _bucket(len(need_draft))
-            if Bd > self.ecfg.max_batch:
-                Bd = self.ecfg.max_batch
+            Bd = min(_bucket(len(need_draft)), self.ecfg.max_batch)
             dtok = np.zeros((Bd,), np.int32)
             dpos = np.zeros((Bd,), np.int32)
             for row, idx in enumerate(need_draft):
                 dtok[row] = batch[idx].next_input()
                 dpos[row] = batch[idx].pos
-            dtable = self.kv.table(
-                [batch[i].seq_id for i in need_draft]
-                + [-1] * (Bd - len(need_draft)), W,
-            )
+            dtable = self._table([batch[i] for i in need_draft], Bd, W)
             fn = self._draft_fn(Bd, W)
             t0 = time.perf_counter()
             out_d = np.asarray(fn(       # asarray = device sync
@@ -1406,18 +1188,14 @@ class ServeEngine:
             for row, idx in enumerate(need_draft):
                 drafts[idx] = out_d[row]
 
-        B = _bucket(n)
-        if B > self.ecfg.max_batch:
-            B = self.ecfg.max_batch
+        B = min(_bucket(n), self.ecfg.max_batch)
         toks = np.zeros((B, K), np.int32)
         pos0 = np.zeros((B,), np.int32)
         for i, s in enumerate(batch):
             toks[i, 0] = s.next_input()
             toks[i, 1:] = drafts[i]
             pos0[i] = s.pos
-        table = self.kv.table(
-            [s.seq_id for s in batch] + [-1] * (B - n), W
-        )
+        table = self._table(batch, B, W)
         fn = self._verify_fn(B, W)
         tail = (jnp.asarray(toks), jnp.asarray(pos0), jnp.asarray(table))
         t0 = time.perf_counter()
@@ -1570,9 +1348,7 @@ class ServeEngine:
                         seqstat(seq)["parked"] = True
                         continue
                     C = _bucket(n)
-                    W = _bucket(
-                        (seq.pos + n - 1) // bs + 1
-                    )
+                    W = _bucket((seq.pos + n - 1) // bs + 1)
                     toks = np.zeros((C,), np.int32)
                     toks[:n] = seq.prompt[seq.pos: seq.pos + n]
                     table = self.kv.table([seq.seq_id], W)[0]
@@ -1638,13 +1414,9 @@ class ServeEngine:
                 return stats
 
             if batch:
-                B = _bucket(len(batch))
-                if B > ecfg.max_batch:
-                    B = ecfg.max_batch
-                    batch = batch[:B]
-                W = _bucket(max(
-                    s.pos // bs + 1 for s in batch
-                ))
+                B = min(_bucket(len(batch)), ecfg.max_batch)
+                batch = batch[:B]
+                W = _bucket(max(s.pos // bs + 1 for s in batch))
                 tok = np.zeros((B,), np.int32)
                 pos = np.zeros((B,), np.int32)
                 temps = np.zeros((B,), np.float32)
@@ -1654,9 +1426,7 @@ class ServeEngine:
                     pos[i] = s.pos
                     temps[i] = s.temperature
                     seeds[i] = s.seed & 0xFFFFFFFF
-                table = self.kv.table(
-                    [s.seq_id for s in batch] + [-1] * (B - len(batch)), W
-                )
+                table = self._table(batch, B, W)
                 fn = self._decode_fn(B, W)
                 stats["decode_call"] = (
                     B, W, int(pos.sum()) + len(batch)
