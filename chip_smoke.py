@@ -11,9 +11,10 @@ entry points a user calls, at the widest configuration the repo supports:
             `data_parallelism_train.py` calls), full CNN, bs 16, 2 epochs
   lm_train  `lm_train.py main()` at d1024 / L16 / H16 / d_ff 4096 / seq 2048,
             global batch 8, bf16, `--attn flash`, dots_saveable remat
-  serve     `serve/http.py main()` at d512 / L8 / H8 / d_ff 2048 bf16 over
-            HTTP/SSE, every stream compared with offline `generate()`; then
-            the xla route, `--precision int8-kv` and `--spec-decode 4`
+  serve     `serve/http.py main()` at d512 / L8 / H4 / d_ff 2048 bf16 over
+            HTTP/SSE (heads of 128: the paged decode kernel's tile), every
+            stream compared with offline `generate()`; then the xla route,
+            `--precision int8-kv` and `--spec-decode 4`
 
 `--chips 4` runs only the cross-chip path and what it is compared with:
 `lm_train.py --dp 2 --tp 2` against one chip, and `run_training --nb-proc 4`.
@@ -48,8 +49,10 @@ LM_ARGS = [
     "--attn", "flash", "--remat", "--remat-policy", "dots_saveable",
     "--log-every", "1", "--step-stats", "--seed", str(SEED),
 ]
-# the serving geometry of train/measure.py measure_serving
-SERVE_MODEL = {"d_model": 512, "n_layers": 8, "n_heads": 8, "d_ff": 2048,
+# the serving geometry of train/measure.py measure_serving, with its 8
+# heads of 64 made 4 of 128: `--decode-impl auto` takes the paged decode
+# kernel where a pool row is whole 128-lane tiles (paged_decode_ok)
+SERVE_MODEL = {"d_model": 512, "n_layers": 8, "n_heads": 4, "d_ff": 2048,
                "vocab": 256, "dtype": "bfloat16", "seed": SEED}
 SERVE_ARGS = [
     "--port", "0", "--max-batch", "8", "--num-blocks", "129",

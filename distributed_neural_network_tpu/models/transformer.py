@@ -647,10 +647,11 @@ def generate(
     # caches are (L, B, H, total, Dh): collapsing (B, H) for the decode
     # kernel is then a free reshape. DNN_TPU_DECODE_IMPL selects the
     # per-step attention: "auto"/"xla" (`masked_attention`), "pallas" (the
-    # ops/decode_pallas.py kernel), "pallas-interpret" (the kernel on the
-    # CPU, for tests). No benchmark cell runs `generate`: it is the tests'
-    # token-exact reference for the serving engine, whose own "auto"
-    # takes the kernel on a TPU (serve/engine.py `_attn_route`).
+    # dense-cache kernel, ops/decode_pallas.py `decode_cache_attention`),
+    # "pallas-interpret" (the kernel on the CPU, for tests). No benchmark
+    # cell runs `generate`: it is the tests' token-exact reference for the
+    # serving engine, whose own "auto" takes the paged kernel of the same
+    # module on a TPU (serve/engine.py `_attn_route`).
     impl = os.environ.get("DNN_TPU_DECODE_IMPL", "auto")
     if impl not in ("auto", "xla", "pallas", "pallas-interpret"):
         raise ValueError(f"unknown decode impl {impl!r} "

@@ -1,65 +1,77 @@
-"""Single-query KV-cache decode attention kernel (Pallas TPU, fwd-only).
+"""Decode attention kernels (Pallas TPU, fwd-only): one query row a
+sequence against its cached keys and values. Two entries, one a caller:
 
-Why a dedicated kernel when `ops/flash_pallas.py` already exists: decode
-attends ONE query row per step against a static-size cache, and the r5
-probes put the XLA lowering of that step ~4x above its HBM-bandwidth
-bound at batch (2.60 ms/step at b16/hd64/cache 640 vs ~0.4 ms of
-unavoidable traffic; b1 IS at the bound, so the gap is the per-step
-small-op chain, not cache size). The training flash kernel cannot help:
-its q axis is a full sequence. This kernel is the decode-shaped
-counterpart:
+**`decode_paged_attention`** - the serving engine's decode step
+(serve/engine.py `ServeEngine._decode_fn`, `decode_impl` "pallas", and
+"auto" on a TPU). It takes the KV pools whole, as they lie in HBM at
+``(L, slots, H, Dh)``, with the layer index, the block table and the
+positions as scalar prefetch, and fetches each sequence's live pages
+itself:
 
-- **One fused pass**: scores, online softmax, and the value gather run
-  in a single `pallas_call` per layer-step - no (B, H, total) f32 score
-  tensor round-trips through HBM between three XLA ops.
-- **Dead-block skipping**: the XLA path attends the FULL padded cache
-  every step and masks (static shapes - the design is right, the work
-  is not). Here the grid still covers total/bk blocks, but a block
-  whose first column is past `pos` skips compute under `pl.when` and
-  clamps its index_map to the boundary block (already resident, no new
-  DMA) - per-step cache traffic is proportional to the LIVE prefix,
-  not the allocation. `pos` rides scalar prefetch
-  (`pltpu.PrefetchScalarGridSpec`) so index_maps can use it.
-- **Per-sequence positions**: ``pos`` may be a scalar (the
-  `models/transformer.py generate` path - every sequence at the same
-  position) or a ``(B,)`` vector - the serving engine's continuous
-  batch, where every slot sits at its own depth (serve/engine.py routes
-  this kernel under the paged gather). The mask and the skip clamp
-  resolve per (batch, head) lane from the prefetched vector.
-- **int8 K/V stream** (`k_scale`/`v_scale` given): the caches arrive in
-  int8 with per-slot f32 scales (lane-replicated, the same layout as
-  flash's lse residual) and each k-block is dequantized IN the k-block
-  loop right before its dot - HBM cache traffic is halved (decode's
-  actual roofline; see the measured-outcome note below), the MXU dots
-  stay in the query dtype. This is the serving int8 KV cache's fused
-  read path (serve/kv_cache.py stores per-(block, head) scales; the
-  engine expands them to per-slot at gather time).
-- **Single-row query on a (8, 128) grid**: Mosaic blocks must tile
-  (8, 128), so the one real query row is lane-broadcast to 8 sublanes
-  by the caller and row 0 of the output is read back - 7 redundant rows
-  cost nothing (the MXU pass is the same) and keep every block legal.
+- **A page is the contiguous ``(block_size, H, Dh)`` tile of one block**
+  (64 KiB at 16 rows of 16 heads of 128 in bfloat16). A fetch step
+  copies up to `_FETCH_STEP_BYTES` of K pages and as many of V into one
+  half of a double-buffered VMEM scratch, one async copy a page through
+  the block table, while the step before it is computed on; the last
+  step of a sequence starts the next sequence's first. A page wholly
+  past ``pos[b]`` is neither copied nor computed on, so the traffic is
+  the live positions rounded up to whole pages (`paged_read_positions`,
+  the engine's ``serve_decode_positions_total{kind="read"}``), whatever
+  the bucket's width.
+- **All heads of a page from the one tile, on the VPU**: the head axis
+  is on sublanes, so scores are the product of the ``(rows, H, Dh)``
+  tile with ``q (H, Dh)`` reduced over lanes, and the value sum is the
+  probabilities broadcast over lanes times the V tile summed over rows:
+  no transpose, no per-head strided load, float32 throughout (the online
+  softmax's m/l/acc are loop carries). Decode attention is one FLOP a
+  byte; the MXU at one query row a head reloads its weights for every
+  128 cache rows, which is what bounded the dense kernel below.
+- One `lax.fori_loop` over the batch's fetch steps, with the loops over
+  a step's copies and its pages inside it, all on traced bounds:
+  nothing is unrolled and every piece is traced once, so lowering a
+  decode program costs the host what it did before (the grid has 40;
+  the chip's host lowers them in 14.8-15.6 s against 15.5-17.1).
+- **Measured (PR 30, TPU v5e, the longdoc cell: batch 16, width 128,
+  14-16 sequences at 256-2,047 positions)**: the 24 layers' calls take
+  5.2 ms at 13,356 live positions, 62 % of the time HBM needs for the
+  live bytes (fetch steps of 256 KiB, 512 KiB and 1 MiB read 59, 62 and
+  61 %: the VPU and the lane reductions bound it, not the copies); the
+  decode program (16, 128) went from 63.0 to 10.9 ms a call, of which
+  46 ms were the gather of the bucket out of the pool and its transpose
+  for the dense kernel, which the engine no longer does (PERF.md
+  section 6).
+- It compiles where a pool row's ``(H, Dh)`` is whole tiles of a float
+  pool (`paged_decode_ok`); an int8 pool, and heads of 64, take the
+  engine's XLA route.
+
+**`decode_cache_attention`** - `models/transformer.py generate`'s dense
+``(B, H, total, Dh)`` cache, behind ``DNN_TPU_DECODE_IMPL=pallas`` (the
+default there is the XLA chain). One fused pass over k blocks of up to
+512 rows on a ``(B * H, total / bk)`` grid:
+
+- **Dead-block skipping**: a block whose first column is past ``pos``
+  skips compute under `pl.when` and clamps its index_map to the boundary
+  block (already resident, no new DMA). ``pos`` rides scalar prefetch, a
+  scalar (`generate`: every sequence at the same position) or ``(B,)``.
+- **int8 K/V stream** (``k_scale``/``v_scale`` given): int8 caches with
+  per-slot f32 scales, lane-replicated, dequantized in the k-block loop.
+  No caller in the program since the engine reads its pool through
+  `decode_paged_attention`; `tests/test_quant.py` holds its parity.
+- **Single-row query on a (8, 128) grid**: the one real query row is
+  lane-broadcast to 8 sublanes and row 0 of the output is read back.
 - Numerics: f32 dot accumulation + f32 online-softmax recurrence
-  (m/l/acc in VMEM scratch), matching `flash_pallas` conventions;
-  parity with the XLA decode path is pinned by
-  `tests/test_decode_pallas.py` up to blockwise reassociation, and the
-  int8 path by `tests/test_quant.py` against the dequantized oracle.
+  (m/l/acc in VMEM scratch); parity with the XLA decode path is pinned
+  by `tests/test_decode_pallas.py` up to blockwise reassociation.
+- **Measured**: at the r5 probes' shapes (d512, cache <= 640) it LOSES
+  to the XLA chain it replaces, 3.69 vs 2.59 ms/step at b16/hd64
+  in-loop, which is why `generate` defaults to XLA. Under the
+  engine's gathered bucket (until PR 30; Dh 128, 2,048 rows, batch 16)
+  it took 14.8 ms of a 65.5 ms decode program at 32.5 % of its roofline
+  (ledger, PR 29).
 
 The reference framework has no attention at all (its model is the
-5-layer CNN, `/root/reference/models/model.py:9-27`); this kernel is
-part of the beyond-reference LM family's inference path
-(`models/transformer.py generate`).
-
-**Measured outcome (r5, TPU v5e, the honest negative result)**: at the
-decode bench shapes (d512, cache <= 640) this kernel LOSES to the XLA
-chain it replaces - 3.69 vs 2.59 ms/step at b16/hd64 in-loop, and
-+~25% isolated at every block size. XLA lowers the einsum/softmax/
-einsum step as one well-tiled batched matmul chain over all B*H heads;
-a per-layer `pallas_call` costs more than the fusion saves, and
-dead-block skipping cannot pay at 640-slot caches. `generate` therefore
-defaults to the XLA path (`DNN_TPU_DECODE_IMPL=auto`); the kernel stays
-selectable (`=pallas`) and parity-tested for the long-cache regime
-where skipping's traffic advantage grows linearly - and the int8 stream
-halves exactly the traffic that regime is bound by.
+5-layer CNN, `/root/reference/models/model.py:9-27`); these kernels are
+part of the beyond-reference LM family's inference path.
 """
 
 from __future__ import annotations
@@ -296,3 +308,206 @@ def decode_kernel_ok(total: int, block_k: int = 512, *,
     XLA path."""
     tile = 4 * _SUBLANES if quantized else 2 * _SUBLANES
     return _divisor_block(block_k, total) % tile == 0
+
+
+# ------------------------------------------------- paged pool (serving)
+
+# K bytes one fetch step moves into VMEM (and as many of V): a page alone
+# is 64 KiB at the served shape, 0.08 us of HBM time, so a step gathers
+# pages until the copy in flight covers the compute on the one before it
+_FETCH_STEP_BYTES = 1 << 19
+# the two scratches hold two fetch steps each, of at least a page: four
+# pages have to fit VMEM beside everything else
+_PAGE_BYTES_MAX = 1 << 20
+
+
+def _pages_per_step(page_bytes: int, width: int) -> int:
+    return max(1, min(width, _FETCH_STEP_BYTES // page_bytes))
+
+
+def _paged_kernel(l_ref, table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sem, *, bs, pps, scale):
+    """One loop over the batch's fetch steps, sequence after sequence, each
+    of up to ``pps`` pages: iteration g starts the copies of step g (HBM
+    -> one half of the VMEM scratch, a page a copy, through the block
+    table) and computes on step g - 1 in the other half, so a sequence's
+    last step overlaps the next sequence's first. A page wholly past
+    ``pos[b]`` is neither copied nor computed on. Positions are never
+    negative, so the divisions truncate (`lax.div`: a floor division's
+    sign fix-up is a quarter of this kernel's lowering time)."""
+    n_seq, h, d = q_ref.shape
+    layer = l_ref[0]
+    div = jax.lax.div
+
+    def steps_of(b):
+        return div(pos_ref[b], pps * bs) + 1
+
+    def live_pages(b, i):
+        return jnp.minimum(pps, div(pos_ref[b], bs) + 1 - i * pps)
+
+    def copies(rows, slot, j):
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[layer, rows], k_buf.at[slot, j], sem.at[0, slot]),
+            pltpu.make_async_copy(
+                v_hbm.at[layer, rows], v_buf.at[slot, j], sem.at[1, slot]),
+        )
+
+    n_steps = jax.lax.fori_loop(
+        0, n_seq, lambda b, n: n + steps_of(b), jnp.int32(0))
+
+    def step(g, carry):
+        # (b, i): the step to fetch. (pb, pi), n_pages, last: the step
+        # fetched last time round, its live pages and whether it ends its
+        # sequence - the one to compute on. m/l/acc: the online softmax
+        b, i, pb, pi, n_pages, last, m, l, acc = carry
+        slot = jax.lax.rem(g, 2)
+        bf = jnp.minimum(b, n_seq - 1)
+        n_fetch = jnp.where(g < n_steps, live_pages(bf, i), 0)
+
+        def start(j, c):
+            blk = table_ref[bf, i * pps + j]
+            for copy in copies(pl.ds(blk * bs, bs), slot, j):
+                copy.start()
+            return c
+
+        jax.lax.fori_loop(0, n_fetch, start, 0)
+
+        def wait(j, c):
+            # a wait needs the copy's size and semaphore, not its source
+            for copy in copies(pl.ds(0, bs), 1 - slot, j):
+                copy.wait()
+            return c
+
+        jax.lax.fori_loop(0, n_pages, wait, 0)
+        pos = pos_ref[pb]
+        q = q_ref[pb].astype(jnp.float32) * scale               # (H, Dh)
+
+        def page(j, c):
+            m, l, acc = c
+            k = k_buf[1 - slot, j].astype(jnp.float32)          # (bs, H, Dh)
+            v = v_buf[1 - slot, j].astype(jnp.float32)
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True)
+            rows = (pi * pps + j) * bs + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+            live = rows <= pos
+            s = jnp.where(live, s, _NEG_BIG)                    # (bs, H, 1)
+            m_new = jnp.maximum(m, s.max(axis=0))               # (H, 1)
+            p = jnp.exp(s - m_new[None])
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + p.sum(axis=0)
+            # a dead row's p is 0, but 0 x a stale inf or nan is not
+            acc = acc * alpha + (p * jnp.where(live, v, 0.0)).sum(axis=0)
+            return m_new, l, acc
+
+        m, l, acc = jax.lax.fori_loop(0, n_pages, page, (m, l, acc))
+
+        @pl.when(last)
+        def _write():
+            o_ref[pb] = (acc / l).astype(o_ref.dtype)
+
+        ends = i + 1 == steps_of(bf)
+        return (
+            jnp.where(ends, b + 1, b), jnp.where(ends, 0, i + 1),
+            bf, i, n_fetch, jnp.logical_and(ends, g < n_steps),
+            jnp.where(last, _NEG_BIG, m), jnp.where(last, 0.0, l),
+            jnp.where(last, 0.0, acc),
+        )
+
+    zero = jnp.int32(0)
+    jax.lax.fori_loop(0, n_steps + 1, step, (
+        zero, zero, zero, zero, zero, False,
+        jnp.full((h, 1), _NEG_BIG, jnp.float32),
+        jnp.zeros((h, 1), jnp.float32),
+        jnp.zeros((h, d), jnp.float32),
+    ))
+
+
+def decode_paged_attention(q, k_pool, v_pool, layer, table, pos, *,
+                           block_size: int, interpret: bool = False):
+    """One decode step of attention for every (sequence, head), read from
+    the serving engine's paged KV pool where it lies.
+
+    q (B, H, Dh) - the current position's query rows; k_pool/v_pool
+    (L, slots, H, Dh) - the whole pools, left in HBM (a page is the
+    contiguous ``(block_size, H, Dh)`` tile of one block); ``layer`` - the
+    layer to read, a scalar that may be traced (the engine's layer scan);
+    table (B, W) int32 - each sequence's block ids in order, entries past
+    its live pages unread; pos (B,) int32 - positions 0..pos[b] are
+    attended. Returns o (B, H, Dh) in q's dtype. Scores, the online
+    softmax and the value sum are float32. Gate a compiled call with
+    `paged_decode_ok`."""
+    b, h, d = q.shape
+    if v_pool.shape != k_pool.shape or k_pool.shape[2:] != (h, d):
+        raise ValueError(
+            f"pools {k_pool.shape} / {v_pool.shape} do not hold q's "
+            f"(H, Dh) = ({h}, {d}) rows"
+        )
+    if table.shape[0] != b or pos.shape != (b,):
+        raise ValueError(
+            f"table {table.shape} and pos {pos.shape} do not describe "
+            f"q's batch of {b}"
+        )
+    if not interpret and not paged_decode_ok(
+            block_size, h, d, k_pool.dtype):
+        raise ValueError(
+            f"decode_paged_attention: a page of (block_size {block_size}, "
+            f"H {h}, Dh {d}) {k_pool.dtype} rows is no tile this kernel "
+            "compiles for (paged_decode_ok) - fall back to the XLA decode "
+            "path"
+        )
+    page_bytes = block_size * h * d * k_pool.dtype.itemsize
+    pps = _pages_per_step(page_bytes, table.shape[1])
+    buf = pltpu.VMEM((2, pps, block_size, h, d), k_pool.dtype)
+    return pl.pallas_call(
+        functools.partial(
+            _paged_kernel, bs=block_size, pps=pps,
+            scale=1.0 / float(d) ** 0.5,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))],
+        ),
+        out_shape=_struct((b, h, d), q.dtype, q, k_pool, v_pool),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        name="decode_paged_attn",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        table.astype(jnp.int32), pos.astype(jnp.int32),
+        q, k_pool, v_pool,
+    )
+
+
+def paged_decode_ok(block_size: int, n_heads: int, head_dim: int,
+                    dtype) -> bool:
+    """True where `decode_paged_attention` compiles: a page is copied and
+    computed on as it lies, so a pool row's (H, Dh) has to be tiles the
+    compiler has for the pool's dtype - Dh whole 128-lane rows and H
+    whole 8-sublane tiles (or the small tiles of 2 and 4 heads), float32
+    or bfloat16 (tests/test_tpu_aot_compile.py compiles them for a
+    described v5e) - and a page has to fit its share of VMEM. An int8
+    pool is not read by this kernel: its per-(block, head) scales would
+    need a copy of their own a page."""
+    dtype = jnp.dtype(dtype)
+    if dtype not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
+        return False
+    page_bytes = block_size * n_heads * head_dim * dtype.itemsize
+    return ((n_heads % _SUBLANES == 0 or n_heads in (2, 4))
+            and head_dim % _LANES == 0 and page_bytes <= _PAGE_BYTES_MAX)
+
+
+def paged_read_positions(pos, block_size: int) -> int:
+    """The cache positions whose pages `decode_paged_attention` fetches
+    for a batch at ``pos`` (host array): every sequence's live positions
+    rounded up to whole pages."""
+    return int(((pos // block_size + 1) * block_size).sum())

@@ -75,7 +75,11 @@ from jax.profiler import TraceAnnotation
 
 from ..models import transformer as tfm
 from ..models.transformer import TransformerConfig, _sinusoid_pe
-from ..ops.decode_pallas import decode_cache_attention, decode_kernel_ok
+from ..ops.decode_pallas import (
+    decode_paged_attention,
+    paged_decode_ok,
+    paged_read_positions,
+)
 from ..ops.quant import prequantize_weight, quantized_matmul
 from ..runtime import on_tpu
 from .kv_cache import KVCacheConfig, OutOfBlocks, PagedKVCache
@@ -146,13 +150,14 @@ class EngineConfig:
     # dequantize-in-step, accuracy gated vs the bf16 oracle
     # (docs/SERVING.md "int8 KV cache")
     kv_dtype: str = "bf16"
-    # per-step attention under the paged gather: "xla" = the einsum/
-    # softmax/einsum chain (`masked_attention`), "pallas" = the tuned decode
-    # kernel (ops/decode_pallas.py) reading the gathered bucket with
-    # per-slot positions (int8 pools stream quantized with fused
-    # dequant), "auto" = pallas on TPU when the bucket's width admits a
-    # sublane-legal block, xla otherwise (off-TPU the kernel only runs
-    # interpreted - a test vehicle, not a fast path)
+    # the decode step's attention: "xla" = gather the table's span out of
+    # the pool, then the einsum/softmax/einsum chain (`masked_attention`);
+    # "pallas" = the paged decode kernel (ops/decode_pallas.py
+    # `decode_paged_attention`), which reads each sequence's live pages
+    # from the pool through the block table; "auto" = pallas on a TPU
+    # where a pool page is a tile the kernel compiles for
+    # (`paged_decode_ok`; an int8 pool is not), xla otherwise (off-TPU
+    # the kernel only runs interpreted - a test vehicle, not a fast path)
     decode_impl: str = "auto"
     # speculative decoding: k > 0 lets each GREEDY slot emit up to k+1
     # tokens per tick (draft k with the early-exit drafter, verify all
@@ -335,21 +340,16 @@ def _span_idx(table, bs: int):
     ).reshape(*table.shape[:-1], -1)
 
 
-def _slot_scales(scales, l, table, bs: int):
-    """An int8 pool's per-(block, head) scales as one scale a slot of the
-    table's span: the same block-table addressing, one repeat a block,
-    (..., W, H) -> (..., W * bs, H)."""
-    return jnp.repeat(_read_rows(scales, l, table), bs, axis=-2)
-
-
 def _read_span(pool, scales, l, table, idx, bs: int, dt):
     """The table's span at layer ``l`` as (..., S, H, Dh) values at
     ``dt``: the rows as they are, or - under an int8 pool, ``scales`` not
-    None - dequantized by their blocks' scales."""
+    None - dequantized by their blocks' (block, head) scales, which ride
+    the same block-table addressing: one repeat a block, (..., W, H) ->
+    (..., W * bs, H)."""
     rows = _read_rows(pool, l, idx)
     if scales is None:
         return rows
-    slot = _slot_scales(scales, l, table, bs)
+    slot = jnp.repeat(_read_rows(scales, l, table), bs, axis=-2)
     return (rows.astype(jnp.float32) * slot[..., None]).astype(dt)
 
 
@@ -690,33 +690,39 @@ class ServeEngine:
         self._zero_scales(blocks)
         return n
 
-    def _attn_route(self, W: int) -> str:
-        """Per-bucket attention impl under the paged gather: the tuned
-        decode kernel when routable, the XLA chain otherwise. The
-        kernel needs the bucket's gathered length W * block_size to
-        admit a sublane-legal k block (16-multiples for bf16, 32 for
-        int8 - ops/decode_pallas.py decode_kernel_ok)."""
+    def _attn_route(self) -> str:
+        """The decode step's attention: the paged kernel where it is
+        asked for or pays, the XLA chain over the gathered span
+        otherwise. The kernel compiles where a pool page is whole tiles
+        of the pool's dtype (ops/decode_pallas.py `paged_decode_ok`: a
+        constraint on block_size, H, Dh and the dtype, none on a
+        bucket's width) and never reads an int8 pool."""
         impl = self.ecfg.decode_impl
         if impl == "xla":
             return "xla"
-        legal = decode_kernel_ok(
-            W * self.ecfg.block_size, quantized=self.quantized
+        cfg = self.cfg
+        legal = paged_decode_ok(
+            self.ecfg.block_size, cfg.n_heads, cfg.head_dim,
+            self.k_pool.dtype,
         )
         if impl == "pallas":
-            if not legal:
+            # off the TPU the kernel runs interpreted and tiles nothing
+            if self.quantized or (on_tpu() and not legal):
                 raise ValueError(
-                    f"decode_impl 'pallas' requested but bucket width "
-                    f"{W} x block_size {self.ecfg.block_size} admits no "
-                    f"sublane-legal k block for "
-                    f"{'int8' if self.quantized else 'bf16'} - use a "
-                    "block_size multiple of "
-                    f"{32 if self.quantized else 16} or decode_impl "
-                    "'auto'"
+                    f"decode_impl 'pallas' requested but the paged decode "
+                    f"kernel does not read a {self.kv_dtype_name()} pool "
+                    f"whose pages are (block_size {self.ecfg.block_size}, "
+                    f"H {cfg.n_heads}, Dh {cfg.head_dim}): it takes a "
+                    "float32 or bfloat16 pool with H 2, 4 or a multiple of "
+                    "8 and Dh a multiple of 128 - use decode_impl 'auto'"
                 )
             return "pallas"
         # auto: the kernel only pays on TPU (off-TPU it would run the
         # Pallas interpreter - a test vehicle, not a fast path)
         return "pallas" if legal and on_tpu() else "xla"
+
+    # the CLI's ``decode -> ...`` line and ``GET /v1/status``
+    decode_route = _attn_route
 
     def _bucket_widths(self, max_width_blocks: int | None = None) -> list:
         """The power-of-two width buckets (in blocks) up to the cap."""
@@ -727,18 +733,6 @@ class ServeEngine:
             widths.append(w)
             w *= 2
         return widths
-
-    def decode_route(self) -> str:
-        """The decode attention route over the width buckets, for the
-        CLI's ``decode -> ...`` line and ``GET /v1/status``: 'pallas' or
-        'xla', with the widths that take the other side named when the
-        buckets split (narrow buckets admit no sublane-legal k block)."""
-        routes = {W: self._attn_route(W) for W in self._bucket_widths()}
-        kinds = set(routes.values())
-        if len(kinds) == 1:
-            return kinds.pop()
-        xla_w = ",".join(str(W) for W, r in routes.items() if r == "xla")
-        return f"pallas (xla at width {xla_w})"
 
     # ------------------------------------------------------ jitted steps
 
@@ -777,7 +771,7 @@ class ServeEngine:
         H, Dh = cfg.n_heads, cfg.head_dim
         bs = self.kv.cfg.block_size
         S = W * bs
-        use_kernel = self._attn_route(W) == "pallas"
+        use_kernel = self._attn_route() == "pallas"
 
         def step(params, k_pool, v_pool, k_scale, v_scale,
                  tok, pos, table, temps, keys):
@@ -791,40 +785,36 @@ class ServeEngine:
                 "rows": blk[:, None] * bs + jnp.arange(bs)[None, :],
                 "flat": blk * bs + pos % bs,
             }
-            idx = _span_idx(table, bs)                        # (B, S)
-            live = (jnp.arange(S)[None, :] <= pos[:, None])[:, None, None, :]
+            if not use_kernel:
+                idx = _span_idx(table, bs)                    # (B, S)
+                live = (
+                    jnp.arange(S)[None, :] <= pos[:, None]
+                )[:, None, None, :]
 
             def cache_step(q, k, v, l, pools):
-                # write this position's row, gather the bucket, attend
+                # write this position's row, then attend over the cache
                 k_pool, v_pool, k_scale, v_scale = pools
                 k_pool, k_scale = _append_block(
                     k_pool, k_scale, l, k.reshape(B, H, Dh), **at)
                 v_pool, v_scale = _append_block(
                     v_pool, v_scale, l, v.reshape(B, H, Dh), **at)
                 pools = (k_pool, v_pool, k_scale, v_scale)
-                if not use_kernel:
-                    ks = _read_span(k_pool, k_scale, l, table, idx, bs, dt)
-                    vs = _read_span(v_pool, v_scale, l, table, idx, bs, dt)
-                    return tfm.masked_attention(
-                        q, ks.transpose(0, 2, 1, 3),
-                        vs.transpose(0, 2, 1, 3), live, dt,
-                    ), pools
-                # the tuned decode kernel masks on `pos` itself and reads
-                # an int8 pool's stream as it is, the dequantization fused
-                # in its k-block loop
-                slot = {} if k_scale is None else {
-                    "k_scale": _slot_scales(
-                        k_scale, l, table, bs).transpose(0, 2, 1),
-                    "v_scale": _slot_scales(
-                        v_scale, l, table, bs).transpose(0, 2, 1),
-                }
-                o = decode_cache_attention(
-                    q.reshape(B, H, Dh),
-                    _read_rows(k_pool, l, idx).transpose(0, 2, 1, 3),
-                    _read_rows(v_pool, l, idx).transpose(0, 2, 1, 3),
-                    pos, interpret=not on_tpu(), **slot,
-                )
-                return o[:, None], pools
+                if use_kernel:
+                    # the kernel fetches each sequence's live pages from
+                    # the pools as `_append_block` returned them (the new
+                    # row is in them) and masks on `pos` itself
+                    o = decode_paged_attention(
+                        q.reshape(B, H, Dh), k_pool, v_pool, l, table, pos,
+                        block_size=bs, interpret=not on_tpu(),
+                    )
+                    return o[:, None], pools
+                # the oracle: gather the table's span, attend under `live`
+                ks = _read_span(k_pool, k_scale, l, table, idx, bs, dt)
+                vs = _read_span(v_pool, v_scale, l, table, idx, bs, dt)
+                return tfm.masked_attention(
+                    q, ks.transpose(0, 2, 1, 3),
+                    vs.transpose(0, 2, 1, 3), live, dt,
+                ), pools
 
             x, pools = _scan_layers(
                 cfg, self._mm, params, x, (k_pool, v_pool, k_scale, v_scale),
@@ -1271,11 +1261,14 @@ class ServeEngine:
         ledger's "prefill" and "decode" by token counts stays an
         apportioning.
 
-        ``decode_call`` is ``(B, W, live)`` for the tick's decode
+        ``decode_call`` is ``(B, W, live, read)`` for the tick's decode
         dispatch, None without one: the batch and width-in-blocks
         bucket the program is shaped for (``B * W * block_size`` padded
-        positions), and the cache positions its queries attend to,
-        ``pos + 1`` summed over the batch. ``prefill_calls`` lists
+        positions), the cache positions its queries attend to,
+        ``pos + 1`` summed over the batch, and the positions the program
+        reads from the pool for them: the pages the paged kernel fetches
+        (``live`` rounded up to whole pages a row of the bucket), or on
+        the ``xla`` route the whole padded span. ``prefill_calls`` lists
         ``(C, W, live)`` per prefill dispatch: the chunk bucket, the
         width, and ``n * pos0 + n * (n + 1) / 2`` for ``n`` tokens from
         ``pos0`` (each attends to all before it and itself). The
@@ -1429,7 +1422,9 @@ class ServeEngine:
                 table = self._table(batch, B, W)
                 fn = self._decode_fn(B, W)
                 stats["decode_call"] = (
-                    B, W, int(pos.sum()) + len(batch)
+                    B, W, int(pos.sum()) + len(batch),
+                    paged_read_positions(pos, bs)
+                    if self._attn_route() == "pallas" else B * W * bs,
                 )
                 nxt, _ = self._run_writer(
                     fn, jnp.asarray(tok), jnp.asarray(pos),

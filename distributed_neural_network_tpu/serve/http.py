@@ -418,11 +418,12 @@ def main(argv=None) -> int:
                    "0 = auto (max(1, n_layers // 8))")
     p.add_argument("--decode-impl", choices=("auto", "xla", "pallas"),
                    default="auto",
-                   help="attention under the paged gather: the tuned "
-                   "Pallas decode kernel ('pallas'; int8 pools stream "
-                   "with fused dequant) vs the XLA chain ('xla'); "
-                   "'auto' routes to the kernel on TPU when the bucket "
-                   "width admits a legal block, XLA otherwise")
+                   help="the decode step's attention: the paged Pallas "
+                   "kernel, which reads the KV pool through the block "
+                   "table ('pallas'; float pools with heads of 128), vs "
+                   "gathering the bucket for the XLA chain ('xla'); "
+                   "'auto' routes to the kernel on TPU where a pool "
+                   "page is a tile it compiles for, XLA otherwise")
     p.add_argument("--eos-token", type=int, default=None)
     p.add_argument("--max-queue", type=int, default=64)
     p.add_argument("--tenant-rate", type=float, default=0.0,

@@ -351,14 +351,15 @@ class ServeScheduler:
         )
         decode_pos = r.counter(
             "serve_decode_positions_total",
-            "Cache positions of decode dispatches: live (attended to) "
-            "and padded (the bucket's shape)",
+            "Cache positions of decode dispatches: live (attended to), "
+            "read (fetched from the pool) and padded (the bucket's shape)",
         )
         prefill_pos = r.counter(
             "serve_prefill_positions_total",
             "Cache positions of prefill dispatches: live and padded",
         )
         self._m_decode_live = decode_pos.labels(kind="live")
+        self._m_decode_read = decode_pos.labels(kind="read")
         self._m_decode_padded = decode_pos.labels(kind="padded")
         self._m_prefill_live = prefill_pos.labels(kind="live")
         self._m_prefill_padded = prefill_pos.labels(kind="padded")
@@ -798,11 +799,12 @@ class ServeScheduler:
         bs = self.engine.ecfg.block_size
         call = stats["decode_call"]
         if call is not None:
-            B, W, live = call
+            B, W, live, read = call
             self._m_decode_calls.labels(
                 batch=str(B), width_blocks=str(W)
             ).inc()
             self._m_decode_live.inc(live)
+            self._m_decode_read.inc(read)
             self._m_decode_padded.inc(B * W * bs)
         for C, W, live in stats["prefill_calls"]:
             self._m_prefill_calls.labels(

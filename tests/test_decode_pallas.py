@@ -12,9 +12,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_neural_network_tpu.models.transformer import masked_attention
+from distributed_neural_network_tpu.ops import decode_pallas
 from distributed_neural_network_tpu.ops.decode_pallas import (
     decode_cache_attention,
     decode_kernel_ok,
+    decode_paged_attention,
+    paged_decode_ok,
+    paged_read_positions,
 )
 
 
@@ -125,3 +130,123 @@ def test_generate_rejects_unknown_or_infeasible_impl(monkeypatch):
     assert not decode_kernel_ok(8 + 9)
     with pytest.raises(ValueError, match="no sublane-legal"):
         tfm.generate(params, prompt, cfg, max_new_tokens=9)
+
+
+# ---------------------------------------------------------- paged pool
+
+# the paged cases' pool: pages of 4 rows, sequences of up to 8 pages,
+# fetch steps of 2 pages (the step's byte target shrunk to two of these
+# small pages, so that a sequence spans several fetch steps)
+BS, W, PPS, LAYERS, BLOCKS, HEADS = 4, 8, 2, 3, 64, 4
+# a row each: the first position; one under and at a page boundary; one
+# under and at a fetch-step boundary; the last position of the bucket
+PAGED_POS = [0, BS - 1, BS, PPS * BS - 1, PPS * BS, W * BS - 1]
+
+
+def _paged_case(dtype, dh, monkeypatch):
+    """(q, clean pools, poisoned pools, table, pos): a shuffled,
+    non-contiguous table whose entries past a sequence's live pages name
+    the scratch block 0, and pools whose every row past ``pos`` (the dead
+    rows of the boundary page, every block no live page names, the
+    scratch block) is NaN in the poisoned copy."""
+    monkeypatch.setattr(
+        decode_pallas, "_FETCH_STEP_BYTES",
+        PPS * BS * HEADS * dh * jnp.dtype(dtype).itemsize,
+    )
+    b = len(PAGED_POS)
+    ks = jax.random.split(jax.random.key(dh), 3)
+    shape = (LAYERS, BLOCKS * BS, HEADS, dh)
+    k_pool = jax.random.normal(ks[0], shape, dtype)
+    v_pool = jax.random.normal(ks[1], shape, dtype)
+    q = jax.random.normal(ks[2], (b, HEADS, dh), dtype)
+    pos = np.asarray(PAGED_POS, np.int32)
+    blocks = np.random.default_rng(dh).permutation(
+        np.arange(1, BLOCKS))[: b * W].reshape(b, W)
+    pages = np.arange(W)[None, :] <= (pos // BS)[:, None]
+    table = np.where(pages, blocks, 0).astype(np.int32)
+    rows = (table[..., None] * BS + np.arange(BS)).reshape(b, W * BS)
+    live = np.arange(W * BS)[None, :] <= pos[:, None]
+    dead = np.ones((BLOCKS * BS,), bool)
+    dead[rows[live]] = False
+    poison = jnp.where(jnp.asarray(dead)[None, :, None, None], jnp.nan, 0.0)
+    return (q, (k_pool, v_pool),
+            (k_pool + poison.astype(dtype), v_pool + poison.astype(dtype)),
+            jnp.asarray(table), jnp.asarray(pos))
+
+
+def _paged_oracle(q, k_pool, v_pool, layer, table, pos):
+    """The engine's `xla` route: gather the table's span, attend under
+    the live mask."""
+    b, w = table.shape
+    rows = (table[..., None] * BS + jnp.arange(BS)).reshape(b, w * BS)
+    live = (jnp.arange(w * BS)[None, :] <= pos[:, None])[:, None, None, :]
+    return masked_attention(
+        q[:, None], k_pool[layer][rows].transpose(0, 2, 1, 3),
+        v_pool[layer][rows].transpose(0, 2, 1, 3), live, q.dtype,
+    )[:, 0]
+
+
+@pytest.mark.parametrize("dh", [64, 128])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_kernel_matches_gather_and_masked_attention(dtype, dh,
+                                                          monkeypatch):
+    """Every row of `PAGED_POS` at once, on the poisoned pool: a NaN
+    from a page or a row past ``pos`` would reach the output."""
+    q, clean, poisoned, table, pos = _paged_case(dtype, dh, monkeypatch)
+    assert decode_pallas._pages_per_step(
+        BS * HEADS * dh * jnp.dtype(dtype).itemsize, W) == PPS
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    for layer in (0, LAYERS - 1):
+        got = decode_paged_attention(
+            q, *poisoned, layer, table, pos, block_size=BS, interpret=True)
+        assert got.dtype == q.dtype
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32),
+            np.asarray(_paged_oracle(q, *clean, layer, table, pos),
+                       np.float32),
+            rtol=tol, atol=tol,
+        )
+
+
+def test_paged_kernel_reads_a_traced_layer_under_scan(monkeypatch):
+    """The engine's call: the layer index is the layer scan's counter."""
+    q, clean, poisoned, table, pos = _paged_case(jnp.float32, 64,
+                                                 monkeypatch)
+
+    @jax.jit
+    def every_layer(k_pool, v_pool):
+        def layer_step(_, layer):
+            return None, decode_paged_attention(
+                q, k_pool, v_pool, layer, table, pos, block_size=BS,
+                interpret=True)
+        return jax.lax.scan(layer_step, None, jnp.arange(LAYERS))[1]
+
+    got = every_layer(*poisoned)
+    for layer in range(LAYERS):
+        np.testing.assert_allclose(
+            np.asarray(got[layer]),
+            np.asarray(_paged_oracle(q, *clean, layer, table, pos)),
+            rtol=2e-6, atol=2e-6,
+        )
+
+
+def test_paged_kernel_gate_and_read_count():
+    """What compiles (tests/test_tpu_aot_compile.py holds the gate to the
+    compiler): whole (H, Dh) tiles of a float pool; and the positions a
+    batch's pages hold."""
+    assert paged_decode_ok(16, 16, 128, jnp.bfloat16)   # the served shape
+    assert paged_decode_ok(16, 8, 256, jnp.float32)
+    assert paged_decode_ok(16, 2, 128, jnp.bfloat16)
+    assert not paged_decode_ok(16, 8, 64, jnp.bfloat16)   # half a lane row
+    assert not paged_decode_ok(16, 12, 128, jnp.bfloat16)
+    assert not paged_decode_ok(16, 16, 128, jnp.int8)     # scales unread
+    assert not paged_decode_ok(1024, 16, 128, jnp.bfloat16)  # a 4 MiB page
+    pos = np.asarray(PAGED_POS)
+    assert paged_read_positions(pos, BS) == sum(
+        (p // BS + 1) * BS for p in PAGED_POS)
+    assert int(pos.sum()) + len(pos) <= paged_read_positions(pos, BS)
+    with pytest.raises(ValueError, match="paged_decode_ok"):
+        decode_paged_attention(
+            jnp.zeros((1, 8, 64)), jnp.zeros((1, 32, 8, 64)),
+            jnp.zeros((1, 32, 8, 64)), 0, jnp.zeros((1, 2), jnp.int32),
+            jnp.zeros((1,), jnp.int32), block_size=16)

@@ -432,12 +432,71 @@ def test_step_reports_buckets_and_live_positions(params, n_devices):
     assert got == [
         ([(4, 1, 4 * 0 + 10)], None),
         ([(4, 2, 4 * 4 + 10)], None),
-        ([(4, 4, 4 * 8 + 10)], (1, 4, 13)),
-        ([], (1, 4, 14)),
+        ([(4, 4, 4 * 8 + 10)], (1, 4, 13, 16)),
+        ([], (1, 4, 14, 16)),
     ]
     # live positions do not depend on the chunking: a token at position
     # p attends to p + 1, so the prompt's first 12 sum to 12 * 13 / 2
     assert sum(c[2] for calls, _ in got for c in calls) == 12 * 13 // 2
+
+
+def _run_tight_pool(params, impl):
+    """`test_preemption_replays_exactly_and_never_restreams`' pool under
+    one decode route: (tokens a sequence, preemptions, decode calls)."""
+    eng = ServeEngine(params, CFG, EngineConfig(
+        max_batch=4, num_blocks=6, block_size=2, max_seq_len=16,
+        decode_impl=impl,
+    ))
+    seqs = [Sequence(i, _prompt(30 + i, 2 + i), 6) for i in range(3)]
+    for s in seqs:
+        eng.add(s)
+    calls = []
+    ticks = 0
+    while (eng.has_work() or eng.preempted) and ticks < 1000:
+        ticks += 1
+        calls.append(eng.step()["decode_call"])
+        if eng.preempted and eng.kv.can_fit(4):
+            eng.add(eng.preempted.popleft())
+    assert all(s.finished for s in seqs)
+    return ([s.out for s in seqs], sum(s.preemptions for s in seqs),
+            [c for c in calls if c is not None])
+
+
+def test_paged_kernel_route_emits_the_xla_routes_tokens(params, n_devices):
+    """decode_impl="pallas" (the paged kernel, interpreted off the TPU)
+    against the oracle route at float32: prompts of three lengths, so
+    the batch sits at mixed positions, through preemptions and their
+    replays. And what each tick's ``decode_call`` says the program read:
+    the pages' positions under the kernel, the whole bucket under XLA."""
+    bs = 2
+    xla, pallas = (_run_tight_pool(params, impl) for impl in ("xla",
+                                                             "pallas"))
+    assert pallas[0] == xla[0]
+    assert pallas[1] == xla[1] > 0, "pool was never tight"
+    assert [c[:3] for c in pallas[2]] == [c[:3] for c in xla[2]]
+    for B, W, live, read in xla[2]:
+        assert read == B * W * bs
+    for B, W, live, read in pallas[2]:
+        assert live <= read <= B * W * bs
+        assert read % bs == 0
+    # the kernel leaves dead pages alone: somewhere the bucket is wider
+    assert sum(c[3] for c in pallas[2]) < sum(c[3] for c in xla[2])
+
+
+def test_pallas_route_refuses_what_the_kernel_does_not_read(params,
+                                                            n_devices):
+    eng = ServeEngine(params, CFG, EngineConfig(
+        max_batch=2, num_blocks=8, block_size=4, max_seq_len=16,
+        kv_dtype="int8", decode_impl="pallas",
+    ))
+    with pytest.raises(ValueError, match="does not read a int8 pool"):
+        eng.decode_route()
+    # under auto the same pool takes the oracle route, on any backend
+    eng = ServeEngine(params, CFG, EngineConfig(
+        max_batch=2, num_blocks=8, block_size=4, max_seq_len=16,
+        kv_dtype="int8",
+    ))
+    assert eng.decode_route() == "xla"
 
 
 def test_all_parked_tick_still_partitions(params, n_devices):

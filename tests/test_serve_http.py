@@ -378,6 +378,8 @@ def test_loop_phases_and_positions_over_a_scripted_run(params, n_devices):
     }
     assert _family(registry, "serve_decode_positions_total") == {
         '{kind="live"}': 9 + (10 + 5) + (11 + 6),
+        # the xla route gathers the whole bucket: read is padded
+        '{kind="read"}': 1 * 4 * 4 + 2 * 4 * 4 + 2 * 4 * 4,
         '{kind="padded"}': 1 * 4 * 4 + 2 * 4 * 4 + 2 * 4 * 4,
     }
     assert _family(registry, "serve_prefill_calls_total") == {
@@ -387,6 +389,29 @@ def test_loop_phases_and_positions_over_a_scripted_run(params, n_devices):
     assert _family(registry, "serve_decode_calls_total") == {
         '{batch="1",width_blocks="4"}': 1,
         '{batch="2",width_blocks="4"}': 2,
+    }
+
+
+def test_publish_tick_counts_what_the_decode_program_read(params,
+                                                          n_devices):
+    """A tick's ``decode_call`` is ``(B, W, live, read)``: `_publish_tick`
+    grows ``serve_decode_positions_total{kind="read"}`` by the fourth, as
+    the paged kernel's route hands it (live rounded up to whole pages),
+    between ``live`` and the bucket's ``padded``."""
+    registry = MetricsRegistry()
+    engine = ServeEngine(params, CFG, EngineConfig(
+        max_batch=4, num_blocks=32, block_size=4, max_seq_len=64,
+    ))
+    scheduler = ServeScheduler(
+        engine, SchedulerConfig(max_queue=8), registry=registry,
+    )
+    for call in ((2, 4, 9, 12), (2, 4, 11, 16), None):
+        scheduler._publish_tick({}, {"decode_call": call,
+                                     "prefill_calls": []})
+    assert _family(registry, "serve_decode_positions_total") == {
+        '{kind="live"}': 9 + 11,
+        '{kind="read"}': 12 + 16,
+        '{kind="padded"}': 2 * (2 * 4 * 4),
     }
 
 

@@ -10,6 +10,7 @@ measurement.
 """
 
 import functools
+import math
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -22,6 +23,8 @@ from jax.sharding import SingleDeviceSharding
 
 from distributed_neural_network_tpu.ops.decode_pallas import (
     decode_cache_attention,
+    decode_paged_attention,
+    paged_decode_ok,
 )
 from distributed_neural_network_tpu.ops.flash import tuned_blocks
 from distributed_neural_network_tpu.ops.flash_pallas import flash_mha
@@ -79,8 +82,8 @@ def _flash(topo, head_dim, *, grad, quant=None):
 
 
 def _decode(topo, batch, total, *, int8):
-    # the serve smoke: d512 / 8 heads -> Dh 64, 16-token blocks, per-slot
-    # positions; `total` = bucket width x block size
+    # the dense-cache kernel (`generate`'s) at d512 / 8 heads -> Dh 64
+    # with per-sequence positions; `total` = the cache's length
     dh = 64
     cache = ((batch, H, total, dh), jnp.int8 if int8 else jnp.bfloat16)
     shapes = [((batch, H, dh), jnp.bfloat16), cache, cache,
@@ -94,6 +97,25 @@ def _decode(topo, batch, total, *, int8):
                                       v_scale=vs)
 
     return fn, _on_one_chip(topo, shapes + [scale, scale])
+
+
+def _decode_paged(topo, batch, width, *, heads=16, dh=128,
+                  dtype=jnp.bfloat16, layers=24, blocks=1537):
+    # the longdoc cell: 24 layers, 1,537 blocks of 16 rows of 16 heads of
+    # 128 in bfloat16; the pools whole, the layer a traced scalar
+    bs = 16
+    assert paged_decode_ok(bs, heads, dh, dtype)
+    pool = ((layers, blocks * bs, heads, dh), dtype)
+    i32 = jnp.int32
+
+    def fn(q, k_pool, v_pool, layer, table, pos):
+        return decode_paged_attention(q, k_pool, v_pool, layer[0], table,
+                                      pos, block_size=bs)
+
+    return fn, _on_one_chip(topo, [
+        ((batch, heads, dh), dtype), pool, pool, ((1,), i32),
+        ((batch, width), i32), ((batch,), i32),
+    ])
 
 
 def _mlp3(topo):
@@ -150,6 +172,16 @@ CASES = {
     "decode_bf16_b8_w16": lambda t: _decode(t, 8, 256, int8=False),
     "decode_int8_b1_w2": lambda t: _decode(t, 1, 32, int8=True),
     "decode_int8_b8_w16": lambda t: _decode(t, 8, 256, int8=True),
+    "decode_paged_bf16_b16_w128": lambda t: _decode_paged(t, 16, 128),
+    "decode_paged_bf16_b1_w1": lambda t: _decode_paged(t, 1, 1),
+    # the serve smoke (chip_smoke.py: d512 / 4 heads of 128, 129 blocks)
+    "decode_paged_bf16_h4_b8_w16": lambda t: _decode_paged(
+        t, 8, 16, heads=4, layers=8, blocks=129),
+    # the gate's other tiles (`paged_decode_ok`): two heads, float32
+    "decode_paged_bf16_h2_b2_w4": lambda t: _decode_paged(
+        t, 2, 4, heads=2, layers=16, blocks=64),
+    "decode_paged_f32_h8_d256_b8_w16": lambda t: _decode_paged(
+        t, 8, 16, heads=8, dh=256, dtype=jnp.float32, layers=8, blocks=129),
     "fused_mlp3_fwd_bwd": _mlp3,
     "flash_bwd_dp2_tp2_shard_map": _flash_dp2_tp2,
 }
@@ -208,3 +240,60 @@ def test_serve_program_holds_no_slab_on_v5e(topo, family, n):
     ).compile().memory_analysis()
     assert mem.temp_size_in_bytes < eng.k_pool[0].nbytes
     assert mem.alias_size_in_bytes >= 2 * eng.k_pool.nbytes
+
+
+@pytest.mark.parametrize("n", [1, 16])
+def test_serve_decode_program_reads_the_pool_through_the_table_on_v5e(
+        topo, monkeypatch, n):
+    """`decode_impl="pallas"` on the described chip, at the longdoc cell's
+    widths (16 heads of 128, 16 of its 24 layers, its pool, the 128-block
+    bucket): the decode program's layer loop holds one Mosaic call, the
+    paged kernel, and neither a gathered bucket nor a copy of a pool
+    beside it - what the program holds at its peak beyond its arguments
+    and outputs stays under one bucket's K rows (the gather -> transpose
+    -> dense kernel route held three, and `temp_size_in_bytes` alone read
+    0 for it at some batch sizes), and both pools are aliased to the
+    outputs. The weights are shapes (nothing runs, so the engine is built
+    around a placeholder tree), and the engine asks the runtime whether
+    it is on a TPU: here the test answers for it."""
+    from distributed_neural_network_tpu.models import transformer as tfm
+    from distributed_neural_network_tpu.serve import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "on_tpu", lambda: True)
+    cfg = tfm.TransformerConfig(
+        vocab_size=50257, d_model=2048, n_heads=16, n_layers=16, d_ff=8192,
+        dtype=jnp.bfloat16,
+    )
+    width, bs, blocks = 128, 16, 1537
+    eng = engine_mod.ServeEngine(
+        {"placeholder": jnp.zeros(())}, cfg,
+        engine_mod.EngineConfig(
+            max_batch=16, num_blocks=width + 1, block_size=bs,
+            max_seq_len=width * bs, prefill_chunk=128,
+            decode_impl="pallas"),
+    )
+    assert eng.decode_route() == "pallas"
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, cfg.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg)),
+    )
+    i32 = jnp.int32
+    pool = ((cfg.n_layers, blocks * bs, cfg.n_heads, cfg.head_dim),
+            cfg.dtype)
+    compiled = eng._decode_fn(n, width).lower(
+        params, *_on_one_chip(topo, [
+            pool, pool, ((n,), i32), ((n,), i32), ((n, width), i32),
+            ((n,), jnp.float32), ((n, 2), jnp.uint32),
+        ])
+    ).compile()
+    assert mosaic_custom_calls(compiled) == 1
+    mem = compiled.memory_analysis()
+    bucket = n * width * bs * cfg.n_heads * cfg.head_dim * 2
+    pools = 2 * math.prod(pool[0]) * 2
+    held = mem.peak_memory_in_bytes - (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        - mem.alias_size_in_bytes)
+    assert max(held, mem.temp_size_in_bytes) < bucket
+    assert mem.alias_size_in_bytes >= pools
