@@ -104,6 +104,14 @@ def kv_block_bytes(n_layers: int, n_heads: int, head_dim: int,
     return total
 
 
+def latent_block_bytes(n_layers: int, row_width: int, block_size: int,
+                       dtype: str = "bf16") -> int:
+    """Device bytes of ONE paged block of a latent pool (serve/engine.py,
+    a module whose `CACHE` is "latent"): `block_size` rows of `row_width`
+    values in every layer, one row for all heads and no scales."""
+    return n_layers * block_size * row_width * dtype_bytes(dtype)
+
+
 def kv_capacity_sequences(usable_blocks: int, block_size: int,
                           seq_len: int) -> int:
     """Concurrent sequences of ``seq_len`` tokens a pool of

@@ -135,16 +135,22 @@ def _pow2s(limit: int) -> list:
     return out
 
 
-def enumerate_grid(ecfg, *, max_width_blocks: int | None = None) -> dict:
+def enumerate_grid(ecfg, *, max_width_blocks: int | None = None,
+                   latent: bool = False) -> dict:
     """The bucket grid ``warmup()`` compiles, from the `EngineConfig`
     alone - family -> [(bucket key), ...]. MUST mirror
     serve/engine.py warmup() exactly; the equality is pinned by
     cache-miss counting in tests/test_servelint.py (serving after
-    warmup compiles zero new programs for every canonical config)."""
+    warmup compiles zero new programs for every canonical config).
+    ``latent``: the engine of a module with a latent cache, whose
+    programs have one table width, the widest
+    (tests/test_pangu_ultra_moe.py pins that grid)."""
     from ..serve.engine import _bucket
 
     kv = ecfg.kv()
     widths = _pow2s(_bucket(max_width_blocks or kv.max_blocks_per_seq))
+    if latent:
+        widths = [_bucket(kv.max_blocks_per_seq)]
     batches = _pow2s(ecfg.max_batch)
     grid = {"decode": [(B, W) for B in batches for W in widths]}
     if ecfg.prefill_chunk > 1:
@@ -260,10 +266,10 @@ def bucket_program(engine, family: str, key: tuple, *,
     params = _sds_tree(
         engine.draft_params if family == "draft" else engine.params
     )
-    pools = (_sds_tree(engine.k_pool), _sds_tree(engine.v_pool))
-    scales = (
-        (_sds_tree(engine.k_scale), _sds_tree(engine.v_scale)) if q else ()
-    )
+    # the donated operands as the engine hands them over: K and V pools
+    # (and int8 scales), or a latent module's one pool
+    pools = tuple(_sds_tree(p) for p in engine._pools())
+    scales = ()
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt)
@@ -297,10 +303,10 @@ def bucket_program(engine, family: str, key: tuple, *,
     else:
         raise ValueError(f"unknown bucket family {family!r}")
 
-    donate = () if family == "draft" else (1, 2, 3, 4) if q else (1, 2)
-    labels = ("params", "k_pool", "v_pool") + (
-        ("k_scale", "v_scale") if q else ()
-    )
+    donate = () if family == "draft" else tuple(range(1, 1 + len(pools)))
+    labels = ("params",) + (
+        ("latent_pool",) if engine.latent else ("k_pool", "v_pool") + (
+            ("k_scale", "v_scale") if q else ()))
     if probe == "drop-donation" and family != "draft":
         # an outer jit swallows the inner boundary's donated_invars:
         # exactly what a refactor that loses donate_argnums looks like

@@ -5,7 +5,9 @@ import importlib
 # The model modules a configuration file's "family" can name
 # (`lm_train.py --model-config`); GPT-2's block (`transformer`) is built from
 # the trainer's own flags. A module is imported when its family is asked for.
-FAMILIES = {"nemotron_h": "nemotron_h"}
+# (`pangu_ultra_moe` is served, `python -m ...serve --model-config`, and not
+# trained: it has no `apply_hidden`, and the trainer says so.)
+FAMILIES = {"nemotron_h": "nemotron_h", "pangu_ultra_moe": "pangu_ultra_moe"}
 
 
 def family_module(family: str):

@@ -1,5 +1,13 @@
 """Decode attention kernels (Pallas TPU, fwd-only): one query row a
-sequence against its cached keys and values. Two entries, one a caller:
+sequence against its cached keys and values. Two entries, one a caller
+each, and at the end of the file the two kernels of a LATENT pool (one row
+`[c ; k_rope]` a position for all heads, serve/engine.py's latent
+programs): `mla_decode_attention` (the absorbed form: all heads against a
+fetch step's rows in one MXU product, the rows' first `rank` values also
+the values) and `mla_prefill_attention` (the expanded form of a prefill
+chunk: a head a grid step, a fetch step's rows up-projected for that head
+in VMEM), both reading live pages through the block table as
+`decode_paged_attention` does.
 
 **`decode_paged_attention`** - the serving engine's decode step
 (serve/engine.py `ServeEngine._decode_fn`, `decode_impl` "pallas", and
@@ -511,3 +519,339 @@ def paged_read_positions(pos, block_size: int) -> int:
     for a batch at ``pos`` (host array): every sequence's live positions
     rounded up to whole pages."""
     return int(((pos // block_size + 1) * block_size).sum())
+
+
+# ------------------------------------- latent pool (MLA, absorbed form)
+
+# the positions one fetch step brings into VMEM: several pages, so that the
+# probabilities' product with the values has a contraction worth the MXU's
+# while (a page alone is 64 rows) and a copy in flight covers the compute
+_MLA_STEP_POSITIONS = 512
+# q, o, the two buffers and the accumulator at the served shape are 11 MiB:
+# over the compiler's default scope, well inside the chip's 128 MiB
+_MLA_VMEM_BYTES = 64 << 20
+
+
+def _mla_paged_kernel(l_ref, table_ref, pos_ref, q_ref, pool_hbm, o_ref,
+                      buf, acc_ref, sem, *, bs, pps, rank, scale):
+    """`_paged_kernel`'s loop over the batch's fetch steps, for a pool of
+    latent rows: a step's pages land one under the other in a half of
+    ``buf`` (pps * bs, W) and all H heads score them in ONE product on the
+    MXU, ``q (H, W) . rows^T``; the probabilities weigh the rows' first
+    ``rank`` values, ``p (H, pps * bs) . rows[:, :rank]``: K and V are the
+    same bytes, fetched once for all heads. Pages past ``pos[b]`` are not
+    copied; what a half still holds from an earlier step (or the zeros it
+    starts with) lies past ``pos`` and weighs nought."""
+    n_seq = q_ref.shape[0]
+    layer = l_ref[0]
+    div = jax.lax.div
+    step_rows = pps * bs
+
+    def steps_of(b):
+        return div(pos_ref[b], step_rows) + 1
+
+    def live_pages(b, i):
+        return jnp.minimum(pps, div(pos_ref[b], bs) + 1 - i * pps)
+
+    def copy(rows, slot, j):
+        return pltpu.make_async_copy(
+            pool_hbm.at[layer, rows], buf.at[slot, pl.ds(j * bs, bs)],
+            sem.at[slot])
+
+    buf[...] = jnp.zeros(buf.shape, buf.dtype)
+    n_steps = jax.lax.fori_loop(
+        0, n_seq, lambda b, n: n + steps_of(b), jnp.int32(0))
+
+    def step(g, carry):
+        # (b, i): the step to fetch; (pb, pi), n_pages, last: the step
+        # fetched last time round - the one to compute on; m, l: the
+        # online softmax's running maximum and sum (the weighted values'
+        # sum is `acc_ref`)
+        b, i, pb, pi, n_pages, last, m, l = carry
+        slot = jax.lax.rem(g, 2)
+        bf = jnp.minimum(b, n_seq - 1)
+        n_fetch = jnp.where(g < n_steps, live_pages(bf, i), 0)
+
+        def start(j, c):
+            blk = table_ref[bf, i * pps + j]
+            copy(pl.ds(blk * bs, bs), slot, j).start()
+            return c
+
+        jax.lax.fori_loop(0, n_fetch, start, 0)
+
+        def wait(j, c):
+            copy(pl.ds(0, bs), 1 - slot, j).wait()
+            return c
+
+        jax.lax.fori_loop(0, n_pages, wait, 0)
+        first = pi == 0
+        m = jnp.where(first, _NEG_BIG, m)
+        l = jnp.where(first, 0.0, l)
+
+        def compute():
+            rows = buf[1 - slot]                            # (rows, W)
+            s = _dot_nt(q_ref[pb], rows) * scale            # (H, rows) f32
+            at = pi * step_rows + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(at <= pos_ref[pb], s, _NEG_BIG)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            pv = _dot_nn(p.astype(rows.dtype), rows[:, :rank])
+            acc_ref[...] = jnp.where(first, 0.0, acc_ref[...] * alpha) + pv
+            return m_new, l * alpha + p.sum(axis=-1, keepdims=True)
+
+        m, l = jax.lax.cond(n_pages > 0, compute, lambda: (m, l))
+
+        @pl.when(last)
+        def _write():
+            o_ref[pb] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+        ends = i + 1 == steps_of(bf)
+        return (
+            jnp.where(ends, b + 1, b), jnp.where(ends, 0, i + 1),
+            bf, i, n_fetch, jnp.logical_and(ends, g < n_steps), m, l,
+        )
+
+    zero = jnp.int32(0)
+    h = q_ref.shape[1]
+    jax.lax.fori_loop(0, n_steps + 1, step, (
+        zero, zero, zero, zero, zero, False,
+        jnp.full((h, 1), _NEG_BIG, jnp.float32),
+        jnp.zeros((h, 1), jnp.float32),
+    ))
+
+
+def mla_decode_attention(q_lat, pool, layer, table, pos, *, block_size: int,
+                         rank: int, scale: float, interpret: bool = False):
+    """One decode step of absorbed-form latent attention for every
+    sequence, all heads at once, read from the serving engine's latent pool
+    where it lies.
+
+    q_lat (B, H, W) - each head's query in the cache row's space (`W_uk
+    q_nope` beside the rotated `q_rope`); pool (L, slots, W) - the whole
+    pool, left in HBM (a page is the contiguous ``(block_size, W)`` tile of
+    one block; a row is `[c ; k_rope]`, its first ``rank`` values also the
+    values); ``layer`` a scalar that may be traced; table (B, W_blocks)
+    int32, entries past a sequence's live pages unread; pos (B,) int32 -
+    positions 0..pos[b] are attended. Returns the latent-space output (B,
+    H, rank) in q_lat's dtype: `sum_j p(j) c(j)`, the `W_uv` and `W_o`
+    products left to the caller. Scores, the online softmax and the
+    accumulator are float32. Gate a compiled call with `mla_decode_ok`."""
+    b, h, w = q_lat.shape
+    if pool.ndim != 3 or pool.shape[2] != w or not 0 < rank <= w:
+        raise ValueError(
+            f"pool {pool.shape} does not hold q_lat's rows of {w} with "
+            f"{rank} values")
+    if table.shape[0] != b or pos.shape != (b,):
+        raise ValueError(
+            f"table {table.shape} and pos {pos.shape} do not describe "
+            f"q_lat's batch of {b}")
+    if not interpret and not mla_decode_ok(block_size, w, rank, pool.dtype):
+        raise ValueError(
+            f"mla_decode_attention: pages of {block_size} {pool.dtype} rows "
+            f"of {w} ({rank} of them values) are no tile this kernel "
+            "compiles for (mla_decode_ok) - fall back to the XLA decode path")
+    pps = max(1, min(table.shape[1], _MLA_STEP_POSITIONS // block_size))
+    return pl.pallas_call(
+        functools.partial(_mla_paged_kernel, bs=block_size, pps=pps,
+                          rank=rank, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((2, pps * block_size, w), pool.dtype),
+                pltpu.VMEM((h, rank), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=_struct((b, h, rank), q_lat.dtype, q_lat, pool),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_MLA_VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name="mla_decode_attn",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        table.astype(jnp.int32), pos.astype(jnp.int32), q_lat, pool,
+    )
+
+
+def mla_decode_ok(block_size: int, width: int, rank: int, dtype) -> bool:
+    """True where `mla_decode_attention` compiles: a page is copied under
+    the step's other pages, so its rows have to be whole sublane tiles of
+    the pool's dtype (16 rows of bfloat16, 8 of float32), and a row and its
+    values whole 128-lane tiles: the device stores a pool's minor axis in
+    such tiles whatever its length, and a copy cannot end inside one, so
+    the engine pads the latent row (576 values) to 640
+    (tests/test_tpu_aot_compile.py compiles the served shape for a
+    described v5e)."""
+    dtype = jnp.dtype(dtype)
+    tile = {jnp.dtype(jnp.bfloat16): 2 * _SUBLANES,
+            jnp.dtype(jnp.float32): _SUBLANES}.get(dtype)
+    return (tile is not None and block_size % tile == 0
+            and width % _LANES == 0 and rank % _LANES == 0)
+
+
+# ------------------------------- latent pool, chunked prefill (expanded)
+
+# cache positions one fetch step of the prefill kernel brings into VMEM and
+# expands: a head's scores against them are (chunk, 1024) float32, 2 MiB
+_MLA_PREFILL_KEYS = 1024
+
+
+def _mla_prefill_kernel(l_ref, table_ref, span_ref, qn_ref, qr_ref, w_ref,
+                        pool_hbm, o_ref, buf, sem, *, bs, pps, rank, nope,
+                        scale):
+    """One head a grid step: the chunk's queries of this head against the
+    cache positions ``0 .. n_keys - 1``, a fetch step of ``pps`` pages at a
+    time, double-buffered. A step's latent rows are EXPANDED in VMEM for
+    this head alone (``rows[:, :rank] . W_kvb,h`` -> its k_nope and v), its
+    scores are ``q_nope . k_nope^T + q_rope . k_rope^T`` (the rotary key
+    read from the rows as it lies, lanes ``rank ..``, the row's padding
+    against the query's), and an online softmax folds them into the
+    float32 accumulator: neither the expanded keys and values nor a score
+    leaves the chip. Pages past the last key are not copied."""
+    layer, pos0, n_keys = l_ref[0], span_ref[0], span_ref[1]
+    div = jax.lax.div
+    step_rows = pps * bs
+    n_steps = div(n_keys + step_rows - 1, step_rows)
+    n_pages_all = div(n_keys + bs - 1, bs)
+    c = qn_ref.shape[1]
+
+    def live_pages(i):
+        return jnp.clip(n_pages_all - i * pps, 0, pps)
+
+    def copy(rows, slot, j):
+        return pltpu.make_async_copy(
+            pool_hbm.at[layer, rows], buf.at[slot, pl.ds(j * bs, bs)],
+            sem.at[slot])
+
+    def fetch(i, slot):
+        def start(j, carry):
+            blk = table_ref[i * pps + j]
+            copy(pl.ds(blk * bs, bs), slot, j).start()
+            return carry
+        jax.lax.fori_loop(0, live_pages(i), start, 0)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _clear():   # what no copy has written yet must be finite
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+
+    fetch(0, 0)
+    q_nope, q_rope = qn_ref[0], qr_ref[0]                   # (C, .)
+    w = w_ref[...]                                          # (rank, nope+v)
+    qpos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+
+    def step(i, carry):
+        m, l, acc = carry
+        slot = jax.lax.rem(i, 2)
+        fetch(i + 1, 1 - slot)      # no page past the last key: no copy
+
+        def wait(j, carry):
+            copy(pl.ds(0, bs), slot, j).wait()
+            return carry
+
+        jax.lax.fori_loop(0, live_pages(i), wait, 0)
+        rows = buf[slot]                                    # (rows, W)
+        kv = _dot_nn(rows[:, :rank], w).astype(rows.dtype)  # (rows, nope+v)
+        s = _dot_nt(q_nope, kv[:, :nope]) + _dot_nt(
+            q_rope, rows[:, rank:rank + q_rope.shape[1]])
+        kpos = i * step_rows + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        s = jnp.where(kpos <= qpos, s * scale, _NEG_BIG)    # (C, rows)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        acc = acc * alpha + _dot_nn(p.astype(rows.dtype), kv[:, nope:])
+        return m_new, l * alpha + p.sum(axis=-1, keepdims=True), acc
+
+    m, l, acc = jax.lax.fori_loop(0, n_steps, step, (
+        jnp.full((c, 1), _NEG_BIG, jnp.float32),
+        jnp.zeros((c, 1), jnp.float32),
+        jnp.zeros((c, w.shape[1] - nope), jnp.float32),
+    ))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def mla_prefill_attention(q_nope, q_rope, w_kvb, pool, layer, table, pos0,
+                          n_keys, *, block_size: int, rank: int,
+                          scale: float, interpret: bool = False):
+    """Expanded-form latent attention of one sequence's prefill chunk over
+    its cache, read from the serving engine's latent pool where it lies.
+
+    q_nope (H, C, nope), q_rope (H, C, R) - the chunk's queries, heads
+    first, the rotary part padded with noughts to whole 128-lane tiles;
+    w_kvb (rank, H * (nope + v)) - a layer's up-projection as the tree
+    holds it, head h's columns `[W_uk | W_uv]`; pool (L, slots, W) - the
+    whole pool in HBM, a row `[c (rank) ; k_rope ; noughts]`, ``W >= rank
+    + R``; ``layer``, ``pos0`` (the chunk's first position) and ``n_keys``
+    (cache positions 0..n_keys - 1 are live; the chunk's own rows are
+    among them) scalars that may be traced; table (W_blocks,) int32. Query
+    i sees key positions <= pos0 + i. Returns o (H, C, v) in q's dtype.
+    Gate a compiled call with `mla_prefill_ok`."""
+    h, c, nope = q_nope.shape
+    width = w_kvb.shape[1] // h
+    if (q_rope.shape[:2] != (h, c) or w_kvb.shape[0] != rank
+            or pool.ndim != 3 or pool.shape[2] < rank + q_rope.shape[2]):
+        raise ValueError(
+            f"q_nope {q_nope.shape}, q_rope {q_rope.shape}, w_kvb "
+            f"{w_kvb.shape} and pool {pool.shape} do not describe one "
+            f"chunk's heads over rows of {rank} + {q_rope.shape[2]}")
+    if not interpret and not mla_prefill_ok(
+            block_size, pool.shape[2], rank, nope, width - nope,
+            q_rope.shape[2], pool.dtype):
+        raise ValueError(
+            f"mla_prefill_attention: pages of {block_size} {pool.dtype} "
+            f"rows of {pool.shape[2]} with heads of {nope} + {width - nope} "
+            "are no tiles this kernel compiles for (mla_prefill_ok)")
+    pps = max(1, min(table.shape[0], _MLA_PREFILL_KEYS // block_size))
+    return pl.pallas_call(
+        functools.partial(_mla_prefill_kernel, bs=block_size, pps=pps,
+                          rank=rank, nope=nope, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(h,),
+            in_specs=[
+                pl.BlockSpec((1, c, nope), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((1, c, q_rope.shape[2]),
+                             lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((rank, width), lambda i, *_: (0, i)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, c, width - nope),
+                                   lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, pps * block_size, pool.shape[2]), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=_struct((h, c, width - nope), q_nope.dtype, q_nope, pool),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_MLA_VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name="mla_prefill_attn",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1), table.astype(jnp.int32),
+        jnp.stack([jnp.asarray(pos0, jnp.int32),
+                   jnp.asarray(n_keys, jnp.int32)]),
+        q_nope, q_rope, w_kvb, pool,
+    )
+
+
+def mla_prefill_ok(block_size: int, width: int, rank: int, nope: int,
+                   v: int, rope: int, dtype) -> bool:
+    """True where `mla_prefill_attention` compiles: `mla_decode_ok`'s pages,
+    and every slice the kernel cuts (the latent, a head's keys and values,
+    the rotary key with the row's padding) whole 128-lane tiles."""
+    return (mla_decode_ok(block_size, width, rank, dtype)
+            and all(n % _LANES == 0 for n in (nope, v, rope))
+            and width >= rank + rope)

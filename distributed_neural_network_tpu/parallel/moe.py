@@ -222,7 +222,7 @@ def moe_ffn(
 
 
 def sigmoid_topk_route(x, wr, bias, *, top_k: int, scale: float):
-    """x (T, d), wr (d, E), bias (E,) -> (experts (T, k) int32, weights
+    """x (T, d), wr (d, E), bias (E,) or None -> (experts (T, k) int32, weights
     (T, k) float32). Scores are sigmoid(x wr) in float32 at the highest
     matmul precision (a choice that flips with the rounding of x moves an
     expert's gradient in the first order); the k largest of score + bias
@@ -231,8 +231,11 @@ def sigmoid_topk_route(x, wr, bias, *, top_k: int, scale: float):
     scores = jax.nn.sigmoid(jnp.matmul(
         x.astype(jnp.float32), wr.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
+    # (a router without a selection bias passes None: chosen in Python,
+    # so a step that has one traces what it traced)
     _, experts = jax.lax.top_k(
-        scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+        scores if bias is None
+        else scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
     return experts, scale * chosen / chosen.sum(-1, keepdims=True)
 
@@ -398,3 +401,71 @@ def moe_held_ffn(x, wr, bias, w_up, w_down, shared_up, shared_down, *,
     stats = {"held": held, "absent": absent,
              "dropped": held - valid.sum().astype(jnp.int32), "load": load}
     return y, stats
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """The gated MLP `(silu(x W_gate) * (x W_up)) W_down`."""
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+def moe_held_gated_serve(x, wr, w_gate, w_up, w_down, shared, *, first: int,
+                         top_k: int, scale: float, tile: int, valid=None,
+                         layer=None):
+    """A chip's share of a sigmoid-routed layer of GATED experts, forward
+    only, in the layout serving wants: the work follows the pairs that
+    arrived.
+
+    x (T, d); wr (d, E) over all E routed experts, no selection bias;
+    w_gate / w_up (H, d, f) and w_down (H, f, d) the H experts held here
+    (`first .. first + H - 1`), each `swiglu`; `shared` the shared expert's
+    (gate, up, down). `valid` (T,) bool marks the rows that are tokens (a
+    bucket's spare rows route nowhere and are not counted). With `layer` (a
+    scalar, traced under a layer scan) the held experts' matrices come
+    stacked over layers, (L, H, d, f), and a tile reads `w[layer, expert]`
+    where it lies: a layer's slice handed in through the scan is a COPY of
+    all H experts' matrices a layer (1.5 GB at the served widths) before the
+    loop reads six of them. Returns (y, stats) as `moe_held_ffn` does,
+    `stats` with `multiplied` in `dropped`'s place.
+
+    The pairs are sorted by held expert (`held_pairs_layout`) into rows of
+    `tile`, each expert's rows starting on a tile boundary, so the tiles
+    that own a pair are a prefix of the buffer: the loop runs over that
+    prefix alone, on a traced bound. A decode tick's 32 tokens bring about
+    16 held pairs to six or seven of 16 experts: with tiles of 16 rows it
+    multiplies (and reads the matrices of) those experts and no others,
+    where the training layout's 512-row tiles of the worst-case buffer
+    would multiply 17 tiles for them. `multiplied` counts the rows of the
+    tiles the loop ran, `held` the rows a pair owns."""
+    dt, f32 = x.dtype, jnp.float32
+    n_held = w_up.shape[-3]
+
+    def of(w, g):
+        return (w[g] if layer is None else w[layer, g]).astype(dt)
+
+    with jax.named_scope("lm.moe.route"):
+        experts, weights = sigmoid_topk_route(x, wr, None, top_k=top_k,
+                                              scale=scale)
+        if valid is not None:   # a spare row's pairs: to no expert here
+            experts = jnp.where(valid[:, None], experts, first - 1)
+        src, ok, pos, tile_group, load, absent = held_pairs_layout(
+            experts, first=first, n_held=n_held, tile=tile)
+        if valid is not None:
+            absent = absent - top_k * jnp.sum(~valid, dtype=jnp.int32)
+        row_weight = jnp.zeros(src.shape, f32).at[pos].set(
+            weights, mode="drop")
+        n_tiles = jnp.sum(-(-load // tile))
+    with jax.named_scope("lm.moe.experts"):
+        def one(i, y):
+            rows = jax.lax.dynamic_slice_in_dim(src, i * tile, tile)
+            live = jax.lax.dynamic_slice_in_dim(ok, i * tile, tile)
+            w = jax.lax.dynamic_slice_in_dim(row_weight, i * tile, tile)
+            g = tile_group[i]
+            xb = jnp.where(live[:, None], x[rows], 0)
+            yb = swiglu(xb, of(w_gate, g), of(w_up, g), of(w_down, g))
+            return y.at[rows].add(yb.astype(f32) * w[:, None])
+
+        y = jax.lax.fori_loop(0, n_tiles, one, jnp.zeros(x.shape, f32))
+    with jax.named_scope("lm.moe.shared"):
+        y = y.astype(dt) + swiglu(x, *(w.astype(dt) for w in shared))
+    return y, {"held": load.sum(), "absent": absent, "load": load,
+               "multiplied": n_tiles * tile}

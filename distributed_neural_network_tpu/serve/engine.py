@@ -58,6 +58,19 @@ q/k/v and the attention output (decode: one row written, the bucket
 gathered, the kernel or `masked_attention`; prefill and verify: the chunk
 written, the span gathered; draft: a local buffer beside the pre-gathered
 history, nothing written).
+
+**A latent cache** (a module whose ``CACHE`` is ``"latent"``,
+models/pangu_ultra_moe.py): a position keeps ONE row for all heads in ONE
+pool ``(L, slots, row)``, under the K pool's name. The constructor decides
+it once, in Python; the two program families of such an engine
+(`_latent_decode`, `_latent_prefill`) have one table width, scan the
+module's dense stack and then its expert stack (`_latent_layers`), run the
+absorbed form on `mla_decode_attention` in decode and the expanded form on
+`mla_prefill_attention` in prefill (``decode_impl`` "xla": the module's own
+`jax.numpy` forms, the oracles), and hand the expert layers' routing
+counts back with the tokens. Speculative decoding and the int8 pool and
+weights are refused for it by name. The per-head programs above are
+untouched by it.
 """
 
 from __future__ import annotations
@@ -77,15 +90,26 @@ from ..models import transformer as tfm
 from ..models.transformer import TransformerConfig, _sinusoid_pe
 from ..ops.decode_pallas import (
     decode_paged_attention,
+    mla_decode_attention,
+    mla_decode_ok,
+    mla_prefill_attention,
+    mla_prefill_ok,
     paged_decode_ok,
     paged_read_positions,
 )
 from ..ops.quant import prequantize_weight, quantized_matmul
 from ..runtime import on_tpu
-from .kv_cache import KVCacheConfig, OutOfBlocks, PagedKVCache
+from .kv_cache import SCRATCH_BLOCK, KVCacheConfig, OutOfBlocks, PagedKVCache
 
 _INT8_MAX = 127.0
 _SCALE_EPS = 1e-30
+_LANES = 128
+# rows of one tile of the expert products (parallel/moe.py
+# `moe_held_gated_serve`): a decode tick's few tokens, a prefill chunk's many
+_MOE_TILE_SMALL, _MOE_TILE = 16, 128
+# cache positions one step of the blocked prefill attention expands and
+# scores (models/pangu_ultra_moe.py `prefill_attention`)
+_PREFILL_KEY_BLOCK = 1024
 
 # the phases that partition `ServeEngine.step` on the host's clock; each
 # is also a `serve.<phase>` span in a profile (docs/SERVING.md "Metrics")
@@ -485,6 +509,68 @@ def _write_then_attend(q, k, v, l, pools, *, at, live, dt):
     return o, (k_pool, v_pool, k_scale, v_scale)
 
 
+def _next_tokens(logits, temps, keys):
+    """A decode program's last step: each row's next token from its logits
+    (B, vocab) - the argmax where its temperature is 0, a draw from
+    ``logits / temperature`` under its key otherwise. (B,) int32."""
+    greedy = jnp.argmax(logits, axis=-1)
+    sampled = jax.vmap(
+        lambda k_, lg, t: jax.random.categorical(
+            k_, lg / jnp.maximum(t, 1e-6)
+        )
+    )(keys, logits, temps)
+    return jnp.where(temps > 0.0, sampled, greedy).astype(jnp.int32)
+
+
+def _pad_last(x, width: int):
+    """x (..., n) -> (..., width), noughts behind."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def _latent_layers(cfg, params, x, pool, cache_step, valid):
+    """The layers of a latent program: the dense stack, then the expert
+    stack, each a scan of the module's block (`block_in` inside
+    ``cache_step(x, lp, l, pool) -> (o, pool)``, `block_out` here) with
+    the pool on the CARRY and the model's layer index in xs, as
+    `_scan_layers` has them and for its reasons. Returns (x, pool,
+    counts): counts the expert layers' routing, packed as int32 - pairs
+    held, pairs absent, rows multiplied, then each expert layer's load
+    over the held experts. (A function of the configuration and not a
+    method: a compiled program that held the engine would keep its weights
+    and pool alive with it.)"""
+    mod = cfg.module
+    tile = _MOE_TILE_SMALL if x.shape[0] <= 4 * _MOE_TILE_SMALL else _MOE_TILE
+    routing = None
+    for kind, n, l0 in mod.layer_stacks(cfg):
+        # the held experts' matrices stay out of the scan's xs: a tile of
+        # the expert products reads the ones it needs from the stack
+        held = {k: v for k, v in params[kind].items()
+                if k in mod.EXPERT_LEAVES}
+        rest = {k: v for k, v in params[kind].items() if k not in held}
+
+        def layer_step(carry, layer, kind=kind, held=held, l0=l0):
+            x, pool = carry
+            lp, l = layer
+            o, pool = cache_step(x, lp, l, pool)
+            x, stats = mod.block_out(
+                x, o, lp, cfg, kind, tile=tile, valid=valid,
+                experts=(held, l - l0) if held else None)
+            return (x, pool), stats
+
+        (x, pool), stats = jax.lax.scan(
+            layer_step, (x, pool), (rest, l0 + jnp.arange(n)))
+        if stats is not None:
+            routing = stats
+    if routing is None:     # no expert layer: nothing routed
+        return x, pool, jnp.zeros((3,), jnp.int32)
+    counts = jnp.concatenate([
+        jnp.stack([routing["held"].sum(), routing["absent"].sum(),
+                   routing["multiplied"].sum()]),
+        routing["load"].reshape(-1),
+    ]).astype(jnp.int32)
+    return x, pool, counts
+
+
 @jax.jit
 def _row_keys(seeds, pos):
     """Each decode row's sampling key from its request's seed (low 32
@@ -517,15 +603,36 @@ class ServeEngine:
     `step()`; admission/cancel mutate the active set under `lock`
     between ticks."""
 
-    def __init__(self, params, cfg: TransformerConfig, ecfg: EngineConfig):
-        if not isinstance(cfg, TransformerConfig):
+    def __init__(self, params, cfg, ecfg: EngineConfig):
+        # what a position's cache row is decides which programs are built,
+        # here and never inside one: per-head K and V (`TransformerConfig`)
+        # or one latent row (a module that says `CACHE = "latent"`)
+        self.latent = getattr(cfg.module, "CACHE", "") == "latent"
+        if not self.latent and not isinstance(cfg, TransformerConfig):
             raise ValueError(
                 f"{cfg.module.NAME}: the serving "
                 "engine does not run this model - its Mamba-2 layers carry "
                 "a recurrent state that the paged KV cache has no place "
                 "for, and the engine's programs know one kind of block"
             )
-        if cfg.n_experts:
+        if self.latent:
+            refused = {
+                "spec_decode": (ecfg.spec_decode, "the early-exit drafter "
+                                "and the verify step are written for "
+                                "per-head K and V pools"),
+                "kv_dtype int8": (ecfg.kv_dtype == "int8", "the per-(block, "
+                                  "head) scales have no head to belong to in "
+                                  "a latent row shared by all heads"),
+                "weight_dtype int8": (ecfg.weight_dtype == "int8", "the "
+                                      "prequantized matmul knows the GPT-2 "
+                                      "block's six matrices"),
+            }
+            for what, (asked, why) in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"{cfg.module.NAME}: {what} is not supported with "
+                        f"a latent cache - {why}")
+        elif cfg.n_experts:
             raise ValueError(
                 "the serving engine supports dense models; MoE decode "
                 "routes through models/transformer.py generate()"
@@ -556,12 +663,32 @@ class ServeEngine:
             # depth the model does not have
             self.draft_params = tfm.early_exit_params(
                 self.params, self.draft_layers)
-        L, H, Dh = cfg.n_layers, cfg.n_heads, cfg.head_dim
         slots = self.kv.cfg.pool_slots
         self.quantized = ecfg.kv_dtype == "int8"
         pool_dt = jnp.int8 if self.quantized else cfg.dtype
-        self.k_pool = jnp.zeros((L, slots, H, Dh), pool_dt)
-        self.v_pool = jnp.zeros((L, slots, H, Dh), pool_dt)
+        # the one width of a latent program's block table (0 = per-head K
+        # and V: the power-of-two width buckets)
+        self._latent_width = 0
+        if self.latent:
+            # ONE pool of latent rows, under the name the K pool has (what
+            # holds the engine's cache is asked for by it); a row is padded
+            # to whole 128-lane tiles, which is how the device stores the
+            # pool's minor axis whatever its length
+            # (the rotary key with its padding is a tile of its own, which
+            # the prefill kernel scores as it lies: 512 + 64 -> 640)
+            self._rope_width = -(-cfg.qk_rope // _LANES) * _LANES
+            self.row_width = -(-(cfg.kv_rank + self._rope_width)
+                               // _LANES) * _LANES
+            self.k_pool = jnp.zeros(
+                (cfg.n_layers, slots, self.row_width), pool_dt)
+            self.v_pool = None
+            self._latent_width = _bucket(self.kv.cfg.max_blocks_per_seq)
+            # expert-layer counts of prefill dispatches not yet fetched
+            self._moe_pending: list = []
+        else:
+            L, H, Dh = cfg.n_layers, cfg.n_heads, cfg.head_dim
+            self.k_pool = jnp.zeros((L, slots, H, Dh), pool_dt)
+            self.v_pool = jnp.zeros((L, slots, H, Dh), pool_dt)
         self.k_scale = self.v_scale = None
         if self.quantized:
             # int8 pool + per-(block, head) f32 scales: the one extra
@@ -648,9 +775,14 @@ class ServeEngine:
         (K + V + any per-(block, head) scales) - analysis/cost.py's
         table, so the serving occupancy gauges and the autoshard HBM
         gate can never disagree on a byte."""
-        from ..analysis.cost import kv_block_bytes
+        from ..analysis.cost import kv_block_bytes, latent_block_bytes
 
         cfg = self.cfg
+        if self.latent:
+            return latent_block_bytes(
+                cfg.n_layers, self.row_width, self.ecfg.block_size,
+                self.kv_dtype_name(),
+            )
         return kv_block_bytes(
             cfg.n_layers, cfg.n_heads, cfg.head_dim,
             self.ecfg.block_size, self.kv_dtype_name(),
@@ -701,6 +833,17 @@ class ServeEngine:
         if impl == "xla":
             return "xla"
         cfg = self.cfg
+        if self.latent:
+            legal = mla_decode_ok(self.ecfg.block_size, self.row_width,
+                                  cfg.kv_rank, self.k_pool.dtype)
+            if impl == "pallas" and on_tpu() and not legal:
+                raise ValueError(
+                    f"decode_impl 'pallas' requested but the latent decode "
+                    f"kernel does not compile for pages of "
+                    f"{self.ecfg.block_size} {self.k_pool.dtype} rows "
+                    "(ops/decode_pallas.py mla_decode_ok) - use 'auto'")
+            return "pallas" if impl == "pallas" or (
+                legal and on_tpu()) else "xla"
         legal = paged_decode_ok(
             self.ecfg.block_size, cfg.n_heads, cfg.head_dim,
             self.k_pool.dtype,
@@ -724,8 +867,29 @@ class ServeEngine:
     # the CLI's ``decode -> ...`` line and ``GET /v1/status``
     decode_route = _attn_route
 
+    def _prefill_route(self) -> str:
+        """A latent module's chunked-prefill attention: the Mosaic kernel
+        that expands a fetch step's latent rows a head at a time in VMEM
+        (ops/decode_pallas.py `mla_prefill_attention`) where `decode_impl`
+        asks for kernels and it compiles, the blocked `jax.numpy` loop
+        (the module's `prefill_attention`, the oracle) otherwise."""
+        impl = self.ecfg.decode_impl
+        if impl == "xla":
+            return "xla"
+        cfg = self.cfg
+        legal = mla_prefill_ok(
+            self.ecfg.block_size, self.row_width, cfg.kv_rank, cfg.qk_nope,
+            cfg.v_head, self._rope_width, self.k_pool.dtype)
+        if on_tpu():
+            return "pallas" if legal else "xla"
+        return "pallas" if impl == "pallas" else "xla"   # interpreted
+
     def _bucket_widths(self, max_width_blocks: int | None = None) -> list:
         """The power-of-two width buckets (in blocks) up to the cap."""
+        if self._latent_width:
+            # the kernel and the blocked prefill walk a table's live part
+            # on traced bounds: a narrower table buys further programs only
+            return [self._latent_width]
         max_w = _bucket(max_width_blocks or self.kv.cfg.max_blocks_per_seq)
         widths = []
         w = 1
@@ -752,6 +916,8 @@ class ServeEngine:
         call). The drafter READS the pools and returns only draft tokens,
         so it has nothing to alias and donates nothing. servelint audits
         the donation contract per bucket (analysis/serve_trace.py)."""
+        if self.latent:   # written as ``program(params, pool, *tail)``
+            return jax.jit(program, donate_argnums=(1,))
         if self.quantized:
             return jax.jit(
                 program, donate_argnums=(1, 2, 3, 4) if writes_pools else ())
@@ -766,6 +932,10 @@ class ServeEngine:
     def _decode_fn(self, B: int, W: int):
         fn = self._step_fns.get((B, W))
         if fn is not None:
+            return fn
+        if self.latent:
+            fn = self._step_fns[(B, W)] = self._jit_bucket(
+                self._latent_decode(B, W))
             return fn
         cfg, dt = self.cfg, self.cfg.dtype
         H, Dh = cfg.n_heads, cfg.head_dim
@@ -821,14 +991,7 @@ class ServeEngine:
                 cache_step,
             )
             logits = tfm.final_logits(params, x[:, 0], dt)
-            greedy = jnp.argmax(logits, axis=-1)
-            sampled = jax.vmap(
-                lambda k_, lg, t: jax.random.categorical(
-                    k_, lg / jnp.maximum(t, 1e-6)
-                )
-            )(keys, logits, temps)
-            nxt = jnp.where(temps > 0.0, sampled, greedy).astype(jnp.int32)
-            return *pools, nxt, logits
+            return *pools, _next_tokens(logits, temps, keys), logits
 
         fn = self._step_fns[(B, W)] = self._jit_bucket(step)
         return fn
@@ -836,6 +999,10 @@ class ServeEngine:
     def _prefill_fn(self, C: int, W: int):
         fn = self._prefill_fns.get((C, W))
         if fn is not None:
+            return fn
+        if self.latent:
+            fn = self._prefill_fns[(C, W)] = self._jit_bucket(
+                self._latent_prefill(C, W))
             return fn
         cfg, dt = self.cfg, self.cfg.dtype
         bs = self.kv.cfg.block_size
@@ -869,6 +1036,103 @@ class ServeEngine:
 
         fn = self._prefill_fns[(C, W)] = self._jit_bucket(prefill)
         return fn
+
+    # --------------------------------------- latent cache (MLA) programs
+
+    def _latent_decode(self, B: int, W: int):
+        cfg, mod = self.cfg, self.cfg.module
+        bs, row_width = self.kv.cfg.block_size, self.row_width
+        S = W * bs
+        use_kernel = self._attn_route() == "pallas"
+
+        def step(params, pool, tok, pos, table, temps, keys):
+            # tok/pos (B,), table (B, W), temps (B,), keys (B, 2)
+            x = mod.embed_tokens(params, tok, cfg)               # (B, d)
+            flat = table[jnp.arange(B), pos // bs] * bs + pos % bs
+            valid = table[:, 0] != SCRATCH_BLOCK    # a spare row: no token
+            if not use_kernel:
+                idx = _span_idx(table, bs)                       # (B, S)
+                live = jnp.arange(S)[None, :] <= pos[:, None]
+
+            def cache_step(x, lp, l, pool):
+                # write this position's row, then attend over the cache
+                # in the absorbed form: the rows as they lie are K and V
+                q_nope, q_rope, row = mod.block_in(x, lp, cfg, pos)
+                pool = _write_rows(pool, l, flat, _pad_last(row, row_width))
+                q_lat = _pad_last(
+                    mod.absorb_q(q_nope, q_rope, lp, cfg), row_width)
+                if use_kernel:
+                    with jax.named_scope("lm.mla.attn"):
+                        o_lat = mla_decode_attention(
+                            q_lat, pool, l, table, pos, block_size=bs,
+                            rank=cfg.kv_rank, scale=cfg.softmax_scale,
+                            interpret=not on_tpu(),
+                        )
+                else:   # the oracle: gather the table's span
+                    o_lat = mod.absorbed_attention(
+                        q_lat, _read_rows(pool, l, idx), live, cfg)
+                return mod.unabsorb_o(o_lat, lp, cfg), pool
+
+            x, pool, counts = _latent_layers(
+                cfg, params, x, pool, cache_step, valid)
+            logits = mod.final_logits(params, x, cfg)
+            return pool, _next_tokens(logits, temps, keys), logits, counts
+
+        step.__name__ = "latent_decode"
+        return step
+
+    def _latent_prefill(self, C: int, W: int):
+        cfg, mod = self.cfg, self.cfg.module
+        bs, row_width = self.kv.cfg.block_size, self.row_width
+        rope_width = self._rope_width
+        key_block = min(_PREFILL_KEY_BLOCK, W * bs)
+        pages = key_block // bs
+        use_kernel = self._prefill_route() == "pallas"
+
+        def prefill(params, pool, toks, pos0, table, n_valid):
+            # toks (C,), pos0 scalar, table (W,), n_valid scalar
+            pv = pos0 + jnp.arange(C)
+            valid = jnp.arange(C) < n_valid
+            x = mod.embed_tokens(params, toks, cfg)              # (C, d)
+            # the chunk's dead tail -> the scratch block
+            flat = jnp.where(valid, table[pv // bs] * bs + pv % bs, 0)
+
+            def cache_step(x, lp, l, pool):
+                # write the chunk's rows, then the expanded form over the
+                # table's live span, the rows just written included, a key
+                # block at a time
+                q_nope, q_rope, rows = mod.block_in(x, lp, cfg, pv)
+                pool = _write_rows(pool, l, flat, _pad_last(rows, row_width))
+                if use_kernel:
+                    # heads first; the rotary part padded like the rows'
+                    with jax.named_scope("lm.mla.attn"):
+                        o = mla_prefill_attention(
+                            q_nope.transpose(1, 0, 2),
+                            _pad_last(q_rope.transpose(1, 0, 2), rope_width),
+                            lp["kv_b"].astype(cfg.dtype), pool, l, table,
+                            pos0, pos0 + n_valid, block_size=bs,
+                            rank=cfg.kv_rank, scale=cfg.softmax_scale,
+                            interpret=not on_tpu(),
+                        )
+                    return o.transpose(1, 0, 2), pool
+
+                def read_rows(j):
+                    blk = jax.lax.dynamic_slice_in_dim(
+                        table, j * pages, pages)
+                    return _read_rows(pool, l, _span_idx(blk, bs))
+
+                o = mod.prefill_attention(
+                    q_nope, q_rope, pv, read_rows, pos0 + n_valid, lp, cfg,
+                    key_block=key_block)
+                return o, pool
+
+            _, pool, counts = _latent_layers(
+                cfg, params, x, pool, cache_step, valid)
+            # no logits: the last prompt token is the decode batch's
+            return pool, counts
+
+        prefill.__name__ = "latent_prefill"
+        return prefill
 
     def _draft_fn(self, B: int, W: int):
         """k greedy early-exit steps in ONE jitted call: reads the paged
@@ -989,6 +1253,8 @@ class ServeEngine:
 
     def _pools(self) -> tuple:
         """The donated operands of a bucket program, in its order."""
+        if self.latent:
+            return (self.k_pool,)
         pools = (self.k_pool, self.v_pool, self.k_scale, self.v_scale)
         return pools if self.quantized else pools[:2]
 
@@ -1004,6 +1270,9 @@ class ServeEngine:
         returns the outputs after them."""
         pools = self._pools()
         out = fn(self.params, *pools, *tail)
+        if self.latent:
+            self.k_pool = out[0]
+            return out[1:]
         self.k_pool, self.v_pool = out[:2]
         if self.quantized:
             self.k_scale, self.v_scale = out[2:4]
@@ -1080,6 +1349,19 @@ class ServeEngine:
         return n
 
     # ------------------------------------------------------------ the tick
+
+    def _moe_stats(self, counts: list) -> dict:
+        """The packed counts of a tick's latent programs (`_latent_layers`)
+        summed: pairs ``held`` and ``absent``, rows ``multiplied`` (the rows
+        a pair owns are the held pairs), and each expert layer's ``load``
+        (layers, held experts)."""
+        total = np.sum(counts, axis=0)
+        return {
+            "held": int(total[0]), "absent": int(total[1]),
+            "multiplied": int(total[2]),
+            "load": total[3:].reshape(
+                self.cfg.n_moe, self.cfg.experts_held[1]),
+        }
 
     def _emit(self, seq: Sequence, tok: int) -> None:
         """One NEW generated token: record, maybe retire, stream."""
@@ -1341,18 +1623,26 @@ class ServeEngine:
                         seqstat(seq)["parked"] = True
                         continue
                     C = _bucket(n)
-                    W = _bucket((seq.pos + n - 1) // bs + 1)
+                    W = self._latent_width or _bucket(
+                        (seq.pos + n - 1) // bs + 1)
                     toks = np.zeros((C,), np.int32)
                     toks[:n] = seq.prompt[seq.pos: seq.pos + n]
                     table = self.kv.table([seq.seq_id], W)[0]
                     fn = self._prefill_fn(C, W)
-                    stats["prefill_calls"].append(
-                        (C, W, n * seq.pos + n * (n + 1) // 2)
-                    )
-                    self._run_writer(
+                    live = n * seq.pos + n * (n + 1) // 2
+                    out = self._run_writer(
                         fn, jnp.asarray(toks), jnp.int32(seq.pos),
                         jnp.asarray(table), jnp.int32(n),
                     )
+                    if self.latent:
+                        # its width: the key blocks the program walks
+                        kb = min(_PREFILL_KEY_BLOCK, W * bs)
+                        W = -(-(seq.pos + n) // kb) * kb // bs
+                        self._moe_pending.append(out[0])
+                        if self._prefill_route() == "pallas":
+                            stats["prefill_kernel_pairs"] = live + stats.get(
+                                "prefill_kernel_pairs", 0)
+                    stats["prefill_calls"].append((C, W, live))
                     seq.pos += n
                     budget -= n
                     self.prefill_tokens += n
@@ -1409,7 +1699,8 @@ class ServeEngine:
             if batch:
                 B = min(_bucket(len(batch)), ecfg.max_batch)
                 batch = batch[:B]
-                W = _bucket(max(s.pos // bs + 1 for s in batch))
+                W = self._latent_width or _bucket(
+                    max(s.pos // bs + 1 for s in batch))
                 tok = np.zeros((B,), np.int32)
                 pos = np.zeros((B,), np.int32)
                 temps = np.zeros((B,), np.float32)
@@ -1421,12 +1712,14 @@ class ServeEngine:
                     seeds[i] = s.seed & 0xFFFFFFFF
                 table = self._table(batch, B, W)
                 fn = self._decode_fn(B, W)
+                kernel = self._attn_route() == "pallas"
                 stats["decode_call"] = (
                     B, W, int(pos.sum()) + len(batch),
-                    paged_read_positions(pos, bs)
-                    if self._attn_route() == "pallas" else B * W * bs,
+                    paged_read_positions(pos, bs) if kernel
+                    else B * W * bs,
                 )
-                nxt, _ = self._run_writer(
+                stats["decode_kernel"] = kernel
+                nxt, *rest = self._run_writer(
                     fn, jnp.asarray(tok), jnp.asarray(pos),
                     jnp.asarray(table), jnp.asarray(temps),
                     _row_keys(seeds, pos),
@@ -1434,7 +1727,14 @@ class ServeEngine:
         lap("decode_host")
         if batch:
             with TraceAnnotation("serve.fetch"):
-                nxt = np.asarray(nxt)
+                if self.latent:
+                    # the expert layers' counts come with the tokens
+                    nxt, *counts = jax.device_get(
+                        [nxt, rest[1], *self._moe_pending])
+                    self._moe_pending.clear()
+                    stats["moe"] = self._moe_stats(counts)
+                else:
+                    nxt = np.asarray(nxt)
             lap("fetch")
             with TraceAnnotation("serve.emit"):
                 for i, s in enumerate(batch):

@@ -349,13 +349,42 @@ def build_model(args):
 
     from ..models.transformer import TransformerConfig, init_params
 
+    dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
+    if getattr(args, "model_config", ""):
+        # a published configuration's file names its family's module
+        # (models/__init__.py FAMILIES); the geometry flags stand unused
+        import json
+
+        from .. import models
+
+        with open(args.model_config) as f:
+            published = json.load(f)
+        try:
+            family = models.family_module(published.get("family"))
+        except KeyError:
+            raise SystemExit(
+                f"--model-config {args.model_config}: family "
+                f"{published.get('family')!r} has no model module here "
+                f"(there is {sorted(models.FAMILIES)})"
+            ) from None
+        if not hasattr(family, "CACHE"):
+            raise SystemExit(
+                f"--model-config {args.model_config}: family "
+                f"{published['family']!r} is not served (its module has no "
+                "cache row for the engine)"
+            )
+        cfg = family.from_published(published, dtype=dtype)
+        params = jax.tree.map(
+            lambda x: x.astype(dtype),
+            family.init_params(jax.random.key(args.seed), cfg))
+        return params, cfg
     cfg = TransformerConfig(
         vocab_size=args.vocab,
         d_model=args.d_model,
         n_heads=args.n_heads,
         n_layers=args.n_layers,
         d_ff=args.d_ff,
-        dtype=jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32,
+        dtype=dtype,
     )
     params = init_params(jax.random.key(args.seed), cfg)
     return params, cfg
@@ -373,6 +402,13 @@ def add_model_args(p: argparse.ArgumentParser) -> None:
                    default="float32")
     p.add_argument("--seed", type=int, default=0,
                    help="init_params seed (the oracle contract)")
+    p.add_argument("--model-config", default="", metavar="FILE",
+                   help="serve the model of a published configuration's "
+                   "file (its \"family\" names the module, "
+                   "models/__init__.py FAMILIES: a latent-attention "
+                   "expert model, e.g. benchmark/families/pangu_ultra_moe/"
+                   "tiny.json) in place of the GPT-2 block of the geometry "
+                   "flags; seeded weights at --dtype")
 
 
 def main(argv=None) -> int:
@@ -522,8 +558,8 @@ def main(argv=None) -> int:
         )
     print(
         f"serving on {server.url} "
-        f"(model d{args.d_model}/L{args.n_layers}/H{args.n_heads} "
-        f"vocab {args.vocab} seed {args.seed}; "
+        f"(model d{cfg.d_model}/L{cfg.n_layers}/H{cfg.n_heads} "
+        f"vocab {cfg.vocab_size} seed {args.seed}; "
         f"{engine.kv.cfg.usable_blocks} KV blocks x "
         f"{args.block_size} tokens [{engine.kv_dtype_name()}, "
         f"{engine.kv_block_bytes():,} B/block]; "

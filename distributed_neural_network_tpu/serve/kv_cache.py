@@ -30,7 +30,12 @@ their dead writes into it. The allocator therefore hands out ids
 
 Pure host bookkeeping + index math; the device pools live on
 `ServeEngine` (functionally updated by the jitted step). Stdlib+numpy
-only, importable without jax.
+only, importable without jax. The allocator does not know what a slot
+holds: per-head K and V rows in two pools, or - for a module with
+latent attention - ONE row `[c ; k_rope]` for all heads in one pool
+`(L, num_blocks * block_size, row)` (serve/engine.py; docs/SERVING.md
+"A latent cache"); block ids, tables, preemption and rewind are the
+same.
 
 Under ``EngineConfig.kv_dtype="int8"`` the device pools are stored
 QUANTIZED - int8 codes plus one f32 scale per (block, head) per layer -

@@ -363,6 +363,35 @@ class ServeScheduler:
         self._m_decode_padded = decode_pos.labels(kind="padded")
         self._m_prefill_live = prefill_pos.labels(kind="live")
         self._m_prefill_padded = prefill_pos.labels(kind="padded")
+        # the Mosaic attention calls' work, and the expert layers' routing
+        # of a module that has them (serve/engine.py `_latent_layers`)
+        self._m_kernel_decode = r.counter(
+            "serve_attn_kernel_positions_total",
+            "Live cached positions handed to Mosaic decode attention calls",
+        ).labels(path="decode")
+        self._m_kernel_prefill = r.counter(
+            "serve_attn_kernel_pairs_total",
+            "Live query-key pairs handed to Mosaic prefill attention calls",
+        ).labels(path="prefill")
+        moe_pairs = r.counter(
+            "serve_moe_pairs_total",
+            "Routed (token, expert) pairs by where their expert lies",
+        )
+        moe_rows = r.counter(
+            "serve_moe_rows_total",
+            "Rows of the expert products: owned by a held pair, multiplied",
+        )
+        self._m_moe = {
+            "held": moe_pairs.labels(where="held"),
+            "absent": moe_pairs.labels(where="absent"),
+            "owned": moe_rows.labels(kind="owned"),
+            "multiplied": moe_rows.labels(kind="multiplied"),
+        }
+        self._m_moe_load = r.gauge(
+            "serve_moe_expert_load_max_over_mean",
+            "Busiest held expert's pairs over the mean, last tick, by "
+            "expert layer",
+        )
         if r is not NULL_REGISTRY:
             self.ledger.publish(r)
 
@@ -806,12 +835,27 @@ class ServeScheduler:
             self._m_decode_live.inc(live)
             self._m_decode_read.inc(read)
             self._m_decode_padded.inc(B * W * bs)
+            if stats.get("decode_kernel"):
+                self._m_kernel_decode.inc(live)
         for C, W, live in stats["prefill_calls"]:
             self._m_prefill_calls.labels(
                 chunk=str(C), width_blocks=str(W)
             ).inc()
             self._m_prefill_live.inc(live)
             self._m_prefill_padded.inc(C * W * bs)
+        # (only a latent module's prefill programs call a Mosaic attention
+        # kernel: other ticks do not bring the key)
+        self._m_kernel_prefill.inc(stats.get("prefill_kernel_pairs", 0))
+        moe = stats.get("moe")
+        if moe is not None:
+            self._m_moe["held"].inc(moe["held"])
+            self._m_moe["absent"].inc(moe["absent"])
+            self._m_moe["owned"].inc(moe["held"])
+            self._m_moe["multiplied"].inc(moe["multiplied"])
+            for layer, load in enumerate(moe["load"]):
+                if load.sum():
+                    self._m_moe_load.labels(layer=str(layer)).set(
+                        float(load.max() / load.mean()))
 
     def _account_step(self, stats: dict, t0: float, t1: float,
                       preempted_before: int) -> None:

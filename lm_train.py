@@ -597,6 +597,13 @@ def main(argv=None) -> int:
                 f"(there is {sorted(models.FAMILIES)}; GPT-2's block is the "
                 "--d-model/--n-heads/... flags)"
             ) from None
+        if not hasattr(family, "apply_hidden"):
+            raise SystemExit(
+                f"--model-config {args.model_config}: family "
+                f"{published['family']!r} is served, not trained (python -m "
+                "distributed_neural_network_tpu.serve --model-config): its "
+                "module has no training forward"
+            )
         args.attn = "flash"  # no sequence axis: the local kernels
         args.vocab = published["vocab_size"]
         cfg = family.from_published(

@@ -64,9 +64,12 @@ def test_the_benchmark_names_the_reader_for_the_longdoc_cell():
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
         entry = next(m for m in json.load(f)["per_layer"]
                      if m["name"] == NAME)
+    # (cells added since are appended to the list: PR 31's latent decode
+    # kernel publishes the same counter)
+    cells = entry.pop("workloads")
+    assert cells[0] == "cerebras-gpt-1.3b.serve-longdoc"
     assert entry == {
         "name": NAME, "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "decode kernel",
         "moves": "serve_tokens_per_s",
-        "workloads": ["cerebras-gpt-1.3b.serve-longdoc"],
     }

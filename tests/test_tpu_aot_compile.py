@@ -24,6 +24,10 @@ from jax.sharding import SingleDeviceSharding
 from distributed_neural_network_tpu.ops.decode_pallas import (
     decode_cache_attention,
     decode_paged_attention,
+    mla_decode_attention,
+    mla_decode_ok,
+    mla_prefill_attention,
+    mla_prefill_ok,
     paged_decode_ok,
 )
 from distributed_neural_network_tpu.ops.flash import tuned_blocks
@@ -118,6 +122,43 @@ def _decode_paged(topo, batch, width, *, heads=16, dh=128,
     ])
 
 
+def _mla_decode(topo, batch, width, *, dtype=jnp.bfloat16):
+    # the docqa cell: 5 layers, 6,145 blocks of 64 latent rows (576 values
+    # padded to 640), 128 heads, tables of 256 blocks
+    bs, row, rank = 64, 640, 512
+    assert mla_decode_ok(bs, row, rank, dtype)
+    i32 = jnp.int32
+
+    def fn(q_lat, pool, layer, table, pos):
+        return mla_decode_attention(q_lat, pool, layer[0], table, pos,
+                                    block_size=bs, rank=rank,
+                                    scale=192 ** -0.5)
+
+    return fn, _on_one_chip(topo, [
+        ((batch, 128, row), dtype), ((5, 6145 * bs, row), dtype),
+        ((1,), i32), ((batch, width), i32), ((batch,), i32),
+    ])
+
+
+def _mla_prefill(topo, chunk):
+    # the docqa cell's prefill attention: a chunk's 128 heads over the
+    # latent pool, a layer's `kv_b` as the tree holds it
+    bs, row, rank = 64, 640, 512
+    dtype, i32 = jnp.bfloat16, jnp.int32
+    assert mla_prefill_ok(bs, row, rank, 128, 128, 128, dtype)
+
+    def fn(q_nope, q_rope, w_kvb, pool, layer, table, span):
+        return mla_prefill_attention(
+            q_nope, q_rope, w_kvb, pool, layer[0], table, span[0], span[1],
+            block_size=bs, rank=rank, scale=192 ** -0.5)
+
+    return fn, _on_one_chip(topo, [
+        ((128, chunk, 128), dtype), ((128, chunk, 128), dtype),
+        ((rank, 128 * 256), dtype), ((5, 6145 * bs, row), dtype),
+        ((1,), i32), ((256,), i32), ((2,), i32),
+    ])
+
+
 def _mlp3(topo):
     # the CNN's classifier head at the smoke's batch 16
     dims = [(16, 400), (400, 120), (120,), (120, 84), (84,), (84, 10),
@@ -172,6 +213,12 @@ CASES = {
     "decode_bf16_b8_w16": lambda t: _decode(t, 8, 256, int8=False),
     "decode_int8_b1_w2": lambda t: _decode(t, 1, 32, int8=True),
     "decode_int8_b8_w16": lambda t: _decode(t, 8, 256, int8=True),
+    "mla_prefill_bf16_c512": lambda t: _mla_prefill(t, 512),
+    "mla_prefill_bf16_c1": lambda t: _mla_prefill(t, 1),
+    "mla_decode_bf16_b32_w256": lambda t: _mla_decode(t, 32, 256),
+    "mla_decode_bf16_b1_w256": lambda t: _mla_decode(t, 1, 256),
+    "mla_decode_f32_b4_w4": lambda t: _mla_decode(t, 4, 4,
+                                                  dtype=jnp.float32),
     "decode_paged_bf16_b16_w128": lambda t: _decode_paged(t, 16, 128),
     "decode_paged_bf16_b1_w1": lambda t: _decode_paged(t, 1, 1),
     # the serve smoke (chip_smoke.py: d512 / 4 heads of 128, 129 blocks)
@@ -240,6 +287,44 @@ def test_serve_program_holds_no_slab_on_v5e(topo, family, n):
     ).compile().memory_analysis()
     assert mem.temp_size_in_bytes < eng.k_pool[0].nbytes
     assert mem.alias_size_in_bytes >= 2 * eng.k_pool.nbytes
+
+
+@pytest.mark.parametrize("family", ["decode", "prefill"])
+def test_latent_serve_program_holds_no_slab_on_v5e(topo, family):
+    """The same for the one pool of a module with a latent cache
+    (tests/test_pangu_ultra_moe.py has the CPU's side): twelve expert
+    layers after a dense one, so both stacks' scans stay loops, the pool on
+    their carry; the `xla` route (the kernels' own compiles are `CASES`)."""
+    from distributed_neural_network_tpu.models import pangu_ultra_moe as pm
+    from distributed_neural_network_tpu.serve.engine import (
+        EngineConfig,
+        ServeEngine,
+    )
+
+    cfg = pm.PanguUltraMoEConfig(n_dense=2, n_moe=12, dtype=jnp.bfloat16)
+    eng = ServeEngine(
+        jax.tree.map(lambda x: x.astype(jnp.bfloat16),
+                     pm.init_params(jax.random.key(0), cfg)), cfg,
+        EngineConfig(max_batch=2, num_blocks=1024, block_size=16,
+                     max_seq_len=64, prefill_chunk=8, decode_impl="xla"),
+    )
+    i32, width = jnp.int32, eng._bucket_widths()[0]
+    if family == "decode":
+        fn = eng._decode_fn(2, width)
+        tail = [((2,), i32), ((2,), i32), ((2, width), i32),
+                ((2,), jnp.float32), ((2, 2), jnp.uint32)]
+    else:
+        fn = eng._prefill_fn(8, width)
+        tail = [((8,), i32), ((), i32), ((width,), i32), ((), i32)]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    params, pool = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (eng.params, eng.k_pool),
+    )
+    mem = fn.lower(params, pool, *_on_one_chip(topo, tail)).compile(
+        ).memory_analysis()
+    assert mem.temp_size_in_bytes < eng.k_pool[0].nbytes
+    assert mem.alias_size_in_bytes >= eng.k_pool.nbytes
 
 
 @pytest.mark.parametrize("n", [1, 16])
