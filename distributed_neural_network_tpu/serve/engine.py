@@ -18,6 +18,20 @@ Two static-shape bucket axes bound compile count: batch size and table
 width both round up to powers of two, so a server that has seen B=4/W=2
 traffic never compiles again for B<=4/W<=2.
 
+**One tick is always in flight** (docs/SERVING.md "The tick"): `step()`
+dispatches tick n + 1's programs BEFORE it fetches tick n's tokens, so
+the host's share of a tick (the fetch's return, the clients' tokens, the
+scheduler's books and admission, the next tick's arrays) runs while the
+device works. Everything tick n + 1's shape depends on is on the host
+already - who decodes, at which ``pos``, the bucket, the block table, the
+temperatures, the seeds, and who ends by count (`Sequence.dispatched_all`).
+Only the VALUE of the input token of a row that decoded in tick n is not:
+it is tick n's ``nxt[row]``, on the device, and `_feed_tokens` (a small
+program of its own, one a batch bucket) moves it into tick n + 1's ``tok``
+there. The pipeline is one tick deep and drains - fetch first, then build -
+where the engine sees that it must: a speculative engine's ticks, a tick
+with nothing to dispatch, `flush()`.
+
 **Prefill/decode separation** (``prefill_chunk > 1``): long prompts pay
 one model call per token on the default path - correct, and bitwise
 identical to `models/transformer.py generate` (the parity pin), but a
@@ -234,7 +248,13 @@ class EngineConfig:
 @dataclass
 class Sequence:
     """One in-flight request's decode state (engine-internal; the
-    scheduler owns queueing/streaming around it)."""
+    scheduler owns queueing/streaming around it).
+
+    ``pos`` counts positions DISPATCHED (handed to a program, whether or
+    not it has finished); ``out`` and ``emitted`` count tokens FETCHED. A
+    tick's tokens are fetched after the next tick is dispatched
+    (`ServeEngine.step`), so between the two at most one generated token
+    a sequence is on the device alone: the one at index ``len(out)``."""
 
     seq_id: int
     prompt: list
@@ -243,7 +263,8 @@ class Sequence:
     seed: int = 0
     on_token: object = None  # callable(seq, token_id, done) or None
 
-    pos: int = 0               # tokens consumed (= KV entries written)
+    pos: int = 0               # positions dispatched (= KV entries written
+    #                            once the programs in flight have run)
     out: list = field(default_factory=list)
     emitted: int = 0           # tokens already streamed (preempt replay)
     finished: bool = False
@@ -257,6 +278,18 @@ class Sequence:
     @property
     def in_prefill(self) -> bool:
         return self.pos < self.prompt_len
+
+    @property
+    def dispatched_all(self) -> bool:
+        """Every position this request can consume is dispatched: it ends
+        by count (``max_new_tokens``) when the tick in flight is fetched."""
+        return self.pos >= self.prompt_len - 1 + self.max_new_tokens
+
+    @property
+    def input_in_flight(self) -> bool:
+        """The token at ``pos`` is the output of a program whose result
+        the host has not fetched yet."""
+        return self.pos - self.prompt_len >= len(self.out)
 
     def next_input(self) -> int:
         """The token this sequence consumes at its current position."""
@@ -588,6 +621,68 @@ def _row_keys(seeds, pos):
     )(seeds, pos)
 
 
+@jax.jit
+def _feed_tokens(board, src, tok):
+    """A decode batch's input tokens where some are still on the device:
+    row i takes ``board[src[i]]``, the token the tick in flight produced
+    for its sequence, where ``src[i] >= 0``, and the host's ``tok[i]``
+    otherwise (a prompt's last token, a replayed token). ``board`` is the
+    unfetched output of the decode program in flight at the largest
+    bucket's length (`_widen`; not donated: the host fetches it
+    afterwards), ``src`` and ``tok`` (B,) int32: one program a batch
+    bucket, as `_row_keys` has, whatever the bucket in flight. A program
+    of its own and not part of the decode programs, for that one's
+    reason: the bucket programs keep their text, and a warm start loads
+    them from the compile cache."""
+    return jnp.where(src >= 0, board[jnp.maximum(src, 0)], tok)
+
+
+@partial(jax.jit, static_argnums=1)
+def _widen(nxt, n: int):
+    """A smaller bucket's tokens (B,) at the largest bucket's length (n,),
+    noughts behind, so that `_feed_tokens` has one shape to read from and
+    a batch that changes its bucket keeps its tick in flight. One program
+    a bucket below the largest; the largest needs none."""
+    return jnp.pad(nxt, (0, n - nxt.shape[0]))
+
+
+@dataclass
+class _Tick:
+    """One tick from its dispatch to its landing: what was handed to the
+    device and has not been fetched. ``stats`` is the tick's own
+    dictionary, begun by `ServeEngine._dispatch` and finished by
+    `ServeEngine._land`."""
+
+    stats: dict
+    # (sequence, the position it consumed) a row of the decode batch
+    rows: list = field(default_factory=list)
+    # every sequence these programs write: seq_id -> its row of the decode
+    # batch, None for a prefill chunk alone. What it holds is not freed
+    # before they have landed
+    touched: dict = field(default_factory=dict)
+    nxt: object = None          # the decode program's tokens, on the device
+    counts: list = field(default_factory=list)   # latent programs' routing
+    spec_batch: list = field(default_factory=list)
+
+    @property
+    def in_flight(self) -> bool:
+        """It dispatched a program (or holds a speculative phase)."""
+        return bool(self.rows or self.spec_batch
+                    or self.stats["prefill_calls"])
+
+
+def _seqstat(stats: dict, s: Sequence) -> dict:
+    """The tick's entry for sequence ``s`` in ``stats["per_seq"]``."""
+    d = stats["per_seq"].get(s.seq_id)
+    if d is None:
+        d = stats["per_seq"][s.seq_id] = {
+            "prefill": 0, "decode": 0, "replayed": 0, "parked": False,
+            # speculative sub-attribution (zero when spec off)
+            "proposed": 0, "accepted": 0, "draft_s": 0.0, "verify_s": 0.0,
+        }
+    return d
+
+
 def _bucket(n: int, lo: int = 1) -> int:
     """Smallest power of two >= n (>= lo)."""
     b = lo
@@ -598,10 +693,12 @@ def _bucket(n: int, lo: int = 1) -> int:
 
 class ServeEngine:
     """The model executor: owns device params + KV pools and advances
-    all active sequences one tick at a time. Single-threaded by
-    contract - exactly one caller (the scheduler loop) drives
-    `step()`; admission/cancel mutate the active set under `lock`
-    between ticks."""
+    all active sequences one tick at a time, with one tick in flight:
+    `step()` dispatches the next tick's programs, then fetches the tick
+    before's tokens and hands them out (`_dispatch`, `_land`).
+    Single-threaded by contract - exactly one caller (the scheduler
+    loop) drives `step()` / `flush()`; admission/cancel mutate the
+    active set under `lock` between ticks."""
 
     def __init__(self, params, cfg, ecfg: EngineConfig):
         # what a position's cache row is decides which programs are built,
@@ -683,8 +780,6 @@ class ServeEngine:
                 (cfg.n_layers, slots, self.row_width), pool_dt)
             self.v_pool = None
             self._latent_width = _bucket(self.kv.cfg.max_blocks_per_seq)
-            # expert-layer counts of prefill dispatches not yet fetched
-            self._moe_pending: list = []
         else:
             L, H, Dh = cfg.n_layers, cfg.n_heads, cfg.head_dim
             self.k_pool = jnp.zeros((L, slots, H, Dh), pool_dt)
@@ -705,6 +800,11 @@ class ServeEngine:
         self._verify_fns: dict = {}
         # family -> largest compiled temp_size_in_bytes, set by warmup()
         self.program_temp_bytes: dict = {}
+        # the tick dispatched and not yet fetched (`step`)
+        self._inflight: _Tick | None = None
+        # the call's seconds by phase and its last reading of the clock
+        self._phase_s = dict.fromkeys(STEP_PHASES, 0.0)
+        self._t_mark = 0.0
         self.ticks = 0
         self.decode_tokens = 0
         self.prefill_tokens = 0
@@ -742,19 +842,29 @@ class ServeEngine:
 
     def cancel(self, seq_id: int) -> bool:
         """Drop a sequence mid-flight (client disconnect); frees its
-        blocks. True when it was active."""
+        blocks. True when it was active. Where the tick in flight holds
+        a row or a chunk of it, its programs still write its blocks: the
+        sequence only ends here, the token in flight is dropped when that
+        tick lands, and its blocks are freed then (`_retire_finished`)."""
         with self.lock:
             for i, s in enumerate(self.active):
                 if s.seq_id == seq_id:
-                    self.active.pop(i)
-                    self._free_seq(seq_id)
                     s.finished = True
+                    if not self._touched(seq_id):
+                        self.active.pop(i)
+                        self._free_seq(seq_id)
                     return True
         return False
 
     def has_work(self) -> bool:
+        """A sequence is active, or a tick is dispatched and unfetched."""
         with self.lock:
-            return bool(self.active)
+            return bool(self.active) or self._inflight is not None
+
+    def _touched(self, seq_id: int) -> bool:
+        """The tick in flight holds a row or a chunk of this sequence."""
+        return (self._inflight is not None
+                and seq_id in self._inflight.touched)
 
     # ------------------------------------------------- bytes + kv dtype
 
@@ -1258,6 +1368,11 @@ class ServeEngine:
         pools = (self.k_pool, self.v_pool, self.k_scale, self.v_scale)
         return pools if self.quantized else pools[:2]
 
+    def _board(self, nxt):
+        """A decode program's tokens as `_feed_tokens` reads them."""
+        n = self.ecfg.max_batch
+        return nxt if nxt.shape[0] == n else _widen(nxt, n)
+
     def _table(self, seqs: list, B: int, W: int):
         """The (B, W) block table of a batch bucket: a row a sequence, the
         bucket's spare rows on the scratch block."""
@@ -1312,14 +1427,15 @@ class ServeEngine:
                     int(mem.temp_size_in_bytes),
                 )
             if family == "draft":
-                fn(*args)  # read-only: no pool state to rebind
+                out = fn(*args)  # read-only: no pool state to rebind
             else:
-                self._run_writer(fn, *tail)
+                out = self._run_writer(fn, *tail)
                 # warmup writes land in the scratch block; its scale is
                 # garbage by contract, but reset anyway so a fresh engine
                 # stays bitwise clean
                 self._zero_scales([0])
             n += 1
+            return out
 
         def zeros(*shape):
             return jnp.zeros(shape, jnp.int32)
@@ -1328,9 +1444,14 @@ class ServeEngine:
             # as `step` calls it: host arrays in, the keys left on the device
             _row_keys(np.zeros((B,), np.uint32), np.zeros((B,), np.int32))
             for W in widths:
-                warm("decode", self._decode_fn(B, W), zeros(B), zeros(B),
-                     zeros(B, W), jnp.zeros((B,), jnp.float32),
-                     jnp.zeros((B, 2), jnp.uint32))
+                nxt = warm("decode", self._decode_fn(B, W), zeros(B),
+                           zeros(B), zeros(B, W),
+                           jnp.zeros((B,), jnp.float32),
+                           jnp.zeros((B, 2), jnp.uint32))[0]
+            # a decode program's tokens into the next tick's batch, as
+            # `_dispatch` calls it (not counted: no bucket programs)
+            _feed_tokens(self._board(nxt), np.zeros((B,), np.int32),
+                         np.zeros((B,), np.int32))
         if self.ecfg.prefill_chunk > 1:
             for C in pow2(self.ecfg.prefill_chunk):
                 for W in widths:
@@ -1378,10 +1499,17 @@ class ServeEngine:
             seq.on_token(seq, tok, done)
 
     def _retire_finished(self) -> list:
-        done = [s for s in self.active if s.finished]
+        """Take what has finished out of the batch and free its blocks,
+        but for a sequence the tick in flight still writes (it ended by
+        its token, or by a cancel, after that tick was dispatched): that
+        one goes when that tick has landed."""
+        done = [s for s in self.active
+                if s.finished and not self._touched(s.seq_id)]
         if done:
+            gone = {s.seq_id for s in done}
             with self.lock:
-                self.active = [s for s in self.active if not s.finished]
+                self.active = [
+                    s for s in self.active if s.seq_id not in gone]
             for s in done:
                 self._free_seq(s.seq_id)
         return done
@@ -1520,28 +1648,72 @@ class ServeEngine:
             if not s.finished:
                 self._rewind_seq(s.seq_id, s.pos)
 
-    def step(self) -> dict:
-        """One engine tick. Returns per-tick stats for the scheduler's
-        ledger/metrics: ``{"decode_tokens", "prefill_tokens",
-        "finished", "parked", "batch", "phase_s", "decode_call",
-        "prefill_calls"}``.
+    def _start_clock(self) -> None:
+        """A call of `step` or `flush` begins: its seconds by phase."""
+        self._phase_s = dict.fromkeys(STEP_PHASES, 0.0)
+        self._t_mark = time.perf_counter()
 
-        ``phase_s`` partitions the call on ``time.perf_counter``, one
+    def _lap(self, phase: str) -> None:
+        """Everything since the last reading of the clock belongs to
+        ``phase`` of the call under way."""
+        now = time.perf_counter()
+        self._phase_s[phase] += now - self._t_mark
+        self._t_mark = now
+
+    def step(self) -> dict:
+        """One call of the serve loop: dispatch the NEXT tick's programs,
+        then fetch the tick in flight's tokens, hand them out and return
+        that tick's stats. With nothing in flight (the first call, after a
+        drain) it dispatches a tick from the host's state first, as ever.
+
+        **The tick in flight.** Tick n + 1 is built while tick n's tokens
+        are still on the device, from what the host can count: positions
+        (``seq.pos`` advances at dispatch), block needs, the table,
+        temperatures, seeds (`_row_keys` of seeds and positions), and who
+        has ended by count (`Sequence.dispatched_all`: left out of tick
+        n + 1). The input token of a row that decoded in tick n goes from
+        tick n's ``nxt`` into tick n + 1's ``tok`` on the device
+        (`_feed_tokens`). An end token is the one end the host cannot
+        count: a sequence whose token in flight turns out to be
+        ``eos_token``, or that is cancelled meanwhile, has one position
+        decoded past its end in tick n + 1; that token is dropped when
+        tick n + 1 lands, never emitted, and the sequence's blocks are
+        freed then and not before (`_retire_finished`). A token reaches
+        its client one host lap after its program finished.
+
+        **The pipeline drains** (fetch first, then build) where the
+        engine sees that it must: with speculative slots (``spec_k > 0``:
+        `_spec_step` needs its tokens on the host; every tick lands in
+        the call that dispatched it); where nothing can be dispatched
+        (all that is left ends by count, or every candidate is parked on
+        blocks: the youngest is only preempted with nothing in flight, in
+        the next call); in `flush()`.
+
+        Returns the landed tick's stats for the scheduler's ledger/
+        metrics: ``{"decode_tokens", "prefill_tokens", "finished",
+        "parked", "batch", "phase_s", "decode_call", "prefill_calls",
+        "dispatch"}``. ``dispatch`` is "ahead" for a tick whose programs
+        were dispatched before the tick before's tokens were fetched,
+        "drained" for one dispatched with nothing in flight, None for one
+        that dispatched nothing.
+
+        ``phase_s`` partitions THIS CALL on ``time.perf_counter``, one
         key per `STEP_PHASES` entry and every instant from entry to
         return in exactly one: ``prefill_host`` (the chunked-prefill
         loop: blocks, arrays, transfers and each `_prefill_fn` dispatch
-        up to its return), ``decode_host`` (batch selection to the
-        return of `_decode_fn`'s dispatch), ``fetch``
-        (``np.asarray(nxt)``: the host blocked until the tick's programs
-        have finished), ``emit`` (the per-sequence loop after the fetch
-        with its `on_token` callbacks, and retiring) and ``spec``
-        (`_spec_step` whole). Each is also a ``serve.<phase>``
-        `TraceAnnotation`, inert while no profile is taken. Dispatch is
-        asynchronous, so the host can time the wait for the prefill and
-        the decode program together (``fetch``) and not each apart:
-        that is why the scheduler's split of the step between the
-        ledger's "prefill" and "decode" by token counts stays an
-        apportioning.
+        up to its return) and ``decode_host`` (batch selection to the
+        return of `_decode_fn`'s dispatch, `_feed_tokens`' with it), both
+        of the tick dispatched here; ``fetch`` (``np.asarray(nxt)`` of the
+        tick landed here: the host blocked until the PREVIOUS dispatch's
+        programs have finished, which have had a whole host lap to do
+        so), ``emit`` (the per-sequence loop after the fetch with its
+        `on_token` callbacks, and retiring) and ``spec`` (`_spec_step`
+        whole). Each is also a ``serve.<phase>`` `TraceAnnotation`, inert
+        while no profile is taken. Dispatch is asynchronous, so the host
+        can time the wait for the prefill and the decode program together
+        (``fetch``) and not each apart: that is why the scheduler's split
+        of the step between the ledger's "prefill" and "decode" by token
+        counts stays an apportioning.
 
         ``decode_call`` is ``(B, W, live, read)`` for the tick's decode
         dispatch, None without one: the batch and width-in-blocks
@@ -1566,38 +1738,81 @@ class ServeEngine:
         ``preemptions``). Ticks with a speculative phase additionally
         carry ``spec`` - ``{"proposed", "accepted", "steps",
         "draft_s", "verify_s", "per_slot"}`` (``per_slot`` = accepted
-        drafts per slot, the acceptance-histogram input)."""
-        phase_s = dict.fromkeys(STEP_PHASES, 0.0)
-        t_mark = time.perf_counter()
+        drafts per slot, the acceptance-histogram input); a latent
+        engine's carry ``moe``, the routing counts of the programs the
+        tick itself dispatched (`_moe_stats`)."""
+        self._start_clock()
+        tick, self._inflight = self._inflight, None
+        if tick is None:
+            tick = self._dispatch(None)
+        if tick.in_flight and not self.spec_k:
+            ahead = self._dispatch(tick)
+            if ahead.in_flight:
+                self._inflight = ahead
+        return self._land(tick)
 
-        def lap(phase: str) -> None:
-            # everything since the last reading belongs to `phase`
-            nonlocal t_mark
-            now = time.perf_counter()
-            phase_s[phase] += now - t_mark
-            t_mark = now
+    def flush(self) -> dict | None:
+        """Land the tick in flight and dispatch none: its tokens reach
+        their clients, nothing is left on the device, and every sequence's
+        ``pos`` and ``out`` agree again - what a drain for migration asks
+        before it exports them (`export_descriptor`). Returns that tick's
+        stats (`step`), None with nothing in flight."""
+        tick, self._inflight = self._inflight, None
+        if tick is None:
+            return None
+        self._start_clock()
+        return self._land(tick)
 
+    def _select(self, todo: list, held: list) -> tuple:
+        """This tick's decode rows among ``todo``: (plain batch,
+        speculative batch, parked), ``held`` the sequences the prefill
+        phase parked. A row's block is allocated here."""
+        ecfg = self.ecfg
+        batch: list[Sequence] = []
+        spec_batch: list[Sequence] = []
+        parked = list(held)
+        for seq in todo:
+            # (one that ends by count may have its last token in flight)
+            if seq.finished or seq.dispatched_all or seq in held:
+                continue
+            if ecfg.prefill_chunk > 1 and seq.in_prefill and (
+                seq.pos < seq.prompt_len - 1
+            ):
+                continue  # still mid-chunked-prefill; next tick
+            if self.spec_k and self._spec_eligible(seq):
+                try:
+                    self.kv.ensure_range(
+                        seq.seq_id, seq.pos + self.spec_k
+                    )
+                    spec_batch.append(seq)
+                    continue
+                except OutOfBlocks:
+                    pass  # degrade to the one-block plain path
+            try:
+                self.kv.ensure(seq.seq_id, seq.pos)
+            except OutOfBlocks:
+                parked.append(seq)
+                continue
+            batch.append(seq)
+        return batch[:ecfg.max_batch], spec_batch, parked
+
+    def _dispatch(self, prev: _Tick | None) -> _Tick:
+        """Build one tick and hand its programs to the device, fetching
+        nothing: the chunked-prefill phase, then the decode batch.
+        ``prev`` is the tick in flight, None where there is none: a row
+        whose input token is ``prev``'s to give takes it on the device
+        (`_feed_tokens`). Advances ``seq.pos`` of every row and chunk."""
         ecfg = self.ecfg
         bs = self.kv.cfg.block_size
         with self.lock:
             todo = list(self.active)
-        parked: list[Sequence] = []
-        stats = {"decode_tokens": 0, "prefill_tokens": 0, "finished": 0,
-                 "parked": 0, "batch": 0, "per_seq": {}, "preempted": [],
-                 "phase_s": phase_s, "decode_call": None,
-                 "prefill_calls": []}
-
-        def seqstat(s: Sequence) -> dict:
-            d = stats["per_seq"].get(s.seq_id)
-            if d is None:
-                d = stats["per_seq"][s.seq_id] = {
-                    "prefill": 0, "decode": 0, "replayed": 0,
-                    "parked": False,
-                    # speculative sub-attribution (zero when spec off)
-                    "proposed": 0, "accepted": 0,
-                    "draft_s": 0.0, "verify_s": 0.0,
-                }
-            return d
+        held: list[Sequence] = []
+        tick = _Tick({"decode_tokens": 0, "prefill_tokens": 0,
+                      "finished": 0, "parked": 0, "batch": 0, "per_seq": {},
+                      "preempted": [], "decode_call": None,
+                      "prefill_calls": [], "dispatch": None})
+        stats = tick.stats
+        seqstat = partial(_seqstat, stats)
 
         # ---- chunked prefill phase (prefill_chunk > 1 only)
         if ecfg.prefill_chunk > 1:
@@ -1619,8 +1834,7 @@ class ServeEngine:
                     try:
                         self.kv.ensure_range(seq.seq_id, seq.pos + n - 1)
                     except OutOfBlocks:
-                        parked.append(seq)
-                        seqstat(seq)["parked"] = True
+                        held.append(seq)
                         continue
                     C = _bucket(n)
                     W = self._latent_width or _bucket(
@@ -1638,75 +1852,54 @@ class ServeEngine:
                         # its width: the key blocks the program walks
                         kb = min(_PREFILL_KEY_BLOCK, W * bs)
                         W = -(-(seq.pos + n) // kb) * kb // bs
-                        self._moe_pending.append(out[0])
+                        tick.counts.append(out[0])
                         if self._prefill_route() == "pallas":
                             stats["prefill_kernel_pairs"] = live + stats.get(
                                 "prefill_kernel_pairs", 0)
                     stats["prefill_calls"].append((C, W, live))
+                    tick.touched[seq.seq_id] = None
                     seq.pos += n
                     budget -= n
                     self.prefill_tokens += n
                     stats["prefill_tokens"] += n
                     seqstat(seq)["prefill"] += n
-        lap("prefill_host")
+        self._lap("prefill_host")
 
         # ---- decode batch: plain slots (one token each) + speculative
         # slots (k drafts verified in one multi-position step)
         with TraceAnnotation("serve.decode_host"):
-            batch: list[Sequence] = []
-            spec_batch: list[Sequence] = []
-            for seq in todo:
-                if seq.finished or seq in parked:
-                    continue
-                if ecfg.prefill_chunk > 1 and seq.in_prefill and (
-                    seq.pos < seq.prompt_len - 1
-                ):
-                    continue  # still mid-chunked-prefill; next tick
-                if self.spec_k and self._spec_eligible(seq):
-                    try:
-                        self.kv.ensure_range(
-                            seq.seq_id, seq.pos + self.spec_k
-                        )
-                        spec_batch.append(seq)
-                        continue
-                    except OutOfBlocks:
-                        pass  # degrade to the one-block plain path
-                try:
-                    self.kv.ensure(seq.seq_id, seq.pos)
-                except OutOfBlocks:
-                    parked.append(seq)
-                    seqstat(seq)["parked"] = True
-                    continue
-                batch.append(seq)
-
+            batch, tick.spec_batch, parked = self._select(todo, held)
+            for s in parked:
+                seqstat(s)["parked"] = True
             stats["parked"] = len(parked)
             if parked:
                 self.stall_events += 1
-            if not batch and not spec_batch:
-                if parked:
-                    # every active sequence is parked on blocks: preempt
-                    # the youngest so the others' next allocation can
-                    # succeed
-                    victim = self._preempt_youngest(parked)
-                    stats["preempted"].append({
-                        "seq_id": victim.seq_id,
-                        "tokens_held": len(victim.out),
-                        "preemptions": victim.preemptions,
-                    })
-                lap("decode_host")
-                return stats
-
+            if batch or tick.spec_batch or stats["prefill_calls"]:
+                stats["dispatch"] = "drained" if prev is None else "ahead"
+            elif parked and prev is None:
+                # every active sequence is parked on blocks: preempt the
+                # youngest so the others' next allocation can succeed
+                # (never under a tick in flight, whose rows hold theirs)
+                victim = self._preempt_youngest(parked)
+                stats["preempted"].append({
+                    "seq_id": victim.seq_id,
+                    "tokens_held": len(victim.out),
+                    "preemptions": victim.preemptions,
+                })
             if batch:
                 B = min(_bucket(len(batch)), ecfg.max_batch)
-                batch = batch[:B]
                 W = self._latent_width or _bucket(
                     max(s.pos // bs + 1 for s in batch))
                 tok = np.zeros((B,), np.int32)
+                src = np.full((B,), -1, np.int32)
                 pos = np.zeros((B,), np.int32)
                 temps = np.zeros((B,), np.float32)
                 seeds = np.zeros((B,), np.uint32)
                 for i, s in enumerate(batch):
-                    tok[i] = s.next_input()
+                    if s.input_in_flight:
+                        src[i] = prev.touched[s.seq_id]
+                    else:
+                        tok[i] = s.next_input()
                     pos[i] = s.pos
                     temps[i] = s.temperature
                     seeds[i] = s.seed & 0xFFFFFFFF
@@ -1719,27 +1912,45 @@ class ServeEngine:
                     else B * W * bs,
                 )
                 stats["decode_kernel"] = kernel
-                nxt, *rest = self._run_writer(
-                    fn, jnp.asarray(tok), jnp.asarray(pos),
-                    jnp.asarray(table), jnp.asarray(temps),
-                    _row_keys(seeds, pos),
+                tick.nxt, *rest = self._run_writer(
+                    fn,
+                    _feed_tokens(self._board(prev.nxt), src, tok)
+                    if (src >= 0).any() else jnp.asarray(tok),
+                    jnp.asarray(pos), jnp.asarray(table),
+                    jnp.asarray(temps), _row_keys(seeds, pos),
                 )
-        lap("decode_host")
-        if batch:
+                if self.latent:
+                    tick.counts.append(rest[1])
+                for i, s in enumerate(batch):
+                    tick.rows.append((s, s.pos))
+                    tick.touched[s.seq_id] = i
+                    s.pos += 1
+        self._lap("decode_host")
+        return tick
+
+    def _land(self, tick: _Tick) -> dict:
+        """Fetch what a tick's programs produced (the one blocking read),
+        hand each new token out (`_emit`: record, maybe retire, stream),
+        run its speculative phase if it has one, retire what finished.
+        Returns the tick's stats, whole, with the call's ``phase_s``."""
+        stats = tick.stats
+        seqstat = partial(_seqstat, stats)
+        if tick.rows or tick.counts:
             with TraceAnnotation("serve.fetch"):
                 if self.latent:
                     # the expert layers' counts come with the tokens
-                    nxt, *counts = jax.device_get(
-                        [nxt, rest[1], *self._moe_pending])
-                    self._moe_pending.clear()
+                    nxt, counts = jax.device_get((tick.nxt, tick.counts))
                     stats["moe"] = self._moe_stats(counts)
                 else:
-                    nxt = np.asarray(nxt)
-            lap("fetch")
+                    nxt = np.asarray(tick.nxt)
+            self._lap("fetch")
+        if tick.rows:
             with TraceAnnotation("serve.emit"):
-                for i, s in enumerate(batch):
-                    consumed_at = s.pos
-                    s.pos += 1
+                for i, (s, consumed_at) in enumerate(tick.rows):
+                    if s.finished:
+                        # it ended (its token, a cancel) while this row
+                        # was in flight: decoded past the end, dropped
+                        continue
                     if consumed_at >= s.prompt_len - 1:
                         # prediction for generated-token index j; after a
                         # preemption the replay re-derives tokens the
@@ -1759,14 +1970,18 @@ class ServeEngine:
                         self.prefill_tokens += 1
                         stats["prefill_tokens"] += 1
                         seqstat(s)["prefill"] += 1
-            lap("emit")
-        if spec_batch:
+            self._lap("emit")
+        if tick.spec_batch:
             with TraceAnnotation("serve.spec"):
-                self._spec_step(spec_batch, stats, seqstat)
-            lap("spec")
-        self.ticks += 1
-        stats["batch"] = len(batch) + len(spec_batch)
-        with TraceAnnotation("serve.emit"):
-            stats["finished"] = len(self._retire_finished())
-        lap("emit")
+                self._spec_step(tick.spec_batch, stats, seqstat)
+            self._lap("spec")
+        if tick.rows or tick.spec_batch:
+            self.ticks += 1
+            stats["batch"] = len(tick.rows) + len(tick.spec_batch)
+        if tick.in_flight:
+            # (a prefill chunk's sequence may have been cancelled under it)
+            with TraceAnnotation("serve.emit"):
+                stats["finished"] = len(self._retire_finished())
+            self._lap("emit")
+        stats["phase_s"] = self._phase_s
         return stats

@@ -358,6 +358,15 @@ class ServeScheduler:
             "serve_prefill_positions_total",
             "Cache positions of prefill dispatches: live and padded",
         )
+        # ticks by how they were dispatched (serve/engine.py `step`):
+        # before the tick before's tokens were fetched, or with nothing
+        # in flight
+        ahead = r.counter(
+            "serve_dispatch_ahead_total",
+            "Ticks dispatched ahead of the last tick's fetch, or drained",
+        )
+        self._m_dispatch = {o: ahead.labels(outcome=o)
+                            for o in ("ahead", "drained")}
         self._m_decode_live = decode_pos.labels(kind="live")
         self._m_decode_read = decode_pos.labels(kind="read")
         self._m_decode_padded = decode_pos.labels(kind="padded")
@@ -595,7 +604,15 @@ class ServeScheduler:
         """Evict every live request as a migration descriptor (loop
         thread, or inline when the loop never started). Cancels are
         enacted FIRST so a client cancel racing the drain wins — its
-        request ends cancelled, not migrated."""
+        request ends cancelled, not migrated. The tick in flight lands
+        before anything is exported: its tokens reach their clients, and
+        no row is left on the device under the blocks freed here."""
+        t0 = self.ledger.now()
+        landed = self.engine.flush()
+        if landed is not None:
+            # (its seconds are the sweep's: the loop's `admit` phase)
+            self._books({}, landed, t0, self.ledger.now(),
+                        len(self.engine.preempted))
         self._enact_cancels()
         # active (running AND parked-on-kv) sequences: both live in
         # engine.active; cancel() frees their blocks
@@ -772,10 +789,9 @@ class ServeScheduler:
                 t1 = t_mark = now()
                 for phase, dt in stats["phase_s"].items():
                     phase_s[phase] += dt
+                # (the next tick's programs are on the device meanwhile)
                 with TraceAnnotation("serve.books"):
-                    self._m_steps.inc()
-                    self._publish_tick(phase_s, stats)
-                    self._account_step(stats, t0, t1, preempted_before)
+                    self._books(phase_s, stats, t0, t1, preempted_before)
                 lap("books")
 
     def _admit_ready(self) -> None:
@@ -815,6 +831,14 @@ class ServeScheduler:
                 break
             self._admit_one(nxt)
 
+    def _books(self, phase_s: dict, stats: dict, t0: float, t1: float,
+               preempted_before: int) -> None:
+        """One landed tick into the registry, the traces and the ledger
+        (loop thread): counted when its tokens have reached the clients."""
+        self._m_steps.inc()
+        self._publish_tick(phase_s, stats)
+        self._account_step(stats, t0, t1, preempted_before)
+
     def _publish_tick(self, phase_s: dict, stats: dict) -> None:
         """Everything the registry learns of one tick's time and
         shapes, in one place and after `serve_engine_steps_total` has
@@ -825,6 +849,8 @@ class ServeScheduler:
         for phase, dt in phase_s.items():
             self._m_loop_s[phase].inc(dt)
             phase_s[phase] = 0.0
+        if stats.get("dispatch") is not None:
+            self._m_dispatch[stats["dispatch"]].inc()
         bs = self.engine.ecfg.block_size
         call = stats["decode_call"]
         if call is not None:

@@ -509,3 +509,41 @@ def test_warmup_builds_one_width_and_the_tick_publishes_its_counters(params):
         assert line in text, line
     assert 'serve_moe_expert_load_max_over_mean{layer="0"}' in text
     assert 'serve_moe_pairs_total{where="absent"}' in text
+
+
+def test_routing_counts_reach_the_tick_that_dispatched_them(params):
+    """With a tick in flight (`step` dispatches tick n + 1 before it fetches
+    tick n) the expert layers' counts that a tick's programs return are
+    fetched with that tick's tokens and land in its own stats: every token a
+    tick's prefill chunk and decode batch carried chose `top_k` experts in
+    each expert layer, held here or absent - also in a tick that only
+    prefilled. And the tokens are the reference's."""
+    eng = _engine(params)
+    seqs = [Sequence(seq_id=i, prompt=list(map(int, some_tokens(n, i))),
+                     max_new_tokens=5)
+            for i, n in enumerate((20, 9, 3))]
+    for s in seqs:
+        eng.add(s)
+    done = []
+    while eng.has_work():
+        done.append(eng.step())
+        tick = eng._inflight
+        if tick is not None:
+            # what it dispatched is still on the device, its own to fetch
+            assert len(tick.counts) == len(
+                tick.stats["prefill_calls"]) + bool(tick.rows)
+            assert "moe" not in tick.stats
+    assert [st["dispatch"] for st in done] == ["drained"] + ["ahead"] * (
+        len(done) - 1)
+    assert any(st["decode_call"] is None and st["prefill_calls"]
+               for st in done), "no tick that only prefilled"
+    for st in done:
+        tokens = st["prefill_tokens"] + st["decode_tokens"]
+        assert tokens > 0
+        moe = st["moe"]
+        assert moe["held"] + moe["absent"] == tokens * CFG.top_k * CFG.n_moe
+        assert moe["load"].sum() == moe["held"]
+    for s in seqs:
+        full = np.asarray(s.prompt + s.out, np.int32)
+        rows = np.arange(s.prompt_len - 1, len(full) - 1)
+        assert list(reference_logits(full, rows).argmax(-1)) == s.out

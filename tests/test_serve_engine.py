@@ -209,12 +209,21 @@ def test_sampled_tokens_are_categorical_under_the_hosts_key(params,
     for s in seqs:
         eng.add(s)
     want = {s.seq_id: [] for s in seqs}
+    tick = 0
     while eng.has_work():
-        # token at a time and blocks for all: every live sequence decodes
-        rows = [s for s in eng.active if not s.finished]
-        assert eng.step()["batch"] == len(rows)
-        _, pos, _, temps, keys, nxt, logits = calls.pop()
+        # token at a time and blocks for all: every sequence decodes until
+        # its count is full. A call lands the tick dispatched a call
+        # before, so the programs' records are taken oldest first
+        rows = [s for s in seqs if tick < s.prompt_len - 1 + 10]
+        st = eng.step()
+        tick += 1
+        assert st["batch"] == len(rows) and len(st["per_seq"]) == len(rows)
+        fed, pos, _, temps, keys, nxt, logits = calls.pop(0)
         for i, s in enumerate(rows):
+            # the token a row consumes past its prompt is the one it was
+            # given a tick before, moved there on the device
+            if pos[i] >= s.prompt_len:
+                assert fed[i] == want[s.seq_id][pos[i] - s.prompt_len]
             key = _host_key(s.seed, int(pos[i]))
             np.testing.assert_array_equal(keys[i], key)
             assert temps[i] == s.temperature
@@ -272,7 +281,11 @@ def test_decoding_tick_reads_the_device_once(params, n_devices,
     log, ran = [], []
 
     def counted(family, fn):
-        return lambda *a: ran.append(family) or fn(*a)
+        def run(*a):
+            ran.append(family)
+            log.append(("ran", ()))
+            return fn(*a)
+        return run
 
     for name in ("np", "jnp", "jax"):
         monkeypatch.setattr(
@@ -285,25 +298,45 @@ def test_decoding_tick_reads_the_device_once(params, n_devices,
     monkeypatch.setattr(
         engine_mod, "_row_keys", counted("keys", engine_mod._row_keys)
     )
+    for small in ("feed", "widen"):
+        name = {"feed": "_feed_tokens", "widen": "_widen"}[small]
+        monkeypatch.setattr(
+            engine_mod, name, counted(small, getattr(engine_mod, name)))
     for i, n in enumerate((13, 5, 9)):
         eng.add(Sequence(i, _prompt(60 + i, n), 6,
                          temperature=temperature, seed=100 + i))
-    decoding = 0
+    eng.step()  # (with nothing in flight a call dispatches two ticks)
+    decoding = fed = 0
     while eng.has_work():
         del log[:], ran[:]
         st = eng.step()
+        # the call dispatched the next tick's programs (none at the end)
+        # and then landed the tick that `st` describes
+        ahead = eng._inflight
+        if ahead is None:
+            assert ran == []
+        else:
+            # the tokens the tick in flight owes go in on the device: one
+            # small program, two where that tick's bucket is a smaller one
+            small = [r for r in ran if r in ("feed", "widen")]
+            fed += bool(small)
+            assert small in ([], ["feed"], ["widen", "feed"])
+            assert [r for r in ran if r not in small] == (
+                ["prefill"] * len(ahead.stats["prefill_calls"])
+                + ["keys", "decode"] * bool(ahead.rows)
+            )
         if st["decode_call"] is None:
             continue
         decoding += 1
-        reads = [n for n, a in log
+        reads = [i for i, (n, a) in enumerate(log)
                  if n == "np.asarray" and isinstance(a[0], jax.Array)]
         assert len(reads) == 1, log
-        assert ran == (
-            ["prefill"] * len(st["prefill_calls"]) + ["keys", "decode"]
-        )
-        others = {n for n, _ in log if not n.startswith("np.")}
+        # ... and the one read comes after every dispatch of the call
+        assert reads[0] > max(
+            [i for i, (n, _) in enumerate(log) if n == "ran"], default=-1)
+        others = {n for n, _ in log if not n.startswith("np.")} - {"ran"}
         assert others <= {"jnp.asarray"}, others
-    assert decoding >= 6
+    assert decoding >= 6 and fed >= 5
 
 
 def test_warmup_leaves_state_clean(params, n_devices):
@@ -367,6 +400,10 @@ def test_cancel_frees_blocks_mid_flight(params, n_devices):
         eng.step()
     assert eng.kv.blocks_in_use > 0
     assert eng.cancel(0) is True
+    # its row is in the tick in flight, whose program still writes its
+    # blocks: they go when that tick has landed
+    assert eng.kv.blocks_in_use > 0 and eng.has_work()
+    eng.step()
     assert eng.kv.blocks_in_use == 0
     assert not eng.has_work()
     assert eng.cancel(0) is False  # idempotent
