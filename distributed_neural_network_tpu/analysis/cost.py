@@ -106,9 +106,13 @@ def kv_block_bytes(n_layers: int, n_heads: int, head_dim: int,
 
 def latent_block_bytes(n_layers: int, row_width: int, block_size: int,
                        dtype: str = "bf16") -> int:
-    """Device bytes of ONE paged block of a latent pool (serve/engine.py,
-    a module whose `CACHE` is "latent"): `block_size` rows of `row_width`
-    values in every layer, one row for all heads and no scales."""
+    """Device bytes of ONE paged block of an engine with one pool of rows
+    (serve/engine.py): a module whose `CACHE` is "latent" (`n_layers` all
+    its layers, one row for all heads) or "hybrid" (`n_layers` its
+    attention layers alone, a row K and V of every KV head; its other
+    layers keep a state a sequence in the state pool, and no block):
+    `block_size`
+    rows of `row_width` values in each of those layers, no scales."""
     return n_layers * block_size * row_width * dtype_bytes(dtype)
 
 

@@ -142,9 +142,10 @@ def enumerate_grid(ecfg, *, max_width_blocks: int | None = None,
     serve/engine.py warmup() exactly; the equality is pinned by
     cache-miss counting in tests/test_servelint.py (serving after
     warmup compiles zero new programs for every canonical config).
-    ``latent``: the engine of a module with a latent cache, whose
-    programs have one table width, the widest
-    (tests/test_pangu_ultra_moe.py pins that grid)."""
+    ``latent``: the engine of a module that brings its own programs (a
+    latent cache; KV rows beside convolution states), which have one table
+    width, the widest (tests/test_pangu_ultra_moe.py and
+    tests/test_lfm2_moe.py pin that grid)."""
     from ..serve.engine import _bucket
 
     kv = ecfg.kv()
@@ -261,13 +262,13 @@ def bucket_program(engine, family: str, key: tuple, *,
     import jax
     import jax.numpy as jnp
 
-    i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
-    q = engine.quantized
+    i32 = jnp.int32
     params = _sds_tree(
         engine.draft_params if family == "draft" else engine.params
     )
     # the donated operands as the engine hands them over: K and V pools
-    # (and int8 scales), or a latent module's one pool
+    # (and int8 scales), a latent module's one pool, or a KV pool and a
+    # state pool (`engine.pool_labels`)
     pools = tuple(_sds_tree(p) for p in engine._pools())
     scales = ()
 
@@ -277,17 +278,12 @@ def bucket_program(engine, family: str, key: tuple, *,
     if family == "decode":
         B, W = key
         fn = engine._decode_fn(B, W)
-        tail = (
-            sds((B,), i32), sds((B,), i32), sds((B, W), i32),
-            sds((B,), f32), sds((B, 2), u32),
-        )
+        tail = _sds_tree(engine.bucket_tail("decode", B, W))
         label = f"decode[B{B},W{W}]"
     elif family == "prefill":
         C, W = key
         fn = engine._prefill_fn(C, W)
-        tail = (
-            sds((C,), i32), sds((), i32), sds((W,), i32), sds((), i32),
-        )
+        tail = _sds_tree(engine.bucket_tail("prefill", C, W))
         label = f"prefill[C{C},W{W}]"
     elif family == "draft":
         B, W = key
@@ -304,9 +300,7 @@ def bucket_program(engine, family: str, key: tuple, *,
         raise ValueError(f"unknown bucket family {family!r}")
 
     donate = () if family == "draft" else tuple(range(1, 1 + len(pools)))
-    labels = ("params",) + (
-        ("latent_pool",) if engine.latent else ("k_pool", "v_pool") + (
-            ("k_scale", "v_scale") if q else ()))
+    labels = ("params",) + engine.pool_labels
     if probe == "drop-donation" and family != "draft":
         # an outer jit swallows the inner boundary's donated_invars:
         # exactly what a refactor that loses donate_argnums looks like

@@ -33,7 +33,8 @@ The parameter tree: `embed`, `head`, `normf_scale`, and the layers stacked BY
 KIND under `dense` and `moe` (two stacks the engine scans one after the
 other; layer l of the model is `dense[l]` for `l < n_dense`, `moe[l -
 n_dense]` after). This module is SERVED (`serve/engine.py`), not trained:
-what the engine asks of it is `CACHE`, `cache_row_width`, `layer_stacks`,
+what the engine asks of it is `CACHE`, `REFUSED`, `cache_row_width`,
+`layer_stacks`,
 `block_in` / `block_out` around its cache step, `absorb_q` / `unabsorb_o`,
 `prefill_attention`, `embed_tokens` and `final_logits`.
 """
@@ -53,6 +54,15 @@ NAME = "pangu_ultra_moe"
 # what the serving engine keeps a position: one row of the latent pool,
 # not per-head K and V
 CACHE = "latent"
+# what of the engine's options this module does not run, with the reason
+REFUSED = {
+    "spec_decode": "the early-exit drafter and the verify step are written "
+                   "for per-head K and V pools",
+    "kv_dtype int8": "the per-(block, head) scales have no head to belong "
+                     "to in a latent row shared by all heads",
+    "weight_dtype int8": "the prequantized matmul knows the GPT-2 block's "
+                         "six matrices",
+}
 
 NEG = -1e30
 

@@ -855,3 +855,201 @@ def mla_prefill_ok(block_size: int, width: int, rank: int, nope: int,
     return (mla_decode_ok(block_size, width, rank, dtype)
             and all(n % _LANES == 0 for n in (nope, v, rope))
             and width >= rank + rope)
+
+
+# --------------------------- grouped-query pool (K and V side by side)
+
+# the positions one fetch step brings into VMEM (1 MiB of bfloat16 rows of
+# 1,024 values: a copy in flight covers the compute on the step before it)
+_GQA_STEP_POSITIONS = 512
+
+
+def _gqa_paged_kernel(l_ref, table_ref, pos_ref, q_ref, pool_hbm, o_ref,
+                      buf, acc_ref, sem, *, bs, pps, groups, scale):
+    """`_mla_paged_kernel`'s loop over the batch's fetch steps, for a pool
+    whose row holds every KV head's keys and values side by side, one
+    128-lane tile a KV head: a step's pages land one under the other in a
+    half of ``buf`` (pps * bs, groups * 128), and KV head g's tile is read
+    ONCE for its query heads: ``q_g (8, 128) . tile_g^T`` on the MXU (the
+    queries lie in the tile's key lanes, noughts against its value lanes; a
+    group's spare query rows are noughts), the online softmax over all
+    groups' scores at once, then ``p_g (8, rows) . tile_g``, whose value
+    lanes are the weighted values (the key lanes come out as well and the
+    caller drops them: the tile is multiplied as it lies). Pages past
+    ``pos[b]`` are not copied; what a half still holds from an earlier step
+    (or the noughts it starts with) lies past ``pos`` and weighs nought."""
+    n_seq = q_ref.shape[0]
+    layer = l_ref[0]
+    div = jax.lax.div
+    step_rows = pps * bs
+    sub = q_ref.shape[1] // groups      # query rows a group: 8 sublanes
+    lanes = q_ref.shape[2]              # a KV head's [k ; v]: 128 lanes
+
+    def steps_of(b):
+        return div(pos_ref[b], step_rows) + 1
+
+    def live_pages(b, i):
+        return jnp.minimum(pps, div(pos_ref[b], bs) + 1 - i * pps)
+
+    def copy(rows, slot, j):
+        return pltpu.make_async_copy(
+            pool_hbm.at[layer, rows], buf.at[slot, pl.ds(j * bs, bs)],
+            sem.at[slot])
+
+    buf[...] = jnp.zeros(buf.shape, buf.dtype)
+    n_steps = jax.lax.fori_loop(
+        0, n_seq, lambda b, n: n + steps_of(b), jnp.int32(0))
+
+    def step(g, carry):
+        # (b, i): the step to fetch; (pb, pi), n_pages, last: the step
+        # fetched last time round - the one to compute on; m, l: the
+        # online softmax's running maximum and sum (the weighted values'
+        # sum is `acc_ref`)
+        b, i, pb, pi, n_pages, last, m, l = carry
+        slot = jax.lax.rem(g, 2)
+        bf = jnp.minimum(b, n_seq - 1)
+        n_fetch = jnp.where(g < n_steps, live_pages(bf, i), 0)
+
+        def start(j, c):
+            blk = table_ref[bf, i * pps + j]
+            copy(pl.ds(blk * bs, bs), slot, j).start()
+            return c
+
+        jax.lax.fori_loop(0, n_fetch, start, 0)
+
+        def wait(j, c):
+            copy(pl.ds(0, bs), 1 - slot, j).wait()
+            return c
+
+        jax.lax.fori_loop(0, n_pages, wait, 0)
+        first = pi == 0
+        m = jnp.where(first, _NEG_BIG, m)
+        l = jnp.where(first, 0.0, l)
+
+        def compute():
+            rows = buf[1 - slot]                     # (rows, groups * 128)
+            q = q_ref[pb]                            # (groups * sub, 128)
+            tiles = [rows[:, k * lanes:(k + 1) * lanes]
+                     for k in range(groups)]
+            s = jnp.concatenate([
+                _dot_nt(q[k * sub:(k + 1) * sub], tiles[k])
+                for k in range(groups)], axis=0) * scale    # (H', rows) f32
+            at = pi * step_rows + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(at <= pos_ref[pb], s, _NEG_BIG)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new).astype(rows.dtype)
+            alpha = jnp.exp(m - m_new)
+            pv = jnp.concatenate([
+                _dot_nn(p[k * sub:(k + 1) * sub], tiles[k])
+                for k in range(groups)], axis=0)            # (H', 128)
+            acc_ref[...] = jnp.where(first, 0.0, acc_ref[...] * alpha) + pv
+            return m_new, l * alpha + p.astype(jnp.float32).sum(
+                axis=-1, keepdims=True)
+
+        m, l = jax.lax.cond(n_pages > 0, compute, lambda: (m, l))
+
+        @pl.when(last)
+        def _write():
+            o_ref[pb] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+        ends = i + 1 == steps_of(bf)
+        return (
+            jnp.where(ends, b + 1, b), jnp.where(ends, 0, i + 1),
+            bf, i, n_fetch, jnp.logical_and(ends, g < n_steps), m, l,
+        )
+
+    zero = jnp.int32(0)
+    h = q_ref.shape[1]
+    jax.lax.fori_loop(0, n_steps + 1, step, (
+        zero, zero, zero, zero, zero, False,
+        jnp.full((h, 1), _NEG_BIG, jnp.float32),
+        jnp.zeros((h, 1), jnp.float32),
+    ))
+
+
+def gqa_decode_attention(q, pool, layer, table, pos, *, block_size: int,
+                         n_kv_heads: int, interpret: bool = False):
+    """One decode step of grouped-query attention for every sequence, read
+    from the serving engine's KV pool where it lies.
+
+    q (B, H, Dh) - the current position's query rows, H a multiple of
+    ``n_kv_heads`` (query head h reads KV head ``h // (H / n_kv_heads)``);
+    pool (L, slots, n_kv_heads * 2 * Dh) - the whole pool, left in HBM: a
+    row is ``[k_0 ; v_0 ; k_1 ; v_1 ; ...]`` (models/lfm2_moe.py `attn_in`),
+    a page the contiguous ``(block_size, row)`` tile of one block; ``layer``
+    a scalar that may be traced; table (B, W) int32, entries past a
+    sequence's live pages unread; pos (B,) int32 - positions 0..pos[b] are
+    attended. Returns o (B, H, Dh) in q's dtype. Scores are scaled by
+    1/sqrt(Dh); scores, the online softmax and the accumulator are float32.
+    Gate a compiled call with `gqa_decode_ok`.
+
+    Around the one Mosaic call the queries are laid out as the kernel reads
+    them - a group's heads on the first rows of an 8-sublane tile, in the
+    key lanes of a KV head's 128 - and the value lanes of its output are
+    cut out again: plain XLA on (B, H, 128) values."""
+    b, h, d = q.shape
+    if pool.ndim != 3 or pool.shape[2] != n_kv_heads * 2 * d or (
+            h % n_kv_heads):
+        raise ValueError(
+            f"pool {pool.shape} does not hold K and V of {n_kv_heads} KV "
+            f"heads of {d} for q's {h} heads")
+    if table.shape[0] != b or pos.shape != (b,):
+        raise ValueError(
+            f"table {table.shape} and pos {pos.shape} do not describe "
+            f"q's batch of {b}")
+    per = h // n_kv_heads
+    if not interpret and not gqa_decode_ok(
+            block_size, n_kv_heads, per, d, pool.dtype):
+        raise ValueError(
+            f"gqa_decode_attention: pages of {block_size} {pool.dtype} rows "
+            f"of {n_kv_heads} x 2 x {d} with {per} queries a KV head are no "
+            "tiles this kernel compiles for (gqa_decode_ok) - fall back to "
+            "the XLA decode path")
+    sub = -(-per // _SUBLANES) * _SUBLANES
+    qk = jnp.zeros((b, n_kv_heads, sub, 2 * d), q.dtype).at[
+        :, :, :per, :d].set(q.reshape(b, n_kv_heads, per, d))
+    pps = max(1, min(table.shape[1], _GQA_STEP_POSITIONS // block_size))
+    o = pl.pallas_call(
+        functools.partial(_gqa_paged_kernel, bs=block_size, pps=pps,
+                          groups=n_kv_heads, scale=1.0 / float(d) ** 0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((2, pps * block_size, pool.shape[2]), pool.dtype),
+                pltpu.VMEM((n_kv_heads * sub, 2 * d), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=_struct((b, n_kv_heads * sub, 2 * d), q.dtype, q, pool),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_MLA_VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name="gqa_decode_attn",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        table.astype(jnp.int32), pos.astype(jnp.int32),
+        qk.reshape(b, n_kv_heads * sub, 2 * d), pool,
+    )
+    return o.reshape(b, n_kv_heads, sub, 2 * d)[:, :, :per, d:].reshape(
+        b, h, d)
+
+
+def gqa_decode_ok(block_size: int, n_kv_heads: int, per_kv: int,
+                  head_dim: int, dtype) -> bool:
+    """True where `gqa_decode_attention` compiles: `mla_decode_ok`'s pages
+    (whole sublane tiles of the pool's dtype), a KV head's keys and values
+    together one 128-lane tile (heads of 64), and a group's queries within
+    one 8-sublane tile (tests/test_tpu_aot_compile.py compiles the served
+    shape for a described v5e)."""
+    return (mla_decode_ok(block_size, n_kv_heads * 2 * head_dim, _LANES,
+                          dtype)
+            and 2 * head_dim == _LANES and 1 <= per_kv <= _SUBLANES)

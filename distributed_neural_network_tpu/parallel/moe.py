@@ -221,13 +221,15 @@ def moe_ffn(
 # turn comes.
 
 
-def sigmoid_topk_route(x, wr, bias, *, top_k: int, scale: float):
+def sigmoid_topk_route(x, wr, bias, *, top_k: int, scale: float,
+                       sum_eps: float = 0.0):
     """x (T, d), wr (d, E), bias (E,) or None -> (experts (T, k) int32, weights
     (T, k) float32). Scores are sigmoid(x wr) in float32 at the highest
     matmul precision (a choice that flips with the rounding of x moves an
     expert's gradient in the first order); the k largest of score + bias
-    are chosen, and their weights are the unbiased scores over their sum,
-    times `scale`. The bias selects only: no gradient reaches it."""
+    are chosen, and their weights are the unbiased scores over their sum
+    (plus `sum_eps`, for a family that states one), times `scale`. The bias
+    selects only: no gradient reaches it."""
     scores = jax.nn.sigmoid(jnp.matmul(
         x.astype(jnp.float32), wr.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
@@ -237,7 +239,9 @@ def sigmoid_topk_route(x, wr, bias, *, top_k: int, scale: float):
         scores if bias is None
         else scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
-    return experts, scale * chosen / chosen.sum(-1, keepdims=True)
+    scaled = scale * chosen
+    total = chosen.sum(-1, keepdims=True)
+    return experts, scaled / (total + sum_eps if sum_eps else total)
 
 
 def relu2(x):
@@ -410,16 +414,20 @@ def swiglu(x, w_gate, w_up, w_down):
 
 def moe_held_gated_serve(x, wr, w_gate, w_up, w_down, shared, *, first: int,
                          top_k: int, scale: float, tile: int, valid=None,
-                         layer=None):
+                         layer=None, bias=None, sum_eps: float = 0.0):
     """A chip's share of a sigmoid-routed layer of GATED experts, forward
     only, in the layout serving wants: the work follows the pairs that
     arrived.
 
-    x (T, d); wr (d, E) over all E routed experts, no selection bias;
+    x (T, d); wr (d, E) over all E routed experts; `bias` (E,) the selection
+    bias of a router that has one (it selects only) and `sum_eps` what it
+    adds to the chosen scores' sum (`sigmoid_topk_route`);
     w_gate / w_up (H, d, f) and w_down (H, f, d) the H experts held here
     (`first .. first + H - 1`), each `swiglu`; `shared` the shared expert's
-    (gate, up, down). `valid` (T,) bool marks the rows that are tokens (a
-    bucket's spare rows route nowhere and are not counted). With `layer` (a
+    (gate, up, down), None for a layer without one (both chosen in Python: a
+    program without them traces what it traced). `valid` (T,) bool marks the
+    rows that are tokens (a bucket's spare rows route nowhere and are not
+    counted). With `layer` (a
     scalar, traced under a layer scan) the held experts' matrices come
     stacked over layers, (L, H, d, f), and a tile reads `w[layer, expert]`
     where it lies: a layer's slice handed in through the scan is a COPY of
@@ -443,8 +451,8 @@ def moe_held_gated_serve(x, wr, w_gate, w_up, w_down, shared, *, first: int,
         return (w[g] if layer is None else w[layer, g]).astype(dt)
 
     with jax.named_scope("lm.moe.route"):
-        experts, weights = sigmoid_topk_route(x, wr, None, top_k=top_k,
-                                              scale=scale)
+        experts, weights = sigmoid_topk_route(x, wr, bias, top_k=top_k,
+                                              scale=scale, sum_eps=sum_eps)
         if valid is not None:   # a spare row's pairs: to no expert here
             experts = jnp.where(valid[:, None], experts, first - 1)
         src, ok, pos, tile_group, load, absent = held_pairs_layout(
@@ -465,7 +473,10 @@ def moe_held_gated_serve(x, wr, w_gate, w_up, w_down, shared, *, first: int,
             return y.at[rows].add(yb.astype(f32) * w[:, None])
 
         y = jax.lax.fori_loop(0, n_tiles, one, jnp.zeros(x.shape, f32))
-    with jax.named_scope("lm.moe.shared"):
-        y = y.astype(dt) + swiglu(x, *(w.astype(dt) for w in shared))
+    if shared is None:
+        y = y.astype(dt)
+    else:
+        with jax.named_scope("lm.moe.shared"):
+            y = y.astype(dt) + swiglu(x, *(w.astype(dt) for w in shared))
     return y, {"held": load.sum(), "absent": absent, "load": load,
                "multiplied": n_tiles * tile}

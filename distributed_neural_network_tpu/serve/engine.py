@@ -85,6 +85,26 @@ absorbed form on `mla_decode_attention` in decode and the expanded form on
 counts back with the tokens. Speculative decoding and the int8 pool and
 weights are refused for it by name. The per-head programs above are
 untouched by it.
+
+**Two kinds of state** (a module whose ``CACHE`` is ``"hybrid"``,
+models/lfm2_moe.py): its attention layers keep a row a position in ONE pool
+``(attention layers, slots, row)`` under the K pool's name, K and V of every
+KV head side by side; its convolution layers keep a state of fixed size a
+SEQUENCE in the state pool ``(convolution layers, state slots, rows, d)``,
+one slot a sequence, taken and freed with its blocks (`kv_cache.py`). Both
+pools are donated to every program and rebound from its outputs. The two
+program families (`_hybrid_decode`, `_hybrid_prefill`) have one table width
+and walk the model's layers in Python, in the published order
+(`_hybrid_layers`): a decode row gathers its state by slot, takes one
+convolution step and scatters the new state; a prefill chunk starts from the
+sequence's state - from noughts where its first position is 0, decided in
+the program, since a freed slot is handed on as it was left - and leaves the
+state of its last valid position. Preemption replays from the tokens, which
+rebuilds the state: there is no snapshot.
+
+**What kind of engine this is** is asked of the module once, in the
+constructor (`_Cache`): the pools, the program families, the table width,
+the kernel's gate and what the module refuses. Nothing below asks again.
 """
 
 from __future__ import annotations
@@ -92,7 +112,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from functools import partial
 
 import jax
@@ -104,6 +124,8 @@ from ..models import transformer as tfm
 from ..models.transformer import TransformerConfig, _sinusoid_pe
 from ..ops.decode_pallas import (
     decode_paged_attention,
+    gqa_decode_attention,
+    gqa_decode_ok,
     mla_decode_attention,
     mla_decode_ok,
     mla_prefill_attention,
@@ -311,8 +333,10 @@ def export_descriptor(seq: Sequence) -> dict:
     function of the token history, and sampled slots key on the
     ABSOLUTE position (`_row_keys` folds ``pos`` into the request
     seed's key) - so prefilling ``prompt + already-emitted tokens`` on
-    any replica reconstructs the byte-identical KV state and the next
-    sampling key, and the
+    any replica reconstructs the byte-identical KV state (and, for a
+    model that keeps one, each convolution layer's state in the state
+    pool: no pool's contents are exported) and the next sampling key,
+    and the
     continuation matches the stream a single never-failing replica
     would have produced. ``emitted`` holds only tokens the client has
     already seen (the dedup rule: they become prompt on resume, never
@@ -594,14 +618,52 @@ def _latent_layers(cfg, params, x, pool, cache_step, valid):
             layer_step, (x, pool), (rest, l0 + jnp.arange(n)))
         if stats is not None:
             routing = stats
-    if routing is None:     # no expert layer: nothing routed
-        return x, pool, jnp.zeros((3,), jnp.int32)
-    counts = jnp.concatenate([
+    return x, pool, _pack_counts(routing)
+
+
+def _pack_counts(routing):
+    """The expert layers' routing counts of one program (each leaf stacked
+    over the expert layers; None: no expert layer, nothing routed) as one
+    int32 vector: pairs held, pairs absent, rows multiplied, then each
+    expert layer's load over the held experts (`ServeEngine._moe_stats`)."""
+    if routing is None:
+        return jnp.zeros((3,), jnp.int32)
+    return jnp.concatenate([
         jnp.stack([routing["held"].sum(), routing["absent"].sum(),
                    routing["multiplied"].sum()]),
         routing["load"].reshape(-1),
     ]).astype(jnp.int32)
-    return x, pool, counts
+
+
+def _hybrid_layers(cfg, params, x, pools, op_step, valid):
+    """The layers of a hybrid program, walked in Python in the model's own
+    order (`layer_plan`: an interleaved pattern of two operator kinds and
+    two feed-forward kinds is no stack to scan): the operator is the
+    program's own ``op_step[kind](x, lp, i, pools) -> (x, pools)``, the
+    module's `*_in` / `*_out` around its cache or state step at index ``i``
+    of that kind's pool; the feed-forward is the module's. ``pools`` = (KV
+    pool, state pool), donated and threaded through every layer: each step
+    reads and writes its rows at a static layer index, so the update is in
+    place (tests/test_lfm2_moe.py pins it on the compiled programs). A
+    layer's matrices are a static slice of their stack; the held experts'
+    stay whole and a tile reads `w[layer, expert]` where it lies. Returns
+    (x, pools, counts) as `_latent_layers` does."""
+    mod = cfg.module
+    tile = mod.expert_tile(cfg, x.shape[0])
+    held = {k: v for k, v in params.get("moe", {}).items()
+            if k in mod.EXPERT_LEAVES}
+    routing = []
+    for op, oi, ff, fi in mod.layer_plan(cfg):
+        x, pools = op_step[op](x, mod.layer_params(params, op, oi), oi, pools)
+        fp = {k: v[fi] for k, v in params[ff].items() if k not in held}
+        x, stats = mod.feed_forward(
+            x, fp, cfg, ff, tile=tile, valid=valid,
+            experts=(held, fi) if ff == "moe" else None)
+        if stats is not None:
+            routing.append(stats)
+    return x, pools, _pack_counts(
+        jax.tree.map(lambda *xs: jnp.stack(xs), *routing) if routing
+        else None)
 
 
 @jax.jit
@@ -644,6 +706,37 @@ def _widen(nxt, n: int):
     a batch that changes its bucket keeps its tick in flight. One program
     a bucket below the largest; the largest needs none."""
     return jnp.pad(nxt, (0, n - nxt.shape[0]))
+
+
+@dataclass(frozen=True)
+class _Cache:
+    """What the model's module keeps between a sequence's programs and which
+    programs serve it: asked of the module once, in `ServeEngine.__init__`,
+    and read everywhere else."""
+
+    # the engine's attributes that hold the donated operands of a bucket
+    # program, in the order it takes and returns them
+    pools: tuple
+    # what servelint's donation audit calls them (analysis/serve_trace.py)
+    labels: tuple
+    # the one width of a program's block table, in blocks (0: per-head K
+    # and V, whose programs come in power-of-two width buckets)
+    width: int = 0
+    # (B, W) -> the decode program, (C, W) -> the prefill program, of a
+    # module that brings its own block (None: the GPT-2 programs)
+    decode: object = None
+    prefill: object = None
+    # the programs hand the expert layers' routing counts back
+    routed: bool = False
+    # the programs take each sequence's slot of the state pool
+    state: bool = False
+    # whether the decode kernel compiles for this pool, and what to say
+    # where it was asked for and does not
+    kernel_ok: bool = False
+    kernel_refusal: str = ""
+    # whether a prefill attention kernel compiles (None: the module brings
+    # none, its chunked prefill is `jax.numpy`)
+    prefill_kernel_ok: bool | None = None
 
 
 @dataclass
@@ -701,42 +794,40 @@ class ServeEngine:
     active set under `lock` between ticks."""
 
     def __init__(self, params, cfg, ecfg: EngineConfig):
-        # what a position's cache row is decides which programs are built,
-        # here and never inside one: per-head K and V (`TransformerConfig`)
-        # or one latent row (a module that says `CACHE = "latent"`)
-        self.latent = getattr(cfg.module, "CACHE", "") == "latent"
-        if not self.latent and not isinstance(cfg, TransformerConfig):
+        # what the module keeps decides which pools and programs are built,
+        # here and never inside one: per-head K and V (`TransformerConfig`),
+        # one latent row (`CACHE = "latent"`), or a KV row in the attention
+        # layers and a state in the others (`CACHE = "hybrid"`)
+        mod = cfg.module
+        kind = getattr(mod, "CACHE", "")
+        if not kind and not isinstance(cfg, TransformerConfig):
             raise ValueError(
-                f"{cfg.module.NAME}: the serving "
-                "engine does not run this model - its Mamba-2 layers carry "
-                "a recurrent state that the paged KV cache has no place "
-                "for, and the engine's programs know one kind of block"
+                f"{mod.NAME}: the serving "
+                "engine does not run this model - its module declares no "
+                "cache (`CACHE`) for its Mamba-2 layers' recurrent state, "
+                "and the engine's programs know the blocks of the modules "
+                "that do"
             )
-        if self.latent:
-            refused = {
-                "spec_decode": (ecfg.spec_decode, "the early-exit drafter "
-                                "and the verify step are written for "
-                                "per-head K and V pools"),
-                "kv_dtype int8": (ecfg.kv_dtype == "int8", "the per-(block, "
-                                  "head) scales have no head to belong to in "
-                                  "a latent row shared by all heads"),
-                "weight_dtype int8": (ecfg.weight_dtype == "int8", "the "
-                                      "prequantized matmul knows the GPT-2 "
-                                      "block's six matrices"),
-            }
-            for what, (asked, why) in refused.items():
-                if asked:
-                    raise ValueError(
-                        f"{cfg.module.NAME}: {what} is not supported with "
-                        f"a latent cache - {why}")
-        elif cfg.n_experts:
+        self.latent = kind == "latent"
+        asked = {"spec_decode": ecfg.spec_decode,
+                 "kv_dtype int8": ecfg.kv_dtype == "int8",
+                 "weight_dtype int8": ecfg.weight_dtype == "int8"}
+        for what, why in getattr(mod, "REFUSED", {}).items():
+            if asked[what]:
+                raise ValueError(
+                    f"{mod.NAME}: {what} is not supported for this "
+                    f"module - {why}")
+        if not kind and cfg.n_experts:
             raise ValueError(
                 "the serving engine supports dense models; MoE decode "
                 "routes through models/transformer.py generate()"
             )
         self.cfg = cfg
         self.ecfg = ecfg
-        self.kv = PagedKVCache(ecfg.kv())
+        kv_cfg = ecfg.kv()
+        if kind == "hybrid":   # a state slot a sequence, and the scratch
+            kv_cfg = replace(kv_cfg, state_slots=ecfg.max_batch + 1)
+        self.kv = PagedKVCache(kv_cfg)
         self.weight_quantized = ecfg.weight_dtype == "int8"
         if self.weight_quantized:
             params = _prequantize_params(params)
@@ -761,11 +852,12 @@ class ServeEngine:
             self.draft_params = tfm.early_exit_params(
                 self.params, self.draft_layers)
         slots = self.kv.cfg.pool_slots
+        bs = ecfg.block_size
         self.quantized = ecfg.kv_dtype == "int8"
         pool_dt = jnp.int8 if self.quantized else cfg.dtype
-        # the one width of a latent program's block table (0 = per-head K
-        # and V: the power-of-two width buckets)
-        self._latent_width = 0
+        self.v_pool = self.state_pool = None
+        self.k_scale = self.v_scale = None
+        one_width = _bucket(self.kv.cfg.max_blocks_per_seq)
         if self.latent:
             # ONE pool of latent rows, under the name the K pool has (what
             # holds the engine's cache is asked for by it); a row is padded
@@ -778,20 +870,69 @@ class ServeEngine:
                                // _LANES) * _LANES
             self.k_pool = jnp.zeros(
                 (cfg.n_layers, slots, self.row_width), pool_dt)
-            self.v_pool = None
-            self._latent_width = _bucket(self.kv.cfg.max_blocks_per_seq)
+            self._cache = _Cache(
+                pools=("k_pool",), labels=("latent_pool",), width=one_width,
+                decode=self._latent_decode, prefill=self._latent_prefill,
+                routed=True,
+                kernel_ok=mla_decode_ok(
+                    bs, self.row_width, cfg.kv_rank, pool_dt),
+                prefill_kernel_ok=mla_prefill_ok(
+                    bs, self.row_width, cfg.kv_rank, cfg.qk_nope,
+                    cfg.v_head, self._rope_width, pool_dt),
+                kernel_refusal=(
+                    f"the latent decode kernel does not compile for pages "
+                    f"of {bs} {jnp.dtype(pool_dt)} rows "
+                    "(ops/decode_pallas.py mla_decode_ok)"))
+        elif kind == "hybrid":
+            # the attention layers' rows in ONE pool under the K pool's
+            # name, K and V of every KV head side by side (per-head (H, Dh)
+            # minor axes of (8, 64) would be stored in (16, 128) tiles, four
+            # times the bytes); the convolution layers' states in the state
+            # pool, a slot a sequence
+            shapes = mod.cache_shapes(cfg)
+            n_kv, self.row_width = shapes["kv"]
+            self.k_pool = jnp.zeros((n_kv, slots, self.row_width), pool_dt)
+            self.state_pool = jnp.zeros(
+                (shapes["state"][0], self.kv.cfg.state_slots)
+                + shapes["state"][1:], pool_dt)
+            self._cache = _Cache(
+                pools=("k_pool", "state_pool"),
+                labels=("kv_pool", "state_pool"), width=one_width,
+                decode=self._hybrid_decode, prefill=self._hybrid_prefill,
+                routed=True, state=True,
+                kernel_ok=gqa_decode_ok(
+                    bs, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads,
+                    cfg.head_dim, pool_dt),
+                kernel_refusal=(
+                    f"the grouped-query decode kernel does not compile for "
+                    f"pages of {bs} {jnp.dtype(pool_dt)} rows of "
+                    f"{cfg.n_kv_heads} x 2 x {cfg.head_dim} "
+                    "(ops/decode_pallas.py gqa_decode_ok)"))
         else:
             L, H, Dh = cfg.n_layers, cfg.n_heads, cfg.head_dim
             self.k_pool = jnp.zeros((L, slots, H, Dh), pool_dt)
             self.v_pool = jnp.zeros((L, slots, H, Dh), pool_dt)
-        self.k_scale = self.v_scale = None
-        if self.quantized:
-            # int8 pool + per-(block, head) f32 scales: the one extra
-            # small array rides the SAME block-table addressing (scale
-            # of slot s = scales[table[s // bs]]), so every gather/
-            # scatter index the bf16 path computes is reused verbatim
-            self.k_scale = jnp.zeros((L, ecfg.num_blocks, H), jnp.float32)
-            self.v_scale = jnp.zeros((L, ecfg.num_blocks, H), jnp.float32)
+            if self.quantized:
+                # int8 pool + per-(block, head) f32 scales: the one extra
+                # small array rides the SAME block-table addressing (scale
+                # of slot s = scales[table[s // bs]]), so every gather/
+                # scatter index the bf16 path computes is reused verbatim
+                self.k_scale = jnp.zeros(
+                    (L, ecfg.num_blocks, H), jnp.float32)
+                self.v_scale = jnp.zeros(
+                    (L, ecfg.num_blocks, H), jnp.float32)
+            names = ("k_pool", "v_pool") + (
+                ("k_scale", "v_scale") if self.quantized else ())
+            self._cache = _Cache(
+                pools=names, labels=names,
+                kernel_ok=paged_decode_ok(bs, H, Dh, pool_dt),
+                kernel_refusal=(
+                    f"the paged decode "
+                    f"kernel does not read a {self.kv_dtype_name()} pool "
+                    f"whose pages are (block_size {bs}, "
+                    f"H {H}, Dh {Dh}): it takes a "
+                    "float32 or bfloat16 pool with H 2, 4 or a multiple of "
+                    "8 and Dh a multiple of 128"))
         self.lock = threading.Lock()
         self.active: list[Sequence] = []
         self._step_fns: dict = {}
@@ -888,9 +1029,9 @@ class ServeEngine:
         from ..analysis.cost import kv_block_bytes, latent_block_bytes
 
         cfg = self.cfg
-        if self.latent:
+        if self.v_pool is None:   # one pool of rows, over its own layers
             return latent_block_bytes(
-                cfg.n_layers, self.row_width, self.ecfg.block_size,
+                self.k_pool.shape[0], self.row_width, self.ecfg.block_size,
                 self.kv_dtype_name(),
             )
         return kv_block_bytes(
@@ -942,32 +1083,13 @@ class ServeEngine:
         impl = self.ecfg.decode_impl
         if impl == "xla":
             return "xla"
-        cfg = self.cfg
-        if self.latent:
-            legal = mla_decode_ok(self.ecfg.block_size, self.row_width,
-                                  cfg.kv_rank, self.k_pool.dtype)
-            if impl == "pallas" and on_tpu() and not legal:
-                raise ValueError(
-                    f"decode_impl 'pallas' requested but the latent decode "
-                    f"kernel does not compile for pages of "
-                    f"{self.ecfg.block_size} {self.k_pool.dtype} rows "
-                    "(ops/decode_pallas.py mla_decode_ok) - use 'auto'")
-            return "pallas" if impl == "pallas" or (
-                legal and on_tpu()) else "xla"
-        legal = paged_decode_ok(
-            self.ecfg.block_size, cfg.n_heads, cfg.head_dim,
-            self.k_pool.dtype,
-        )
+        legal = self._cache.kernel_ok
         if impl == "pallas":
             # off the TPU the kernel runs interpreted and tiles nothing
             if self.quantized or (on_tpu() and not legal):
                 raise ValueError(
-                    f"decode_impl 'pallas' requested but the paged decode "
-                    f"kernel does not read a {self.kv_dtype_name()} pool "
-                    f"whose pages are (block_size {self.ecfg.block_size}, "
-                    f"H {cfg.n_heads}, Dh {cfg.head_dim}): it takes a "
-                    "float32 or bfloat16 pool with H 2, 4 or a multiple of "
-                    "8 and Dh a multiple of 128 - use decode_impl 'auto'"
+                    f"decode_impl 'pallas' requested but "
+                    f"{self._cache.kernel_refusal} - use decode_impl 'auto'"
                 )
             return "pallas"
         # auto: the kernel only pays on TPU (off-TPU it would run the
@@ -984,22 +1106,19 @@ class ServeEngine:
         asks for kernels and it compiles, the blocked `jax.numpy` loop
         (the module's `prefill_attention`, the oracle) otherwise."""
         impl = self.ecfg.decode_impl
-        if impl == "xla":
+        if impl == "xla" or self._cache.prefill_kernel_ok is None:
             return "xla"
-        cfg = self.cfg
-        legal = mla_prefill_ok(
-            self.ecfg.block_size, self.row_width, cfg.kv_rank, cfg.qk_nope,
-            cfg.v_head, self._rope_width, self.k_pool.dtype)
+        legal = self._cache.prefill_kernel_ok
         if on_tpu():
             return "pallas" if legal else "xla"
         return "pallas" if impl == "pallas" else "xla"   # interpreted
 
     def _bucket_widths(self, max_width_blocks: int | None = None) -> list:
         """The power-of-two width buckets (in blocks) up to the cap."""
-        if self._latent_width:
+        if self._cache.width:
             # the kernel and the blocked prefill walk a table's live part
             # on traced bounds: a narrower table buys further programs only
-            return [self._latent_width]
+            return [self._cache.width]
         max_w = _bucket(max_width_blocks or self.kv.cfg.max_blocks_per_seq)
         widths = []
         w = 1
@@ -1026,8 +1145,10 @@ class ServeEngine:
         call). The drafter READS the pools and returns only draft tokens,
         so it has nothing to alias and donates nothing. servelint audits
         the donation contract per bucket (analysis/serve_trace.py)."""
-        if self.latent:   # written as ``program(params, pool, *tail)``
-            return jax.jit(program, donate_argnums=(1,))
+        if self._cache.decode is not None:
+            # a module's own: ``program(params, *pools, *tail)``
+            return jax.jit(program, donate_argnums=tuple(
+                range(1, 1 + len(self._cache.pools))))
         if self.quantized:
             return jax.jit(
                 program, donate_argnums=(1, 2, 3, 4) if writes_pools else ())
@@ -1043,9 +1164,9 @@ class ServeEngine:
         fn = self._step_fns.get((B, W))
         if fn is not None:
             return fn
-        if self.latent:
+        if self._cache.decode is not None:
             fn = self._step_fns[(B, W)] = self._jit_bucket(
-                self._latent_decode(B, W))
+                self._cache.decode(B, W))
             return fn
         cfg, dt = self.cfg, self.cfg.dtype
         H, Dh = cfg.n_heads, cfg.head_dim
@@ -1110,9 +1231,9 @@ class ServeEngine:
         fn = self._prefill_fns.get((C, W))
         if fn is not None:
             return fn
-        if self.latent:
+        if self._cache.prefill is not None:
             fn = self._prefill_fns[(C, W)] = self._jit_bucket(
-                self._latent_prefill(C, W))
+                self._cache.prefill(C, W))
             return fn
         cfg, dt = self.cfg, self.cfg.dtype
         bs = self.kv.cfg.block_size
@@ -1244,6 +1365,122 @@ class ServeEngine:
         prefill.__name__ = "latent_prefill"
         return prefill
 
+    # ------------------ KV rows and convolution states (hybrid) programs
+
+    def _hybrid_decode(self, B: int, W: int):
+        cfg, mod = self.cfg, self.cfg.module
+        bs = self.kv.cfg.block_size
+        S = W * bs
+        use_kernel = self._attn_route() == "pallas"
+
+        def step(params, kv_pool, state_pool, tok, pos, table, slots,
+                 temps, keys):
+            # tok/pos (B,), table (B, W), slots (B,) the rows' state slots,
+            # temps (B,), keys (B, 2)
+            x = mod.embed_tokens(params, tok, cfg)               # (B, d)
+            flat = table[jnp.arange(B), pos // bs] * bs + pos % bs
+            valid = table[:, 0] != SCRATCH_BLOCK    # a spare row: no token
+            fresh = (pos == 0)[:, None, None]       # no state before 0
+            if not use_kernel:
+                idx = _span_idx(table, bs)                       # (B, S)
+                live = jnp.arange(S)[None, :] <= pos[:, None]
+
+            def conv_step(x, lp, i, pools):
+                # each row's state by its slot, one step of the convolution,
+                # the new state back: the last `taps - 1` rows of [state ; z]
+                # (`next_state` behind one position)
+                kv_pool, state_pool = pools
+                gate, z = mod.conv_in(x, lp, cfg)
+                tail = jnp.where(fresh, 0, state_pool[i, slots])
+                c, zz = jax.vmap(
+                    lambda t, z_: mod.conv_mix(t, z_[None], lp, cfg)
+                )(tail, z)
+                state_pool = state_pool.at[i, slots].set(zz[:, 1:])
+                return (mod.conv_out(x, gate, c[:, 0], lp, cfg),
+                        (kv_pool, state_pool))
+
+            def attn_step(x, lp, i, pools):
+                # write this position's row, then attend over the cache
+                kv_pool, state_pool = pools
+                q, row = mod.attn_in(x, lp, cfg, pos)
+                kv_pool = _write_rows(kv_pool, i, flat, row)
+                if use_kernel:
+                    with jax.named_scope("lm.attn.attn"):
+                        o = gqa_decode_attention(
+                            q, kv_pool, i, table, pos, block_size=bs,
+                            n_kv_heads=cfg.n_kv_heads,
+                            interpret=not on_tpu(),
+                        )
+                else:   # the oracle: gather the table's span
+                    o = mod.decode_attention(
+                        q, _read_rows(kv_pool, i, idx), live, cfg)
+                return mod.attn_out(x, o, lp, cfg), (kv_pool, state_pool)
+
+            x, pools, counts = _hybrid_layers(
+                cfg, params, x, (kv_pool, state_pool),
+                {"conv": conv_step, "attn": attn_step}, valid)
+            logits = mod.final_logits(params, x, cfg)
+            return *pools, _next_tokens(logits, temps, keys), logits, counts
+
+        step.__name__ = "hybrid_decode"
+        return step
+
+    def _hybrid_prefill(self, C: int, W: int):
+        cfg, mod = self.cfg, self.cfg.module
+        bs = self.kv.cfg.block_size
+        key_block = min(_PREFILL_KEY_BLOCK, W * bs)
+        pages = key_block // bs
+
+        def prefill(params, kv_pool, state_pool, toks, pos0, table, slot,
+                    n_valid):
+            # toks (C,), pos0 scalar, table (W,), slot the sequence's state
+            # slot, n_valid scalar
+            pv = pos0 + jnp.arange(C)
+            valid = jnp.arange(C) < n_valid
+            x = mod.embed_tokens(params, toks, cfg)              # (C, d)
+            # the chunk's dead tail -> the scratch block
+            flat = jnp.where(valid, table[pv // bs] * bs + pv % bs, 0)
+
+            def conv_step(x, lp, i, pools):
+                # the chunk's convolution starts from the sequence's state
+                # (noughts at position 0: the slot is as its last owner
+                # left it) and leaves the state behind its last VALID
+                # position (`next_state`)
+                kv_pool, state_pool = pools
+                gate, z = mod.conv_in(x, lp, cfg)
+                tail = jnp.where(pos0 == 0, 0, state_pool[i, slot])
+                c, zz = mod.conv_mix(tail, z, lp, cfg)
+                state_pool = state_pool.at[i, slot].set(
+                    mod.next_state(zz, n_valid, cfg))
+                return (mod.conv_out(x, gate, c, lp, cfg),
+                        (kv_pool, state_pool))
+
+            def attn_step(x, lp, i, pools):
+                # write the chunk's rows, then attend over the table's live
+                # span, the rows just written included, a key block at a time
+                kv_pool, state_pool = pools
+                q, rows = mod.attn_in(x, lp, cfg, pv)
+                kv_pool = _write_rows(kv_pool, i, flat, rows)
+
+                def read_rows(j):
+                    blk = jax.lax.dynamic_slice_in_dim(
+                        table, j * pages, pages)
+                    return _read_rows(kv_pool, i, _span_idx(blk, bs))
+
+                o = mod.prefill_attention(
+                    q, pv, read_rows, pos0 + n_valid, cfg,
+                    key_block=key_block)
+                return mod.attn_out(x, o, lp, cfg), (kv_pool, state_pool)
+
+            _, pools, counts = _hybrid_layers(
+                cfg, params, x, (kv_pool, state_pool),
+                {"conv": conv_step, "attn": attn_step}, valid)
+            # no logits: the last prompt token is the decode batch's
+            return *pools, counts
+
+        prefill.__name__ = "hybrid_prefill"
+        return prefill
+
     def _draft_fn(self, B: int, W: int):
         """k greedy early-exit steps in ONE jitted call: reads the paged
         pool (history < pos), keeps the in-flight draft K/V in a local
@@ -1363,10 +1600,12 @@ class ServeEngine:
 
     def _pools(self) -> tuple:
         """The donated operands of a bucket program, in its order."""
-        if self.latent:
-            return (self.k_pool,)
-        pools = (self.k_pool, self.v_pool, self.k_scale, self.v_scale)
-        return pools if self.quantized else pools[:2]
+        return tuple(getattr(self, name) for name in self._cache.pools)
+
+    @property
+    def pool_labels(self) -> tuple:
+        """`_pools`' names, as the donation audit reports them."""
+        return self._cache.labels
 
     def _board(self, nxt):
         """A decode program's tokens as `_feed_tokens` reads them."""
@@ -1383,17 +1622,38 @@ class ServeEngine:
         """Dispatch one pool-writing bucket program: the pools (and int8
         scales) go in donated and are rebound from its leading outputs;
         returns the outputs after them."""
-        pools = self._pools()
-        out = fn(self.params, *pools, *tail)
-        if self.latent:
-            self.k_pool = out[0]
-            return out[1:]
-        self.k_pool, self.v_pool = out[:2]
-        if self.quantized:
-            self.k_scale, self.v_scale = out[2:4]
-        return out[len(pools):]
+        names = self._cache.pools
+        out = fn(self.params, *self._pools(), *tail)
+        for name, pool in zip(names, out):
+            setattr(self, name, pool)
+        return out[len(names):]
+
+    def _state_slots(self, seq_ids: list, *, scalar: bool = False) -> tuple:
+        """What a program of an engine with a state pool takes behind its
+        table: the state slot of each of ``seq_ids`` (the scratch slot for
+        -1, a bucket's spare row), ``(len(seq_ids),)`` int32 - a scalar for
+        a prefill chunk's one sequence. Nothing for the other engines."""
+        if not self._cache.state:
+            return ()
+        rows = self.kv.state_rows(seq_ids)
+        return (jnp.asarray(rows[0] if scalar else rows),)
 
     # ----------------------------------------------------------- warmup
+
+    def bucket_tail(self, family: str, n: int, W: int) -> tuple:
+        """The operands behind the pools of a ``decode`` (batch ``n``) or
+        ``prefill`` (chunk ``n``) program of table width ``W``, all nought:
+        what `warmup` calls a bucket with (every write lands in the scratch
+        block and the scratch state slot) and what servelint traces it at
+        (analysis/serve_trace.py), so that neither repeats the other."""
+        i32 = jnp.int32
+        if family == "decode":
+            return (jnp.zeros((n,), i32), jnp.zeros((n,), i32),
+                    jnp.zeros((n, W), i32), *self._state_slots([-1] * n),
+                    jnp.zeros((n,), jnp.float32),
+                    jnp.zeros((n, 2), jnp.uint32))
+        return (jnp.zeros((n,), i32), i32(0), jnp.zeros((W,), i32),
+                *self._state_slots([-1], scalar=True), i32(0))
 
     def warmup(self, *, max_width_blocks: int | None = None) -> int:
         """Pre-compile the (batch, width) bucket grid with dummy calls
@@ -1444,10 +1704,8 @@ class ServeEngine:
             # as `step` calls it: host arrays in, the keys left on the device
             _row_keys(np.zeros((B,), np.uint32), np.zeros((B,), np.int32))
             for W in widths:
-                nxt = warm("decode", self._decode_fn(B, W), zeros(B),
-                           zeros(B), zeros(B, W),
-                           jnp.zeros((B,), jnp.float32),
-                           jnp.zeros((B, 2), jnp.uint32))[0]
+                nxt = warm("decode", self._decode_fn(B, W),
+                           *self.bucket_tail("decode", B, W))[0]
             # a decode program's tokens into the next tick's batch, as
             # `_dispatch` calls it (not counted: no bucket programs)
             _feed_tokens(self._board(nxt), np.zeros((B,), np.int32),
@@ -1456,8 +1714,8 @@ class ServeEngine:
             for C in pow2(self.ecfg.prefill_chunk):
                 for W in widths:
                     if C <= W * bs:
-                        warm("prefill", self._prefill_fn(C, W), zeros(C),
-                             jnp.int32(0), zeros(W), jnp.int32(0))
+                        warm("prefill", self._prefill_fn(C, W),
+                             *self.bucket_tail("prefill", C, W))
         if self.spec_k:
             # the speculative bucket families: drafter + K-position
             # verify per (batch, width)
@@ -1474,14 +1732,19 @@ class ServeEngine:
     def _moe_stats(self, counts: list) -> dict:
         """The packed counts of a tick's latent programs (`_latent_layers`)
         summed: pairs ``held`` and ``absent``, rows ``multiplied`` (the rows
-        a pair owns are the held pairs), and each expert layer's ``load``
-        (layers, held experts)."""
+        a pair owns are the held pairs), each expert layer's ``load``
+        (layers, held experts), and ``experts_read`` of ``experts_held``."""
         total = np.sum(counts, axis=0)
+        n_held = self.cfg.experts_held[1]
         return {
             "held": int(total[0]), "absent": int(total[1]),
             "multiplied": int(total[2]),
-            "load": total[3:].reshape(
-                self.cfg.n_moe, self.cfg.experts_held[1]),
+            "load": total[3:].reshape(self.cfg.n_moe, n_held),
+            # over every expert layer of every program: the held experts
+            # that own a row (whose matrices the tile loop read), and the
+            # held experts
+            "experts_read": int(sum((c[3:] > 0).sum() for c in counts)),
+            "experts_held": len(counts) * self.cfg.n_moe * n_held,
         }
 
     def _emit(self, seq: Sequence, tok: int) -> None:
@@ -1837,7 +2100,7 @@ class ServeEngine:
                         held.append(seq)
                         continue
                     C = _bucket(n)
-                    W = self._latent_width or _bucket(
+                    W = self._cache.width or _bucket(
                         (seq.pos + n - 1) // bs + 1)
                     toks = np.zeros((C,), np.int32)
                     toks[:n] = seq.prompt[seq.pos: seq.pos + n]
@@ -1846,12 +2109,15 @@ class ServeEngine:
                     live = n * seq.pos + n * (n + 1) // 2
                     out = self._run_writer(
                         fn, jnp.asarray(toks), jnp.int32(seq.pos),
-                        jnp.asarray(table), jnp.int32(n),
+                        jnp.asarray(table),
+                        *self._state_slots([seq.seq_id], scalar=True),
+                        jnp.int32(n),
                     )
-                    if self.latent:
+                    if self._cache.width:
                         # its width: the key blocks the program walks
                         kb = min(_PREFILL_KEY_BLOCK, W * bs)
                         W = -(-(seq.pos + n) // kb) * kb // bs
+                    if self._cache.routed:
                         tick.counts.append(out[0])
                         if self._prefill_route() == "pallas":
                             stats["prefill_kernel_pairs"] = live + stats.get(
@@ -1888,7 +2154,7 @@ class ServeEngine:
                 })
             if batch:
                 B = min(_bucket(len(batch)), ecfg.max_batch)
-                W = self._latent_width or _bucket(
+                W = self._cache.width or _bucket(
                     max(s.pos // bs + 1 for s in batch))
                 tok = np.zeros((B,), np.int32)
                 src = np.full((B,), -1, np.int32)
@@ -1917,9 +2183,11 @@ class ServeEngine:
                     _feed_tokens(self._board(prev.nxt), src, tok)
                     if (src >= 0).any() else jnp.asarray(tok),
                     jnp.asarray(pos), jnp.asarray(table),
+                    *self._state_slots(
+                        [s.seq_id for s in batch] + [-1] * (B - len(batch))),
                     jnp.asarray(temps), _row_keys(seeds, pos),
                 )
-                if self.latent:
+                if self._cache.routed:
                     tick.counts.append(rest[1])
                 for i, s in enumerate(batch):
                     tick.rows.append((s, s.pos))
@@ -1937,7 +2205,7 @@ class ServeEngine:
         seqstat = partial(_seqstat, stats)
         if tick.rows or tick.counts:
             with TraceAnnotation("serve.fetch"):
-                if self.latent:
+                if self._cache.routed:
                     # the expert layers' counts come with the tokens
                     nxt, counts = jax.device_get((tick.nxt, tick.counts))
                     stats["moe"] = self._moe_stats(counts)

@@ -407,7 +407,9 @@ def add_model_args(p: argparse.ArgumentParser) -> None:
                    "file (its \"family\" names the module, "
                    "models/__init__.py FAMILIES: a latent-attention "
                    "expert model, e.g. benchmark/families/pangu_ultra_moe/"
-                   "tiny.json) in place of the GPT-2 block of the geometry "
+                   "tiny.json, or a short-convolution and grouped-query "
+                   "expert model, benchmark/families/lfm2_moe/tiny.json) "
+                   "in place of the GPT-2 block of the geometry "
                    "flags; seeded weights at --dtype")
 
 

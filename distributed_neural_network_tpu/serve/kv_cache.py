@@ -45,6 +45,16 @@ concurrent sequences one HBM budget holds (`analysis/cost.py
 kv_block_bytes` prices it exactly; docs/SERVING.md "int8 KV cache").
 The allocator itself is dtype-blind; the engine zeroes a freed block's
 scales so reuse is history-free (deterministic preemption replay).
+
+**State slots** (``KVCacheConfig.state_slots > 0``; a module whose layers
+keep a state of fixed size a sequence beside the cache rows a position,
+models/lfm2_moe.py): a sequence takes ONE slot of the engine's state pool
+with its first block and gives it back with its blocks (`free`, or a
+`rewind` to nothing). Slot 0 is scratch, as block 0 is: a bucket's spare
+rows read and write it. A slot is handed on as it was left - the programs
+start a sequence's state from noughts at position 0 themselves, because the
+next owner's first program may be dispatched while the last owner's tick is
+still in flight.
 """
 
 from __future__ import annotations
@@ -82,6 +92,9 @@ class KVCacheConfig:
     num_blocks: int = 64
     block_size: int = 16
     max_seq_len: int = 512
+    # slots of the engine's state pool, the scratch slot 0 included (0: the
+    # model keeps no state beside its cache rows)
+    state_slots: int = 0
 
     def __post_init__(self):
         if self.num_blocks < 2:
@@ -124,6 +137,8 @@ class PagedKVCache:
         self._free = list(range(cfg.num_blocks - 1, SCRATCH_BLOCK, -1))
         self._seq_blocks: dict[int, list[int]] = {}
         self._seq_used: dict[int, int] = {}  # tokens written (pos + 1)
+        self._free_states = list(range(cfg.state_slots - 1, SCRATCH_BLOCK, -1))
+        self._seq_state: dict[int, int] = {}
         self.alloc_total = 0
         self.free_total = 0
 
@@ -147,6 +162,19 @@ class PagedKVCache:
         Advisory (the engine thread may race it); admission uses it as
         the cheap first gate before the queue."""
         return self.cfg.blocks_for_tokens(n_tokens) <= self.free_blocks
+
+    @property
+    def state_slots_in_use(self) -> int:
+        with self._lock:
+            return len(self._seq_state)
+
+    def state_rows(self, seq_ids) -> np.ndarray:
+        """Each sequence's slot of the state pool, (len(seq_ids),) int32;
+        the scratch slot for an id that holds none (a bucket's spare row)."""
+        with self._lock:
+            return np.asarray(
+                [self._seq_state.get(sid, SCRATCH_BLOCK) for sid in seq_ids],
+                np.int32)
 
     def seq_block_ids(self, seq_id: int) -> list[int]:
         with self._lock:
@@ -183,10 +211,26 @@ class PagedKVCache:
                     raise OutOfBlocks(
                         1, 0, self.cfg.usable_blocks
                     )
+                self._take_state(seq_id)
                 blocks.append(self._free.pop())
                 self.alloc_total += 1
             if pos + 1 > self._seq_used.get(seq_id, 0):
                 self._seq_used[seq_id] = pos + 1
+
+    def _take_state(self, seq_id: int) -> None:
+        """A state slot for a sequence about to take a block, if the pool
+        has state slots and it holds none yet (under the lock). Out of
+        slots is out of blocks: the same backpressure."""
+        if not self.cfg.state_slots or seq_id in self._seq_state:
+            return
+        if not self._free_states:
+            raise OutOfBlocks(1, 0, self.cfg.usable_blocks)
+        self._seq_state[seq_id] = self._free_states.pop()
+
+    def _drop_state(self, seq_id: int) -> None:
+        slot = self._seq_state.pop(seq_id, None)
+        if slot is not None:
+            self._free_states.append(slot)
 
     def ensure_range(self, seq_id: int, end_pos: int) -> None:
         """`ensure` every position up to ``end_pos`` inclusive (the
@@ -206,6 +250,8 @@ class PagedKVCache:
                 raise OutOfBlocks(
                     missing, len(self._free), self.cfg.usable_blocks
                 )
+            if missing > 0:
+                self._take_state(seq_id)
             for _ in range(max(missing, 0)):
                 blocks.append(self._free.pop())
                 self.alloc_total += 1
@@ -252,6 +298,7 @@ class PagedKVCache:
                 self._seq_used.pop(seq_id, None)
                 if not blocks:
                     self._seq_blocks.pop(seq_id, None)
+                    self._drop_state(seq_id)
             return freed
 
     def free(self, seq_id: int) -> int:
@@ -261,6 +308,7 @@ class PagedKVCache:
         with self._lock:
             blocks = self._seq_blocks.pop(seq_id, [])
             self._seq_used.pop(seq_id, None)
+            self._drop_state(seq_id)
             # append in allocation order so pop() (the next alloc) hands
             # back the most recently written block first (LIFO)
             self._free.extend(blocks)

@@ -396,6 +396,18 @@ class ServeScheduler:
             "owned": moe_rows.labels(kind="owned"),
             "multiplied": moe_rows.labels(kind="multiplied"),
         }
+        moe_experts = r.counter(
+            "serve_moe_experts_total",
+            "Held experts over every expert layer of every program "
+            "dispatched: read (own a routed row) and held",
+        )
+        self._m_moe_experts = {k: moe_experts.labels(kind=k)
+                               for k in ("read", "held")}
+        self._m_state_slots = r.gauge(
+            "serve_state_slots_in_use",
+            "Slots of the state pool held by sequences (a model whose "
+            "layers keep a state a sequence beside the KV cache)",
+        )
         self._m_moe_load = r.gauge(
             "serve_moe_expert_load_max_over_mean",
             "Busiest held expert's pairs over the mean, last tick, by "
@@ -878,6 +890,8 @@ class ServeScheduler:
             self._m_moe["absent"].inc(moe["absent"])
             self._m_moe["owned"].inc(moe["held"])
             self._m_moe["multiplied"].inc(moe["multiplied"])
+            self._m_moe_experts["read"].inc(moe["experts_read"])
+            self._m_moe_experts["held"].inc(moe["experts_held"])
             for layer, load in enumerate(moe["load"]):
                 if load.sum():
                     self._m_moe_load.labels(layer=str(layer)).set(
@@ -922,6 +936,7 @@ class ServeScheduler:
             self.ledger.add("kv_alloc_stall", t0, t1)
         self._m_active.set(len(eng.active))
         self._m_kv_used.set(kv.blocks_in_use)
+        self._m_state_slots.set(kv.state_slots_in_use)
         self._m_kv_bytes_used.set(
             kv.blocks_in_use * self._kv_block_bytes
         )

@@ -59,7 +59,7 @@ def test_reader_is_the_share_of_ticks_dispatched_ahead(reader, ahead,
     assert reader.read(obs) == pytest.approx(want, rel=1e-12)
 
 
-def test_the_benchmark_names_the_reader_for_both_serving_cells():
+def test_the_benchmark_names_the_reader_for_every_serving_cell():
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
         entry = next(m for m in json.load(f)["per_layer"]
                      if m["name"] == NAME)
@@ -68,5 +68,6 @@ def test_the_benchmark_names_the_reader_for_both_serving_cells():
         "source": "program_counter", "layer": "engine",
         "moves": "serve_tokens_per_s",
         "workloads": ["cerebras-gpt-1.3b.serve-longdoc",
-                      "openpangu-ultra-moe-718b.serve-docqa-6k"],
+                      "openpangu-ultra-moe-718b.serve-docqa-6k",
+                      "lfm2-24b-a2b.serve-reason-1k"],
     }

@@ -24,6 +24,8 @@ from jax.sharding import SingleDeviceSharding
 from distributed_neural_network_tpu.ops.decode_pallas import (
     decode_cache_attention,
     decode_paged_attention,
+    gqa_decode_attention,
+    gqa_decode_ok,
     mla_decode_attention,
     mla_decode_ok,
     mla_prefill_attention,
@@ -140,6 +142,23 @@ def _mla_decode(topo, batch, width, *, dtype=jnp.bfloat16):
     ])
 
 
+def _gqa_decode(topo, batch, width, *, dtype=jnp.bfloat16):
+    # the reason-1k cell: 2 attention layers, 6,145 blocks of 64 rows of
+    # [k ; v] of 8 KV heads of 64, 32 query heads, tables of 128 blocks
+    bs, kv, per, dh = 64, 8, 4, 64
+    assert gqa_decode_ok(bs, kv, per, dh, dtype)
+    i32 = jnp.int32
+
+    def fn(q, pool, layer, table, pos):
+        return gqa_decode_attention(q, pool, layer[0], table, pos,
+                                    block_size=bs, n_kv_heads=kv)
+
+    return fn, _on_one_chip(topo, [
+        ((batch, kv * per, dh), dtype), ((2, 6145 * bs, kv * 2 * dh), dtype),
+        ((1,), i32), ((batch, width), i32), ((batch,), i32),
+    ])
+
+
 def _mla_prefill(topo, chunk):
     # the docqa cell's prefill attention: a chunk's 128 heads over the
     # latent pool, a layer's `kv_b` as the tree holds it
@@ -218,6 +237,10 @@ CASES = {
     "mla_decode_bf16_b32_w256": lambda t: _mla_decode(t, 32, 256),
     "mla_decode_bf16_b1_w256": lambda t: _mla_decode(t, 1, 256),
     "mla_decode_f32_b4_w4": lambda t: _mla_decode(t, 4, 4,
+                                                  dtype=jnp.float32),
+    "gqa_decode_bf16_b64_w128": lambda t: _gqa_decode(t, 64, 128),
+    "gqa_decode_bf16_b1_w128": lambda t: _gqa_decode(t, 1, 128),
+    "gqa_decode_f32_b4_w4": lambda t: _gqa_decode(t, 4, 4,
                                                   dtype=jnp.float32),
     "decode_paged_bf16_b16_w128": lambda t: _decode_paged(t, 16, 128),
     "decode_paged_bf16_b1_w1": lambda t: _decode_paged(t, 1, 1),
