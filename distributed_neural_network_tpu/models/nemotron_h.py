@@ -40,6 +40,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..ops.flash import grouped_query_flash_attention
+from ..ops.flash_pallas import block_remat_policy
 from ..ops.ssd import ssd_scan
 from ..parallel.moe import moe_held_ffn
 
@@ -299,9 +300,9 @@ def apply_hidden(params, tokens, cfg: NemotronHConfig, *,
         return x + y, stats
 
     if cfg.remat:
-        policy = (getattr(jax.checkpoint_policies, cfg.remat_policy)
-                  if cfg.remat_policy else None)
-        block = jax.checkpoint(block, policy=policy, static_argnums=(2,))
+        block = jax.checkpoint(
+            block, policy=block_remat_policy(cfg.remat_policy),
+            static_argnums=(2,))
     seen = dict.fromkeys(KINDS, 0)
     routing = []
     for letter in cfg.pattern:

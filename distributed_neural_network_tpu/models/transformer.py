@@ -44,6 +44,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..ops.decode_pallas import decode_cache_attention, decode_kernel_ok
+from ..ops.flash_pallas import block_remat_policy
 from ..parallel.moe import expert_capacity, moe_ffn
 from ..parallel.ring import (
     attention,
@@ -93,7 +94,8 @@ class TransformerConfig:
     # full remat's ~1/3, while still dropping the non-dot intermediates
     # that OOM the 16 GB chip at d1024/b8 no-remat (measured r5:
     # AllocateBuffer on 512 MB stacked-scan temps). The canonical TPU
-    # memory/FLOP trade between "none" and "full".
+    # memory/FLOP trade between "none" and "full". A dots-saving policy
+    # keeps the flash kernel's output too (`block_remat_policy`).
     remat_policy: str = ""
     # rematerialize ONLY the attention inner call (scores/softmax/values):
     # the (B, H, S, S) score tensor - the piece that actually OOMs at long
@@ -436,9 +438,8 @@ def _blocks(params, tokens, cfg: TransformerConfig, *, seq_axis=None,
                                  ep_axis=ep_axis, capacity=cap)
 
     if cfg.remat:
-        policy = (getattr(jax.checkpoint_policies, cfg.remat_policy)
-                  if cfg.remat_policy else None)
-        block = jax.checkpoint(block, policy=policy)
+        block = jax.checkpoint(
+            block, policy=block_remat_policy(cfg.remat_policy))
     x, aux = jax.lax.scan(block, x, params["layers"])
     return x, aux.mean()
 

@@ -74,11 +74,16 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..parallel.collectives import vma_union
 from .quant import QUANT_FORMATS, quantize
+
+# checkpoint names of the forward kernel's two products among the
+# residuals (o, lse): what `block_remat_policy` lets a block keep
+FLASH_SAVED = ("flash_out", "flash_lse")
 
 _NEG_BIG = -1e30  # large-negative mask; avoids -inf NaN propagation
 _LANES = 128  # TPU lane width: per-row residuals are lane-replicated
@@ -591,6 +596,7 @@ def _flash(q, k, v, causal, scale, blocks, interpret, quant):
 def _flash_fwd(q, k, v, causal, scale, blocks, interpret, quant):
     o, lse = _any_fwd_call(q, k, v, blocks=blocks, scale=scale,
                            causal=causal, interpret=interpret, quant=quant)
+    o, lse = map(checkpoint_name, (o, lse), FLASH_SAVED)
     return o, (q, k, v, o, lse)
 
 
@@ -603,6 +609,24 @@ def _flash_bwd(causal, scale, blocks, interpret, quant, res, g):
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def block_remat_policy(name: str):
+    """`jax.checkpoint` policy for a block from a `remat_policy` name
+    ("" = save nothing). A policy that keeps matmul results also keeps the
+    forward kernel's (`FLASH_SAVED`): they are the attention's matmul
+    results, but come out of a `pallas_call`, which no dots policy sees -
+    without the names the backward pass runs `flash_fwd` a second time.
+    Every other policy is returned as named."""
+    if not name:
+        return None
+    policies = jax.checkpoint_policies
+    policy = getattr(policies, name)
+    if policy in (policies.dots_saveable,
+                  policies.dots_with_no_batch_dims_saveable):
+        policy = policies.save_from_both_policies(
+            policy, policies.save_only_these_names(*FLASH_SAVED))
+    return policy
 
 
 def flash_mha(q, k, v, *, causal: bool = True, scale=None,

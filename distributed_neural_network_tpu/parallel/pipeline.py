@@ -52,6 +52,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import compat
 from ..models import transformer as tfm
+from ..ops.flash_pallas import block_remat_policy
 from ..ops.sgd import sgd_step
 from .collectives import vary_like
 
@@ -191,9 +192,8 @@ def pipeline_lm_loss(
             return x, aux
 
         if cfg.remat:
-            policy = (getattr(jax.checkpoint_policies, cfg.remat_policy)
-                      if cfg.remat_policy else None)
-            block = jax.checkpoint(block, policy=policy)
+            block = jax.checkpoint(
+                block, policy=block_remat_policy(cfg.remat_policy))
         x, auxes = jax.lax.scan(block, x, layers)
         return x, jnp.sum(auxes)
 

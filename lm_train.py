@@ -126,9 +126,10 @@ def main(argv=None) -> int:
                    "~1/3 more FLOPs for far less activation memory")
     p.add_argument("--remat-policy", default="",
                    help="jax.checkpoint_policies name applied with --remat "
-                   "(e.g. dots_saveable: store matmul outputs, recompute "
-                   "only elementwise - a few percent FLOP tax instead of "
-                   "full remat's ~1/3); '' = save nothing")
+                   "(e.g. dots_saveable: store matmul outputs and the "
+                   "flash kernel's, recompute only elementwise - a few "
+                   "percent FLOP tax instead of full remat's ~1/3); '' = "
+                   "save nothing")
     p.add_argument("--remat-attn", action="store_true",
                    help="rematerialize ONLY the attention scores/softmax in "
                    "backward: avoids storing the (B,H,S,S) tensor for a few "

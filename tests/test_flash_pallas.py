@@ -17,8 +17,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_neural_network_tpu.analysis.trace import _sub_jaxprs
 from distributed_neural_network_tpu.ops.flash_pallas import (
     FlashBlocks,
+    block_remat_policy,
     flash_mha,
 )
 from distributed_neural_network_tpu.parallel.ring import attention
@@ -186,3 +188,65 @@ def test_tuned_blocks_file_matching(tmp_path, monkeypatch):
         assert flash.tuned_blocks(3000, 64) == FlashBlocks()
     finally:
         flash.tuned_blocks.cache_clear()
+
+
+def _kernel_calls(jaxpr, counts=None):
+    """{kernel name: pallas_call equations} over a jaxpr and all it nests."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            counts[name] = counts.get(name, 0) + 1
+            continue
+        for sub, _ in _sub_jaxprs(eqn):
+            _kernel_calls(sub, counts)
+    return counts
+
+
+@pytest.mark.parametrize(
+    "policy,quant,fwd_calls",
+    [
+        # a dots-saving policy keeps the forward kernel's (o, lse) too:
+        # one forward a block, none in the backward pass
+        ("dots_saveable", None, 2),
+        ("dots_with_no_batch_dims_saveable", None, 2),
+        ("dots_saveable", "int8", 2),
+        # full recomputation keeps nothing (the hybrid cell's memory
+        # contract): the backward pass runs the forward kernel again
+        ("", None, 4),
+        ("nothing_saveable", None, 4),
+    ],
+)
+def test_remat_policy_keeps_forward_kernel_output(n_devices, policy, quant,
+                                                  fwd_calls):
+    """Two checkpointed blocks of projection + flash_mha + projection: how
+    often the gradient runs the forward kernel follows from the policy's
+    name alone (`block_remat_policy`), and a kept value is the recomputed
+    one - the gradient equals the un-checkpointed one bit for bit."""
+    b, s, h, d = 1, 128, 2, 64
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(b, s, h * d)) * 0.3, jnp.float32)
+    ws = [tuple(jnp.asarray(rng.normal(size=shape) * 0.05, jnp.float32)
+                for shape in ((h * d, 3 * h * d), (h * d, h * d)))
+          for _ in range(2)]
+    blocks = FlashBlocks(64, 64, 64, 64, 64, 64)
+
+    def block(x, w):
+        q, k, v = jnp.moveaxis((x @ w[0]).reshape(b, s, 3, h, d), 2, 0)
+        o = flash_mha(q, k, v, blocks=blocks, interpret=True, quant=quant)
+        return x + o.reshape(b, s, h * d) @ w[1]
+
+    def grad_of(block):
+        def loss(x, ws):
+            for w in ws:
+                x = block(x, w)
+            return jnp.sum(x * x)
+        return jax.grad(loss, argnums=(0, 1))
+
+    kept = grad_of(jax.checkpoint(block, policy=block_remat_policy(policy)))
+    calls = _kernel_calls(jax.make_jaxpr(kept)(x, ws).jaxpr)
+    fwd = "flash_fwd_quant" if quant else "flash_fwd"
+    assert calls == {fwd: fwd_calls, "flash_bwd_dq": 2, "flash_bwd_dkv": 2}
+    for a, e in zip(jax.tree.leaves(kept(x, ws)),
+                    jax.tree.leaves(grad_of(block)(x, ws))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(e))

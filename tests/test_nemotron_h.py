@@ -23,6 +23,9 @@ finally:
     sys.path.remove(BENCH)
 
 from distributed_neural_network_tpu.models import nemotron_h as nh  # noqa: E402
+from distributed_neural_network_tpu.ops.flash_pallas import (  # noqa: E402
+    block_remat_policy,
+)
 from distributed_neural_network_tpu.ops.ssd import ssd_scan  # noqa: E402
 from distributed_neural_network_tpu.parallel import moe  # noqa: E402
 from distributed_neural_network_tpu.train import lm  # noqa: E402
@@ -123,20 +126,29 @@ def program_loss_and_grads(params, tok, tgt, cfg):
     return jax.value_and_grad(loss)(params)
 
 
-@pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
-    (jnp.float32, 2e-6, 2e-5),
+@pytest.mark.parametrize("dtype,loss_tol,grad_tol,remat_policy", [
+    (jnp.float32, 2e-6, 2e-5, ""),
     # bfloat16 compute against the float32 reference: rounding of every
     # activation to 2^-9; at these tiny widths the worst leaf's gradient
     # differs by 2-3 % of its norm (the cell's own limit is set on the chip)
-    (jnp.bfloat16, 2e-3, 6e-2),
-], ids=["float32", "bfloat16"])
+    (jnp.bfloat16, 2e-3, 6e-2, ""),
+    # a named policy changes what a block keeps, never the numbers
+    (jnp.float32, 2e-6, 2e-5, "dots_saveable"),
+], ids=["float32", "bfloat16", "float32-dots_saveable"])
 def test_loss_and_gradients_equal_the_familys_reference(
-        family, model, dtype, loss_tol, grad_tol):
-    params, ref = family.reference.loss_and_grads(5, model, TRAFFIC)
+        family, model, monkeypatch, dtype, loss_tol, grad_tol, remat_policy):
+    traffic = dict(TRAFFIC, remat_policy=remat_policy)
+    params, ref = family.reference.loss_and_grads(5, model, traffic)
     tok, tgt = batch_fn()(0)
     want_loss, want = ref(params, tok, tgt)
-    cfg = family.program.config(model, TRAFFIC, dtype)
+    cfg = family.program.config(model, traffic, dtype)
+    # the blocks' policy comes from the one helper, by the traffic's name
+    asked = []
+    monkeypatch.setattr(
+        nh, "block_remat_policy",
+        lambda name: asked.append(name) or block_remat_policy(name))
     got_loss, got = program_loss_and_grads(params, tok, tgt, cfg)
+    assert asked == [remat_policy]
     assert abs(float(got_loss) - float(want_loss)) <= loss_tol * float(
         want_loss)
     flat_want = dict(jax.tree.leaves_with_path(want))

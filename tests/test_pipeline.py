@@ -16,6 +16,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from distributed_neural_network_tpu.models import transformer as tfm
+from distributed_neural_network_tpu.ops.flash_pallas import block_remat_policy
 from distributed_neural_network_tpu.parallel import pipeline as pp
 from distributed_neural_network_tpu.train import lm as lmtrain
 
@@ -351,10 +352,12 @@ def test_deep_interleave_pp2(n_devices, v, m):
 
 
 @pytest.mark.parametrize("remat_policy", ["", "dots_saveable"])
-def test_interleave_with_remat_matches(n_devices, remat_policy):
+def test_interleave_with_remat_matches(n_devices, monkeypatch, remat_policy):
     """Block remat inside the lap-indexed chunk scan: same loss. The
     dots_saveable parametrization pins that remat_policy reaches the
-    pipeline path too (r5 review: it was silently dropped there)."""
+    pipeline path too (r5 review: it was silently dropped there), through
+    the one helper, whose policy gives the loss and the gradients that
+    jax's own policy of that name gives."""
     import dataclasses
 
     cfg = dataclasses.replace(CFG8, remat=True, remat_policy=remat_policy)
@@ -366,9 +369,14 @@ def test_interleave_with_remat_matches(n_devices, remat_policy):
         seq_axis=None, tp_axis=None, attn_impl="full", axes=(),
     ))
     sharded, specs = pp.shard_pp_params(params, cfg, mesh, interleave=2)
-    got = float(
-        jax.jit(
-            jax.shard_map(
+
+    def loss_and_grads(policy_of):
+        asked = []
+        monkeypatch.setattr(
+            pp, "block_remat_policy",
+            lambda name: asked.append(name) or policy_of(name))
+        loss, grads = jax.jit(
+            jax.value_and_grad(jax.shard_map(
                 lambda p, tok, tgt: pp.pipeline_lm_loss(
                     p, tok, tgt, cfg,
                     n_microbatches=4, tp_axis=None,
@@ -377,10 +385,22 @@ def test_interleave_with_remat_matches(n_devices, remat_policy):
                 mesh=mesh,
                 in_specs=(specs, P(pp.DATA_AXIS), P(pp.DATA_AXIS)),
                 out_specs=P(),
-            )
+            ))
         )(sharded, tokens, targets)
-    )
+        assert set(asked) == {remat_policy}
+        return float(loss), grads
+
+    got, grads = loss_and_grads(block_remat_policy)
     assert np.isclose(got, want, rtol=2e-5), (got, want)
+    if not remat_policy:
+        return  # no policy either way: the same program
+    # what the blocks' policy was before the helper: jax's own of that name
+    parent, parent_grads = loss_and_grads(
+        lambda name: getattr(jax.checkpoint_policies, name))
+    assert np.isclose(got, parent, rtol=2e-5), (got, parent)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(parent_grads)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-5, atol=1e-6)
 
 
 def test_pp_adam_learns(n_devices):

@@ -266,6 +266,45 @@ def test_kernel_compiles_for_v5e(topo, case):
     )
 
 
+@pytest.mark.parametrize("remat_policy,calls", [
+    ("dots_saveable", 3), ("", 4),
+])
+def test_train_step_runs_the_forward_kernel_once_under_dots_saveable(
+        topo, monkeypatch, remat_policy, calls):
+    """A two-layer GPT-2 training step at `gpt2-medium.pretrain-1k`'s
+    widths (16 heads of 64, 8 x 1024 tokens, Adam, bfloat16) on the
+    described chip: under `dots_saveable` the layer loops hold three Mosaic
+    call sites (forward, dQ, dK/dV) - the blocks keep the forward kernel's
+    output with their matmuls' (`block_remat_policy`) - and under full
+    recomputation four, the forward kernel again in the backward loop. The
+    dispatch asks the runtime whether it is on a TPU: here the test answers
+    for it."""
+    from distributed_neural_network_tpu.models import transformer as tfm
+    from distributed_neural_network_tpu.ops import flash as flash_mod
+    from distributed_neural_network_tpu.train import lm
+
+    monkeypatch.setattr(flash_mod, "on_tpu", lambda: True)
+    cfg = tfm.TransformerConfig(
+        vocab_size=50257, d_model=1024, n_heads=16, n_layers=2, d_ff=4096,
+        dtype=jnp.bfloat16, remat=True, remat_policy=remat_policy,
+    )
+    mesh = Mesh([[[topo.devices[0]]]],
+                (lm.DATA_AXIS, lm.SEQ_AXIS, lm.TP_AXIS))
+    step = lm.make_lm_train_step(cfg, mesh, lr=3e-4, attn_impl="flash",
+                                 optimizer="adam")
+    state = jax.tree.map(
+        lambda x, sharding: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                 sharding=sharding),
+        lm.abstract_lm_state(cfg, mesh, "adam"),
+        lm.make_lm_shardings(cfg, mesh, "adam")[1:],
+    )
+    tok = jax.ShapeDtypeStruct(
+        (8, 1024), jnp.int32,
+        sharding=NamedSharding(mesh, P(lm.DATA_AXIS, lm.SEQ_AXIS)))
+    compiled = step.lower(*state, tok, tok).compile()
+    assert mosaic_custom_calls(compiled) == calls
+
+
 @pytest.mark.parametrize("family,n", [
     ("decode", 1), ("decode", 2), ("prefill", 1), ("prefill", 8),
 ])

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from distributed_neural_network_tpu.models import transformer as tfm
+from distributed_neural_network_tpu.ops.flash_pallas import block_remat_policy
 from distributed_neural_network_tpu.ops.sgd import init_momentum
 from distributed_neural_network_tpu.train import lm
 
@@ -185,6 +186,43 @@ def test_remat_matches_no_remat(n_devices):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
             )
+
+
+@pytest.mark.parametrize(
+    "policy", ["dots_saveable", "dots_with_no_batch_dims_saveable",
+               "nothing_saveable"])
+def test_remat_policy_comes_from_the_helper(n_devices, monkeypatch, policy):
+    """The blocks' checkpoint policy is `block_remat_policy` of the
+    configuration's name, and it changes what a block keeps, never the
+    numbers: loss and gradients are what jax's own policy of that name gave
+    (the step before the helper), at test_remat_matches_no_remat's
+    tolerances."""
+    cfg = tfm.TransformerConfig(vocab_size=32, d_model=32, n_heads=4,
+                                n_layers=2, d_ff=64, remat=True,
+                                remat_policy=policy)
+    tokens, targets = lm.make_copy_task(
+        jax.random.key(1), batch=4, seq_len=16, vocab=32)
+    params = tfm.init_params(jax.random.key(0), cfg)
+
+    def loss_and_grads(policy_of):
+        asked = []
+        monkeypatch.setattr(
+            tfm, "block_remat_policy",
+            lambda name: asked.append(name) or policy_of(name))
+        loss, grads = jax.value_and_grad(lambda p: lm.lm_loss(
+            p, tokens, targets, cfg,
+            seq_axis=None, tp_axis=None, attn_impl="flash", axes=(),
+        ))(params)
+        assert asked == [policy]
+        return float(loss), grads
+
+    got, grads = loss_and_grads(block_remat_policy)
+    parent, parent_grads = loss_and_grads(
+        lambda name: getattr(jax.checkpoint_policies, name))
+    assert np.isclose(got, parent, rtol=1e-6), (got, parent)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(parent_grads)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.slow
