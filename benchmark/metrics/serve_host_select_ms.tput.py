@@ -1,0 +1,14 @@
+"""Mean milliseconds a tick of the untraced tail that the engine's host
+phases (`prefill_host`, `decode_host`) spend in their part `select` (the
+`serve.select` span): the chunks and the decode batch with their blocks
+(the prefill loop's choice with `kv.ensure_range`, `_select` with
+`kv.ensure`):
+`serve_host_seconds_total{part="select"}` over `serve_engine_steps_total`.
+A program that does not publish the family reads None.
+
+The reader of the three serving cells (moves serve_tokens_per_s)."""
+from lib import untraced
+
+
+def read(obs):
+    return untraced.ms_a_tick(obs, 'serve_host_seconds_total{part="select"}')

@@ -147,9 +147,54 @@ _MOE_TILE_SMALL, _MOE_TILE = 16, 128
 # scores (models/pangu_ultra_moe.py `prefill_attention`)
 _PREFILL_KEY_BLOCK = 1024
 
-# the phases that partition `ServeEngine.step` on the host's clock; each
-# is also a `serve.<phase>` span in a profile (docs/SERVING.md "Metrics")
-STEP_PHASES = ("prefill_host", "decode_host", "fetch", "emit", "spec")
+# the phases that partition `ServeEngine.step`, and the parts of its two
+# host phases (`prefill_host`, `decode_host`) that build a tick and hand it
+# to the device (docs/SERVING.md "Where a tick goes")
+STEP_PHASES = ("prefill_host", "decode_host", "fetch", "emit", "spec",
+               "release")
+HOST_PARTS = ("select", "stage", "dispatch")
+
+
+class Phases:
+    """The time of one thread by phase, on one clock, each phase also a
+    ``serve.<name>`` `TraceAnnotation` (inert while no profile is taken).
+    `to` reads the clock once: the phase under way ends there, its span
+    closes, and the next one's opens. So a profile's spans and the seconds
+    in ``s`` are the same intervals, and consecutive `to` calls leave no
+    instant between their phases out."""
+
+    def __init__(self, names: tuple, clock=time.monotonic):
+        self.names, self.clock = names, clock
+        self.s = dict.fromkeys(names, 0.0)
+        self.open = None        # (name, span) of the phase under way
+        self.t = 0.0            # when it began
+
+    def to(self, name: str | None) -> float:
+        """End the phase under way now and begin ``name`` (None: none);
+        returns now."""
+        t = self.clock()
+        if self.open is not None:
+            self.s[self.open[0]] += t - self.t
+            self.open[1].__exit__(None, None, None)
+            self.open = None
+        self.t = t
+        if name is not None:
+            span = TraceAnnotation("serve." + name)
+            span.__enter__()
+            self.open = (name, span)
+        return t
+
+    def take(self) -> dict:
+        """The seconds counted up to now; the count starts again, the phase
+        under way going on in it."""
+        fresh = dict.fromkeys(self.names, 0.0)
+        if self.open is not None:
+            t = self.clock()
+            self.s[self.open[0]] += t - self.t
+            self.t = t
+        s, self.s = self.s, fresh
+        return s
+
 
 # the weight matrices --precision int8-w stores quantized (per-column
 # int8 codes + per-column f32 scales, ops/quant.py prequantize_weight);
@@ -793,7 +838,8 @@ class ServeEngine:
     loop) drives `step()` / `flush()`; admission/cancel mutate the
     active set under `lock` between ticks."""
 
-    def __init__(self, params, cfg, ecfg: EngineConfig):
+    def __init__(self, params, cfg, ecfg: EngineConfig, *,
+                 clock=time.monotonic):
         # what the module keeps decides which pools and programs are built,
         # here and never inside one: per-head K and V (`TransformerConfig`),
         # one latent row (`CACHE = "latent"`), or a KV row in the attention
@@ -943,9 +989,11 @@ class ServeEngine:
         self.program_temp_bytes: dict = {}
         # the tick dispatched and not yet fetched (`step`)
         self._inflight: _Tick | None = None
-        # the call's seconds by phase and its last reading of the clock
-        self._phase_s = dict.fromkeys(STEP_PHASES, 0.0)
-        self._t_mark = 0.0
+        # a call's seconds by phase, and its host phases' by part, on the
+        # clock the scheduler's phases and ledger read too
+        self.clock = clock
+        self.phase = Phases(STEP_PHASES, clock)
+        self.part = Phases(HOST_PARTS, clock)
         self.ticks = 0
         self.decode_tokens = 0
         self.prefill_tokens = 0
@@ -1842,12 +1890,12 @@ class ServeEngine:
                 dpos[row] = batch[idx].pos
             dtable = self._table([batch[i] for i in need_draft], Bd, W)
             fn = self._draft_fn(Bd, W)
-            t0 = time.perf_counter()
+            t0 = self.clock()
             out_d = np.asarray(fn(       # asarray = device sync
                 self.draft_params, *self._pools(),
                 jnp.asarray(dtok), jnp.asarray(dpos), jnp.asarray(dtable),
             ))
-            draft_s = time.perf_counter() - t0
+            draft_s = self.clock() - t0
             for row, idx in enumerate(need_draft):
                 drafts[idx] = out_d[row]
 
@@ -1861,10 +1909,10 @@ class ServeEngine:
         table = self._table(batch, B, W)
         fn = self._verify_fn(B, W)
         tail = (jnp.asarray(toks), jnp.asarray(pos0), jnp.asarray(table))
-        t0 = time.perf_counter()
+        t0 = self.clock()
         (nxt,) = self._run_writer(fn, *tail)
         nxt = np.asarray(nxt)
-        verify_s = time.perf_counter() - t0
+        verify_s = self.clock() - t0
 
         sp = stats["spec"] = {
             "proposed": 0, "accepted": 0, "steps": 1,
@@ -1911,18 +1959,6 @@ class ServeEngine:
             if not s.finished:
                 self._rewind_seq(s.seq_id, s.pos)
 
-    def _start_clock(self) -> None:
-        """A call of `step` or `flush` begins: its seconds by phase."""
-        self._phase_s = dict.fromkeys(STEP_PHASES, 0.0)
-        self._t_mark = time.perf_counter()
-
-    def _lap(self, phase: str) -> None:
-        """Everything since the last reading of the clock belongs to
-        ``phase`` of the call under way."""
-        now = time.perf_counter()
-        self._phase_s[phase] += now - self._t_mark
-        self._t_mark = now
-
     def step(self) -> dict:
         """One call of the serve loop: dispatch the NEXT tick's programs,
         then fetch the tick in flight's tokens, hand them out and return
@@ -1954,29 +1990,36 @@ class ServeEngine:
 
         Returns the landed tick's stats for the scheduler's ledger/
         metrics: ``{"decode_tokens", "prefill_tokens", "finished",
-        "parked", "batch", "phase_s", "decode_call", "prefill_calls",
-        "dispatch"}``. ``dispatch`` is "ahead" for a tick whose programs
-        were dispatched before the tick before's tokens were fetched,
-        "drained" for one dispatched with nothing in flight, None for one
-        that dispatched nothing.
+        "parked", "batch", "phase_s", "host_s", "decode_call",
+        "prefill_calls", "dispatch", "found"}``. ``dispatch`` is "ahead"
+        for a tick whose programs were dispatched before the tick before's
+        tokens were fetched, "drained" for one dispatched with nothing in
+        flight, None for one that dispatched nothing. ``found`` lists
+        ``(program, device)`` for each of the tick's bucket programs
+        (`_call`): "prefill" or "decode", and "idle" where the device had
+        finished all it was handed when the host called the program, so
+        that it waited on the host, "busy" where it had not.
 
-        ``phase_s`` partitions THIS CALL on ``time.perf_counter``, one
-        key per `STEP_PHASES` entry and every instant from entry to
-        return in exactly one: ``prefill_host`` (the chunked-prefill
-        loop: blocks, arrays, transfers and each `_prefill_fn` dispatch
-        up to its return) and ``decode_host`` (batch selection to the
-        return of `_decode_fn`'s dispatch, `_feed_tokens`' with it), both
-        of the tick dispatched here; ``fetch`` (``np.asarray(nxt)`` of the
-        tick landed here: the host blocked until the PREVIOUS dispatch's
-        programs have finished, which have had a whole host lap to do
-        so), ``emit`` (the per-sequence loop after the fetch with its
-        `on_token` callbacks, and retiring) and ``spec`` (`_spec_step`
-        whole). Each is also a ``serve.<phase>`` `TraceAnnotation`, inert
-        while no profile is taken. Dispatch is asynchronous, so the host
-        can time the wait for the prefill and the decode program together
-        (``fetch``) and not each apart: that is why the scheduler's split
-        of the step between the ledger's "prefill" and "decode" by token
-        counts stays an apportioning.
+        ``phase_s`` partitions THIS CALL, one key per `STEP_PHASES`
+        entry, each a `Phases` interval (a ``serve.<phase>`` span and its
+        seconds) and every instant from entry to return in one of them:
+        ``prefill_host`` (the chunked-prefill loop) and ``decode_host``
+        (the decode batch), both of the tick dispatched here; ``fetch``
+        (``np.asarray(nxt)`` of the tick landed here: the host blocked
+        until the PREVIOUS dispatch's programs have finished, which have
+        had a whole host lap to do so), ``emit`` (the per-sequence loop
+        after the fetch with its `on_token` callbacks, and retiring),
+        ``spec`` (`_spec_step` whole) and ``release`` (the landed tick's
+        arrays on the device let go, the call's last phase). ``host_s`` splits the two host
+        phases by `HOST_PARTS`, spans nested in theirs: ``select`` (the
+        batch and the chunks, their blocks), ``stage`` (host arrays, the
+        table, the transfers, `_state_slots`, `_row_keys`, `_feed_tokens`)
+        and ``dispatch`` (each bucket program's call up to its return).
+        Dispatch is asynchronous, so the host can time the wait for the
+        prefill and the decode program together (``fetch``) and not each
+        apart: that is why the scheduler's split of the step between the
+        ledger's "prefill" and "decode" by token counts stays an
+        apportioning.
 
         ``decode_call`` is ``(B, W, live, read)`` for the tick's decode
         dispatch, None without one: the batch and width-in-blocks
@@ -2004,7 +2047,6 @@ class ServeEngine:
         drafts per slot, the acceptance-histogram input); a latent
         engine's carry ``moe``, the routing counts of the programs the
         tick itself dispatched (`_moe_stats`)."""
-        self._start_clock()
         tick, self._inflight = self._inflight, None
         if tick is None:
             tick = self._dispatch(None)
@@ -2021,10 +2063,7 @@ class ServeEngine:
         before it exports them (`export_descriptor`). Returns that tick's
         stats (`step`), None with nothing in flight."""
         tick, self._inflight = self._inflight, None
-        if tick is None:
-            return None
-        self._start_clock()
-        return self._land(tick)
+        return None if tick is None else self._land(tick)
 
     def _select(self, todo: list, held: list) -> tuple:
         """This tick's decode rows among ``todo``: (plain batch,
@@ -2059,12 +2098,31 @@ class ServeEngine:
             batch.append(seq)
         return batch[:ecfg.max_batch], spec_batch, parked
 
+    def _call(self, program: str, fn, tail: tuple, stats: dict) -> tuple:
+        """`_run_writer` of one of a tick's bucket programs, as the host
+        part ``dispatch``. Just before the call it asks, without blocking,
+        whether the pool the program takes, the output of the bucket
+        program dispatched before it, is ready: then that program had
+        finished and the device waited on the host for this one (but for
+        the tick's small programs and transfers, just handed to it). Noted
+        in ``stats["found"]`` as ``(program, "idle" or "busy")``."""
+        self.part.to("dispatch")
+        idle = getattr(self, self._cache.pools[0]).is_ready()
+        out = self._run_writer(fn, *tail)
+        stats["found"].append((program, "idle" if idle else "busy"))
+        return out
+
     def _dispatch(self, prev: _Tick | None) -> _Tick:
         """Build one tick and hand its programs to the device, fetching
-        nothing: the chunked-prefill phase, then the decode batch.
-        ``prev`` is the tick in flight, None where there is none: a row
-        whose input token is ``prev``'s to give takes it on the device
-        (`_feed_tokens`). Advances ``seq.pos`` of every row and chunk."""
+        nothing: the chunked-prefill phase, then the decode batch, each in
+        the host parts ``select``, ``stage`` and ``dispatch``. ``prev`` is
+        the tick in flight, None where there is none: a row whose input
+        token is ``prev``'s to give takes it on the device
+        (`_feed_tokens`). Advances ``seq.pos`` of every row and chunk.
+        Leaves ``decode_host`` under way."""
+        phase, part = self.phase, self.part
+        phase.to("prefill_host")
+        part.to("select")
         ecfg = self.ecfg
         bs = self.kv.cfg.block_size
         with self.lock:
@@ -2073,183 +2131,188 @@ class ServeEngine:
         tick = _Tick({"decode_tokens": 0, "prefill_tokens": 0,
                       "finished": 0, "parked": 0, "batch": 0, "per_seq": {},
                       "preempted": [], "decode_call": None,
-                      "prefill_calls": [], "dispatch": None})
+                      "prefill_calls": [], "dispatch": None, "found": []})
         stats = tick.stats
         seqstat = partial(_seqstat, stats)
 
         # ---- chunked prefill phase (prefill_chunk > 1 only)
         if ecfg.prefill_chunk > 1:
-            with TraceAnnotation("serve.prefill_host"):
-                budget = ecfg.prefill_token_budget or ecfg.prefill_chunk
-                for seq in todo:
-                    if budget <= 0:
-                        break
-                    if not seq.in_prefill or seq.finished:
-                        continue
-                    # leave the LAST prompt token to the decode batch:
-                    # its logits produce the first generated token there,
-                    # so first-token sampling/argmax runs on the same path
-                    # for every sequence
-                    remaining = seq.prompt_len - 1 - seq.pos
-                    if remaining <= 0:
-                        continue
-                    n = min(remaining, ecfg.prefill_chunk, budget)
-                    try:
-                        self.kv.ensure_range(seq.seq_id, seq.pos + n - 1)
-                    except OutOfBlocks:
-                        held.append(seq)
-                        continue
-                    C = _bucket(n)
-                    W = self._cache.width or _bucket(
-                        (seq.pos + n - 1) // bs + 1)
-                    toks = np.zeros((C,), np.int32)
-                    toks[:n] = seq.prompt[seq.pos: seq.pos + n]
-                    table = self.kv.table([seq.seq_id], W)[0]
-                    fn = self._prefill_fn(C, W)
-                    live = n * seq.pos + n * (n + 1) // 2
-                    out = self._run_writer(
-                        fn, jnp.asarray(toks), jnp.int32(seq.pos),
+            budget = ecfg.prefill_token_budget or ecfg.prefill_chunk
+            for seq in todo:
+                if budget <= 0:
+                    break
+                if not seq.in_prefill or seq.finished:
+                    continue
+                # leave the LAST prompt token to the decode batch: its
+                # logits produce the first generated token there, so
+                # first-token sampling/argmax runs on the same path for
+                # every sequence
+                remaining = seq.prompt_len - 1 - seq.pos
+                if remaining <= 0:
+                    continue
+                n = min(remaining, ecfg.prefill_chunk, budget)
+                try:
+                    self.kv.ensure_range(seq.seq_id, seq.pos + n - 1)
+                except OutOfBlocks:
+                    held.append(seq)
+                    continue
+                part.to("stage")
+                C = _bucket(n)
+                W = self._cache.width or _bucket((seq.pos + n - 1) // bs + 1)
+                toks = np.zeros((C,), np.int32)
+                toks[:n] = seq.prompt[seq.pos: seq.pos + n]
+                table = self.kv.table([seq.seq_id], W)[0]
+                fn = self._prefill_fn(C, W)
+                live = n * seq.pos + n * (n + 1) // 2
+                tail = (jnp.asarray(toks), jnp.int32(seq.pos),
                         jnp.asarray(table),
                         *self._state_slots([seq.seq_id], scalar=True),
-                        jnp.int32(n),
-                    )
-                    if self._cache.width:
-                        # its width: the key blocks the program walks
-                        kb = min(_PREFILL_KEY_BLOCK, W * bs)
-                        W = -(-(seq.pos + n) // kb) * kb // bs
-                    if self._cache.routed:
-                        tick.counts.append(out[0])
-                        if self._prefill_route() == "pallas":
-                            stats["prefill_kernel_pairs"] = live + stats.get(
-                                "prefill_kernel_pairs", 0)
-                    stats["prefill_calls"].append((C, W, live))
-                    tick.touched[seq.seq_id] = None
-                    seq.pos += n
-                    budget -= n
-                    self.prefill_tokens += n
-                    stats["prefill_tokens"] += n
-                    seqstat(seq)["prefill"] += n
-        self._lap("prefill_host")
+                        jnp.int32(n))
+                out = self._call("prefill", fn, tail, stats)
+                part.to("select")
+                if self._cache.width:
+                    # its width: the key blocks the program walks
+                    kb = min(_PREFILL_KEY_BLOCK, W * bs)
+                    W = -(-(seq.pos + n) // kb) * kb // bs
+                if self._cache.routed:
+                    tick.counts.append(out[0])
+                    if self._prefill_route() == "pallas":
+                        stats["prefill_kernel_pairs"] = live + stats.get(
+                            "prefill_kernel_pairs", 0)
+                stats["prefill_calls"].append((C, W, live))
+                tick.touched[seq.seq_id] = None
+                seq.pos += n
+                budget -= n
+                self.prefill_tokens += n
+                stats["prefill_tokens"] += n
+                seqstat(seq)["prefill"] += n
+        part.to(None)
 
         # ---- decode batch: plain slots (one token each) + speculative
         # slots (k drafts verified in one multi-position step)
-        with TraceAnnotation("serve.decode_host"):
-            batch, tick.spec_batch, parked = self._select(todo, held)
-            for s in parked:
-                seqstat(s)["parked"] = True
-            stats["parked"] = len(parked)
-            if parked:
-                self.stall_events += 1
-            if batch or tick.spec_batch or stats["prefill_calls"]:
-                stats["dispatch"] = "drained" if prev is None else "ahead"
-            elif parked and prev is None:
-                # every active sequence is parked on blocks: preempt the
-                # youngest so the others' next allocation can succeed
-                # (never under a tick in flight, whose rows hold theirs)
-                victim = self._preempt_youngest(parked)
-                stats["preempted"].append({
-                    "seq_id": victim.seq_id,
-                    "tokens_held": len(victim.out),
-                    "preemptions": victim.preemptions,
-                })
-            if batch:
-                B = min(_bucket(len(batch)), ecfg.max_batch)
-                W = self._cache.width or _bucket(
-                    max(s.pos // bs + 1 for s in batch))
-                tok = np.zeros((B,), np.int32)
-                src = np.full((B,), -1, np.int32)
-                pos = np.zeros((B,), np.int32)
-                temps = np.zeros((B,), np.float32)
-                seeds = np.zeros((B,), np.uint32)
-                for i, s in enumerate(batch):
-                    if s.input_in_flight:
-                        src[i] = prev.touched[s.seq_id]
-                    else:
-                        tok[i] = s.next_input()
-                    pos[i] = s.pos
-                    temps[i] = s.temperature
-                    seeds[i] = s.seed & 0xFFFFFFFF
-                table = self._table(batch, B, W)
-                fn = self._decode_fn(B, W)
-                kernel = self._attn_route() == "pallas"
-                stats["decode_call"] = (
-                    B, W, int(pos.sum()) + len(batch),
-                    paged_read_positions(pos, bs) if kernel
-                    else B * W * bs,
-                )
-                stats["decode_kernel"] = kernel
-                tick.nxt, *rest = self._run_writer(
-                    fn,
-                    _feed_tokens(self._board(prev.nxt), src, tok)
-                    if (src >= 0).any() else jnp.asarray(tok),
-                    jnp.asarray(pos), jnp.asarray(table),
-                    *self._state_slots(
-                        [s.seq_id for s in batch] + [-1] * (B - len(batch))),
-                    jnp.asarray(temps), _row_keys(seeds, pos),
-                )
-                if self._cache.routed:
-                    tick.counts.append(rest[1])
-                for i, s in enumerate(batch):
-                    tick.rows.append((s, s.pos))
-                    tick.touched[s.seq_id] = i
-                    s.pos += 1
-        self._lap("decode_host")
+        phase.to("decode_host")
+        part.to("select")
+        batch, tick.spec_batch, parked = self._select(todo, held)
+        for s in parked:
+            seqstat(s)["parked"] = True
+        stats["parked"] = len(parked)
+        if parked:
+            self.stall_events += 1
+        if batch or tick.spec_batch or stats["prefill_calls"]:
+            stats["dispatch"] = "drained" if prev is None else "ahead"
+        elif parked and prev is None:
+            # every active sequence is parked on blocks: preempt the
+            # youngest so the others' next allocation can succeed (never
+            # under a tick in flight, whose rows hold theirs)
+            victim = self._preempt_youngest(parked)
+            stats["preempted"].append({
+                "seq_id": victim.seq_id,
+                "tokens_held": len(victim.out),
+                "preemptions": victim.preemptions,
+            })
+        if batch:
+            part.to("stage")
+            B = min(_bucket(len(batch)), ecfg.max_batch)
+            W = self._cache.width or _bucket(
+                max(s.pos // bs + 1 for s in batch))
+            tok = np.zeros((B,), np.int32)
+            src = np.full((B,), -1, np.int32)
+            pos = np.zeros((B,), np.int32)
+            temps = np.zeros((B,), np.float32)
+            seeds = np.zeros((B,), np.uint32)
+            for i, s in enumerate(batch):
+                if s.input_in_flight:
+                    src[i] = prev.touched[s.seq_id]
+                else:
+                    tok[i] = s.next_input()
+                pos[i] = s.pos
+                temps[i] = s.temperature
+                seeds[i] = s.seed & 0xFFFFFFFF
+            table = self._table(batch, B, W)
+            fn = self._decode_fn(B, W)
+            kernel = self._attn_route() == "pallas"
+            stats["decode_call"] = (
+                B, W, int(pos.sum()) + len(batch),
+                paged_read_positions(pos, bs) if kernel
+                else B * W * bs,
+            )
+            stats["decode_kernel"] = kernel
+            tail = (
+                _feed_tokens(self._board(prev.nxt), src, tok)
+                if (src >= 0).any() else jnp.asarray(tok),
+                jnp.asarray(pos), jnp.asarray(table),
+                *self._state_slots(
+                    [s.seq_id for s in batch] + [-1] * (B - len(batch))),
+                jnp.asarray(temps), _row_keys(seeds, pos),
+            )
+            for i, s in enumerate(batch):
+                tick.rows.append((s, s.pos))
+                tick.touched[s.seq_id] = i
+                s.pos += 1
+            tick.nxt, *rest = self._call("decode", fn, tail, stats)
+            if self._cache.routed:
+                tick.counts.append(rest[1])
+        part.to(None)
         return tick
 
     def _land(self, tick: _Tick) -> dict:
         """Fetch what a tick's programs produced (the one blocking read),
         hand each new token out (`_emit`: record, maybe retire, stream),
-        run its speculative phase if it has one, retire what finished.
-        Returns the tick's stats, whole, with the call's ``phase_s``."""
+        run its speculative phase if it has one, retire what finished, let
+        go of the tick's arrays on the device (``release``). Ends the
+        call's phases; returns the tick's stats with the call's seconds."""
         stats = tick.stats
         seqstat = partial(_seqstat, stats)
+        phase = self.phase
         if tick.rows or tick.counts:
-            with TraceAnnotation("serve.fetch"):
-                if self._cache.routed:
-                    # the expert layers' counts come with the tokens
-                    nxt, counts = jax.device_get((tick.nxt, tick.counts))
-                    stats["moe"] = self._moe_stats(counts)
-                else:
-                    nxt = np.asarray(tick.nxt)
-            self._lap("fetch")
+            phase.to("fetch")
+            if self._cache.routed:
+                # the expert layers' counts come with the tokens
+                nxt, counts = jax.device_get((tick.nxt, tick.counts))
+                stats["moe"] = self._moe_stats(counts)
+            else:
+                nxt = np.asarray(tick.nxt)
         if tick.rows:
-            with TraceAnnotation("serve.emit"):
-                for i, (s, consumed_at) in enumerate(tick.rows):
-                    if s.finished:
-                        # it ended (its token, a cancel) while this row
-                        # was in flight: decoded past the end, dropped
-                        continue
-                    if consumed_at >= s.prompt_len - 1:
-                        # prediction for generated-token index j; after a
-                        # preemption the replay re-derives tokens the
-                        # sequence already holds (j < len(out)) -
-                        # deterministic by construction (greedy, or the
-                        # per-position sampling key), so they are dropped,
-                        # not re-appended/re-streamed
-                        j = consumed_at + 1 - s.prompt_len
-                        if j == len(s.out):
-                            self._emit(s, int(nxt[i]))
-                        else:
-                            seqstat(s)["replayed"] += 1
-                        self.decode_tokens += 1
-                        stats["decode_tokens"] += 1
-                        seqstat(s)["decode"] += 1
+            phase.to("emit")
+            for i, (s, consumed_at) in enumerate(tick.rows):
+                if s.finished:
+                    # it ended (its token, a cancel) while this row was in
+                    # flight: decoded past the end, dropped
+                    continue
+                if consumed_at >= s.prompt_len - 1:
+                    # prediction for generated-token index j; after a
+                    # preemption the replay re-derives tokens the sequence
+                    # already holds (j < len(out)) - deterministic by
+                    # construction (greedy, or the per-position sampling
+                    # key), so they are dropped, not re-appended/re-streamed
+                    j = consumed_at + 1 - s.prompt_len
+                    if j == len(s.out):
+                        self._emit(s, int(nxt[i]))
                     else:
-                        self.prefill_tokens += 1
-                        stats["prefill_tokens"] += 1
-                        seqstat(s)["prefill"] += 1
-            self._lap("emit")
+                        seqstat(s)["replayed"] += 1
+                    self.decode_tokens += 1
+                    stats["decode_tokens"] += 1
+                    seqstat(s)["decode"] += 1
+                else:
+                    self.prefill_tokens += 1
+                    stats["prefill_tokens"] += 1
+                    seqstat(s)["prefill"] += 1
         if tick.spec_batch:
-            with TraceAnnotation("serve.spec"):
-                self._spec_step(tick.spec_batch, stats, seqstat)
-            self._lap("spec")
+            phase.to("spec")
+            self._spec_step(tick.spec_batch, stats, seqstat)
         if tick.rows or tick.spec_batch:
             self.ticks += 1
             stats["batch"] = len(tick.rows) + len(tick.spec_batch)
         if tick.in_flight:
             # (a prefill chunk's sequence may have been cancelled under it)
-            with TraceAnnotation("serve.emit"):
-                stats["finished"] = len(self._retire_finished())
-            self._lap("emit")
-        stats["phase_s"] = self._phase_s
+            phase.to("emit")
+            stats["finished"] = len(self._retire_finished())
+        # the caller keeps the husk to its return, not the arrays; the
+        # seconds' dictionaries are made before the last reading, so that
+        # no collection they set off falls outside every phase
+        phase.to("release")
+        tick.nxt = tick.counts = None
+        stats["host_s"] = self.part.take()
+        stats["phase_s"] = phase.take()
+        phase.to(None)
         return stats

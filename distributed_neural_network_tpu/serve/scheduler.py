@@ -59,7 +59,9 @@ from jax.profiler import TraceAnnotation
 
 from ..utils.goodput import GoodputLedger
 from ..utils.obs import NULL_REGISTRY
-from .engine import STEP_PHASES, ServeEngine, Sequence, export_descriptor
+from .engine import (
+    HOST_PARTS, STEP_PHASES, Phases, ServeEngine, Sequence, export_descriptor,
+)
 from .reqtrace import RequestTraceRecorder
 
 # histogram buckets for TTFT / inter-token latency: 1 ms .. 60 s
@@ -71,8 +73,9 @@ LATENCY_BUCKETS = (
 
 # the phases that partition the loop thread's time
 # (`serve_loop_seconds_total{phase}`): the scheduler's own and the
-# engine's, each also a `serve.<phase>` span in a profile
-LOOP_PHASES = ("admit", "books", "wait") + STEP_PHASES
+# engine's, each a `Phases` interval (a `serve.<phase>` span)
+SCHED_PHASES = ("admit", "books", "wait")
+LOOP_PHASES = SCHED_PHASES + STEP_PHASES
 
 
 class AdmissionError(Exception):
@@ -178,13 +181,11 @@ class ServeScheduler:
         cfg: SchedulerConfig | None = None,
         *,
         registry=NULL_REGISTRY,
-        clock=time.monotonic,
         tracer=None,
     ):
         self.engine = engine
         self.cfg = cfg or SchedulerConfig()
         self.registry = registry
-        self._clock = clock
         self.tracer = tracer
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
@@ -202,8 +203,11 @@ class ServeScheduler:
         self._draining = False
         self._drained = threading.Event()
         self._drain_out: list = []
-        self.ledger = GoodputLedger(taxonomy="serve", clock=clock)
+        # the loop thread's phases, the engine's and the ledger's
+        # intervals on one clock, the engine's
+        self.ledger = GoodputLedger(taxonomy="serve", clock=engine.clock)
         self.ledger.start()
+        self._phase = Phases(SCHED_PHASES, engine.clock)
         # per-request lifecycle records on the ledger's clock, so the
         # two accountings reconcile (tools/request_trace.py --ledger)
         self.reqtrace = RequestTraceRecorder(
@@ -367,6 +371,21 @@ class ServeScheduler:
         )
         self._m_dispatch = {o: ahead.labels(outcome=o)
                             for o in ("ahead", "drained")}
+        # the engine's host phases by part, and its bucket programs by
+        # whether the device had finished all it was handed when each was
+        # called (serve/engine.py `_call`): "idle", it waited on the host
+        host_s = r.counter(
+            "serve_host_seconds_total",
+            "Seconds of the engine's host phases, by part",
+        )
+        self._m_host_s = {p: host_s.labels(part=p) for p in HOST_PARTS}
+        found = r.counter(
+            "serve_dispatch_found_total",
+            "Bucket-program dispatches by whether the device was idle",
+        )
+        self._m_found = {(p, d): found.labels(program=p, device=d)
+                         for p in ("prefill", "decode")
+                         for d in ("idle", "busy")}
         self._m_decode_live = decode_pos.labels(kind="live")
         self._m_decode_read = decode_pos.labels(kind="read")
         self._m_decode_padded = decode_pos.labels(kind="padded")
@@ -750,61 +769,46 @@ class ServeScheduler:
     def _loop(self) -> None:
         eng = self.engine
         cfg = self.cfg
-        now = self.ledger.now
-        # the loop thread's seconds by phase since the last tick was
-        # published: everything between two readings of the clock goes
-        # to exactly one phase, so the phases sum to the thread's wall
-        # time, less what `eng.step` spends outside its own phases
-        phase_s = dict.fromkeys(LOOP_PHASES, 0.0)
-        t_mark = now()
-
-        def lap(phase: str) -> float:
-            nonlocal t_mark
-            t = now()
-            phase_s[phase] += t - t_mark
-            t_mark = t
-            return t
-
+        phase = self._phase
         while self._running:
+            # every instant in one phase but for the spans' own enter and
+            # exit and the engine's call and return; the loop's checks for
+            # work are its waits'
+            phase.to("wait")
             if self._draining:
-                with TraceAnnotation("serve.admit"):
-                    self._drain_sweep()
-                lap("admit")
-                with TraceAnnotation("serve.wait"), self._work:
+                phase.to("admit")
+                self._drain_sweep()
+                phase.to("wait")
+                with self._work:
                     self._work.wait(timeout=cfg.idle_poll_s)
-                lap("wait")
                 continue
             with self._work:
                 have_queued = self._queued > 0
-            if not have_queued and not eng.has_work() and not eng.preempted:
-                with TraceAnnotation("serve.wait"), self._work:
+            if not (have_queued or eng.has_work() or eng.preempted):
+                with self._work:
                     self._work.wait(timeout=cfg.idle_poll_s)
-                lap("wait")
                 continue
+            phase.to(None)
 
             with TraceAnnotation("serve.tick", tick=eng.ticks):
-                # one pair of readings serves the ledger and the phase
-                t_form0 = t_mark
-                with TraceAnnotation("serve.admit"):
-                    self._admit_ready()
-                t_form1 = lap("admit")
-                if t_form1 > t_form0:
-                    self.ledger.add(
-                        "batch_formation_idle", t_form0, t_form1
-                    )
-                if not eng.has_work():
-                    continue
+                t_form0 = phase.to("admit")
+                self._admit_ready()
+                work = eng.has_work()
                 preempted_before = len(eng.preempted)
-                t0 = lap("admit")
-                with TraceAnnotation("serve.step"):
-                    stats = eng.step()
-                t1 = t_mark = now()
-                for phase, dt in stats["phase_s"].items():
-                    phase_s[phase] += dt
+                t0 = phase.to(None)
+                if work:
+                    with TraceAnnotation("serve.step"):
+                        stats = eng.step()
                 # (the next tick's programs are on the device meanwhile)
-                with TraceAnnotation("serve.books"):
-                    self._books(phase_s, stats, t0, t1, preempted_before)
-                lap("books")
+                t1 = phase.to("books")
+                # the phases' readings of the clock serve the ledger too
+                if t0 > t_form0:
+                    self.ledger.add("batch_formation_idle", t_form0, t0)
+                if work:
+                    self._books(phase.take() | stats["phase_s"], stats, t0,
+                                t1, preempted_before)
+                phase.to(None)
+        phase.to(None)
 
     def _admit_ready(self) -> None:
         """The admission pass of one loop iteration (loop thread):
@@ -856,11 +860,14 @@ class ServeScheduler:
         shapes, in one place and after `serve_engine_steps_total` has
         counted it: a scrape between two ticks sees whole ticks (one
         step's end to the next, as that counter beats), so
-        delta(seconds) / delta(steps) is a mean per tick. Empties
-        ``phase_s``."""
+        delta(seconds) / delta(steps) is a mean per tick. ``phase_s``:
+        the loop thread's seconds by phase since the last tick."""
         for phase, dt in phase_s.items():
             self._m_loop_s[phase].inc(dt)
-            phase_s[phase] = 0.0
+        for part, dt in stats.get("host_s", {}).items():
+            self._m_host_s[part].inc(dt)
+        for program, device in stats.get("found", ()):
+            self._m_found[program, device].inc()
         if stats.get("dispatch") is not None:
             self._m_dispatch[stats["dispatch"]].inc()
         bs = self.engine.ecfg.block_size

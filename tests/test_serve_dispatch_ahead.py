@@ -15,7 +15,10 @@ Bars:
 - a speculative engine never dispatches ahead;
 - a drain for migration lands the tick in flight first;
 - `serve_dispatch_ahead_total` counts what the engine did, and
-  `serve_engine_steps_total` still beats once a landed tick.
+  `serve_engine_steps_total` still beats once a landed tick;
+- a bucket program called once the device has run dry finds it idle
+  (`serve_dispatch_found_total`), and every bucket program is counted
+  idle or busy.
 """
 
 import time
@@ -392,6 +395,38 @@ def test_scheduler_counts_ahead_and_drained_ticks(params, n_devices):
     # the loop's phases still cover its ticks: the engine's five are there
     loop = _counter(registry, "serve_loop_seconds_total")
     assert loop['{phase="fetch"}'] > 0 and loop['{phase="decode_host"}'] > 0
+
+
+def test_a_dispatch_finds_the_device_idle_once_it_has_run_dry(params,
+                                                              n_devices):
+    """Before each call the test waits for the device to finish all it was
+    handed; the first bucket program the call dispatches then finds it
+    idle. The found states are published with the tick that dispatched
+    them, as the calls' buckets are, so the two families count the same
+    programs."""
+    registry = MetricsRegistry()
+    eng = _engine(params, prefill_chunk=4)
+    sched = ServeScheduler(eng, SchedulerConfig(), registry=registry)
+    for i, n in enumerate((9, 5, 6)):
+        eng.add(Sequence(i, _prompt(270 + i, n), 5))
+    firsts = []
+    while eng.has_work():
+        jax.block_until_ready(eng._pools())
+        stats = eng.step()
+        sched._publish_tick(stats["phase_s"], stats)
+        if stats["found"]:
+            firsts.append(stats["found"][0])
+    sched.close(finalize=False)
+    # the first call dispatches two ticks, the second behind the first's
+    # programs; every later call one, after the wait
+    del firsts[1]
+    assert firsts and set(firsts) <= {("prefill", "idle"), ("decode", "idle")}
+    found = _counter(registry, "serve_dispatch_found_total")
+    calls = [_counter(registry, name) for name in (
+        "serve_prefill_calls_total", "serve_decode_calls_total")]
+    assert sum(found.values()) == sum(sum(c.values()) for c in calls) > 0
+    assert found['{device="idle",program="prefill"}'] >= 1
+    assert found['{device="idle",program="decode"}'] >= 1
 
 
 def test_drain_for_migration_lands_the_tick_in_flight_first(params,

@@ -18,7 +18,6 @@ Bars:
   phases and reports the buckets it dispatched with their live positions.
 """
 
-import time
 import types
 
 import jax
@@ -29,6 +28,7 @@ import pytest
 from distributed_neural_network_tpu.models import transformer as tfm
 from distributed_neural_network_tpu.serve import engine as engine_mod
 from distributed_neural_network_tpu.serve.engine import (
+    HOST_PARTS,
     STEP_PHASES,
     EngineConfig,
     Sequence,
@@ -413,18 +413,21 @@ def test_cancel_frees_blocks_mid_flight(params, n_devices):
 
 
 def _timed_step(eng):
-    t0 = time.perf_counter()
+    t0 = eng.clock()
     st = eng.step()
-    return st, time.perf_counter() - t0
+    return st, eng.clock() - t0
 
 
 def _check_phases(st, wall):
-    ph = st["phase_s"]
+    ph, host = st["phase_s"], st["host_s"]
     assert tuple(ph) == STEP_PHASES == (
-        "prefill_host", "decode_host", "fetch", "emit", "spec")
-    assert all(v >= 0.0 for v in ph.values()), ph
+        "prefill_host", "decode_host", "fetch", "emit", "spec", "release")
+    assert tuple(host) == HOST_PARTS == ("select", "stage", "dispatch")
+    assert all(v >= 0.0 for v in (*ph.values(), *host.values())), (ph, host)
     # entry to return, every instant in exactly one phase
     assert abs(sum(ph.values()) - wall) <= max(0.05 * wall, 0.002), (ph, wall)
+    # the parts are nested in the two host phases
+    assert sum(host.values()) <= ph["prefill_host"] + ph["decode_host"]
 
 
 @pytest.mark.parametrize("chunk,spec", [(1, 0), (4, 0), (4, 2)])
@@ -437,20 +440,28 @@ def test_step_phase_seconds_partition_the_call(params, n_devices, chunk,
     for i, n in enumerate((9, 5)):
         eng.add(Sequence(i, _prompt(40 + i, n), 4))
     seen = dict.fromkeys(STEP_PHASES, 0.0)
+    parts = dict.fromkeys(HOST_PARTS, 0.0)
     steps = 0
     while eng.has_work():
         st, wall = _timed_step(eng)
         _check_phases(st, wall)
         for k, v in st["phase_s"].items():
             seen[k] += v
+        for k, v in st["host_s"].items():
+            parts[k] += v
         steps += 1
         assert steps < 100
     assert seen["prefill_host"] > 0 and seen["decode_host"] > 0
-    assert seen["emit"] > 0
+    assert seen["emit"] > 0 and seen["release"] > 0
     # greedy slots from their prompt's last token on go through
     # `_spec_step` whole, which fetches for itself
     assert (seen["spec"] > 0) == bool(spec)
     assert (seen["fetch"] > 0) == (not spec)
+    # what the host does to build and hand over a tick is its three parts
+    host = seen["prefill_host"] + seen["decode_host"]
+    assert sum(parts.values()) == pytest.approx(host, rel=0.02), (parts, host)
+    assert parts["select"] > 0 and parts["stage"] > 0
+    assert parts["dispatch"] > 0
 
 
 def test_step_reports_buckets_and_live_positions(params, n_devices):
@@ -538,7 +549,8 @@ def test_pallas_route_refuses_what_the_kernel_does_not_read(params,
 
 def test_all_parked_tick_still_partitions(params, n_devices):
     """The early return (nothing could run, the youngest is preempted)
-    carries the same keys: its time is prefill_host and decode_host."""
+    carries the same keys: its time is prefill_host, decode_host and
+    release."""
     eng = ServeEngine(params, CFG, EngineConfig(
         max_batch=4, num_blocks=6, block_size=2, max_seq_len=16,
     ))

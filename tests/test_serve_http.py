@@ -318,6 +318,7 @@ def test_loop_phases_and_positions_over_a_scripted_run(params, n_devices):
     the schedule is fixed: tick 1 prefills A[0:4] (nothing decodes),
     tick 2 A[4:8] and decodes A, tick 3 B[0:4] and decodes A and B, tick
     4 decodes both and retires both."""
+    from distributed_neural_network_tpu.serve.engine import HOST_PARTS
     from distributed_neural_network_tpu.serve.scheduler import LOOP_PHASES
 
     registry = MetricsRegistry()
@@ -355,16 +356,26 @@ def test_loop_phases_and_positions_over_a_scripted_run(params, n_devices):
     assert registry.counter("serve_engine_steps_total").value == 4
     assert len(ends) == 4
     # the phases between the first and the last tick's end sum to the
-    # loop thread's wall time there (the last tick is published whole)
+    # loop thread's wall time there (the last tick is published whole,
+    # its `release` with it)
     wall = ends[-1] - ends[0]
-    assert published() - sums[1] == pytest.approx(wall, rel=0.05)
+    assert published() - sums[1] == pytest.approx(wall, rel=0.02)
     phases = _family(registry, "serve_loop_seconds_total")
     assert set(phases) == {'{phase="%s"}' % p for p in LOOP_PHASES}
     assert phases['{phase="spec"}'] == 0.0
     assert all(v >= 0.0 for v in phases.values())
     for p in ("admit", "books", "prefill_host", "decode_host", "fetch",
-              "emit"):
+              "emit", "release"):
         assert phases['{phase="%s"}' % p] > 0.0, p
+    # the host phases' parts, and each of the six bucket programs found
+    # the device idle or busy
+    parts = _family(registry, "serve_host_seconds_total")
+    assert set(parts) == {'{part="%s"}' % p for p in HOST_PARTS}
+    assert sum(parts.values()) <= (phases['{phase="prefill_host"}']
+                                   + phases['{phase="decode_host"}'])
+    assert all(v > 0.0 for v in parts.values()), parts
+    found = _family(registry, "serve_dispatch_found_total")
+    assert sum(found.values()) == 3 + 3
 
     # the hand count: a prompt token at position p attends to p + 1, a
     # decode at position p likewise (in closed form, whatever the chunks:
@@ -476,9 +487,13 @@ def test_profile_nests_fetch_in_step_in_tick(params, tmp_path, n_devices):
     for name in ("serve.admit", "serve.books"):
         assert all(inside(ev, by_name["serve.tick"])
                    for ev in by_name[name]), name
-    for name in ("serve.decode_host", "serve.emit"):
+    for name in ("serve.decode_host", "serve.emit", "serve.release"):
         assert all(inside(ev, by_name["serve.step"])
                    for ev in by_name[name]), name
+    host = by_name["serve.prefill_host"] + by_name["serve.decode_host"]
+    for name in ("serve.select", "serve.stage", "serve.dispatch"):
+        assert by_name[name] and all(
+            inside(ev, host) for ev in by_name[name]), name
 
 
 def test_null_registry_loop_runs_and_publishes_nothing(params, n_devices):
