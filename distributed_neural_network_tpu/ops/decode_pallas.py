@@ -26,28 +26,29 @@ itself:
   the live positions rounded up to whole pages (`paged_read_positions`,
   the engine's ``serve_decode_positions_total{kind="read"}``), whatever
   the bucket's width.
-- **All heads of a page from the one tile, on the VPU**: the head axis
-  is on sublanes, so scores are the product of the ``(rows, H, Dh)``
-  tile with ``q (H, Dh)`` reduced over lanes, and the value sum is the
-  probabilities broadcast over lanes times the V tile summed over rows:
-  no transpose, no per-head strided load, float32 throughout (the online
-  softmax's m/l/acc are loop carries). Decode attention is one FLOP a
-  byte; the MXU at one query row a head reloads its weights for every
-  128 cache rows, which is what bounded the dense kernel below.
+- **All heads of a step in two MXU products**: a step's pages lie in
+  VMEM as ``(pps, block_size, H, Dh)`` and are read as ONE matrix of
+  ``pps * block_size * H`` rows of Dh, a position's H rows together (at
+  16 heads of 128 in bfloat16 a position's rows are one tile, so the view
+  costs no copy). ``S = q (H, Dh) . rows^T`` in the pool's dtype with
+  float32 accumulation gives every head's score against every row, the
+  positions along lanes; a select keeps the block diagonal (row r is
+  head ``r mod H``'s) up to ``pos[b]``, the online softmax runs along
+  lanes in float32 (m, l and acc are loop carries), and ``P . rows_V``,
+  P in the pool's dtype, is every head's own value sum at once: P is
+  nought off its diagonal. Nothing of a page is widened to float32.
+  The boundary page is copied whole and a step's uncopied pages keep an
+  older step's rows, so a sequence's last step sets its V rows past
+  ``pos[b]`` to nought in VMEM before the product (0 x a stale NaN is
+  NaN on the MXU); K's are dropped by the select.
 - One `lax.fori_loop` over the batch's fetch steps, with the loops over
-  a step's copies and its pages inside it, all on traced bounds:
-  nothing is unrolled and every piece is traced once, so lowering a
-  decode program costs the host what it did before (the grid has 40;
-  the chip's host lowers them in 14.8-15.6 s against 15.5-17.1).
-- **Measured (PR 30, TPU v5e, the longdoc cell: batch 16, width 128,
-  14-16 sequences at 256-2,047 positions)**: the 24 layers' calls take
-  5.2 ms at 13,356 live positions, 62 % of the time HBM needs for the
-  live bytes (fetch steps of 256 KiB, 512 KiB and 1 MiB read 59, 62 and
-  61 %: the VPU and the lane reductions bound it, not the copies); the
-  decode program (16, 128) went from 63.0 to 10.9 ms a call, of which
-  46 ms were the gather of the bucket out of the pool and its transpose
-  for the dense kernel, which the engine no longer does (PERF.md
-  section 6).
+  a step's copies inside it, all on traced bounds: nothing is unrolled
+  and every piece is traced once.
+- **Measured on a TPU v5e** at the longdoc cell's shapes (24 layers,
+  batch 16, tables of 128 pages): the 24 calls at 18,587 live positions
+  take 5.03 ms, 89 % of the time HBM needs for the live K and V rows
+  (91 % with every sequence at 2,047, 86 % at mixed page and step
+  edges); its copies alone take 4.94 ms, so they bound it.
 - It compiles where a pool row's ``(H, Dh)`` is whole tiles of a float
   pool (`paged_decode_ok`); an int8 pool, and heads of 64, take the
   engine's XLA route.
@@ -344,6 +345,7 @@ def _paged_kernel(l_ref, table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
     negative, so the divisions truncate (`lax.div`: a floor division's
     sign fix-up is a quarter of this kernel's lowering time)."""
     n_seq, h, d = q_ref.shape
+    n_rows = pps * bs * h
     layer = l_ref[0]
     div = jax.lax.div
 
@@ -388,27 +390,39 @@ def _paged_kernel(l_ref, table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
             return c
 
         jax.lax.fori_loop(0, n_pages, wait, 0)
-        pos = pos_ref[pb]
-        q = q_ref[pb].astype(jnp.float32) * scale               # (H, Dh)
 
-        def page(j, c):
-            m, l, acc = c
-            k = k_buf[1 - slot, j].astype(jnp.float32)          # (bs, H, Dh)
-            v = v_buf[1 - slot, j].astype(jnp.float32)
-            s = jnp.sum(k * q[None], axis=-1, keepdims=True)
-            rows = (pi * pps + j) * bs + jax.lax.broadcasted_iota(
+        def compute():
+            # the step's positions up to pos[pb]; past them lie the rest of
+            # the boundary page and the pages not copied, which still hold
+            # an older step: only a sequence's last step has any
+            n_live = pos_ref[pb] - pi * pps * bs + 1
+
+            @pl.when(last)
+            def _clear_dead_values():
+                # a dead row's p is 0, but 0 x a stale inf or nan is not
+                v = v_buf[1 - slot]                     # (pps, bs, H, Dh)
+                at = bs * jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) + (
+                    jax.lax.broadcasted_iota(jnp.int32, v.shape, 1))
+                v_buf[1 - slot] = jnp.where(at < n_live, v, jnp.zeros_like(v))
+
+            # the step as one matrix of rows, a position's H rows together:
+            # row r is head r mod H's at the step's position r div H
+            k = k_buf[1 - slot].reshape(n_rows, d)
+            v = v_buf[1 - slot].reshape(n_rows, d)
+            s = _dot_nt(q_ref[pb].astype(k.dtype), k) * scale  # (H, rows)
+            r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            own = jax.lax.rem(r, h) == jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0)
-            live = rows <= pos
-            s = jnp.where(live, s, _NEG_BIG)                    # (bs, H, 1)
-            m_new = jnp.maximum(m, s.max(axis=0))               # (H, 1)
-            p = jnp.exp(s - m_new[None])
+            s = jnp.where(own & (r < n_live * h), s, _NEG_BIG)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
             alpha = jnp.exp(m - m_new)
-            l = l * alpha + p.sum(axis=0)
-            # a dead row's p is 0, but 0 x a stale inf or nan is not
-            acc = acc * alpha + (p * jnp.where(live, v, 0.0)).sum(axis=0)
-            return m_new, l, acc
+            # P is nought off its block diagonal, so this one product is
+            # every head's own value sum
+            return (m_new, l * alpha + p.sum(axis=-1, keepdims=True),
+                    acc * alpha + _dot_nn(p.astype(v.dtype), v))
 
-        m, l, acc = jax.lax.fori_loop(0, n_pages, page, (m, l, acc))
+        m, l, acc = jax.lax.cond(n_pages > 0, compute, lambda: (m, l, acc))
 
         @pl.when(last)
         def _write():
@@ -442,8 +456,12 @@ def decode_paged_attention(q, k_pool, v_pool, layer, table, pos, *,
     layer to read, a scalar that may be traced (the engine's layer scan);
     table (B, W) int32 - each sequence's block ids in order, entries past
     its live pages unread; pos (B,) int32 - positions 0..pos[b] are
-    attended. Returns o (B, H, Dh) in q's dtype. Scores, the online
-    softmax and the value sum are float32. Gate a compiled call with
+    attended. Returns o (B, H, Dh) in q's dtype. Scores are the product
+    of q and the K rows in the pool's dtype, accumulated in float32 and
+    scaled by 1/sqrt(Dh) after it; the online softmax is float32; the
+    value sum is the product of the probabilities, rounded to the pool's
+    dtype, and the V rows, accumulated in float32 (a float32 pool keeps
+    float32 operands throughout). Gate a compiled call with
     `paged_decode_ok`."""
     b, h, d = q.shape
     if v_pool.shape != k_pool.shape or k_pool.shape[2:] != (h, d):

@@ -144,29 +144,36 @@ PAGED_POS = [0, BS - 1, BS, PPS * BS - 1, PPS * BS, W * BS - 1]
 
 
 def _paged_case(dtype, dh, monkeypatch):
+    """(q, clean pools, poisoned pools, table, pos) at the shapes above,
+    with the fetch step shrunk to `PPS` pages (`_poisoned_pools`)."""
+    monkeypatch.setattr(
+        decode_pallas, "_FETCH_STEP_BYTES",
+        PPS * BS * HEADS * dh * jnp.dtype(dtype).itemsize,
+    )
+    return _poisoned_pools(dtype, HEADS, dh, BS, W, PAGED_POS, seed=dh)
+
+
+def _poisoned_pools(dtype, heads, dh, bs, width, positions, *, seed,
+                    layers=LAYERS, blocks=BLOCKS):
     """(q, clean pools, poisoned pools, table, pos): a shuffled,
     non-contiguous table whose entries past a sequence's live pages name
     the scratch block 0, and pools whose every row past ``pos`` (the dead
     rows of the boundary page, every block no live page names, the
     scratch block) is NaN in the poisoned copy."""
-    monkeypatch.setattr(
-        decode_pallas, "_FETCH_STEP_BYTES",
-        PPS * BS * HEADS * dh * jnp.dtype(dtype).itemsize,
-    )
-    b = len(PAGED_POS)
-    ks = jax.random.split(jax.random.key(dh), 3)
-    shape = (LAYERS, BLOCKS * BS, HEADS, dh)
+    b = len(positions)
+    ks = jax.random.split(jax.random.key(seed), 3)
+    shape = (layers, blocks * bs, heads, dh)
     k_pool = jax.random.normal(ks[0], shape, dtype)
     v_pool = jax.random.normal(ks[1], shape, dtype)
-    q = jax.random.normal(ks[2], (b, HEADS, dh), dtype)
-    pos = np.asarray(PAGED_POS, np.int32)
-    blocks = np.random.default_rng(dh).permutation(
-        np.arange(1, BLOCKS))[: b * W].reshape(b, W)
-    pages = np.arange(W)[None, :] <= (pos // BS)[:, None]
-    table = np.where(pages, blocks, 0).astype(np.int32)
-    rows = (table[..., None] * BS + np.arange(BS)).reshape(b, W * BS)
-    live = np.arange(W * BS)[None, :] <= pos[:, None]
-    dead = np.ones((BLOCKS * BS,), bool)
+    q = jax.random.normal(ks[2], (b, heads, dh), dtype)
+    pos = np.asarray(positions, np.int32)
+    blocks_ = np.random.default_rng(seed).permutation(
+        np.arange(1, blocks))[: b * width].reshape(b, width)
+    pages = np.arange(width)[None, :] <= (pos // bs)[:, None]
+    table = np.where(pages, blocks_, 0).astype(np.int32)
+    rows = (table[..., None] * bs + np.arange(bs)).reshape(b, width * bs)
+    live = np.arange(width * bs)[None, :] <= pos[:, None]
+    dead = np.ones((blocks * bs,), bool)
     dead[rows[live]] = False
     poison = jnp.where(jnp.asarray(dead)[None, :, None, None], jnp.nan, 0.0)
     return (q, (k_pool, v_pool),
@@ -174,12 +181,12 @@ def _paged_case(dtype, dh, monkeypatch):
             jnp.asarray(table), jnp.asarray(pos))
 
 
-def _paged_oracle(q, k_pool, v_pool, layer, table, pos):
+def _paged_oracle(q, k_pool, v_pool, layer, table, pos, bs=BS):
     """The engine's `xla` route: gather the table's span, attend under
     the live mask."""
     b, w = table.shape
-    rows = (table[..., None] * BS + jnp.arange(BS)).reshape(b, w * BS)
-    live = (jnp.arange(w * BS)[None, :] <= pos[:, None])[:, None, None, :]
+    rows = (table[..., None] * bs + jnp.arange(bs)).reshape(b, w * bs)
+    live = (jnp.arange(w * bs)[None, :] <= pos[:, None])[:, None, None, :]
     return masked_attention(
         q[:, None], k_pool[layer][rows].transpose(0, 2, 1, 3),
         v_pool[layer][rows].transpose(0, 2, 1, 3), live, q.dtype,
@@ -228,6 +235,37 @@ def test_paged_kernel_reads_a_traced_layer_under_scan(monkeypatch):
             np.asarray(_paged_oracle(q, *clean, layer, table, pos)),
             rtol=2e-6, atol=2e-6,
         )
+
+
+@pytest.mark.parametrize("dtype,heads", [
+    (jnp.bfloat16, 16),                       # the longdoc cell's pages
+    (jnp.bfloat16, 2), (jnp.bfloat16, 4),     # the gate's small tiles
+    (jnp.float32, 2), (jnp.float32, 4),
+])
+def test_paged_kernel_at_its_own_fetch_step(dtype, heads):
+    """Pages of 16 rows of ``heads`` heads of 128 at the kernel's own fetch
+    step (8 pages at the longdoc cell's 16 heads in bfloat16; 16 to 64
+    where a position's rows are fewer than a sublane tile), on the
+    poisoned pool: a sequence at position 0, one mid-page, one that ends
+    a page, one that ends and one that starts a fetch step, one in the
+    third step and one that fills a table of three steps and a page."""
+    bs, dh = 16, 128
+    pps = decode_pallas._pages_per_step(
+        bs * heads * dh * jnp.dtype(dtype).itemsize, 1 << 20)
+    width, step = 3 * pps + 1, pps * bs
+    positions = [0, 7, bs - 1, step - 1, step, 2 * step + 7, width * bs - 1]
+    q, clean, poisoned, table, pos = _poisoned_pools(
+        dtype, heads, dh, bs, width, positions, seed=heads, layers=2,
+        blocks=len(positions) * width + 1)
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    got = decode_paged_attention(q, *poisoned, 1, table, pos, block_size=bs,
+                                 interpret=True)
+    assert got.dtype == q.dtype
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(_paged_oracle(q, *clean, 1, table, pos, bs), np.float32),
+        rtol=tol, atol=tol,
+    )
 
 
 def test_paged_kernel_gate_and_read_count():
