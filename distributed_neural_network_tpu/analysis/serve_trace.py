@@ -146,13 +146,13 @@ def enumerate_grid(ecfg, *, max_width_blocks: int | None = None,
     latent cache; KV rows beside convolution states), which have one table
     width, the widest (tests/test_pangu_ultra_moe.py and
     tests/test_lfm2_moe.py pin that grid)."""
-    from ..serve.engine import _bucket
+    from ..serve.engine import _bucket, batch_buckets
 
     kv = ecfg.kv()
     widths = _pow2s(_bucket(max_width_blocks or kv.max_blocks_per_seq))
     if latent:
         widths = [_bucket(kv.max_blocks_per_seq)]
-    batches = _pow2s(ecfg.max_batch)
+    batches = batch_buckets(ecfg.max_batch)
     grid = {"decode": [(B, W) for B in batches for W in widths]}
     if ecfg.prefill_chunk > 1:
         grid["prefill"] = [
@@ -739,8 +739,8 @@ def static_decode_tokens_per_s(engine, hw="cpu-host") -> dict:
     from .trace import collect_trace
 
     hw = HARDWARE_MODELS[hw] if isinstance(hw, str) else hw
-    # the largest grid bucket: widest pow2 batch warmup compiles
-    B = max(_pow2s(engine.ecfg.max_batch))
+    # the largest grid bucket: the widest batch warmup compiles
+    B = engine.ecfg.max_batch
     W = _bucket(engine.kv.cfg.max_blocks_per_seq)
     program = bucket_program(engine, "decode", (B, W))
     traced = program.make_jaxpr()

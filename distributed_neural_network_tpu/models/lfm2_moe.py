@@ -37,12 +37,14 @@ sublayers stacked BY KIND: `conv` and `attn` (the operators, each with its
 `op_norm`), `dense` and `moe` (the feed-forwards, each with its `ff_norm`).
 Layer l of the model is one entry of one operator stack and one entry of one
 feed-forward stack (`layer_plan`). This module is SERVED (`serve/engine.py`),
-not trained. What the engine asks of it: `CACHE`, `cache_shapes`,
-`layer_plan`, an `*_in` / `*_out` pair for each operator kind around the
-engine's own cache step (`conv_in`, `conv_mix`, `next_state`, `conv_out`;
-`attn_in`, `attn_out`), `feed_forward`, `decode_attention`,
-`prefill_attention`, `expert_tile`, `embed_tokens` and `final_logits`; and `REFUSED`, what of the
-engine's options it does not run, with the reason.
+not trained. What the engine asks of it: `CACHE`, `POOLS` (which pool each
+operator kind keeps), `cache_shapes`, `layer_plan`; for the kind whose rows
+are paged, `attn_in` / `attn_out` around the engine's own cache step with
+`decode_attention`, `prefill_attention` and `decode_kernel` / `kernel_gate`;
+for the kind kept a sequence, its state step whole (`conv_decode`,
+`conv_prefill`); `feed_forward`, `expert_tile`, `embed_tokens` and
+`final_logits`; and `REFUSED`, what of the engine's options it does not run,
+with the reason.
 """
 
 from __future__ import annotations
@@ -55,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.decode_pallas import gqa_decode_attention, gqa_decode_ok
 from ..parallel.moe import moe_held_gated_serve, swiglu
 from .pangu_ultra_moe import NEG, layer_params, rms_norm, rope
 
@@ -63,6 +66,9 @@ NAME = "lfm2_moe"
 # layers (a paged pool), and a state of fixed size a sequence in the
 # convolution layers (a slot of the state pool), `cache_shapes`
 CACHE = "hybrid"
+# which pool each operator kind keeps: rows a position (paged), or a state of
+# fixed size a sequence (`conv_decode` / `conv_prefill` step it whole)
+POOLS = {"attn": "rows", "conv": "state"}
 
 REFUSED = {
     "spec_decode": "a rejected draft would have to take the convolution "
@@ -299,6 +305,32 @@ def next_state(zz, n_valid, cfg: Lfm2MoEConfig):
     return jax.lax.dynamic_slice_in_dim(zz, n_valid, cfg.conv_taps - 1)
 
 
+def conv_decode(x, lp, i, cfg: Lfm2MoEConfig, pool, slots, pos):
+    """A convolution layer's decode step over its state in the state pool,
+    whole: x (B, d) at positions `pos` (B,), each row's state at slot
+    `slots` (B,) of convolution layer `i` of `pool`; one step of the
+    convolution (from noughts at position 0: a slot is handed on as its last
+    owner left it) and the new state back, the last `taps - 1` rows of
+    `[state ; z]` (`next_state` behind one position). Returns (x, pool)."""
+    gate, z = conv_in(x, lp, cfg)
+    tail = jnp.where((pos == 0)[:, None, None], 0, pool[i, slots])
+    c, zz = jax.vmap(lambda t, z_: conv_mix(t, z_[None], lp, cfg))(tail, z)
+    pool = pool.at[i, slots].set(zz[:, 1:])
+    return conv_out(x, gate, c[:, 0], lp, cfg), pool
+
+
+def conv_prefill(x, lp, i, cfg: Lfm2MoEConfig, pool, slot, pos0, n_valid):
+    """A convolution layer's step for a prefill chunk x (C, d) from position
+    `pos0`: it starts from the sequence's state at slot `slot` (noughts at
+    position 0) and leaves the state behind its last VALID position
+    (`next_state`). Returns (x, pool)."""
+    gate, z = conv_in(x, lp, cfg)
+    tail = jnp.where(pos0 == 0, 0, pool[i, slot])
+    c, zz = conv_mix(tail, z, lp, cfg)
+    pool = pool.at[i, slot].set(next_state(zz, n_valid, cfg))
+    return conv_out(x, gate, c, lp, cfg), pool
+
+
 def conv_out(x, gate, c, lp, cfg: Lfm2MoEConfig):
     """The operator's second half: (C * c) W_out into the residual."""
     dt = cfg.dtype
@@ -369,6 +401,26 @@ def decode_attention(q, rows, live, cfg: Lfm2MoEConfig):
         o = jnp.einsum("bgqs,bsgd->bgqd", p, v,
                        preferred_element_type=jnp.float32)
         return o.reshape(q.shape).astype(cfg.dtype)
+
+
+def decode_kernel(q, pool, layer, table, pos, cfg: Lfm2MoEConfig, *,
+                  block_size: int, interpret: bool):
+    """The decode attention on the Mosaic kernel, over the pool where it
+    lies (`gqa_decode_attention`)."""
+    with jax.named_scope("lm.attn.attn"):
+        return gqa_decode_attention(
+            q, pool, layer, table, pos, block_size=block_size,
+            n_kv_heads=cfg.n_kv_heads, interpret=interpret)
+
+
+def kernel_gate(cfg: Lfm2MoEConfig, block_size: int, dtype) -> tuple:
+    """(whether the decode kernel compiles for this pool, what to say where
+    it was asked for and does not)."""
+    return (gqa_decode_ok(block_size, cfg.n_kv_heads,
+                          cfg.n_heads // cfg.n_kv_heads, cfg.head_dim, dtype),
+            f"the grouped-query decode kernel does not compile for pages of "
+            f"{block_size} {jnp.dtype(dtype)} rows of {cfg.n_kv_heads} x 2 x "
+            f"{cfg.head_dim} (ops/decode_pallas.py gqa_decode_ok)")
 
 
 def prefill_attention(q, qpos, read_rows, n_keys, cfg: Lfm2MoEConfig, *,

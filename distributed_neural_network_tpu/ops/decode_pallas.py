@@ -550,16 +550,19 @@ _MLA_STEP_POSITIONS = 512
 _MLA_VMEM_BYTES = 64 << 20
 
 
-def _mla_paged_kernel(l_ref, table_ref, pos_ref, q_ref, pool_hbm, o_ref,
-                      buf, acc_ref, sem, *, bs, pps, rank, scale):
-    """`_paged_kernel`'s loop over the batch's fetch steps, for a pool of
-    latent rows: a step's pages land one under the other in a half of
-    ``buf`` (pps * bs, W) and all H heads score them in ONE product on the
-    MXU, ``q (H, W) . rows^T``; the probabilities weigh the rows' first
-    ``rank`` values, ``p (H, pps * bs) . rows[:, :rank]``: K and V are the
-    same bytes, fetched once for all heads. Pages past ``pos[b]`` are not
-    copied; what a half still holds from an earlier step (or the zeros it
-    starts with) lies past ``pos`` and weighs nought."""
+def _fetch_step_loop(l_ref, table_ref, pos_ref, q_ref, pool_hbm, o_ref, buf,
+                     acc_ref, sem, *, bs, pps, scores, weigh, round_p):
+    """`_paged_kernel`'s loop over the batch's fetch steps, for the kernels
+    that read ONE pool of rows (latent, grouped-query): a step's pages land
+    one under the other in a half of ``buf`` (pps * bs, row) while the step
+    before is computed on from the other half. A step's products are the
+    kernel's: ``scores(q, rows) -> (s, ctx)``, the sequence's query rows'
+    scaled float32 scores (H', rows), and ``weigh(p, ctx) -> (H', v)``, the
+    probabilities against the values; between them the online softmax over
+    all query rows at once, its probabilities in the pool's dtype where
+    ``round_p`` (the running sum then adds them as rounded). Pages past
+    ``pos[b]`` are not copied; what a half still holds from an earlier step
+    (or the zeros it starts with) lies past ``pos`` and weighs nought."""
     n_seq = q_ref.shape[0]
     layer = l_ref[0]
     div = jax.lax.div
@@ -607,17 +610,20 @@ def _mla_paged_kernel(l_ref, table_ref, pos_ref, q_ref, pool_hbm, o_ref,
         l = jnp.where(first, 0.0, l)
 
         def compute():
-            rows = buf[1 - slot]                            # (rows, W)
-            s = _dot_nt(q_ref[pb], rows) * scale            # (H, rows) f32
+            rows = buf[1 - slot]                          # (rows, row)
+            s, ctx = scores(q_ref[pb], rows)           # (H', rows) f32
             at = pi * step_rows + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
             s = jnp.where(at <= pos_ref[pb], s, _NEG_BIG)
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
+            if round_p:
+                p = p.astype(rows.dtype)
             alpha = jnp.exp(m - m_new)
-            pv = _dot_nn(p.astype(rows.dtype), rows[:, :rank])
+            pv = weigh(p, ctx)
             acc_ref[...] = jnp.where(first, 0.0, acc_ref[...] * alpha) + pv
-            return m_new, l * alpha + p.sum(axis=-1, keepdims=True)
+            return m_new, l * alpha + p.astype(jnp.float32).sum(
+                axis=-1, keepdims=True)
 
         m, l = jax.lax.cond(n_pages > 0, compute, lambda: (m, l))
 
@@ -638,6 +644,18 @@ def _mla_paged_kernel(l_ref, table_ref, pos_ref, q_ref, pool_hbm, o_ref,
         jnp.full((h, 1), _NEG_BIG, jnp.float32),
         jnp.zeros((h, 1), jnp.float32),
     ))
+
+
+def _mla_paged_kernel(*refs, bs, pps, rank, scale):
+    """`_fetch_step_loop` over a pool of latent rows (pps * bs, W): all H
+    heads score a step's rows in ONE product on the MXU, ``q (H, W) .
+    rows^T``; the float32 probabilities weigh the rows' first ``rank``
+    values, ``p (H, pps * bs) . rows[:, :rank]``: K and V are the same
+    bytes, fetched once for all heads."""
+    _fetch_step_loop(
+        *refs, bs=bs, pps=pps, round_p=False,
+        scores=lambda q, rows: (_dot_nt(q, rows) * scale, rows),
+        weigh=lambda p, rows: _dot_nn(p.astype(rows.dtype), rows[:, :rank]))
 
 
 def mla_decode_attention(q_lat, pool, layer, table, pos, *, block_size: int,
@@ -877,113 +895,42 @@ def mla_prefill_ok(block_size: int, width: int, rank: int, nope: int,
 
 # --------------------------- grouped-query pool (K and V side by side)
 
-# the positions one fetch step brings into VMEM (1 MiB of bfloat16 rows of
-# 1,024 values: a copy in flight covers the compute on the step before it)
+# the positions one fetch step of a grouped-query kernel brings into VMEM (1
+# MiB of bfloat16 rows of 1,024 values, 1.25 MiB of split rows of 1,280: a
+# copy in flight covers the compute on the step before it)
 _GQA_STEP_POSITIONS = 512
 
 
-def _gqa_paged_kernel(l_ref, table_ref, pos_ref, q_ref, pool_hbm, o_ref,
-                      buf, acc_ref, sem, *, bs, pps, groups, scale):
-    """`_mla_paged_kernel`'s loop over the batch's fetch steps, for a pool
-    whose row holds every KV head's keys and values side by side, one
-    128-lane tile a KV head: a step's pages land one under the other in a
-    half of ``buf`` (pps * bs, groups * 128), and KV head g's tile is read
-    ONCE for its query heads: ``q_g (8, 128) . tile_g^T`` on the MXU (the
-    queries lie in the tile's key lanes, noughts against its value lanes; a
-    group's spare query rows are noughts), the online softmax over all
-    groups' scores at once, then ``p_g (8, rows) . tile_g``, whose value
-    lanes are the weighted values (the key lanes come out as well and the
-    caller drops them: the tile is multiplied as it lies). Pages past
-    ``pos[b]`` are not copied; what a half still holds from an earlier step
-    (or the noughts it starts with) lies past ``pos`` and weighs nought."""
-    n_seq = q_ref.shape[0]
-    layer = l_ref[0]
-    div = jax.lax.div
-    step_rows = pps * bs
+def _by_group(p, values):
+    """Each group's probabilities (its run of ``p``'s rows) against its own
+    values, the groups' results stacked: (H', v)."""
+    sub = p.shape[0] // len(values)
+    return jnp.concatenate([_dot_nn(p[k * sub:(k + 1) * sub], v)
+                            for k, v in enumerate(values)], axis=0)
+
+
+def _gqa_paged_kernel(*refs, bs, pps, groups, scale):
+    """`_fetch_step_loop` over a pool whose row holds every KV head's keys
+    and values side by side, one 128-lane tile a KV head (pps * bs, groups *
+    128): KV head g's tile is read ONCE for its query heads, ``q_g (8, 128)
+    . tile_g^T`` on the MXU (the queries lie in the tile's key lanes,
+    noughts against its value lanes; a group's spare query rows are
+    noughts), the online softmax over all groups' scores at once, then ``p_g
+    (8, rows) . tile_g``, whose value lanes are the weighted values (the key
+    lanes come out as well and the caller drops them: the tile is multiplied
+    as it lies)."""
+    q_ref = refs[3]
     sub = q_ref.shape[1] // groups      # query rows a group: 8 sublanes
     lanes = q_ref.shape[2]              # a KV head's [k ; v]: 128 lanes
 
-    def steps_of(b):
-        return div(pos_ref[b], step_rows) + 1
+    def scores(q, rows):
+        tiles = [rows[:, k * lanes:(k + 1) * lanes] for k in range(groups)]
+        return jnp.concatenate([
+            _dot_nt(q[k * sub:(k + 1) * sub], tiles[k])
+            for k in range(groups)], axis=0) * scale, tiles
 
-    def live_pages(b, i):
-        return jnp.minimum(pps, div(pos_ref[b], bs) + 1 - i * pps)
-
-    def copy(rows, slot, j):
-        return pltpu.make_async_copy(
-            pool_hbm.at[layer, rows], buf.at[slot, pl.ds(j * bs, bs)],
-            sem.at[slot])
-
-    buf[...] = jnp.zeros(buf.shape, buf.dtype)
-    n_steps = jax.lax.fori_loop(
-        0, n_seq, lambda b, n: n + steps_of(b), jnp.int32(0))
-
-    def step(g, carry):
-        # (b, i): the step to fetch; (pb, pi), n_pages, last: the step
-        # fetched last time round - the one to compute on; m, l: the
-        # online softmax's running maximum and sum (the weighted values'
-        # sum is `acc_ref`)
-        b, i, pb, pi, n_pages, last, m, l = carry
-        slot = jax.lax.rem(g, 2)
-        bf = jnp.minimum(b, n_seq - 1)
-        n_fetch = jnp.where(g < n_steps, live_pages(bf, i), 0)
-
-        def start(j, c):
-            blk = table_ref[bf, i * pps + j]
-            copy(pl.ds(blk * bs, bs), slot, j).start()
-            return c
-
-        jax.lax.fori_loop(0, n_fetch, start, 0)
-
-        def wait(j, c):
-            copy(pl.ds(0, bs), 1 - slot, j).wait()
-            return c
-
-        jax.lax.fori_loop(0, n_pages, wait, 0)
-        first = pi == 0
-        m = jnp.where(first, _NEG_BIG, m)
-        l = jnp.where(first, 0.0, l)
-
-        def compute():
-            rows = buf[1 - slot]                     # (rows, groups * 128)
-            q = q_ref[pb]                            # (groups * sub, 128)
-            tiles = [rows[:, k * lanes:(k + 1) * lanes]
-                     for k in range(groups)]
-            s = jnp.concatenate([
-                _dot_nt(q[k * sub:(k + 1) * sub], tiles[k])
-                for k in range(groups)], axis=0) * scale    # (H', rows) f32
-            at = pi * step_rows + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(at <= pos_ref[pb], s, _NEG_BIG)
-            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new).astype(rows.dtype)
-            alpha = jnp.exp(m - m_new)
-            pv = jnp.concatenate([
-                _dot_nn(p[k * sub:(k + 1) * sub], tiles[k])
-                for k in range(groups)], axis=0)            # (H', 128)
-            acc_ref[...] = jnp.where(first, 0.0, acc_ref[...] * alpha) + pv
-            return m_new, l * alpha + p.astype(jnp.float32).sum(
-                axis=-1, keepdims=True)
-
-        m, l = jax.lax.cond(n_pages > 0, compute, lambda: (m, l))
-
-        @pl.when(last)
-        def _write():
-            o_ref[pb] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-        ends = i + 1 == steps_of(bf)
-        return (
-            jnp.where(ends, b + 1, b), jnp.where(ends, 0, i + 1),
-            bf, i, n_fetch, jnp.logical_and(ends, g < n_steps), m, l,
-        )
-
-    zero = jnp.int32(0)
-    h = q_ref.shape[1]
-    jax.lax.fori_loop(0, n_steps + 1, step, (
-        zero, zero, zero, zero, zero, False,
-        jnp.full((h, 1), _NEG_BIG, jnp.float32),
-        jnp.zeros((h, 1), jnp.float32),
-    ))
+    _fetch_step_loop(*refs, bs=bs, pps=pps, round_p=True, scores=scores,
+                     weigh=_by_group)
 
 
 def gqa_decode_attention(q, pool, layer, table, pos, *, block_size: int,
@@ -1071,3 +1018,140 @@ def gqa_decode_ok(block_size: int, n_kv_heads: int, per_kv: int,
     return (mla_decode_ok(block_size, n_kv_heads * 2 * head_dim, _LANES,
                           dtype)
             and 2 * head_dim == _LANES and 1 <= per_kv <= _SUBLANES)
+
+
+# ---------------- grouped-query pool, keys wider than values (split row)
+
+def _split_gqa_paged_kernel(*refs, bs, pps, groups, nope, rope, rope_tile,
+                            vdim, scale):
+    """`_fetch_step_loop` over a pool whose row keeps the KV heads' unrotated
+    key parts, their rotated parts and their values apart, each part a run
+    of whole lane tiles: `[k_nope_0 ; ... ; k_rope_0 ; ... ; v_0 ; ...]`. KV
+    head g's three slices are read ONCE for its query rows (a group's rows
+    are ``sub`` sublanes, two tiles at 16 queries a KV head): scores are
+    ``q_nope_g . nope_g^T + q_rope_g . rope_tile^T`` (the group's rotated
+    queries lie in their head's lanes of the ``rope_tile`` lanes that hold
+    it, noughts against the other heads'), the online softmax over all
+    groups' scores at once, then ``p_g . v_g``."""
+    sub = refs[3].shape[1] // groups
+    rope_at, v_at = groups * nope, groups * (nope + rope)
+
+    def scores(q, rows):
+        s, values = [], []
+        for k in range(groups):
+            qk = q[k * sub:(k + 1) * sub]
+            at = rope_at + (k * rope // rope_tile) * rope_tile
+            s.append(_dot_nt(qk[:, :nope], rows[:, k * nope:(k + 1) * nope])
+                     + _dot_nt(qk[:, nope:], rows[:, at:at + rope_tile]))
+            values.append(rows[:, v_at + k * vdim:v_at + (k + 1) * vdim])
+        return jnp.concatenate(s, axis=0) * scale, values
+
+    _fetch_step_loop(*refs, bs=bs, pps=pps, round_p=True, scores=scores,
+                     weigh=_by_group)
+
+
+def split_gqa_decode_attention(q, pool, layer, table, pos, *, block_size: int,
+                               n_kv_heads: int, rope: int, v_dim: int,
+                               interpret: bool = False):
+    """One decode step of grouped-query attention whose keys are wider than
+    its values, read from the serving engine's KV pool where it lies.
+
+    q (B, H, qk) - the current position's query rows, each head's first
+    ``rope`` values its rotated part (models/mimo_v2.py `qkv`), H a multiple
+    of ``n_kv_heads`` (query head h reads KV head ``h // (H / n_kv_heads)``);
+    pool (L, slots, n_kv_heads * (qk + v_dim)) - the whole pool, left in HBM:
+    a row is every KV head's unrotated key part, then every head's rotated
+    part, then every head's values (models/mimo_v2.py `to_row`), a page the
+    contiguous ``(block_size, row)`` tile of one block; ``layer`` a scalar
+    that may be traced; table (B, W) int32, entries past a sequence's live
+    pages unread; pos (B,) int32 - positions 0..pos[b] are attended. Returns
+    o (B, H, v_dim) in q's dtype. Scores are scaled by 1/sqrt(qk); scores
+    and the value sum are MXU products in the pool's dtype with float32
+    accumulation, the online softmax float32. Gate a compiled call with
+    `split_gqa_decode_ok`.
+
+    Around the one Mosaic call the queries are laid out as the kernel reads
+    them - a group's heads on the first rows of its sublane tiles, the
+    unrotated part in the first lanes and the rotated part in its head's
+    lanes of the rotated keys' tile - and the group's spare rows are cut off
+    the output again: plain XLA on (B, H, qk) values."""
+    b, h, qk = q.shape
+    nope = qk - rope
+    row = n_kv_heads * (qk + v_dim)
+    if pool.ndim != 3 or pool.shape[2] != row or h % n_kv_heads:
+        raise ValueError(
+            f"pool {pool.shape} does not hold keys of {qk} and values of "
+            f"{v_dim} for {n_kv_heads} KV heads under q's {h} heads")
+    if table.shape[0] != b or pos.shape != (b,):
+        raise ValueError(
+            f"table {table.shape} and pos {pos.shape} do not describe "
+            f"q's batch of {b}")
+    per = h // n_kv_heads
+    if not interpret and not split_gqa_decode_ok(
+            block_size, n_kv_heads, per, qk, rope, v_dim, pool.dtype):
+        raise ValueError(
+            f"split_gqa_decode_attention: pages of {block_size} {pool.dtype} "
+            f"rows of {n_kv_heads} x ({qk} + {v_dim}) with {per} queries a KV "
+            "head are no tiles this kernel compiles for (split_gqa_decode_ok)"
+            " - fall back to the XLA decode path")
+    sub = -(-per // _SUBLANES) * _SUBLANES
+    # the lanes read for a KV head's rotated keys: the lane tile that holds
+    # them (two heads' at 64), or all heads' where they fill less than one
+    tile = min(_LANES, n_kv_heads * rope)
+    qg = q.reshape(b, n_kv_heads, per, qk)
+    qa = jnp.zeros((b, n_kv_heads, sub, nope + tile), q.dtype).at[
+        :, :, :per, :nope].set(qg[..., rope:])
+    for k in range(n_kv_heads):
+        at = nope + k * rope % tile
+        qa = qa.at[:, k, :per, at:at + rope].set(qg[:, k, :, :rope])
+    pps = max(1, min(table.shape[1], _GQA_STEP_POSITIONS // block_size))
+    o = pl.pallas_call(
+        functools.partial(_split_gqa_paged_kernel, bs=block_size, pps=pps,
+                          groups=n_kv_heads, nope=nope, rope=rope,
+                          rope_tile=tile, vdim=v_dim,
+                          scale=1.0 / float(qk) ** 0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((2, pps * block_size, row), pool.dtype),
+                pltpu.VMEM((n_kv_heads * sub, v_dim), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=_struct((b, n_kv_heads * sub, v_dim), q.dtype, q, pool),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_MLA_VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name="split_gqa_decode_attn",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        table.astype(jnp.int32), pos.astype(jnp.int32),
+        qa.reshape(b, n_kv_heads * sub, nope + tile), pool,
+    )
+    return o.reshape(b, n_kv_heads, sub, v_dim)[:, :, :per].reshape(
+        b, h, v_dim)
+
+
+def split_gqa_decode_ok(block_size: int, n_kv_heads: int, per_kv: int,
+                        qk: int, rope: int, v_dim: int, dtype) -> bool:
+    """True where `split_gqa_decode_attention` compiles: `mla_decode_ok`'s
+    pages (whole sublane tiles of the pool's dtype), each KV head's
+    unrotated key part and its values whole 128-lane tiles, the rotated
+    parts whole tiles together with a head's never across two, and a
+    group's queries within two 8-sublane tiles (tests/test_tpu_aot_compile.py
+    compiles the served shape for a described v5e)."""
+    nope = qk - rope
+    rope_all = n_kv_heads * rope
+    return (mla_decode_ok(block_size, n_kv_heads * (qk + v_dim), _LANES,
+                          dtype)
+            and nope % _LANES == 0 and v_dim % _LANES == 0
+            and rope_all % _LANES == 0 and _LANES % rope == 0
+            and 1 <= per_kv <= 2 * _SUBLANES)

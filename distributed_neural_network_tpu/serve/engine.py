@@ -87,20 +87,25 @@ weights are refused for it by name. The per-head programs above are
 untouched by it.
 
 **Two kinds of state** (a module whose ``CACHE`` is ``"hybrid"``,
-models/lfm2_moe.py): its attention layers keep a row a position in ONE pool
-``(attention layers, slots, row)`` under the K pool's name, K and V of every
-KV head side by side; its convolution layers keep a state of fixed size a
-SEQUENCE in the state pool ``(convolution layers, state slots, rows, d)``,
-one slot a sequence, taken and freed with its blocks (`kv_cache.py`). Both
-pools are donated to every program and rebound from its outputs. The two
-program families (`_hybrid_decode`, `_hybrid_prefill`) have one table width
-and walk the model's layers in Python, in the published order
-(`_hybrid_layers`): a decode row gathers its state by slot, takes one
-convolution step and scatters the new state; a prefill chunk starts from the
-sequence's state - from noughts where its first position is 0, decided in
-the program, since a freed slot is handed on as it was left - and leaves the
-state of its last valid position. Preemption replays from the tokens, which
-rebuilds the state: there is no snapshot.
+models/lfm2_moe.py, models/mimo_v2.py): the module declares which pool each
+of its operator kinds keeps (``POOLS``). A kind of ``"rows"`` keeps a row a
+position in ONE paged pool ``(such layers, slots, row)`` under the K pool's
+name (LFM2's attention layers, K and V of every KV head side by side;
+MiMo's full-attention layers); a kind of ``"state"`` keeps a state of fixed
+size a SEQUENCE in the state pool ``(such layers, state slots, ...)``, one
+slot a sequence, taken and freed with its blocks (`kv_cache.py`): LFM2's
+convolution states, MiMo's window layers' rings of their last ``window``
+rows. Both pools are donated to every program and rebound from its outputs.
+The two program families (`_hybrid_decode`, `_hybrid_prefill`) have one
+table width and walk the model's layers in Python, in the published order
+(`_hybrid_layers`). A rows kind's step is the engine's: the module's
+``<kind>_in``, the row written, the module's kernel or `jax.numpy` oracle
+over the pool, ``<kind>_out``. A state kind's step is the module's whole
+(``<kind>_decode``, ``<kind>_prefill``), over its slot of the state pool:
+whether what a slot holds is the sequence's own - noughts before position 0,
+ring rows whose position is not its own - is decided in the program from
+positions, since a freed slot is handed on as it was left. Preemption
+replays from the tokens, which rebuilds the state: there is no snapshot.
 
 **What kind of engine this is** is asked of the module once, in the
 constructor (`_Cache`): the pools, the program families, the table width,
@@ -124,8 +129,6 @@ from ..models import transformer as tfm
 from ..models.transformer import TransformerConfig, _sinusoid_pe
 from ..ops.decode_pallas import (
     decode_paged_attention,
-    gqa_decode_attention,
-    gqa_decode_ok,
     mla_decode_attention,
     mla_decode_ok,
     mla_prefill_attention,
@@ -684,9 +687,10 @@ def _hybrid_layers(cfg, params, x, pools, op_step, valid):
     """The layers of a hybrid program, walked in Python in the model's own
     order (`layer_plan`: an interleaved pattern of two operator kinds and
     two feed-forward kinds is no stack to scan): the operator is the
-    program's own ``op_step[kind](x, lp, i, pools) -> (x, pools)``, the
-    module's `*_in` / `*_out` around its cache or state step at index ``i``
-    of that kind's pool; the feed-forward is the module's. ``pools`` = (KV
+    program's own ``op_step[kind](x, lp, i, pools) -> (x, pools)`` at index
+    ``i`` of that kind's pool (`ServeEngine._hybrid_steps`: the engine's
+    paged step around the module's `*_in` / `*_out`, or the module's own
+    state step); the feed-forward is the module's. ``pools`` = (KV
     pool, state pool), donated and threaded through every layer: each step
     reads and writes its rows at a static layer index, so the update is in
     place (tests/test_lfm2_moe.py pins it on the compiled programs). A
@@ -782,6 +786,12 @@ class _Cache:
     # whether a prefill attention kernel compiles (None: the module brings
     # none, its chunked prefill is `jax.numpy`)
     prefill_kernel_ok: bool | None = None
+    # (operator kind, "rows" or "state") of a module with two kinds of
+    # state, in the order of its `POOLS`: which pool each kind keeps
+    kinds: tuple = ()
+    # the module's count of a prefill program's attention pairs by layer
+    # kind (`attn_pairs`), None where it keeps none
+    attn_pairs: object = None
 
 
 @dataclass
@@ -827,6 +837,15 @@ def _bucket(n: int, lo: int = 1) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def batch_buckets(max_batch: int) -> list:
+    """The decode batch buckets of an engine: the powers of two under
+    ``max_batch`` and ``max_batch`` itself (a batch is padded to the
+    smallest that holds it, `_dispatch`)."""
+    out = [1 << i for i in range(max_batch.bit_length())
+           if 1 << i < max_batch]
+    return out + [max_batch]
 
 
 class ServeEngine:
@@ -930,30 +949,26 @@ class ServeEngine:
                     f"of {bs} {jnp.dtype(pool_dt)} rows "
                     "(ops/decode_pallas.py mla_decode_ok)"))
         elif kind == "hybrid":
-            # the attention layers' rows in ONE pool under the K pool's
-            # name, K and V of every KV head side by side (per-head (H, Dh)
-            # minor axes of (8, 64) would be stored in (16, 128) tiles, four
-            # times the bytes); the convolution layers' states in the state
-            # pool, a slot a sequence
+            # the rows of the layers that keep a row a position in ONE pool
+            # under the K pool's name, every KV head's keys and values side
+            # by side (per-head (H, Dh) minor axes of (8, 64) would be
+            # stored in (16, 128) tiles, four times the bytes); the states
+            # of the layers that keep one a sequence in the state pool, a
+            # slot a sequence
             shapes = mod.cache_shapes(cfg)
             n_kv, self.row_width = shapes["kv"]
             self.k_pool = jnp.zeros((n_kv, slots, self.row_width), pool_dt)
             self.state_pool = jnp.zeros(
                 (shapes["state"][0], self.kv.cfg.state_slots)
                 + shapes["state"][1:], pool_dt)
+            kernel_ok, refusal = mod.kernel_gate(cfg, bs, pool_dt)
             self._cache = _Cache(
                 pools=("k_pool", "state_pool"),
                 labels=("kv_pool", "state_pool"), width=one_width,
                 decode=self._hybrid_decode, prefill=self._hybrid_prefill,
-                routed=True, state=True,
-                kernel_ok=gqa_decode_ok(
-                    bs, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads,
-                    cfg.head_dim, pool_dt),
-                kernel_refusal=(
-                    f"the grouped-query decode kernel does not compile for "
-                    f"pages of {bs} {jnp.dtype(pool_dt)} rows of "
-                    f"{cfg.n_kv_heads} x 2 x {cfg.head_dim} "
-                    "(ops/decode_pallas.py gqa_decode_ok)"))
+                routed=True, state=True, kernel_ok=kernel_ok,
+                kernel_refusal=refusal, kinds=tuple(mod.POOLS.items()),
+                attn_pairs=getattr(mod, "attn_pairs", None))
         else:
             L, H, Dh = cfg.n_layers, cfg.n_heads, cfg.head_dim
             self.k_pool = jnp.zeros((L, slots, H, Dh), pool_dt)
@@ -1413,7 +1428,14 @@ class ServeEngine:
         prefill.__name__ = "latent_prefill"
         return prefill
 
-    # ------------------ KV rows and convolution states (hybrid) programs
+    # ------------- rows a position and states a sequence (hybrid) programs
+
+    def _hybrid_steps(self, rows_step, state_step) -> dict:
+        """The operator step of each of the module's kinds, from which pool
+        it keeps (`_Cache.kinds`): ``rows_step(kind)`` or
+        ``state_step(kind)``, each ``(x, lp, i, pools) -> (x, pools)``."""
+        return {kind: (rows_step if where == "rows" else state_step)(kind)
+                for kind, where in self._cache.kinds}
 
     def _hybrid_decode(self, B: int, W: int):
         cfg, mod = self.cfg, self.cfg.module
@@ -1428,45 +1450,43 @@ class ServeEngine:
             x = mod.embed_tokens(params, tok, cfg)               # (B, d)
             flat = table[jnp.arange(B), pos // bs] * bs + pos % bs
             valid = table[:, 0] != SCRATCH_BLOCK    # a spare row: no token
-            fresh = (pos == 0)[:, None, None]       # no state before 0
             if not use_kernel:
                 idx = _span_idx(table, bs)                       # (B, S)
                 live = jnp.arange(S)[None, :] <= pos[:, None]
 
-            def conv_step(x, lp, i, pools):
-                # each row's state by its slot, one step of the convolution,
-                # the new state back: the last `taps - 1` rows of [state ; z]
-                # (`next_state` behind one position)
-                kv_pool, state_pool = pools
-                gate, z = mod.conv_in(x, lp, cfg)
-                tail = jnp.where(fresh, 0, state_pool[i, slots])
-                c, zz = jax.vmap(
-                    lambda t, z_: mod.conv_mix(t, z_[None], lp, cfg)
-                )(tail, z)
-                state_pool = state_pool.at[i, slots].set(zz[:, 1:])
-                return (mod.conv_out(x, gate, c[:, 0], lp, cfg),
-                        (kv_pool, state_pool))
+            def state_step(kind):
+                # the module's own step over each row's slot, whole
+                advance = getattr(mod, kind + "_decode")
 
-            def attn_step(x, lp, i, pools):
-                # write this position's row, then attend over the cache
-                kv_pool, state_pool = pools
-                q, row = mod.attn_in(x, lp, cfg, pos)
-                kv_pool = _write_rows(kv_pool, i, flat, row)
-                if use_kernel:
-                    with jax.named_scope("lm.attn.attn"):
-                        o = gqa_decode_attention(
-                            q, kv_pool, i, table, pos, block_size=bs,
-                            n_kv_heads=cfg.n_kv_heads,
-                            interpret=not on_tpu(),
-                        )
-                else:   # the oracle: gather the table's span
-                    o = mod.decode_attention(
-                        q, _read_rows(kv_pool, i, idx), live, cfg)
-                return mod.attn_out(x, o, lp, cfg), (kv_pool, state_pool)
+                def one(x, lp, i, pools):
+                    kv_pool, state_pool = pools
+                    x, state_pool = advance(x, lp, i, cfg, state_pool, slots,
+                                            pos)
+                    return x, (kv_pool, state_pool)
+                return one
+
+            def rows_step(kind):
+                into, out = (getattr(mod, kind + "_in"),
+                             getattr(mod, kind + "_out"))
+
+                def one(x, lp, i, pools):
+                    # write this position's row, then attend over the cache
+                    kv_pool, state_pool = pools
+                    q, row = into(x, lp, cfg, pos)
+                    kv_pool = _write_rows(kv_pool, i, flat, row)
+                    if use_kernel:
+                        o = mod.decode_kernel(
+                            q, kv_pool, i, table, pos, cfg, block_size=bs,
+                            interpret=not on_tpu())
+                    else:   # the oracle: gather the table's span
+                        o = mod.decode_attention(
+                            q, _read_rows(kv_pool, i, idx), live, cfg)
+                    return out(x, o, lp, cfg), (kv_pool, state_pool)
+                return one
 
             x, pools, counts = _hybrid_layers(
                 cfg, params, x, (kv_pool, state_pool),
-                {"conv": conv_step, "attn": attn_step}, valid)
+                self._hybrid_steps(rows_step, state_step), valid)
             logits = mod.final_logits(params, x, cfg)
             return *pools, _next_tokens(logits, temps, keys), logits, counts
 
@@ -1489,40 +1509,46 @@ class ServeEngine:
             # the chunk's dead tail -> the scratch block
             flat = jnp.where(valid, table[pv // bs] * bs + pv % bs, 0)
 
-            def conv_step(x, lp, i, pools):
-                # the chunk's convolution starts from the sequence's state
-                # (noughts at position 0: the slot is as its last owner
-                # left it) and leaves the state behind its last VALID
-                # position (`next_state`)
-                kv_pool, state_pool = pools
-                gate, z = mod.conv_in(x, lp, cfg)
-                tail = jnp.where(pos0 == 0, 0, state_pool[i, slot])
-                c, zz = mod.conv_mix(tail, z, lp, cfg)
-                state_pool = state_pool.at[i, slot].set(
-                    mod.next_state(zz, n_valid, cfg))
-                return (mod.conv_out(x, gate, c, lp, cfg),
-                        (kv_pool, state_pool))
+            def state_step(kind):
+                # the module's own step over the sequence's slot, whole: it
+                # starts from what the slot holds of the sequence (decided
+                # from positions: the slot is as its last owner left it)
+                # and leaves the state behind the last VALID position
+                advance = getattr(mod, kind + "_prefill")
 
-            def attn_step(x, lp, i, pools):
-                # write the chunk's rows, then attend over the table's live
-                # span, the rows just written included, a key block at a time
-                kv_pool, state_pool = pools
-                q, rows = mod.attn_in(x, lp, cfg, pv)
-                kv_pool = _write_rows(kv_pool, i, flat, rows)
+                def one(x, lp, i, pools):
+                    kv_pool, state_pool = pools
+                    x, state_pool = advance(x, lp, i, cfg, state_pool, slot,
+                                            pos0, n_valid)
+                    return x, (kv_pool, state_pool)
+                return one
 
-                def read_rows(j):
-                    blk = jax.lax.dynamic_slice_in_dim(
-                        table, j * pages, pages)
-                    return _read_rows(kv_pool, i, _span_idx(blk, bs))
+            def rows_step(kind):
+                into, out = (getattr(mod, kind + "_in"),
+                             getattr(mod, kind + "_out"))
 
-                o = mod.prefill_attention(
-                    q, pv, read_rows, pos0 + n_valid, cfg,
-                    key_block=key_block)
-                return mod.attn_out(x, o, lp, cfg), (kv_pool, state_pool)
+                def one(x, lp, i, pools):
+                    # write the chunk's rows, then attend over the table's
+                    # live span, the rows just written included, a key block
+                    # at a time
+                    kv_pool, state_pool = pools
+                    q, rows = into(x, lp, cfg, pv)
+                    kv_pool = _write_rows(kv_pool, i, flat, rows)
+
+                    def read_rows(j):
+                        blk = jax.lax.dynamic_slice_in_dim(
+                            table, j * pages, pages)
+                        return _read_rows(kv_pool, i, _span_idx(blk, bs))
+
+                    o = mod.prefill_attention(
+                        q, pv, read_rows, pos0 + n_valid, cfg,
+                        key_block=key_block)
+                    return out(x, o, lp, cfg), (kv_pool, state_pool)
+                return one
 
             _, pools, counts = _hybrid_layers(
                 cfg, params, x, (kv_pool, state_pool),
-                {"conv": conv_step, "attn": attn_step}, valid)
+                self._hybrid_steps(rows_step, state_step), valid)
             # no logits: the last prompt token is the decode batch's
             return *pools, counts
 
@@ -1748,7 +1774,7 @@ class ServeEngine:
         def zeros(*shape):
             return jnp.zeros(shape, jnp.int32)
 
-        for B in pow2(self.ecfg.max_batch):
+        for B in batch_buckets(self.ecfg.max_batch):
             # as `step` calls it: host arrays in, the keys left on the device
             _row_keys(np.zeros((B,), np.uint32), np.zeros((B,), np.int32))
             for W in widths:
@@ -1767,7 +1793,7 @@ class ServeEngine:
         if self.spec_k:
             # the speculative bucket families: drafter + K-position
             # verify per (batch, width)
-            for B in pow2(self.ecfg.max_batch):
+            for B in batch_buckets(self.ecfg.max_batch):
                 for W in widths:
                     warm("draft", self._draft_fn(B, W), zeros(B), zeros(B),
                          zeros(B, W))
@@ -2174,6 +2200,12 @@ class ServeEngine:
                     # its width: the key blocks the program walks
                     kb = min(_PREFILL_KEY_BLOCK, W * bs)
                     W = -(-(seq.pos + n) // kb) * kb // bs
+                if self._cache.attn_pairs is not None:
+                    pairs = self._cache.attn_pairs(
+                        self.cfg, seq.pos, n, C, W * bs)
+                    stats["attn_pairs"] = {
+                        k: v + stats.get("attn_pairs", {}).get(k, 0)
+                        for k, v in pairs.items()}
                 if self._cache.routed:
                     tick.counts.append(out[0])
                     if self._prefill_route() == "pallas":

@@ -408,7 +408,9 @@ def add_model_args(p: argparse.ArgumentParser) -> None:
                    "models/__init__.py FAMILIES: a latent-attention "
                    "expert model, e.g. benchmark/families/pangu_ultra_moe/"
                    "tiny.json, or a short-convolution and grouped-query "
-                   "expert model, benchmark/families/lfm2_moe/tiny.json) "
+                   "expert model, benchmark/families/lfm2_moe/tiny.json, "
+                   "or a window- and full-attention expert model, "
+                   "benchmark/configs/mimo-v2.5.json) "
                    "in place of the GPT-2 block of the geometry "
                    "flags; seeded weights at --dtype")
 

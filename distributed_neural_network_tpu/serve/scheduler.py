@@ -401,6 +401,14 @@ class ServeScheduler:
             "serve_attn_kernel_pairs_total",
             "Live query-key pairs handed to Mosaic prefill attention calls",
         ).labels(path="prefill")
+        # a prefill program's attention pairs by layer kind, of a module
+        # that counts them (models/mimo_v2.py `attn_pairs`): scored, and
+        # kept by the masks
+        self._m_attn_pairs = r.counter(
+            "serve_attn_pairs_total",
+            "Query-key pairs of prefill attention, by layer kind: scored "
+            "and live (kept by the causal and window masks)",
+        )
         moe_pairs = r.counter(
             "serve_moe_pairs_total",
             "Routed (token, expert) pairs by where their expert lies",
@@ -891,6 +899,8 @@ class ServeScheduler:
         # (only a latent module's prefill programs call a Mosaic attention
         # kernel: other ticks do not bring the key)
         self._m_kernel_prefill.inc(stats.get("prefill_kernel_pairs", 0))
+        for (layers, kind), n in stats.get("attn_pairs", {}).items():
+            self._m_attn_pairs.labels(layers=layers, kind=kind).inc(n)
         moe = stats.get("moe")
         if moe is not None:
             self._m_moe["held"].inc(moe["held"])

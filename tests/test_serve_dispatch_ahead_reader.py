@@ -69,5 +69,6 @@ def test_the_benchmark_names_the_reader_for_every_serving_cell():
         "moves": "serve_tokens_per_s",
         "workloads": ["cerebras-gpt-1.3b.serve-longdoc",
                       "openpangu-ultra-moe-718b.serve-docqa-6k",
-                      "lfm2-24b-a2b.serve-reason-1k"],
+                      "lfm2-24b-a2b.serve-reason-1k",
+                      "mimo-v2.5.serve-mixed-32k"],
     }

@@ -31,6 +31,8 @@ from distributed_neural_network_tpu.ops.decode_pallas import (
     mla_prefill_attention,
     mla_prefill_ok,
     paged_decode_ok,
+    split_gqa_decode_attention,
+    split_gqa_decode_ok,
 )
 from distributed_neural_network_tpu.ops.flash import tuned_blocks
 from distributed_neural_network_tpu.ops.flash_pallas import flash_mha
@@ -159,6 +161,26 @@ def _gqa_decode(topo, batch, width, *, dtype=jnp.bfloat16):
     ])
 
 
+def _split_gqa_decode(topo, batch, width, *, dtype=jnp.bfloat16):
+    # the mixed-32k cell's full layers: 2 layers, 12,289 blocks of 64 rows
+    # of 4 KV heads' keys of 192 (128 unrotated + 64 rotated) and values of
+    # 128, 64 query heads, tables of 1,024 blocks
+    bs, kv, per, qk, rope, v = 64, 4, 16, 192, 64, 128
+    assert split_gqa_decode_ok(bs, kv, per, qk, rope, v, dtype)
+    i32 = jnp.int32
+
+    def fn(q, pool, layer, table, pos):
+        return split_gqa_decode_attention(
+            q, pool, layer[0], table, pos, block_size=bs, n_kv_heads=kv,
+            rope=rope, v_dim=v)
+
+    return fn, _on_one_chip(topo, [
+        ((batch, kv * per, qk), dtype),
+        ((2, 12289 * bs, kv * (qk + v)), dtype),
+        ((1,), i32), ((batch, width), i32), ((batch,), i32),
+    ])
+
+
 def _mla_prefill(topo, chunk):
     # the docqa cell's prefill attention: a chunk's 128 heads over the
     # latent pool, a layer's `kv_b` as the tree holds it
@@ -242,6 +264,12 @@ CASES = {
     "gqa_decode_bf16_b1_w128": lambda t: _gqa_decode(t, 1, 128),
     "gqa_decode_f32_b4_w4": lambda t: _gqa_decode(t, 4, 4,
                                                   dtype=jnp.float32),
+    "split_gqa_decode_bf16_b48_w1024": lambda t: _split_gqa_decode(
+        t, 48, 1024),
+    "split_gqa_decode_bf16_b1_w1024": lambda t: _split_gqa_decode(
+        t, 1, 1024),
+    "split_gqa_decode_f32_b4_w4": lambda t: _split_gqa_decode(
+        t, 4, 4, dtype=jnp.float32),
     "decode_paged_bf16_b16_w128": lambda t: _decode_paged(t, 16, 128),
     "decode_paged_bf16_b1_w1": lambda t: _decode_paged(t, 1, 1),
     # the serve smoke (chip_smoke.py: d512 / 4 heads of 128, 129 blocks)
@@ -443,4 +471,61 @@ def test_serve_decode_program_reads_the_pool_through_the_table_on_v5e(
         mem.argument_size_in_bytes + mem.output_size_in_bytes
         - mem.alias_size_in_bytes)
     assert max(held, mem.temp_size_in_bytes) < bucket
+    assert mem.alias_size_in_bytes >= pools
+
+
+@pytest.mark.parametrize("family,n", [("decode", 48), ("prefill", 512)])
+def test_mimo_serve_programs_compile_on_v5e(topo, monkeypatch, family, n):
+    """The served programs of `mimo-v2.5.serve-mixed-32k` at its widths and
+    pools (7 layers: 2 full, 5 window; 16 held experts; 12,289 blocks of 64;
+    the rings of 48 sequences), `decode_impl="auto"` on the described chip:
+    the decode program's only Mosaic calls are the full layers' kernel, one
+    a full layer, and the prefill program has none (its attention is plain
+    XLA); both pools are aliased to the outputs and what a program holds
+    beyond them stays under a gigabyte. The weights are shapes (nothing
+    runs, so the engine is built around a placeholder tree with a small
+    pool of its own), and the engine asks the runtime whether it is on a
+    TPU: here the test answers for it."""
+    import json
+
+    from distributed_neural_network_tpu.models import mimo_v2
+    from distributed_neural_network_tpu.serve import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "on_tpu", lambda: True)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mimo-v2.5.json")) as f:
+        cfg = mimo_v2.from_published(json.load(f), dtype=jnp.bfloat16)
+    eng = engine_mod.ServeEngine(
+        {"placeholder": jnp.zeros(())}, cfg,
+        engine_mod.EngineConfig(
+            max_batch=2, num_blocks=65, block_size=64, max_seq_len=34304,
+            prefill_chunk=512, decode_impl="auto"),
+    )
+    assert eng.decode_route() == "pallas"
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, jnp.float32 if x.ndim < 2 else cfg.dtype,
+            sharding=one_chip),
+        jax.eval_shape(lambda: mimo_v2.init_params(jax.random.key(0), cfg)),
+    )
+    kv = ((cfg.n_full, 12289 * 64, cfg.row("full")), cfg.dtype)
+    rings = ((cfg.n_window, 48 + 1, cfg.window, cfg.row("window")),
+             cfg.dtype)
+    width = eng._bucket_widths()[0]
+    tail = [jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)
+            for t in eng.bucket_tail(family, n, width)]
+    fn = (eng._decode_fn if family == "decode" else eng._prefill_fn)(
+        n, width)
+    compiled = fn.lower(params, *_on_one_chip(topo, [kv, rings]),
+                        *tail).compile()
+    assert mosaic_custom_calls(compiled) == (
+        cfg.n_full if family == "decode" else 0)
+    mem = compiled.memory_analysis()
+    pools = sum(math.prod(s) * 2 for s, _ in (kv, rings))
+    held = mem.peak_memory_in_bytes - (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        - mem.alias_size_in_bytes)
+    assert max(held, mem.temp_size_in_bytes) < 1 << 30
     assert mem.alias_size_in_bytes >= pools
