@@ -39,11 +39,12 @@ window layer's `sink` too), `dense` and `moe` (each with its `ff_norm`). This
 module is SERVED (`serve/engine.py`), not trained. What the engine asks of
 it: `CACHE`, `POOLS`, `cache_shapes`, `layer_plan`, for the kind whose rows
 are paged `full_in` / `full_out` around the engine's own cache step with
-`decode_attention`, `prefill_attention` and `decode_kernel` / `kernel_gate`,
-for the kind kept a sequence `window_decode` / `window_prefill` (its state
-step whole), `attn_pairs`, `feed_forward`, `expert_tile`, `embed_tokens`,
-`final_logits`; and `REFUSED`, what of the engine's options it does not run,
-with the reason.
+`decode_attention` and `prefill_attention` (the oracles) and their kernels
+`decode_kernel` / `kernel_gate` and `prefill_kernel` / `prefill_kernel_gate`
+/ `prefill_kernel_scored`, for the kind kept a sequence `window_decode` /
+`window_prefill` (its state step whole), `attn_pairs`, `feed_forward`,
+`expert_tile`, `embed_tokens`, `final_logits`; and `REFUSED`, what of the
+engine's options it does not run, with the reason.
 """
 
 from __future__ import annotations
@@ -59,6 +60,9 @@ import numpy as np
 from ..ops.decode_pallas import (
     split_gqa_decode_attention,
     split_gqa_decode_ok,
+    split_gqa_prefill_attention,
+    split_gqa_prefill_ok,
+    split_gqa_prefill_pairs,
 )
 from ..parallel.moe import moe_held_gated_serve, swiglu
 from .lfm2_moe import expert_tile
@@ -440,7 +444,9 @@ def prefill_attention(q, qpos, read_rows, n_keys, cfg: MiMoV2Config, *,
     positions `j * key_block ..` as (key_block, row) and they are folded
     into a float32 online softmax, so that no score block larger than (H, C,
     key_block) is made and the blocks past the last live key are not read.
-    Query i sees key positions <= qpos[i]. Returns o (C, H, v)."""
+    Query i sees key positions <= qpos[i]. Returns o (C, H, v). The oracle
+    of `prefill_kernel`, and the engine's prefill where that kernel is not
+    taken."""
     dt, f32 = cfg.dtype, jnp.float32
     c, kv = q.shape[0], cfg.n_kv_full
     qg = q.reshape(c, kv, cfg.n_heads // kv, cfg.qk_head)
@@ -471,6 +477,37 @@ def prefill_attention(q, qpos, read_rows, n_keys, cfg: MiMoV2Config, *,
     o = acc / jnp.maximum(l, 1e-30)[..., None]            # (KV, G, C, v)
     return o.transpose(2, 0, 1, 3).reshape(
         c, cfg.n_heads, cfg.v_head).astype(dt)
+
+
+def prefill_kernel(q, pool, layer, table, pos0, n_keys, cfg: MiMoV2Config,
+                   *, block_size: int, interpret: bool):
+    """A full layer's prefill attention on the Mosaic kernel, over the pool
+    where it lies (`split_gqa_prefill_attention`): the chunk's queries q (C,
+    H, qk) at positions `pos0 ..` over cache positions `0 .. n_keys - 1`,
+    the chunk's own rows among them -> o (C, H, v)."""
+    with jax.named_scope("lm.attn.full"):
+        return split_gqa_prefill_attention(
+            q, pool, layer, table, pos0, n_keys, block_size=block_size,
+            n_kv_heads=cfg.n_kv_full, rope=cfg.rope_dim, v_dim=cfg.v_head,
+            interpret=interpret)
+
+
+def prefill_kernel_gate(cfg: MiMoV2Config, block_size: int, dtype) -> bool:
+    """Whether the prefill kernel compiles for this pool (where it does not,
+    the engine's prefill takes `prefill_attention`)."""
+    return split_gqa_prefill_ok(
+        block_size, cfg.n_kv_full, cfg.n_heads // cfg.n_kv_full,
+        cfg.qk_head, cfg.rope_dim, cfg.v_head, dtype)
+
+
+def prefill_kernel_scored(cfg: MiMoV2Config, pos0: int, n: int, chunk: int,
+                          *, block_size: int, width: int) -> int:
+    """The (query, key) pairs one full layer's prefill kernel scores for `n`
+    tokens from `pos0` in a chunk bucket of `chunk` over a table of `width`
+    blocks (`split_gqa_prefill_pairs`)."""
+    return split_gqa_prefill_pairs(pos0, n, chunk,
+                                   cfg.n_heads // cfg.n_kv_full, width,
+                                   block_size)
 
 
 def ring_positions(last, cfg: MiMoV2Config):
@@ -547,19 +584,21 @@ def window_prefill(x, lp, i, cfg: MiMoV2Config, pool, slot, pos0, n_valid):
 
 
 def attn_pairs(cfg: MiMoV2Config, pos0: int, n: int, chunk: int,
-               keys: int) -> dict:
+               scored: int) -> dict:
     """The (query, key) pairs of one prefill program's attention, summed
     over the layers of each kind: ("full" | "window", "live" | "scored").
-    Scored: a full layer's chunk of `chunk` rows against the `keys` cache
-    positions its key blocks walk, a window layer's against the ring and the
-    chunk. Live: what the masks keep for the `n` tokens from `pos0` - every
-    position up to its own in a full layer, the last `window` of them in a
-    window layer."""
+    Scored: a full layer's `scored` pairs, what its attention walks (the
+    blocked loop's chunk of `chunk` rows against its key blocks, or the
+    kernel's blocks of query positions against their fetch steps,
+    `prefill_kernel_scored`), a window layer's chunk against the ring and
+    the chunk. Live: what the masks keep for the `n` tokens from `pos0` -
+    every position up to its own in a full layer, the last `window` of them
+    in a window layer."""
     w = cfg.window
     span = np.arange(pos0, pos0 + n)
     return {
         ("full", "live"): cfg.n_full * (n * pos0 + n * (n + 1) // 2),
-        ("full", "scored"): cfg.n_full * chunk * keys,
+        ("full", "scored"): cfg.n_full * scored,
         ("window", "live"): cfg.n_window * int(
             np.minimum(span + 1, w).sum()),
         ("window", "scored"): cfg.n_window * chunk * (w + chunk),

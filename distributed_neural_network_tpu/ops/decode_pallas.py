@@ -7,7 +7,10 @@ fetch step's rows in one MXU product, the rows' first `rank` values also
 the values) and `mla_prefill_attention` (the expanded form of a prefill
 chunk: a head a grid step, a fetch step's rows up-projected for that head
 in VMEM), both reading live pages through the block table as
-`decode_paged_attention` does.
+`decode_paged_attention` does; after them the kernels of a pool whose row
+holds every KV head's keys and values (grouped-query attention):
+`gqa_decode_attention`, `split_gqa_decode_attention` (keys wider than
+values) and its prefill sibling `split_gqa_prefill_attention`.
 
 **`decode_paged_attention`** - the serving engine's decode step
 (serve/engine.py `ServeEngine._decode_fn`, `decode_impl` "pallas", and
@@ -1022,6 +1025,24 @@ def gqa_decode_ok(block_size: int, n_kv_heads: int, per_kv: int,
 
 # ---------------- grouped-query pool, keys wider than values (split row)
 
+def _split_query_lanes(qg, rope: int, tile: int, rows: int):
+    """Queries (..., KV, per, qk), each head's rotated part first, laid out
+    as the split-row kernels read them: (..., KV, rows, nope + tile), a KV
+    head's query heads on its first ``per`` rows, each head's unrotated part
+    in the first lanes and its rotated part in its own lanes of the
+    ``tile`` lanes that hold the rotated keys (the lane tile that holds them,
+    two heads' at 64, or all heads' where they fill less than one),
+    noughts elsewhere."""
+    *lead, kv, per, qk = qg.shape
+    nope = qk - rope
+    qa = jnp.zeros((*lead, kv, rows, nope + tile), qg.dtype).at[
+        ..., :per, :nope].set(qg[..., rope:])
+    for k in range(kv):
+        at = nope + k * rope % tile
+        qa = qa.at[..., k, :per, at:at + rope].set(qg[..., k, :, :rope])
+    return qa
+
+
 def _split_gqa_paged_kernel(*refs, bs, pps, groups, nope, rope, rope_tile,
                             vdim, scale):
     """`_fetch_step_loop` over a pool whose row keeps the KV heads' unrotated
@@ -1095,15 +1116,9 @@ def split_gqa_decode_attention(q, pool, layer, table, pos, *, block_size: int,
             "head are no tiles this kernel compiles for (split_gqa_decode_ok)"
             " - fall back to the XLA decode path")
     sub = -(-per // _SUBLANES) * _SUBLANES
-    # the lanes read for a KV head's rotated keys: the lane tile that holds
-    # them (two heads' at 64), or all heads' where they fill less than one
     tile = min(_LANES, n_kv_heads * rope)
-    qg = q.reshape(b, n_kv_heads, per, qk)
-    qa = jnp.zeros((b, n_kv_heads, sub, nope + tile), q.dtype).at[
-        :, :, :per, :nope].set(qg[..., rope:])
-    for k in range(n_kv_heads):
-        at = nope + k * rope % tile
-        qa = qa.at[:, k, :per, at:at + rope].set(qg[:, k, :, :rope])
+    qa = _split_query_lanes(q.reshape(b, n_kv_heads, per, qk), rope, tile,
+                            sub)
     pps = max(1, min(table.shape[1], _GQA_STEP_POSITIONS // block_size))
     o = pl.pallas_call(
         functools.partial(_split_gqa_paged_kernel, bs=block_size, pps=pps,
@@ -1155,3 +1170,243 @@ def split_gqa_decode_ok(block_size: int, n_kv_heads: int, per_kv: int,
             and nope % _LANES == 0 and v_dim % _LANES == 0
             and rope_all % _LANES == 0 and _LANES % rope == 0
             and 1 <= per_kv <= 2 * _SUBLANES)
+
+
+# ------------------ split-row grouped-query pool, chunked prefill
+
+# query rows one grid step of the split prefill kernel scores against a
+# fetch step (a block of query positions, each with the query heads of a KV
+# head), and the cache positions a fetch step brings into VMEM: a KV head's
+# scores are (1,024, 1,024) float32, 4 MiB, and a step's rows 2.5 MiB. On a
+# TPU v5e a chunk of 512 over 32k keys took 6.3 ms a layer at this tiling,
+# 13.5 at 1,024 x 256 and 10.0 at 512 x 512: a longer step leaves fewer
+# updates of the running maxima, sums and accumulators a key
+_SPLIT_PREFILL_ROWS = 1024
+_SPLIT_PREFILL_KEYS = 1024
+
+
+def _split_prefill_tiles(chunk: int, per_kv: int, width: int,
+                         block_size: int) -> tuple:
+    """(query positions a grid step, pages a fetch step) of
+    `split_gqa_prefill_attention` for a chunk of ``chunk`` positions over a
+    table of ``width`` blocks."""
+    return (min(chunk, max(1, _SPLIT_PREFILL_ROWS // per_kv)),
+            max(1, min(width, _SPLIT_PREFILL_KEYS // block_size)))
+
+
+def _split_gqa_prefill_kernel(l_ref, table_ref, span_ref, q_ref, pool_hbm,
+                              o_ref, buf, m_sc, l_sc, acc_sc, sem, *, bs, pps,
+                              per, nope, rope, rope_tile, vdim, scale):
+    """One block of query positions a grid step, all KV heads: the cache
+    positions the block's queries can see, ``0 .. min(its last position,
+    n_keys - 1)``, a fetch step of ``pps`` pages at a time, double-buffered
+    through the block table (pages past them are not copied). A step's rows
+    serve every KV head in turn: head g's query rows (the block's positions,
+    each with the head's ``per`` query heads) score its unrotated and
+    rotated key slices, ``q_nope . k_nope^T + q_rope . rope_tile^T``, scaled
+    in float32; an online softmax folds them into the head's float32
+    accumulator with ``p . v``, p in the pool's dtype. Steps wholly at or
+    before the block's first position need no mask; the later ones keep key
+    position <= query position. A block with no token writes noughts."""
+    layer, pos0, n_keys = l_ref[0], span_ref[0], span_ref[1]
+    groups, tm = q_ref.shape[0], q_ref.shape[1]
+    tq, step_rows = tm // per, pps * bs
+    div = jax.lax.div
+    rope_at, v_at = groups * nope, groups * (nope + rope)
+    q_lo = pos0 + pl.program_id(0) * tq          # the block's first position
+    live = q_lo < n_keys
+    n_seen = jnp.where(live, jnp.minimum(q_lo + tq, n_keys), 0)
+    n_steps = div(n_seen + step_rows - 1, step_rows)
+    n_pages = div(n_seen + bs - 1, bs)
+    n_plain = jnp.minimum(div(q_lo + 1, step_rows), n_steps)
+
+    def live_pages(i):
+        return jnp.clip(n_pages - i * pps, 0, pps)
+
+    def copy(rows, slot, j):
+        return pltpu.make_async_copy(
+            pool_hbm.at[layer, rows], buf.at[slot, pl.ds(j * bs, bs)],
+            sem.at[slot])
+
+    def fetch(i, slot):
+        def start(j, carry):
+            blk = table_ref[i * pps + j]
+            copy(pl.ds(blk * bs, bs), slot, j).start()
+            return carry
+        jax.lax.fori_loop(0, live_pages(i), start, 0)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _clear():   # what no copy has written yet must be finite
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+
+    m_sc[...] = jnp.full(m_sc.shape, _NEG_BIG, m_sc.dtype)
+    l_sc[...] = jnp.zeros(l_sc.shape, l_sc.dtype)
+    acc_sc[...] = jnp.zeros(acc_sc.shape, acc_sc.dtype)
+    fetch(0, 0)
+
+    def step(i, masked):
+        slot = jax.lax.rem(i, 2)
+        fetch(i + 1, 1 - slot)      # no page past the last key: no copy
+
+        def wait(j, carry):
+            copy(pl.ds(0, bs), slot, j).wait()
+            return carry
+
+        jax.lax.fori_loop(0, live_pages(i), wait, 0)
+        rows = buf[slot]                                   # (rows, row)
+        if masked:
+            shape = (tm, step_rows)
+            keep = i * step_rows + jax.lax.broadcasted_iota(
+                jnp.int32, shape, 1) <= q_lo + div(
+                    jax.lax.broadcasted_iota(jnp.int32, shape, 0), per)
+        for g in range(groups):
+            q = q_ref[g]                                   # (tm, nope + tile)
+            at = rope_at + (g * rope // rope_tile) * rope_tile
+            s = (_dot_nt(q[:, :nope], rows[:, g * nope:(g + 1) * nope])
+                 + _dot_nt(q[:, nope:], rows[:, at:at + rope_tile])) * scale
+            if masked:
+                s = jnp.where(keep, s, _NEG_BIG)
+            m = m_sc[g][:, :1]
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            m_sc[g] = jnp.broadcast_to(m_new, m_sc.shape[1:])
+            l_sc[g] = jnp.broadcast_to(
+                l_sc[g][:, :1] * alpha + p.sum(axis=-1, keepdims=True),
+                l_sc.shape[1:])
+            acc_sc[g] = acc_sc[g] * alpha + _dot_nn(
+                p.astype(rows.dtype),
+                rows[:, v_at + g * vdim:v_at + (g + 1) * vdim])
+
+    def plain(i, carry):
+        step(i, False)
+        return carry
+
+    def masked(i, carry):
+        step(i, True)
+        return carry
+
+    jax.lax.fori_loop(0, n_plain, plain, 0)
+    jax.lax.fori_loop(n_plain, n_steps, masked, 0)
+    for g in range(groups):
+        o_ref[g] = (acc_sc[g] / jnp.maximum(l_sc[g][:, :1], 1e-30)).astype(
+            o_ref.dtype)
+
+
+def split_gqa_prefill_attention(q, pool, layer, table, pos0, n_keys, *,
+                                block_size: int, n_kv_heads: int, rope: int,
+                                v_dim: int, interpret: bool = False):
+    """Grouped-query attention of one sequence's prefill chunk whose keys
+    are wider than its values, read from the serving engine's KV pool where
+    it lies: `split_gqa_decode_attention`'s pool and rows, a chunk of
+    queries.
+
+    q (C, H, qk) - the chunk's queries, each head's first ``rope`` values
+    its rotated part (models/mimo_v2.py `qkv`); pool (L, slots, n_kv_heads *
+    (qk + v_dim)) - the whole pool in HBM, a row as `to_row` lays it out;
+    ``layer``, ``pos0`` (the chunk's first position) and ``n_keys`` (cache
+    positions 0..n_keys - 1 are live, the chunk's own rows among them)
+    scalars that may be traced; table (W,) int32. Query i sees key positions
+    <= pos0 + i; a query at or past ``n_keys`` (a bucket's dead tail) is
+    not a token and its output is not one. Returns o (C, H, v_dim) in q's
+    dtype. Scores are MXU products in the pool's dtype with float32
+    accumulation, scaled by 1/sqrt(qk) after it; the online softmax is
+    float32 and its probabilities weigh the values in the pool's dtype, as
+    models/mimo_v2.py `prefill_attention` does. A fetched page serves every
+    query head of its KV head for a whole block of query positions; a fetch
+    step wholly after a block's last position is neither fetched nor
+    scored, and a block wholly past ``n_keys`` fetches nothing
+    (`split_gqa_prefill_pairs` counts what is scored). Gate a compiled call
+    with `split_gqa_prefill_ok`.
+
+    Around the one Mosaic call the queries are laid out as the kernel reads
+    them - a KV head's rows the chunk's positions, each with its query
+    heads, the unrotated part in the first lanes and the rotated part in its
+    head's lanes of the rotated keys' tile - and the output is laid back:
+    plain XLA on (C, H, qk) values."""
+    c, h, qk = q.shape
+    nope = qk - rope
+    row = n_kv_heads * (qk + v_dim)
+    if pool.ndim != 3 or pool.shape[2] != row or h % n_kv_heads:
+        raise ValueError(
+            f"pool {pool.shape} does not hold keys of {qk} and values of "
+            f"{v_dim} for {n_kv_heads} KV heads under q's {h} heads")
+    if table.ndim != 1:
+        raise ValueError(f"table {table.shape}: one sequence's block ids")
+    per = h // n_kv_heads
+    if not interpret and not split_gqa_prefill_ok(
+            block_size, n_kv_heads, per, qk, rope, v_dim, pool.dtype):
+        raise ValueError(
+            f"split_gqa_prefill_attention: pages of {block_size} "
+            f"{pool.dtype} rows of {n_kv_heads} x ({qk} + {v_dim}) with {per} "
+            "queries a KV head are no tiles this kernel compiles for "
+            "(split_gqa_prefill_ok)")
+    tq, pps = _split_prefill_tiles(c, per, table.shape[0], block_size)
+    if c % tq:
+        raise ValueError(
+            f"a chunk of {c} is no whole number of blocks of {tq} queries")
+    tm = tq * per
+    tile = min(_LANES, n_kv_heads * rope)
+    qa = _split_query_lanes(q.reshape(c, n_kv_heads, per, qk), rope, tile,
+                            per).transpose(1, 0, 2, 3).reshape(
+        n_kv_heads, c * per, nope + tile)
+    o = pl.pallas_call(
+        functools.partial(_split_gqa_prefill_kernel, bs=block_size, pps=pps,
+                          per=per, nope=nope, rope=rope, rope_tile=tile,
+                          vdim=v_dim, scale=1.0 / float(qk) ** 0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(c // tq,),
+            in_specs=[
+                pl.BlockSpec((n_kv_heads, tm, nope + tile),
+                             lambda i, *_: (0, i, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((n_kv_heads, tm, v_dim),
+                                   lambda i, *_: (0, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, pps * block_size, row), pool.dtype),
+                pltpu.VMEM((n_kv_heads, tm, _LANES), jnp.float32),
+                pltpu.VMEM((n_kv_heads, tm, _LANES), jnp.float32),
+                pltpu.VMEM((n_kv_heads, tm, v_dim), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=_struct((n_kv_heads, c * per, v_dim), q.dtype, q, pool),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_MLA_VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name="split_gqa_prefill_attn",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1), table.astype(jnp.int32),
+        jnp.stack([jnp.asarray(pos0, jnp.int32),
+                   jnp.asarray(n_keys, jnp.int32)]),
+        qa, pool,
+    )
+    return o.reshape(n_kv_heads, c, per, v_dim).transpose(1, 0, 2, 3).reshape(
+        c, h, v_dim)
+
+
+def split_gqa_prefill_ok(block_size: int, n_kv_heads: int, per_kv: int,
+                         qk: int, rope: int, v_dim: int, dtype) -> bool:
+    """True where `split_gqa_prefill_attention` compiles: the decode
+    kernel's pages and lane slices (`split_gqa_decode_ok`: it reads the same
+    rows), whatever the chunk (a block of query rows is a chunk's whole
+    rows, or 1,024 of them; tests/test_tpu_aot_compile.py compiles the
+    served chunks for a described v5e)."""
+    return split_gqa_decode_ok(block_size, n_kv_heads, per_kv, qk, rope,
+                               v_dim, dtype)
+
+
+def split_gqa_prefill_pairs(pos0: int, n: int, chunk: int, per_kv: int,
+                            width: int, block_size: int) -> int:
+    """The (query, key) pairs one `split_gqa_prefill_attention` call scores
+    for ``n`` tokens from ``pos0`` in a chunk bucket of ``chunk`` over a
+    table of ``width`` blocks: every block of query positions that holds a
+    token, whole, against the whole fetch steps it walks (host integers)."""
+    tq, pps = _split_prefill_tiles(chunk, per_kv, width, block_size)
+    step = pps * block_size
+    return sum(tq * -(-min(pos0 + lo + tq, pos0 + n) // step) * step
+               for lo in range(0, n, tq))

@@ -99,8 +99,9 @@ rows. Both pools are donated to every program and rebound from its outputs.
 The two program families (`_hybrid_decode`, `_hybrid_prefill`) have one
 table width and walk the model's layers in Python, in the published order
 (`_hybrid_layers`). A rows kind's step is the engine's: the module's
-``<kind>_in``, the row written, the module's kernel or `jax.numpy` oracle
-over the pool, ``<kind>_out``. A state kind's step is the module's whole
+``<kind>_in``, the row written, the module's kernel (in decode; in prefill
+where it declares one) or `jax.numpy` oracle over the pool,
+``<kind>_out``. A state kind's step is the module's whole
 (``<kind>_decode``, ``<kind>_prefill``), over its slot of the state pool:
 whether what a slot holds is the sequence's own - noughts before position 0,
 ring rows whose position is not its own - is decided in the program from
@@ -109,7 +110,7 @@ replays from the tokens, which rebuilds the state: there is no snapshot.
 
 **What kind of engine this is** is asked of the module once, in the
 constructor (`_Cache`): the pools, the program families, the table width,
-the kernel's gate and what the module refuses. Nothing below asks again.
+the kernels' gates and what the module refuses. Nothing below asks again.
 """
 
 from __future__ import annotations
@@ -786,6 +787,9 @@ class _Cache:
     # whether a prefill attention kernel compiles (None: the module brings
     # none, its chunked prefill is `jax.numpy`)
     prefill_kernel_ok: bool | None = None
+    # the (query, key) pairs a layer's prefill kernel scores for a chunk, of
+    # a hybrid module that declares one (`prefill_kernel_scored`)
+    prefill_scored: object = None
     # (operator kind, "rows" or "state") of a module with two kinds of
     # state, in the order of its `POOLS`: which pool each kind keeps
     kinds: tuple = ()
@@ -962,12 +966,17 @@ class ServeEngine:
                 (shapes["state"][0], self.kv.cfg.state_slots)
                 + shapes["state"][1:], pool_dt)
             kernel_ok, refusal = mod.kernel_gate(cfg, bs, pool_dt)
+            prefill_gate = getattr(mod, "prefill_kernel_gate", None)
             self._cache = _Cache(
                 pools=("k_pool", "state_pool"),
                 labels=("kv_pool", "state_pool"), width=one_width,
                 decode=self._hybrid_decode, prefill=self._hybrid_prefill,
                 routed=True, state=True, kernel_ok=kernel_ok,
-                kernel_refusal=refusal, kinds=tuple(mod.POOLS.items()),
+                kernel_refusal=refusal,
+                prefill_kernel_ok=(None if prefill_gate is None
+                                   else prefill_gate(cfg, bs, pool_dt)),
+                prefill_scored=getattr(mod, "prefill_kernel_scored", None),
+                kinds=tuple(mod.POOLS.items()),
                 attn_pairs=getattr(mod, "attn_pairs", None))
         else:
             L, H, Dh = cfg.n_layers, cfg.n_heads, cfg.head_dim
@@ -1163,11 +1172,13 @@ class ServeEngine:
     decode_route = _attn_route
 
     def _prefill_route(self) -> str:
-        """A latent module's chunked-prefill attention: the Mosaic kernel
-        that expands a fetch step's latent rows a head at a time in VMEM
-        (ops/decode_pallas.py `mla_prefill_attention`) where `decode_impl`
-        asks for kernels and it compiles, the blocked `jax.numpy` loop
-        (the module's `prefill_attention`, the oracle) otherwise."""
+        """A module's chunked-prefill attention over its paged pool: a
+        Mosaic kernel (a latent module's `mla_prefill_attention`, which
+        expands a fetch step's latent rows a head at a time in VMEM; the
+        `prefill_kernel` a hybrid module declares with its gate) where
+        `decode_impl` asks for kernels and it compiles, the blocked
+        `jax.numpy` loop (the module's `prefill_attention`, the oracle)
+        otherwise, and always for a module that declares no kernel."""
         impl = self.ecfg.decode_impl
         if impl == "xla" or self._cache.prefill_kernel_ok is None:
             return "xla"
@@ -1498,6 +1509,7 @@ class ServeEngine:
         bs = self.kv.cfg.block_size
         key_block = min(_PREFILL_KEY_BLOCK, W * bs)
         pages = key_block // bs
+        use_kernel = self._prefill_route() == "pallas"
 
         def prefill(params, kv_pool, state_pool, toks, pos0, table, slot,
                     n_valid):
@@ -1529,11 +1541,17 @@ class ServeEngine:
 
                 def one(x, lp, i, pools):
                     # write the chunk's rows, then attend over the table's
-                    # live span, the rows just written included, a key block
-                    # at a time
+                    # live span, the rows just written included: the
+                    # module's kernel over the pool where it lies, or a key
+                    # block at a time
                     kv_pool, state_pool = pools
                     q, rows = into(x, lp, cfg, pv)
                     kv_pool = _write_rows(kv_pool, i, flat, rows)
+                    if use_kernel:
+                        o = mod.prefill_kernel(
+                            q, kv_pool, i, table, pos0, pos0 + n_valid, cfg,
+                            block_size=bs, interpret=not on_tpu())
+                        return out(x, o, lp, cfg), (kv_pool, state_pool)
 
                     def read_rows(j):
                         blk = jax.lax.dynamic_slice_in_dim(
@@ -2196,19 +2214,27 @@ class ServeEngine:
                         jnp.int32(n))
                 out = self._call("prefill", fn, tail, stats)
                 part.to("select")
+                kernel = self._prefill_route() == "pallas"
+                table_width = W
                 if self._cache.width:
                     # its width: the key blocks the program walks
                     kb = min(_PREFILL_KEY_BLOCK, W * bs)
                     W = -(-(seq.pos + n) // kb) * kb // bs
                 if self._cache.attn_pairs is not None:
+                    # what a layer with paged rows scores: the kernel's
+                    # blocks of query positions against their fetch steps,
+                    # or the chunk against the key blocks walked
+                    scored = (self._cache.prefill_scored(
+                        self.cfg, seq.pos, n, C, block_size=bs,
+                        width=table_width) if kernel else C * W * bs)
                     pairs = self._cache.attn_pairs(
-                        self.cfg, seq.pos, n, C, W * bs)
+                        self.cfg, seq.pos, n, C, scored)
                     stats["attn_pairs"] = {
                         k: v + stats.get("attn_pairs", {}).get(k, 0)
                         for k, v in pairs.items()}
                 if self._cache.routed:
                     tick.counts.append(out[0])
-                    if self._prefill_route() == "pallas":
+                    if kernel:
                         stats["prefill_kernel_pairs"] = live + stats.get(
                             "prefill_kernel_pairs", 0)
                 stats["prefill_calls"].append((C, W, live))

@@ -896,8 +896,8 @@ class ServeScheduler:
             ).inc()
             self._m_prefill_live.inc(live)
             self._m_prefill_padded.inc(C * W * bs)
-        # (only a latent module's prefill programs call a Mosaic attention
-        # kernel: other ticks do not bring the key)
+        # (only the prefill programs of a module with a prefill attention
+        # kernel call one: other ticks do not bring the key)
         self._m_kernel_prefill.inc(stats.get("prefill_kernel_pairs", 0))
         for (layers, kind), n in stats.get("attn_pairs", {}).items():
             self._m_attn_pairs.labels(layers=layers, kind=kind).inc(n)

@@ -27,10 +27,13 @@ import pytest
 
 from conftest import BENCH, ROOT
 from distributed_neural_network_tpu.models import mimo_v2 as mm
+import distributed_neural_network_tpu.ops.decode_pallas as dp
 from distributed_neural_network_tpu.ops.decode_pallas import (
     paged_read_positions,
     split_gqa_decode_attention,
     split_gqa_decode_ok,
+    split_gqa_prefill_ok,
+    split_gqa_prefill_pairs,
 )
 from distributed_neural_network_tpu.parallel.moe import moe_held_gated_serve
 from distributed_neural_network_tpu.serve.engine import (
@@ -206,7 +209,7 @@ def test_window_steps_over_the_ring_are_the_band(params):
 
 
 def test_attn_pairs_counts_what_the_masks_keep():
-    pairs = mm.attn_pairs(CFG, 10, 5, 8, 64)
+    pairs = mm.attn_pairs(CFG, 10, 5, 8, 8 * 64)
     assert pairs[("full", "live")] == 2 * (5 * 10 + 15)
     assert pairs[("full", "scored")] == 2 * 8 * 64
     assert pairs[("window", "live")] == 3 * 5 * 8     # each keeps 8
@@ -330,6 +333,95 @@ def test_kernel_gate():
     assert not split_gqa_decode_ok(64, 4, 16, 192, 64, 128, jnp.int8)
 
 
+# the prefill kernel's tiles at a test's sizes: fetch steps of two pages of
+# 8 and blocks of 4 query positions a KV head's 4 query heads, so that a
+# chunk of 16 is four blocks and a prefix of 45 three steps
+PREFILL_TILES = {"_SPLIT_PREFILL_KEYS": 16, "_SPLIT_PREFILL_ROWS": 16}
+_prefill_calls = {}
+
+
+def _prefill_kernel(dtype, chunk):
+    """The kernel at 4 KV heads of 4 queries, keys of 80 (16 unrotated, 64
+    rotated: two heads' rotated parts a 128-lane tile, as at the served
+    widths) and values of 16, jitted once a chunk and type (`pos0` and
+    `n_keys` traced)."""
+    key = (dtype, chunk)
+    if key not in _prefill_calls:
+        _prefill_calls[key] = jax.jit(
+            lambda q, pool, table, pos0, n_keys:
+            dp.split_gqa_prefill_attention(
+                q, pool, 1, table, pos0, n_keys, block_size=8, n_kv_heads=4,
+                rope=64, v_dim=16, interpret=True))
+    return _prefill_calls[key]
+
+
+@pytest.mark.parametrize("dtype,tol,chunk,pos0,n_valid,scored", [
+    (jnp.float32, 1e-5, 1, 0, 1, 16),      # a sequence's first token
+    (jnp.float32, 1e-5, 16, 0, 16, 4 * 16 * 4),   # a chunk against itself
+    (jnp.bfloat16, 2e-2, 16, 0, 16, 4 * 16 * 4),    # two bfloat16 steps
+    (jnp.float32, 1e-5, 16, 13, 11, 3 * 4 * 32),   # mid-page, dead tail
+    (jnp.float32, 1e-5, 16, 45, 16, 4 * 4 * 64),    # past 3 steps
+    (jnp.float32, 1e-5, 1, 45, 1, 48)])
+def test_prefill_kernel_interpreted_matches_the_blocked_oracle(
+        monkeypatch, dtype, tol, chunk, pos0, n_valid, scored):
+    """`split_gqa_prefill_attention` against `prefill_attention` (the
+    engine's `xla` route, blocked over keys of 16) at a row laid out as the
+    served one, a layer of two, through a scrambled table: a chunk of one
+    and a whole chunk, from position 0, from inside a page, past several
+    fetch steps, a bucket's dead tail (its rows are not compared). Pages
+    wholly past the last key, and blocks not in the table, hold NaN: a
+    fetch of one would carry it into the output. `scored` is what the
+    kernel scores, counted by hand: every block of 4 query positions that
+    holds a token against the whole steps of 16 keys up to its last
+    position (`split_gqa_prefill_pairs`). bfloat16: the probabilities are
+    rounded to the pool's type before they weigh the values, as the oracle
+    rounds them, and the output to bfloat16."""
+    for name, value in PREFILL_TILES.items():
+        monkeypatch.setattr(dp, name, value)
+    bs, n_keys = 8, pos0 + n_valid
+    rng = np.random.default_rng(pos0 + chunk)
+    pool = rng.normal(size=(2, 40 * bs, 4 * (80 + 16))).astype(np.float32)
+    table = (1 + rng.permutation(39)[:16]).astype(np.int32)
+    poisoned = np.full_like(pool, np.nan)
+    for b in table[:-(-n_keys // bs)]:
+        poisoned[:, b * bs:(b + 1) * bs] = pool[:, b * bs:(b + 1) * bs]
+    q = jnp.asarray(rng.normal(size=(chunk, 16, 80)), dtype)
+    o = _prefill_kernel(dtype, chunk)(
+        q, jnp.asarray(poisoned, dtype), jnp.asarray(table), jnp.int32(pos0),
+        jnp.int32(n_keys))
+    cfg = mm.MiMoV2Config(d_model=64, n_heads=16, qk_head=80, v_head=16,
+                          rope_dim=64, n_kv_full=4, n_kv_window=4,
+                          dtype=dtype)
+    rows = jnp.asarray(pool[1], dtype)
+
+    def read_rows(j):
+        blk = jax.lax.dynamic_slice_in_dim(jnp.asarray(table), 2 * j, 2)
+        return rows[(blk[:, None] * bs + jnp.arange(bs)).reshape(-1)]
+    ref = mm.prefill_attention(q, pos0 + jnp.arange(chunk), read_rows,
+                               n_keys, cfg, key_block=16)
+    assert o.dtype == dtype and o.shape == (chunk, 16, 16)
+    assert np.isfinite(np.asarray(o, np.float32)).all()
+    assert np.abs(np.asarray(o, np.float32)[:n_valid] - np.asarray(
+        ref, np.float32)[:n_valid]).max() < tol
+    assert split_gqa_prefill_pairs(pos0, n_valid, chunk, 4, 16, bs) == scored
+    assert mm.prefill_kernel_scored(cfg, pos0, n_valid, chunk, block_size=bs,
+                                    width=16) == scored
+
+
+def test_prefill_kernel_gate():
+    """It reads the rows the decode kernel reads, so it compiles where that
+    one does, whatever the chunk."""
+    for args in [(64, 4, 16, 192, 64, 128, jnp.bfloat16),
+                 (8, 4, 16, 192, 64, 128, jnp.bfloat16),
+                 (64, 1, 4, 24, 8, 16, jnp.float32),
+                 (64, 4, 16, 192, 64, 128, jnp.int8)]:
+        assert split_gqa_prefill_ok(*args) == split_gqa_decode_ok(*args)
+    assert mm.prefill_kernel_gate(CFG, 8, jnp.float32) is False
+    with open(CONFIG_FILE) as f:
+        served = FAMILY.program.config(json.load(f), {}, jnp.bfloat16)
+    assert mm.prefill_kernel_gate(served, 64, jnp.bfloat16) is True
+
+
 # ------------------------------------------------------------- the engine
 
 def _engine(params, **kw):
@@ -436,6 +528,75 @@ def test_prefill_then_decode_through_both_pools_matches_the_reference(
     assert eng.kv.state_slots_in_use == 0 and eng.kv.blocks_in_use == 0
 
 
+def test_prefill_kernel_serves_the_blocked_loops_tokens_and_counts_its_pairs(
+        params, monkeypatch):
+    """The engine's chunked prefill on the interpreted kernel (`decode_impl`
+    "pallas": the module declares it, `_Cache.prefill_kernel_ok`) against
+    the same engine on the `xla` route (the blocked loop, held to the
+    reference above), fetch steps cut to two pages so that a prompt of 40
+    spans three of them: the same greedy tokens from logits within `TOL`;
+    on the kernel
+    `serve_attn_kernel_pairs_total{path="prefill"}` grows by the chunks'
+    live pairs (on the blocked loop not at all), and the full layers'
+    scored pairs are the kernel's walk, `prefill_kernel_scored`, where the
+    blocked loop's are the chunk against its key blocks of 64."""
+    for name, value in PREFILL_TILES.items():
+        monkeypatch.setattr(dp, name, value)
+    count, calls = mm.attn_pairs, []
+
+    def counting(cfg, pos0, n, chunk, scored):
+        calls.append((pos0, n, chunk, scored))
+        return count(cfg, pos0, n, chunk, scored)
+    monkeypatch.setattr(mm, "attn_pairs", counting)
+    out, seen, published = {}, {}, {}
+    for impl in ("xla", "pallas"):
+        calls.clear()
+        eng = _engine(params, decode_impl=impl)
+        assert eng._prefill_route() == impl
+        registry = MetricsRegistry()
+        scheduler = ServeScheduler(eng, SchedulerConfig(), registry=registry)
+        step = eng.step
+
+        def publishing():
+            stats = step()
+            scheduler._publish_tick(stats["phase_s"], stats)
+            return stats
+        eng.step = publishing
+        seqs = _mixed([(40, 3)], seed=50)
+        try:
+            seen[impl] = _drive(eng, seqs)
+        finally:
+            scheduler.close()
+        out[impl] = [s.out for s in seqs]
+        published[impl] = {
+            line.rsplit(" ", 1)[0]: float(line.rsplit(" ", 1)[1])
+            for line in registry.render().splitlines()
+            if line.startswith("serve_attn_")}
+        live = sum(n * p0 + n * (n + 1) // 2 for p0, n, _, _ in calls)
+        kernel = published[impl][
+            'serve_attn_kernel_pairs_total{path="prefill"}']
+        scored = published[impl][
+            'serve_attn_pairs_total{kind="scored",layers="full"}']
+        assert [(p0, n) for p0, n, _, _ in calls] == [(0, 16), (16, 16),
+                                                      (32, 7)]
+        if impl == "xla":
+            assert kernel == 0
+            assert all(s == c * 64 for _, _, c, s in calls)
+        else:
+            assert kernel == live
+            assert all(s == mm.prefill_kernel_scored(
+                CFG, p0, n, c, block_size=8, width=8)
+                for p0, n, c, s in calls)
+            # the last chunk's two blocks of 4 queries, 32-35 and 36-38,
+            # against three steps of 16 keys
+            assert calls[2] == (32, 7, 8, 4 * 48 + 4 * 48)
+        assert scored == CFG.n_full * sum(s for _, _, _, s in calls)
+    assert out["xla"] == out["pallas"] and len(out["xla"][0]) == 3
+    assert seen["xla"].keys() == seen["pallas"].keys()
+    assert max(np.abs(seen["xla"][k] - seen["pallas"][k]).max()
+               for k in seen["xla"]) < TOL
+
+
 def test_a_reused_slot_is_masked_by_position_in_the_program(params):
     """The rings are left full of large values, as a last owner might have
     left them: every sequence's programs keep only the rows its own
@@ -538,9 +699,9 @@ def test_the_tick_publishes_its_counters(params, monkeypatch):
     calls = []
     count = mm.attn_pairs
 
-    def counting(cfg, pos0, n, chunk, keys):
-        calls.append((pos0, n, chunk, keys))
-        return count(cfg, pos0, n, chunk, keys)
+    def counting(cfg, pos0, n, chunk, scored):
+        calls.append((pos0, n, chunk, scored))
+        return count(cfg, pos0, n, chunk, scored)
     monkeypatch.setattr(mm, "attn_pairs", counting)
     eng = _engine(params, decode_impl="pallas")
     n = eng.warmup()
@@ -572,12 +733,14 @@ def test_the_tick_publishes_its_counters(params, monkeypatch):
     tokens = sum(s.prompt_len - 1 + len(s.out) for s in seqs)
     assert pairs == tokens * CFG.top_k * CFG.n_moe and 0 < held < pairs
     assert peak_slots == 3 and read >= live > 0
-    # each prefill program counted once, in its chunk bucket, against the
-    # one table width's 64 keys: 19 + 19 + 11 prompt tokens in chunks of up
-    # to 16 a tick
+    # each prefill program counted once, in its chunk bucket, at what the
+    # prefill kernel walks over the one table width of 8 blocks: 19 + 19 +
+    # 11 prompt tokens in chunks of up to 16 a tick
     assert sum(m for _, m, _, _ in calls) == 19 + 19 + 11
-    assert all(c == 1 << (m - 1).bit_length() and k == 64
-               for _, m, c, k in calls)
+    assert all(c == 1 << (m - 1).bit_length()
+               and k == mm.prefill_kernel_scored(CFG, p0, m, c, block_size=8,
+                                                 width=8)
+               for p0, m, c, k in calls)
     want = {}
     for call in calls:
         for k, v in count(CFG, *call).items():

@@ -33,6 +33,8 @@ from distributed_neural_network_tpu.ops.decode_pallas import (
     paged_decode_ok,
     split_gqa_decode_attention,
     split_gqa_decode_ok,
+    split_gqa_prefill_attention,
+    split_gqa_prefill_ok,
 )
 from distributed_neural_network_tpu.ops.flash import tuned_blocks
 from distributed_neural_network_tpu.ops.flash_pallas import flash_mha
@@ -181,6 +183,25 @@ def _split_gqa_decode(topo, batch, width, *, dtype=jnp.bfloat16):
     ])
 
 
+def _split_gqa_prefill(topo, chunk):
+    # the mixed-32k cell's full layers' prefill attention: a chunk's 64
+    # heads over the pool of `_split_gqa_decode`
+    bs, kv, per, qk, rope, v = 64, 4, 16, 192, 64, 128
+    dtype, i32 = jnp.bfloat16, jnp.int32
+    assert split_gqa_prefill_ok(bs, kv, per, qk, rope, v, dtype)
+
+    def fn(q, pool, layer, table, span):
+        return split_gqa_prefill_attention(
+            q, pool, layer[0], table, span[0], span[1], block_size=bs,
+            n_kv_heads=kv, rope=rope, v_dim=v)
+
+    return fn, _on_one_chip(topo, [
+        ((chunk, kv * per, qk), dtype),
+        ((2, 12289 * bs, kv * (qk + v)), dtype),
+        ((1,), i32), ((1024,), i32), ((2,), i32),
+    ])
+
+
 def _mla_prefill(topo, chunk):
     # the docqa cell's prefill attention: a chunk's 128 heads over the
     # latent pool, a layer's `kv_b` as the tree holds it
@@ -270,6 +291,8 @@ CASES = {
         t, 1, 1024),
     "split_gqa_decode_f32_b4_w4": lambda t: _split_gqa_decode(
         t, 4, 4, dtype=jnp.float32),
+    "split_gqa_prefill_bf16_c512": lambda t: _split_gqa_prefill(t, 512),
+    "split_gqa_prefill_bf16_c1": lambda t: _split_gqa_prefill(t, 1),
     "decode_paged_bf16_b16_w128": lambda t: _decode_paged(t, 16, 128),
     "decode_paged_bf16_b1_w1": lambda t: _decode_paged(t, 1, 1),
     # the serve smoke (chip_smoke.py: d512 / 4 heads of 128, 129 blocks)
@@ -479,10 +502,11 @@ def test_mimo_serve_programs_compile_on_v5e(topo, monkeypatch, family, n):
     """The served programs of `mimo-v2.5.serve-mixed-32k` at its widths and
     pools (7 layers: 2 full, 5 window; 16 held experts; 12,289 blocks of 64;
     the rings of 48 sequences), `decode_impl="auto"` on the described chip:
-    the decode program's only Mosaic calls are the full layers' kernel, one
-    a full layer, and the prefill program has none (its attention is plain
-    XLA); both pools are aliased to the outputs and what a program holds
-    beyond them stays under a gigabyte. The weights are shapes (nothing
+    each program's only Mosaic calls are the full layers' kernel, one a full
+    layer (decode `split_gqa_decode_attn`, prefill `split_gqa_prefill_attn`;
+    the window layers' attention is plain XLA); both pools are aliased to
+    the outputs and what a program holds beyond them stays under a
+    gigabyte. The weights are shapes (nothing
     runs, so the engine is built around a placeholder tree with a small
     pool of its own), and the engine asks the runtime whether it is on a
     TPU: here the test answers for it."""
@@ -502,7 +526,7 @@ def test_mimo_serve_programs_compile_on_v5e(topo, monkeypatch, family, n):
             max_batch=2, num_blocks=65, block_size=64, max_seq_len=34304,
             prefill_chunk=512, decode_impl="auto"),
     )
-    assert eng.decode_route() == "pallas"
+    assert eng.decode_route() == eng._prefill_route() == "pallas"
     one_chip = SingleDeviceSharding(topo.devices[0])
     params = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(
@@ -520,8 +544,7 @@ def test_mimo_serve_programs_compile_on_v5e(topo, monkeypatch, family, n):
         n, width)
     compiled = fn.lower(params, *_on_one_chip(topo, [kv, rings]),
                         *tail).compile()
-    assert mosaic_custom_calls(compiled) == (
-        cfg.n_full if family == "decode" else 0)
+    assert mosaic_custom_calls(compiled) == cfg.n_full
     mem = compiled.memory_analysis()
     pools = sum(math.prod(s) * 2 for s, _ in (kv, rings))
     held = mem.peak_memory_in_bytes - (
@@ -529,3 +552,50 @@ def test_mimo_serve_programs_compile_on_v5e(topo, monkeypatch, family, n):
         - mem.alias_size_in_bytes)
     assert max(held, mem.temp_size_in_bytes) < 1 << 30
     assert mem.alias_size_in_bytes >= pools
+
+
+def test_lfm2_prefill_program_takes_no_kernel_on_v5e(topo, monkeypatch):
+    """LFM2's module declares no prefill kernel: on the described chip,
+    `decode_impl="auto"` lowers its hybrid prefill program with no Mosaic
+    call, to the text of the `xla` route, while its decode program takes
+    its kernel. The weights are shapes; the engine asks the runtime whether
+    it is on a TPU, and the test answers for it."""
+    import sys
+
+    from distributed_neural_network_tpu.serve import engine as engine_mod
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "benchmark"))
+    try:
+        from lib import harness
+
+        family = harness.load_family("lfm2_moe", "serve")
+        model = harness.load_json("families", "lfm2_moe", "tiny.json")
+    finally:
+        sys.path.remove(os.path.join(root, "benchmark"))
+    cfg = family.program.config(model, {}, jnp.bfloat16)
+    monkeypatch.setattr(engine_mod, "on_tpu", lambda: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    text = {}
+    for impl in ("auto", "xla"):
+        eng = engine_mod.ServeEngine(
+            {"placeholder": jnp.zeros(())}, cfg, engine_mod.EngineConfig(
+                max_batch=2, num_blocks=64, block_size=16, max_seq_len=256,
+                prefill_chunk=16, decode_impl=impl))
+        assert eng._cache.prefill_kernel_ok is None
+        assert eng._prefill_route() == "xla"
+        params = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, jnp.float32 if x.ndim < 2 else cfg.dtype,
+                sharding=one_chip),
+            jax.eval_shape(lambda: cfg.module.init_params(
+                jax.random.key(0), cfg)))
+        pools = [jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip)
+                 for p in eng._pools()]
+        width = eng._bucket_widths()[0]
+        tail = [jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)
+                for t in eng.bucket_tail("prefill", 16, width)]
+        text[impl] = eng._prefill_fn(16, width).lower(
+            params, *pools, *tail).as_text()
+    assert "tpu_custom_call" not in text["auto"]
+    assert text["auto"] == text["xla"]
